@@ -15,15 +15,10 @@ On a TPU slice the same script rides ICI; add --flash for the pallas
 flash kernel per ring block (interpreter-mode on CPU: slow but exact).
 """
 
-import os
 import sys
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
-
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
 
 import argparse
 

@@ -13,15 +13,10 @@ Run (single device or dp):
 Add --flash for the fused pallas kernel (interpreter-mode on CPU).
 """
 
-import os
 import sys
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
-
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
 
 import argparse
 import dataclasses

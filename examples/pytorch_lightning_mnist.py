@@ -9,15 +9,10 @@ data.
 Run:  python examples/pytorch_lightning_mnist.py --epochs 4
 """
 
-import os
 import sys
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
-
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
 
 import argparse
 

@@ -6,15 +6,10 @@ becomes ``import horovod_tpu.torch as hvd``. Synthetic MNIST-shaped data.
 Run:  python examples/pytorch_mnist.py --steps 60
 """
 
-import os
 import sys
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
-
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
 
 import argparse
 

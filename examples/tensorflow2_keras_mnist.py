@@ -7,15 +7,10 @@ horovod_tpu.tensorflow.keras as hvd``. Synthetic MNIST-shaped data.
 Run:  python examples/tensorflow2_keras_mnist.py --epochs 3
 """
 
-import os
 import sys
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
-
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
 
 import argparse
 

@@ -8,15 +8,10 @@ dataset downloads in this image).
 Run:  python examples/tensorflow2_mnist.py --steps 60
 """
 
-import os
 import sys
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
-
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
 
 import argparse
 

@@ -10,15 +10,10 @@ Run (virtual 8-device CPU mesh):
         python examples/uneven_data_join.py
 """
 
-import os
 import sys
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
-
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
 
 import argparse
 

@@ -35,8 +35,8 @@ from horovod_tpu.compression import Compression  # noqa: F401
 # ``hvd.metrics.start_metrics_flusher()``, ...
 from horovod_tpu import metrics  # noqa: F401
 # Overlapped gradient sync: algorithm selection (auto|psum|rs_ag|
-# chunked_rs_ag), chunked RS+AG pipelines, backward taps, latency-hiding
-# scheduler wiring (docs/PERFORMANCE.md).
+# chunked_rs_ag), chunked RS+AG pipelines, backward taps
+# (docs/PERFORMANCE.md).
 from horovod_tpu import overlap  # noqa: F401
 # Continuous-batching inference: hvd.serving.InferenceEngine (paged KV
 # cache, request scheduler, multi-replica dispatch — docs/SERVING.md).

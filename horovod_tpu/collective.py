@@ -1008,8 +1008,7 @@ def _eager_run_inner(kind, tree, params, param_key, negotiate_key,
             out = _INTRACE[kind](t, *params)
             return tuple(o[None] for o in jax.tree_util.tree_leaves(out))
 
-        from horovod_tpu.utils.compat import shard_map as _shard_map
-        smapped = _shard_map(
+        smapped = jax.shard_map(
             body, mesh=m,
             in_specs=tuple(P(axis) for _ in leaves),
             out_specs=P(axis))
@@ -1854,10 +1853,7 @@ def broadcast_object(obj, root_rank: int = 0, name: Optional[str] = None):
         if source:
             buf[:] = payload
         out = mhu.broadcast_one_to_all(buf, is_source=source)
-        # jax 0.4.x broadcast_one_to_all returns sub-32-bit payloads
-        # UPCAST (uint8 -> uint32, values preserved); cast back before
-        # reading raw bytes or every 4th byte of the pickle is real.
-        return pickle.loads(np.asarray(out).astype(np.uint8).tobytes())
+        return pickle.loads(np.asarray(out).tobytes())
     return obj
 
 

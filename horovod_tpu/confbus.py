@@ -252,7 +252,6 @@ _add("HOROVOD_SERVE_AUTH_TOKEN", "serve_auth_token", secret=True,
 # Everything else config.refresh() resolves: registered (the drift test
 # and GET /config see the full surface) but immutable via the bus.
 _IMMUTABLE_FIELDS: Dict[str, str] = {
-    "HOROVOD_XLA_LATENCY_HIDING": "xla_latency_hiding",
     "HOROVOD_TIMELINE": "timeline_path",
     "HOROVOD_TIMELINE_MARK_CYCLES": "timeline_mark_cycles",
     "HOROVOD_TRACE_JAX_PROFILER": "trace_jax_profiler",

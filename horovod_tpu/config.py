@@ -51,14 +51,10 @@ class Config:
     # {fp32, bf16, int8, fp8} sets the default wire precision (auto
     # resolution upgrades its rs_ag picks to the quantized variants,
     # bf16 casts the payload around the collective);
-    # HOROVOD_OVERLAP_CHUNKS is the pipeline depth of chunked_rs_ag;
-    # HOROVOD_XLA_LATENCY_HIDING=1 wires the XLA latency-hiding-scheduler
-    # flags at init so async collectives overlap compute (TPU only; must
-    # be set before the backend initializes).
+    # HOROVOD_OVERLAP_CHUNKS is the pipeline depth of chunked_rs_ag.
     allreduce_algorithm: str = "auto"
     allreduce_wire: str = "fp32"
     overlap_chunks: int = 4
-    xla_latency_hiding: bool = False
     # Topology override (parallel/mesh.py detect_topology):
     # HOROVOD_TOPOLOGY="XxY" factors the world into a simulated torus on
     # CPU/tests (on TPU the dims come from device coords and this is
@@ -549,7 +545,6 @@ def refresh() -> Config:
         allreduce_algorithm=_env_algorithm(),
         allreduce_wire=_env_wire(),
         overlap_chunks=_env_chunks(),
-        xla_latency_hiding=_env_bool("HOROVOD_XLA_LATENCY_HIDING"),
         topology=_env_topology(),
         mesh=_env_mesh(),
         mp_rules=_env_mp_rules(),

@@ -98,14 +98,6 @@ def _ctx() -> _Context:
     return _CTX
 
 
-def _distributed_initialized() -> bool:
-    try:
-        return jax.distributed.is_initialized()
-    except AttributeError:
-        from jax._src import distributed
-        return distributed.global_state.client is not None
-
-
 def init(devices: Optional[Sequence] = None, axis_name: str = AXIS_NAME,
          coordinator_address: Optional[str] = None,
          num_processes: Optional[int] = None,
@@ -146,25 +138,13 @@ def init(devices: Optional[Sequence] = None, axis_name: str = AXIS_NAME,
     with _LOCK:
         # Upstream reads its HOROVOD_* knob surface once at horovod_init;
         # same contract here (config.py documents the TPU-inert ones).
-        # Read BEFORE anything touches a jax backend: the latency-hiding
-        # scheduler rides XLA_FLAGS, which are consumed at backend
-        # creation — after jax.devices() below it would be too late.
         from horovod_tpu import config as _config
         cfg = _config.refresh()
-        lhs_applied = False
-        if cfg.xla_latency_hiding:
-            from horovod_tpu import overlap as _overlap
-            lhs_applied = _overlap.enable_latency_hiding()
         if coordinator_address is not None or (
                 num_processes is not None and num_processes > 1):
             # init() must stay reentrant (elastic re-init, shutdown/init
             # cycles); jax.distributed may only be initialized once.
-            if not _distributed_initialized():
-                # Multi-process CPU (tests, local launchers): cross-process
-                # computations need the gloo collectives backend selected
-                # before the CPU client exists (no-op elsewhere).
-                from horovod_tpu.utils.compat import enable_cpu_collectives
-                enable_cpu_collectives()
+            if not jax.distributed.is_initialized():
                 jax.distributed.initialize(
                     coordinator_address=coordinator_address,
                     num_processes=num_processes,
@@ -221,7 +201,7 @@ def init(devices: Optional[Sequence] = None, axis_name: str = AXIS_NAME,
         # already collective; one extra sync is noise). Re-inits (elastic
         # re-mesh) stamp a new epoch marker into every shard.
         from horovod_tpu import timeline as _tl
-        if jax.process_count() > 1 and _distributed_initialized():
+        if jax.process_count() > 1 and jax.distributed.is_initialized():
             t = _tl.get_timeline()
             if t is not None and t.rank is None:
                 # Timeline was started before the distributed runtime came
@@ -251,9 +231,7 @@ def init(devices: Optional[Sequence] = None, axis_name: str = AXIS_NAME,
         from horovod_tpu import blackbox as _blackbox
         _blackbox.on_init(cfg)
         # Resolved comm-knob gauges (hvd.metrics()-visible): the algorithm
-        # as an info-style labeled gauge, chunk depth and whether the
-        # latency-hiding flags actually applied (False on CPU runs or
-        # when the backend beat init() to initialization). Inactive
+        # as an info-style labeled gauge and the chunk depth. Inactive
         # algorithm labels are zeroed so a re-init with a different knob
         # (bench --sweep-comm) leaves exactly one label at 1.
         from horovod_tpu.overlap import ALGORITHMS as _algs
@@ -274,8 +252,6 @@ def init(devices: Optional[Sequence] = None, axis_name: str = AXIS_NAME,
         for _i in range(max(len(topo), 4)):
             _metrics.gauge("config_topology", dim=str(_i)).set(
                 topo[_i] if _i < len(topo) else 0)
-        _metrics.gauge("config_xla_latency_hiding").set(
-            1 if lhs_applied else 0)
         # Resolved dp x mp degrees — hvd.doctor()'s _check_sharding reads
         # config_mesh_mp to tell "replicated by choice" from "sharded".
         _metrics.gauge("config_mesh_dp").set(_dp)
@@ -465,7 +441,6 @@ def build_info() -> dict:
         # need the world size to resolve).
         "mesh": (mesh_spec() if _CTX is not None else (cfg.mesh or None)),
         "mp_rules": cfg.mp_rules,
-        "xla_latency_hiding": cfg.xla_latency_hiding,
         "autotune": cfg.autotune,
         "autotune_mode": cfg.autotune_mode,
         "profile_on_stall": cfg.profile_on_stall,

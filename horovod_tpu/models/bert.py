@@ -15,7 +15,6 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from horovod_tpu.utils.compat import remat_policy as _remat_policy
 
 
 @dataclasses.dataclass(frozen=True)
@@ -140,8 +139,8 @@ class Bert(nn.Module):
             if cfg.remat_policy == "dots":
                 layer = nn.remat(
                     EncoderLayer,
-                    policy=_remat_policy(
-                        "dots_with_no_batch_dims_saveable"))
+                    policy=(jax.checkpoint_policies
+                            .dots_with_no_batch_dims_saveable))
             elif cfg.remat_policy == "full":
                 layer = nn.remat(EncoderLayer)
             else:

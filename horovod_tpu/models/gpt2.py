@@ -24,7 +24,6 @@ from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.parallel.sharding import PartitionRules
 
-from horovod_tpu.utils.compat import remat_policy as _remat_policy
 
 
 @dataclasses.dataclass(frozen=True)
@@ -172,8 +171,8 @@ class GPT2(nn.Module):
             if cfg.remat_policy == "dots":
                 block = nn.remat(
                     Block, static_argnums=(3,),
-                    policy=_remat_policy(
-                        "dots_with_no_batch_dims_saveable"))
+                    policy=(jax.checkpoint_policies
+                            .dots_with_no_batch_dims_saveable))
             elif cfg.remat_policy == "full":
                 block = nn.remat(Block, static_argnums=(3,))
             else:

@@ -33,7 +33,6 @@ from horovod_tpu.models.gpt2 import loss_fn  # same next-token CE  # noqa: F401
 from horovod_tpu.models.gpt2 import loss_fn_moe  # CE + aux  # noqa: F401
 from horovod_tpu.parallel.sharding import PartitionRules
 
-from horovod_tpu.utils.compat import remat_policy as _remat_policy
 
 __all__ = ["Llama", "LlamaConfig", "loss_fn", "loss_fn_moe",
            "partition_rules", "apply_rope"]
@@ -232,8 +231,8 @@ class Llama(nn.Module):
             if cfg.remat_policy == "dots":
                 block = nn.remat(
                     Block, static_argnums=(4,),
-                    policy=_remat_policy(
-                        "dots_with_no_batch_dims_saveable"))
+                    policy=(jax.checkpoint_policies
+                            .dots_with_no_batch_dims_saveable))
             elif cfg.remat_policy == "full":
                 block = nn.remat(Block, static_argnums=(4,))
             else:

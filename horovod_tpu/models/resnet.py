@@ -75,7 +75,7 @@ class ResNet(nn.Module):
     # are pmean-ed over this axis (upstream horovod/torch/sync_batch_norm.py
     # semantics) — use inside shard_map with the axis bound. None = local BN.
     bn_cross_replica_axis: str | None = None
-    # BN moment-accumulation dtype experiment (ROOFLINE.md ceiling list):
+    # BN moment-accumulation dtype experiment (measured negative on chip):
     # None keeps flax's fp32-stats BatchNorm; jnp.bfloat16 halves the HBM
     # traffic of the statistics passes via ops.batch_norm.TunableBatchNorm
     # (checkpoint-compatible variable layout either way).

@@ -37,7 +37,6 @@ from jax.sharding import PartitionSpec as P
 from horovod_tpu.models.llama import RMSNorm
 from horovod_tpu.parallel.sharding import PartitionRules
 
-from horovod_tpu.utils.compat import remat_policy as _remat_policy
 
 __all__ = ["T5", "T5Config", "relative_position_bucket", "seq2seq_loss",
            "partition_rules"]
@@ -212,8 +211,8 @@ def _maybe_remat(cfg: T5Config, layer_cls):
         return layer_cls
     if cfg.remat_policy == "dots":
         return nn.remat(layer_cls,
-                        policy=_remat_policy(
-                            "dots_with_no_batch_dims_saveable"))
+                        policy=(jax.checkpoint_policies
+                                .dots_with_no_batch_dims_saveable))
     if cfg.remat_policy == "full":
         return nn.remat(layer_cls)
     raise ValueError(f"unknown remat_policy {cfg.remat_policy!r}: "

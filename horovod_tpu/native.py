@@ -12,10 +12,13 @@ Builds on demand with ``make`` (g++) on first use.
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
 from typing import List, Optional
+
+log = logging.getLogger("horovod_tpu")
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _CPP_DIR = os.path.join(_REPO, "cpp")
@@ -30,9 +33,13 @@ def _build() -> bool:
     try:
         subprocess.run(["make", "-C", _CPP_DIR], capture_output=True,
                        check=True, timeout=120)
-        return os.path.exists(_SO_PATH)
-    except Exception:
+    except (OSError, subprocess.SubprocessError) as e:
+        detail = getattr(e, "stderr", b"") or b""
+        log.warning("native core: `make -C %s` failed (%s); using the "
+                    "pure-Python fallbacks. %s", _CPP_DIR, e,
+                    detail.decode(errors="replace")[-400:])
         return False
+    return os.path.exists(_SO_PATH)
 
 
 def load() -> Optional[ctypes.CDLL]:

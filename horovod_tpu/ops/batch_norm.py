@@ -1,11 +1,11 @@
 """Batch norm with a tunable statistics dtype + the space-to-depth stem.
 
-ROOFLINE.md's headline-ceiling analysis pins ResNet-50 at ~32% MFU with the
-BN statistics passes as the bound: flax's ``nn.BatchNorm`` always promotes
+The round-4 roofline analysis pinned ResNet-50 at ~32% HFU with the BN
+statistics passes as the bound: flax's ``nn.BatchNorm`` always promotes
 moment accumulation to float32 (`flax/linen/normalization._compute_stats`),
 so every BN reads its activation tensor at fp32 bandwidth. The two
-experiments the roofline prescribes, CPU-prepped behind flags so they can
-be measured the moment a chip answers (VERDICT r3 item 6):
+experiments that analysis prescribed sit behind flags (VERDICT r3 item 6);
+both measured negative on the chip (ROADMAP "Closed — do not retry"):
 
 - :class:`TunableBatchNorm` — flax-BatchNorm-compatible module (same
   params/batch_stats layout, checkpoint-interchangeable) whose moment

@@ -30,16 +30,19 @@ point generalises to neighbouring shapes until the tuner fills them in.
 from __future__ import annotations
 
 import json
+import logging
 import math
 import os
 import threading
 from pathlib import Path
 from typing import Dict, List, Optional, Tuple
 
+log = logging.getLogger("horovod_tpu")
+
 __all__ = ["lookup", "lookup_full", "record", "load_table", "save_table",
            "table_path", "DEFAULT_TILES", "KINDS"]
 
-DEFAULT_TILES = (256, 512)   # measured fastest on v5e (ROOFLINE.md r1)
+DEFAULT_TILES = (256, 512)   # measured fastest on v5e for fwd+bwd (round 1)
 KINDS = ("causal", "full", "ring")
 
 _lock = threading.Lock()
@@ -76,9 +79,13 @@ def load_table(path: Optional[os.PathLike] = None) -> dict:
             try:
                 with open(p) as f:
                     _cache[key] = (mtime, json.load(f))
-            except (OSError, ValueError):
+            except (OSError, ValueError) as e:
                 # Truncated/corrupt table: serve defaults, don't take
-                # training down over a tuning hint.
+                # training down over a tuning hint — but say so (once:
+                # the defaults are cached against this file version).
+                log.warning("flash tile table %s is unreadable (%s); every "
+                            "shape falls back to the default tiles %s",
+                            p, e, DEFAULT_TILES)
                 _cache[key] = (mtime, _empty_table())
         return _cache[key][1]
 

@@ -553,9 +553,8 @@ def grad(fun: Callable, argnums=0, op: int = C.Average,
     identity taps on each top-level parameter group
     (:func:`horovod_tpu.overlap.tap_params`): every group's gradient is
     synchronized *inside* the backward, the moment it is produced —
-    reverse production order for free — so XLA (especially with
-    ``HOROVOD_XLA_LATENCY_HIDING=1``) overlaps the collectives with the
-    rest of the backward instead of serializing them after it.
+    reverse production order for free — so XLA overlaps the collectives
+    with the rest of the backward instead of serializing them after it.
     """
     if overlap:
         from horovod_tpu import overlap as _overlap

@@ -50,13 +50,12 @@ def make_mesh(axes: Dict[str, int], devices: Optional[Sequence] = None,
             f"mesh {dict(zip(names, sizes))} needs {total} devices, "
             f"have {len(devs)}")
     if devices is None and jax.default_backend() == "tpu":
-        try:
-            arr = mesh_utils.create_device_mesh(
-                tuple(sizes),
-                allow_split_physical_axes=allow_split_physical_axes)
-            return Mesh(arr, names)
-        except Exception:
-            pass  # fall through to the naive reshape
+        # A failure here raises: a naive reshape would still run, with
+        # the logical axes laid across the torus at random.
+        arr = mesh_utils.create_device_mesh(
+            tuple(sizes),
+            allow_split_physical_axes=allow_split_physical_axes)
+        return Mesh(arr, names)
     arr = np.asarray(devs, dtype=object).reshape(tuple(sizes))
     return Mesh(arr, names)
 

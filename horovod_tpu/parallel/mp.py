@@ -390,15 +390,14 @@ def wrap_spmd(body: Callable, mesh: Mesh) -> Callable:
     leading dim, runs ``body`` (whose tp collectives see the ``"mp"``
     axis), and restacks. ``check_vma=False`` for the same reason as
     ``hvd.spmd`` — the tp psums are manual, not replication-tracked."""
-    from horovod_tpu.utils.compat import shard_map
 
     def inner(*args):
         local = jax.tree_util.tree_map(lambda a: a[0], args)
         out = body(*local)
         return jax.tree_util.tree_map(lambda a: a[None], out)
 
-    mapped = shard_map(inner, mesh=mesh, in_specs=P(MP_AXIS),
-                       out_specs=P(MP_AXIS), check_vma=False)
+    mapped = jax.shard_map(inner, mesh=mesh, in_specs=P(MP_AXIS),
+                           out_specs=P(MP_AXIS), check_vma=False)
 
     def wrapped(*args):
         return mapped(*args)

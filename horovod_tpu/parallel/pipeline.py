@@ -107,10 +107,7 @@ def _vary_over(axis_name: str, *xs):
     """Mark fresh zeros as varying over the pipe axis: under a multi-axis
     ``shard_map`` the scan carry's output is pp-varying (ppermute), and jax
     requires the initial carry to match (vma typing)."""
-    try:
-        return tuple(lax.pcast(x, (axis_name,), to="varying") for x in xs)
-    except (AttributeError, TypeError):
-        return xs
+    return tuple(lax.pcast(x, (axis_name,), to="varying") for x in xs)
 
 
 def pipeline_apply(stage_fn: Callable, stage_params: Any,
@@ -349,10 +346,7 @@ def _x_dependent_leaf_mask(stage_fn, stage_params, x_struct):
     test is a conservative taint walk over the jaxpr: a leaf is "dependent"
     if any path from the x invars reaches it (over-approximation only ever
     stashes more, never corrupts)."""
-    try:
-        from jax.extend import core as jcore       # public alias
-    except ImportError:                            # older jax
-        from jax._src import core as jcore
+    from jax.extend import core as jcore
 
     def residuals(p, xx):
         return jax.tree_util.tree_leaves(jax.vjp(stage_fn, p, xx)[1])

@@ -2,9 +2,9 @@
 recompile detection, memory accounting, triggered profiling, and the
 ``hvd.doctor()`` automated diagnosis.
 
-ROOFLINE.md answers "is this step as fast as the hardware allows?" by hand:
-one-off tools lower a train step, read XLA's compiled-program cost analysis,
-and divide by the device peak. This module makes that analysis a permanent
+"Is this step as fast as the hardware allows?" used to be answered by hand:
+one-off tools lowered a train step, read XLA's compiled-program cost analysis,
+and divided by the device peak. This module makes that analysis a permanent
 subsystem — the third observability layer on top of metrics (aggregates)
 and tracing (timelines):
 
@@ -94,38 +94,42 @@ HBM_GBPS: Dict[str, float] = {
 
 
 def _device_kind() -> str:
-    try:
-        import jax
-        return getattr(jax.devices()[0], "device_kind", "")
-    except Exception:
-        return ""
+    import jax
+    return jax.devices()[0].device_kind
+
+
+def _device_peak(table: Dict[str, float], env: str, what: str,
+                 device_kind: Optional[str]) -> Optional[float]:
+    override = os.environ.get(env)
+    if override:
+        return float(override)
+    kind = device_kind if device_kind is not None else _device_kind()
+    for k, v in table.items():
+        if k in kind:
+            return v
+    if "TPU" in kind:
+        # A utilization quietly missing from a chip record reads as "not
+        # applicable"; a TPU this table does not know is a gap to fill.
+        raise ValueError(
+            f"no {what} for device kind {kind!r}: add it to "
+            f"horovod_tpu.profiler (known: {sorted(table)}), or set {env}")
+    return None
 
 
 def peak_tflops(device_kind: Optional[str] = None) -> Optional[float]:
-    """Peak bf16 TFLOP/s of the local device, or None when unknown (CPU
-    test meshes). ``HOROVOD_PEAK_TFLOPS`` overrides — which is also how
-    CPU smokes exercise the utilization gauges deterministically."""
-    env = os.environ.get("HOROVOD_PEAK_TFLOPS")
-    if env:
-        return float(env)
-    kind = device_kind if device_kind is not None else _device_kind()
-    for k, v in PEAK_TFLOPS_BF16.items():
-        if k in kind:
-            return v
-    return None
+    """Peak bf16 TFLOP/s of the local device. None off-TPU (CPU test
+    meshes have no peak); a TPU kind missing from the table raises.
+    ``HOROVOD_PEAK_TFLOPS`` overrides — which is also how CPU smokes
+    exercise the utilization gauges deterministically."""
+    return _device_peak(PEAK_TFLOPS_BF16, "HOROVOD_PEAK_TFLOPS",
+                        "peak bf16 TFLOP/s", device_kind)
 
 
 def hbm_gbps(device_kind: Optional[str] = None) -> Optional[float]:
-    """HBM bandwidth GB/s of the local device, or None when unknown.
-    ``HOROVOD_HBM_GBPS`` overrides."""
-    env = os.environ.get("HOROVOD_HBM_GBPS")
-    if env:
-        return float(env)
-    kind = device_kind if device_kind is not None else _device_kind()
-    for k, v in HBM_GBPS.items():
-        if k in kind:
-            return v
-    return None
+    """HBM bandwidth GB/s of the local device; same contract as
+    :func:`peak_tflops`. ``HOROVOD_HBM_GBPS`` overrides."""
+    return _device_peak(HBM_GBPS, "HOROVOD_HBM_GBPS", "HBM GB/s",
+                        device_kind)
 
 
 def utilization(flops: float, dt: float, model_flops: Optional[float] = None,
@@ -470,9 +474,11 @@ def count_trace(name: str, **meta) -> None:
 
 def cost_from(compiled) -> Dict[str, float]:
     """Extract flops / bytes accessed / peak HBM from a
-    ``jax.stages.Compiled`` (or ``Lowered``) — tolerant of backends that
-    return lists, partial dicts, or no memory analysis at all."""
-    flops = nbytes = 0.0
+    ``jax.stages.Compiled`` (or ``Lowered``). Backends differ in shape —
+    a list of dicts, a partial dict, no memory analysis — and those read
+    as zeros; an analysis that *raises* is only tolerated off-TPU, where
+    nothing is measured. On the chip it would zero every utilization."""
+    flops = nbytes = peak = 0.0
     try:
         cost = compiled.cost_analysis()
         if isinstance(cost, (list, tuple)):
@@ -480,10 +486,6 @@ def cost_from(compiled) -> Dict[str, float]:
         if cost:
             flops = float(cost.get("flops", 0.0) or 0.0)
             nbytes = float(cost.get("bytes accessed", 0.0) or 0.0)
-    except Exception:
-        pass
-    peak = 0.0
-    try:
         mem = compiled.memory_analysis()
         if mem is not None:
             peak = (float(getattr(mem, "argument_size_in_bytes", 0))
@@ -491,7 +493,10 @@ def cost_from(compiled) -> Dict[str, float]:
                     + float(getattr(mem, "temp_size_in_bytes", 0))
                     - float(getattr(mem, "alias_size_in_bytes", 0)))
     except Exception:
-        pass
+        import jax
+        if jax.default_backend() == "tpu":
+            raise
+        logger.debug("cost analysis unavailable", exc_info=True)
     return {"flops": flops, "bytes_accessed": nbytes,
             "peak_hbm_bytes": max(0.0, peak)}
 
@@ -1173,9 +1178,9 @@ def _check_overlap(snap, report=None) -> List[Dict]:
         "compute" + ("" if big else
                      " and no bucket used the chunked pipeline"),
         "set algorithm='chunked_rs_ag' (HOROVOD_ALLREDUCE_ALGORITHM) with "
-        "HOROVOD_OVERLAP_CHUNKS=4..8 on large buckets, enable "
-        "DistributedOptimizer(overlap=True) or hvd.grad(overlap=True), "
-        "and HOROVOD_XLA_LATENCY_HIDING=1 on TPU.",
+        "HOROVOD_OVERLAP_CHUNKS=4..8 on large buckets, "
+        "and enable DistributedOptimizer(overlap=True) or "
+        "hvd.grad(overlap=True).",
         overlap_efficiency=eff)]
 
 

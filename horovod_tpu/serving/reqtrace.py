@@ -64,7 +64,7 @@ from typing import Any, Dict, List, Optional
 __all__ = ["TraceContext", "mint_context", "enabled", "span", "emit",
            "instant", "events", "reset", "flush", "SPAN_KINDS"]
 
-#: the span taxonomy, for docs and tooling (client side, then server side)
+#: the span kinds, for docs and tooling (client side, then server side)
 SPAN_KINDS = (
     "SUBMIT", "ATTEMPT", "RETRY", "HEDGE", "HEDGE_WIN", "BREAKER_WAIT",
     "CLIENT_FIRST_TOKEN", "MIGRATE", "MIGRATE_FALLBACK",

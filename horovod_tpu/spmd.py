@@ -35,9 +35,8 @@ def spmd(fn: Callable, *, in_specs: Any = None, out_specs: Any = None,
         in_specs = P()
     if out_specs is None:
         out_specs = P()
-    from horovod_tpu.utils.compat import shard_map
-    mapped = shard_map(fn, mesh=m, in_specs=in_specs, out_specs=out_specs,
-                       check_vma=False)
+    mapped = jax.shard_map(fn, mesh=m, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
     return jax.jit(mapped, donate_argnums=donate_argnums,
                    static_argnums=static_argnums)
 

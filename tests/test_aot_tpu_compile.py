@@ -1,0 +1,137 @@
+"""AOT compile guard: the programs chip_smoke.py runs, compiled for a
+v5e 2x2 by the real TPU compiler (libtpu builds a topology description
+with no chip attached), so a kernel or a step the chip's compiler refuses
+fails here before any chip time is spent. The interpret choice of the
+flash kernel is patched by the test; the product has no switch for it."""
+
+import dataclasses
+import importlib
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+import horovod_tpu as hvd
+from horovod_tpu.models.gpt2 import GPT2, GPT2Config
+
+pytest.importorskip("libtpu")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+import chip_smoke  # noqa: E402
+
+_FLASH = chip_smoke._sizes(False).flash
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+    return topologies.get_topology_desc(
+        topology_name="v5e:2x2", platform="tpu").devices
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _keep_aot_programs_out_of_the_cache():
+    # A topology-only client cannot load what it compiled, so an entry
+    # written here would only ever be a failed read on the next run.
+    name = "jax_persistent_cache_min_compile_time_secs"
+    before = getattr(jax.config, name)
+    jax.config.update(name, float("inf"))
+    yield
+    jax.config.update(name, before)
+
+
+@pytest.fixture()
+def mosaic(monkeypatch):
+    """Compile the flash kernels for the chip instead of interpreting."""
+    monkeypatch.setattr(
+        importlib.import_module("horovod_tpu.ops.flash_attention"),
+        "_use_interpret", lambda: False)
+
+
+@pytest.fixture()
+def restore_world():
+    yield
+    hvd.init()          # back onto the session's 8 CPU devices
+
+
+def _shapes(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+@pytest.mark.parametrize("variant", _FLASH, ids=[v[0] for v in _FLASH])
+def test_flash_variant_compiles_for_v5e(v5e, mosaic, variant):
+    _, B, T, H, D, dtype, causal, packed, masked, offset = variant
+    on = SingleDeviceSharding(v5e[0])
+    x = jax.ShapeDtypeStruct((B, T, H, D), jnp.dtype(dtype), sharding=on)
+    seg = jax.ShapeDtypeStruct((B, T), jnp.int32, sharding=on) \
+        if packed else None
+    mask = jax.ShapeDtypeStruct((B, T), jnp.bool_, sharding=on) \
+        if masked else None
+
+    def fwd_bwd(q, k, v, seg, mask):
+        def loss(q, k, v):
+            o = chip_smoke._flash_attention(
+                q, k, v, causal=causal, key_mask=mask, seg=seg,
+                offset=offset)
+            return jnp.sum(o.astype(jnp.float32))
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    lowered = jax.jit(fwd_bwd).lower(x, x, x, seg, mask)
+    assert "tpu_custom_call" in lowered.as_text()
+    lowered.compile()
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_spmd_train_step_compiles_for_v5e(v5e, mosaic, restore_world, n_dev):
+    hvd.init(devices=v5e[:n_dev])
+    assert hvd.topology() == ((2, 2) if n_dev == 4 else (1,))
+    cfg = dataclasses.replace(GPT2Config.medium(), num_layers=2,
+                              attention="flash", remat=True,
+                              remat_policy="dots")
+    opt, step = chip_smoke._train_step(hvd, cfg)
+    replicated = NamedSharding(hvd.mesh(), P())
+    params = jax.eval_shape(lambda: chip_smoke._init_params(cfg))
+    tokens = jax.ShapeDtypeStruct((8 * n_dev, 1024), jnp.int32,
+                                  sharding=hvd.spmd_data_sharding())
+    lowered = step.lower(_shapes(params, replicated),
+                         _shapes(jax.eval_shape(opt.init, params),
+                                 replicated), tokens)
+    assert "tpu_custom_call" in lowered.as_text()
+    hlo = lowered.compile().as_text()
+    # the gradient sync is in the program exactly when there is a peer
+    assert (" all-reduce(" in hlo) == (n_dev > 1)
+
+
+def test_engine_programs_compile_for_v5e_with_cache_donation(
+        v5e, restore_world, monkeypatch):
+    from horovod_tpu.serving import InferenceEngine
+    hvd.init(devices=v5e[:1])
+    cfg = dataclasses.replace(GPT2Config.medium(), num_layers=2)
+    params = jax.tree_util.tree_map(
+        lambda x: np.zeros(x.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: chip_smoke._init_params(cfg)))
+    # the engine donates its cache only off-CPU; take that branch
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    eng = InferenceEngine(GPT2(cfg), params, slots=8, max_len=1024,
+                          block_size=16, prefix_cache=True, name="aot")
+    monkeypatch.undo()
+    assert eng._donate == (1,)
+    on = SingleDeviceSharding(v5e[0])
+
+    def vec(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on)
+
+    tail = (vec(8), vec(8), vec(8, dtype=jnp.bool_), vec(8), vec(8), None)
+    state = (_shapes(eng.params, on), _shapes(eng._cache, on))
+    for pure, steps in ((eng._decode_pure, eng.spec_k + 1),
+                        (eng._prefill_pure, eng.prefill_chunk)):
+        mem = jax.jit(pure, donate_argnums=eng._donate).lower(
+            *state, vec(steps, 8), *tail).compile().memory_analysis()
+        # the K/V pools are updated in place, not copied every token
+        assert mem.alias_size_in_bytes >= eng._pool_bytes

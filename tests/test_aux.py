@@ -340,6 +340,27 @@ class TestRunner:
                         np=2)
         assert rc == 0
 
+    def test_single_local_worker_keeps_the_ambient_platform(
+            self, tmp_path, monkeypatch):
+        # One worker has nothing to share an accelerator with: the
+        # launcher must not put it on the CPU unasked (on a TPU host the
+        # ambient platform is the TPU). Several workers are forced there.
+        from horovod_tpu.runner.launcher import run
+        show = ["python", "-c",
+                "import os; print('platform', "
+                "os.environ.get('JAX_PLATFORMS'))"]
+        monkeypatch.delenv("JAX_PLATFORMS")
+        assert run(show, np=1, output_filename=str(tmp_path / "one"),
+                   timeout=120) == 0
+        assert "platform None" in (
+            tmp_path / "one" / "rank.0" / "stdout").read_text()
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+        assert run(show, np=2, output_filename=str(tmp_path / "two"),
+                   timeout=120) == 0
+        for r in range(2):
+            assert "platform cpu" in (
+                tmp_path / "two" / f"rank.{r}" / "stdout").read_text()
+
     def test_local_run_failure_raises(self):
         with pytest.raises(RuntimeError):
             runner_run(["python", "-c", "raise SystemExit(3)"], np=2)

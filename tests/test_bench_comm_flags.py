@@ -59,31 +59,6 @@ class TestParsing:
         bench._apply_comm_flags(args)
         assert os.environ["HOROVOD_MESH"] == "dp2xmp1"
 
-    def test_supervisor_forwards_flags(self, bench, monkeypatch):
-        seen = {}
-
-        def fake_run(cmd, timeout=None, **kw):
-            seen["cmd"] = cmd
-
-            class R:
-                returncode = 0
-            return R()
-
-        monkeypatch.setenv("HVD_BENCH_PROBE_ATTEMPTS", "1")
-        monkeypatch.setattr(bench, "_probe_backend", lambda t: "ok")
-        monkeypatch.setattr(bench.subprocess, "run", fake_run)
-        args = bench._build_parser().parse_args(
-            ["--model", "mnist", "--allreduce-alg", "rs_ag",
-             "--overlap-chunks", "2", "--topology", "2x2",
-             "--mesh", "dp2xmp2", "--sweep-comm"])
-        assert bench._supervise(args) == 0
-        cmd = seen["cmd"]
-        assert "--allreduce-alg" in cmd and "rs_ag" in cmd
-        assert "--overlap-chunks" in cmd and "2" in cmd
-        assert "--topology" in cmd and "2x2" in cmd
-        assert "--mesh" in cmd and "dp2xmp2" in cmd
-        assert "--sweep-comm" in cmd
-
     def test_apply_comm_flags_sets_env(self, bench, monkeypatch):
         # setenv (not delenv) so monkeypatch records the pre-test state
         # even when the variable is absent: _apply_comm_flags writes

@@ -14,7 +14,6 @@ import horovod_tpu as hvd
 from horovod_tpu.parallel.fsdp import (flat_size, fsdp_adamw, fsdp_apply,
                                        fsdp_scan_blocks, fsdp_shard_params,
                                        stack_layer_shards)
-from horovod_tpu.utils.compat import shard_map as _compat_shard_map
 
 N = 8
 D = 16
@@ -142,7 +141,7 @@ class TestFsdpTp:
             return lax.pmean(l, "dp"), g
 
         mesh = make_mesh({"dp": DP, "tp": TP})
-        fn = jax.jit(_compat_shard_map(
+        fn = jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=(P("tp", "dp"), P("dp")),
             out_specs=(P(), P("tp", "dp")), check_vma=False))
         l, g = fn(jnp.asarray(shards), jnp.asarray(x))

@@ -589,12 +589,19 @@ class TestConfigKnobs:
         monkeypatch.delenv("HOROVOD_OVERLAP_CHUNKS")
         hconfig.refresh()
 
-    def test_latency_hiding_skipped_on_cpu(self, monkeypatch):
-        # JAX_PLATFORMS=cpu (the test harness) must skip the TPU flags —
-        # and must NOT touch XLA_FLAGS.
-        before = os.environ.get("XLA_FLAGS")
-        assert overlap.enable_latency_hiding() is False
-        assert os.environ.get("XLA_FLAGS") == before
+    def test_init_never_edits_compiler_flags(self, monkeypatch):
+        # The HOROVOD_XLA_LATENCY_HIDING knob appended --xla_tpu_* flags
+        # to XLA_FLAGS, which jaxlib 0.9 answers by aborting the process.
+        # The knob is gone: init() leaves both flag variables alone even
+        # when an old environment still sets it.
+        monkeypatch.setenv("HOROVOD_XLA_LATENCY_HIDING", "1")
+        before = {k: os.environ.get(k)
+                  for k in ("XLA_FLAGS", "LIBTPU_INIT_ARGS")}
+        hvd.init()
+        assert {k: os.environ.get(k) for k in before} == before
+        assert "latency_hiding" not in (os.environ.get("XLA_FLAGS") or "")
+        assert not hasattr(overlap, "enable_latency_hiding")
+        assert "xla_latency_hiding" not in hvd.build_info()
 
     def test_config_gauges_visible(self):
         snap = hvd.metrics()

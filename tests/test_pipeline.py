@@ -8,7 +8,6 @@ from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvd
 from horovod_tpu.parallel.pipeline import pipeline_apply, pipeline_loss
-from horovod_tpu.utils.compat import shard_map as _compat_shard_map
 
 N = 8          # stages
 M = 4          # microbatches
@@ -278,7 +277,7 @@ class TestGPT2PipelineTensorParallel:
         specs = block_specs_tp("pp", "tp")
         mesh = make_mesh({"pp": S, "tp": TP})
         step = gpt2_pp_tp_loss_and_grad(cfg, pp_axis="pp", tp_axis="tp")
-        fn = jax.jit(_compat_shard_map(
+        fn = jax.jit(jax.shard_map(
             step, mesh=mesh,
             in_specs=(specs, P(), P()),
             out_specs=(P(), specs, P()),
@@ -338,7 +337,7 @@ class TestGPT2PipelineTensorParallel:
             gr = jax.tree_util.tree_map(lambda g: lax.pmean(g, "dp"), gr)
             return l, gb, gr
 
-        fn = jax.jit(_compat_shard_map(
+        fn = jax.jit(jax.shard_map(
             step, mesh=mesh,
             in_specs=(specs, P(), P(None, "dp")),
             out_specs=(P(), specs, P()),
@@ -386,7 +385,7 @@ class TestGPT2PipelineTensorParallel:
         specs = block_specs_tp("pp", "tp", extra_dims=1)
         mesh = make_mesh({"pp": S, "tp": TP})
         step = gpt2_pp_tp_loss_and_grad_interleaved(cfg, "pp", "tp")
-        fn = jax.jit(_compat_shard_map(
+        fn = jax.jit(jax.shard_map(
             step, mesh=mesh,
             in_specs=(specs, P(), P()),
             out_specs=(P(), specs, P()),
@@ -548,7 +547,7 @@ class Test1F1B:
         mesh = make_mesh({"pp": S, "tp": TP})
         step = gpt2_pp_tp_1f1b_loss_and_grad(cfg, pp_axis="pp",
                                              tp_axis="tp")
-        fn = jax.jit(_compat_shard_map(
+        fn = jax.jit(jax.shard_map(
             step, mesh=mesh,
             in_specs=(specs, P(), P()),
             out_specs=(P(), specs, P()),
@@ -746,7 +745,7 @@ class TestInterleaved1F1B:
         mesh = make_mesh({"pp": S, "tp": TP})
         step = gpt2_pp_tp_interleaved_1f1b_loss_and_grad(
             cfg, rounds=R, pp_axis="pp", tp_axis="tp")
-        fn = jax.jit(_compat_shard_map(
+        fn = jax.jit(jax.shard_map(
             step, mesh=mesh,
             in_specs=(specs, P(), P()),
             out_specs=(P(), specs, P()),

@@ -67,6 +67,28 @@ class TestUtilization:
         monkeypatch.setenv("HOROVOD_HBM_GBPS", "123")
         assert profiler.hbm_gbps() == 123.0
 
+    def test_a_tpu_missing_from_the_table_is_an_error(self, monkeypatch):
+        # On the chip path a missing peak used to drop hfu/mfu from the
+        # record without a word; the CPU mesh still reads None.
+        monkeypatch.delenv("HOROVOD_PEAK_TFLOPS", raising=False)
+        monkeypatch.delenv("HOROVOD_HBM_GBPS", raising=False)
+        assert profiler.peak_tflops("TPU v5 lite") == 197.0
+        assert profiler.peak_tflops("cpu") is None
+        with pytest.raises(ValueError, match="TPU v9"):
+            profiler.peak_tflops("TPU v9")
+        with pytest.raises(ValueError, match="HOROVOD_HBM_GBPS"):
+            profiler.hbm_gbps("TPU v9")
+
+    def test_failed_cost_analysis_is_an_error_on_tpu(self, monkeypatch):
+        class Broken:
+            def cost_analysis(self):
+                raise RuntimeError("no cost analysis")
+
+        assert profiler.cost_from(Broken())["flops"] == 0.0   # CPU: zeros
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(RuntimeError, match="no cost analysis"):
+            profiler.cost_from(Broken())
+
 
 class TestDescribe:
     def test_arrays_by_shape_dtype(self):

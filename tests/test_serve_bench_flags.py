@@ -26,23 +26,6 @@ class TestParsing:
         assert args.serve
         assert not bench._build_parser().parse_args([]).serve
 
-    def test_supervisor_forwards_serve(self, bench, monkeypatch):
-        seen = {}
-
-        def fake_run(cmd, timeout=None, **kw):
-            seen["cmd"] = cmd
-
-            class R:
-                returncode = 0
-            return R()
-
-        monkeypatch.setenv("HVD_BENCH_PROBE_ATTEMPTS", "1")
-        monkeypatch.setattr(bench, "_probe_backend", lambda t: "ok")
-        monkeypatch.setattr(bench.subprocess, "run", fake_run)
-        args = bench._build_parser().parse_args(["--serve"])
-        assert bench._supervise(args) == 0
-        assert "--serve" in seen["cmd"]
-
     def test_serve_bench_tool_parser(self, bench):
         sb = bench._load_serve_bench()
         args = sb._build_parser().parse_args(
@@ -57,15 +40,13 @@ class TestServeLineEmits:
     def test_serve_line_records_percentiles(self):
         """End-to-end CPU guard: ``bench.py --serve`` emits one JSON line
         with throughput + ttft/tpot/queue-wait percentiles and the
-        paged-cache accounting fields that also land in
-        BENCH_SELF.jsonl."""
+        paged-cache accounting fields."""
         env = dict(os.environ, JAX_PLATFORMS="cpu",
                    HVD_SERVE_BENCH_REQUESTS="6",
                    HVD_SERVE_BENCH_RATE="50",
                    HVD_SERVE_BENCH_SLOTS="3")
         out = subprocess.run(
-            [sys.executable, os.path.join(_REPO, "bench.py"), "--serve",
-             "--inner"],
+            [sys.executable, os.path.join(_REPO, "bench.py"), "--serve"],
             capture_output=True, text=True, timeout=900, env=env,
             cwd=_REPO)
         assert out.returncode == 0, out.stderr[-2000:]

@@ -315,6 +315,77 @@ class TestEngineParity:
         assert req.result(1) == list(want)
 
 
+class TestCacheDonation:
+    """On the chip the engine donates its cache to every dispatch, so the
+    pools it passed in are DELETED when the call returns; the CPU runtime
+    ignores donation, and no test ever ran with it. These tests delete the
+    input pools by hand after each dispatch, as the chip does."""
+
+    @staticmethod
+    def _donate_like_the_chip(eng):
+        for name in ("_decode_jit", "_prefill_jit"):
+            def run(params, cache, *rest, _jitted=getattr(eng, name)):
+                out = jax.block_until_ready(_jitted(params, cache, *rest))
+                for pool in (cache.kp, cache.vp, cache.ks, cache.vs):
+                    if pool is not None:
+                        pool.delete()
+                return out
+            setattr(eng, name, run)
+
+    @pytest.mark.parametrize("kv_quant", [None, "int8"])
+    def test_serving_and_migration_survive_deleted_inputs(
+            self, gpt2_setup, rng, kv_quant):
+        model, params, cfg = gpt2_setup
+        eng = InferenceEngine(model, params, slots=3, max_len=48,
+                              block_size=4, prefill_chunk=4,
+                              prefix_cache=True, kv_quant=kv_quant,
+                              name=f"donate-{kv_quant}")
+        self._donate_like_the_chip(eng)
+        a = list(rng.integers(1, cfg.vocab_size, 13))
+        b = a[:8] + list(rng.integers(1, cfg.vocab_size, 5))   # shares 2 blocks
+        first = eng.submit(a, 6)
+        eng.run_until_idle()
+        second = eng.submit(b, 6)                # prefix hit + copy-on-write
+        # KV migration reads the cache between dispatches (export_kv) and
+        # replaces it outside the jit (import_blocks).
+        half = eng.submit(a, 6, prefill_only=True)
+        eng.run_until_idle()
+        grafted = eng.admit_prefilled(a, 6, *half.kv_export)
+        eng.run_until_idle()
+        assert eng.stats()["prefix"]["hits"] >= 1
+        assert grafted.result(1) == first.result(1)
+        if kv_quant is None:                     # fp32 pool: token parity
+            for prompt, req in ((a, first), (b, second)):
+                want = np.asarray(generate(
+                    model, params, jnp.asarray([prompt], jnp.int32),
+                    6))[0, len(prompt):]
+                assert req.result(1) == list(want)
+        assert eng.decode_compiles == 1 and eng.prefill_compiles == 1
+
+    def test_a_failed_dispatch_fails_the_engine_cleanly(self, gpt2_setup):
+        # The dispatch consumed the cache and then raised: nothing the
+        # failure path or close() does may touch the deleted buffers.
+        model, params, cfg = gpt2_setup
+        eng = InferenceEngine(model, params, slots=2, max_len=32,
+                              block_size=4, prefill_chunk=1,
+                              name="donate-fail")
+        self._donate_like_the_chip(eng)
+        consume = eng._decode_jit
+
+        def consume_then_raise(*args):
+            consume(*args)
+            raise RuntimeError("device fell over")
+
+        eng._decode_jit = consume_then_raise
+        req = eng.submit([5, 6, 7], 4)
+        eng.start()
+        req.result(30)                           # terminal, not hung
+        assert req.status == RequestStatus.FAILED and req.retryable
+        assert "device fell over" in eng.failed
+        eng.close()
+        assert eng.stats()["active"] == 0
+
+
 class TestContinuousBatching:
     def test_midflight_admission_one_compile_paged_savings(
             self, llama_setup, rng):

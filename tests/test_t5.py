@@ -157,7 +157,7 @@ class TestT5Model:
         sharded = shard_pytree(params, mesh, partition_rules())
         s_src = jax.device_put(src, NamedSharding(mesh, P("dp")))
         s_tgt = jax.device_put(tgt, NamedSharding(mesh, P("dp")))
-        with jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh:
+        with jax.set_mesh(mesh):
             got = jax.jit(lambda p: jax.grad(
                 lambda p: seq2seq_loss(model, p, s_src, s_tgt))(p)
             )(sharded)

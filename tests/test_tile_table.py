@@ -62,6 +62,33 @@ def test_missing_table_falls_back_to_default(tmp_path):
         tile_table.DEFAULT_TILES
 
 
+def test_corrupt_table_falls_back_loudly_and_once(tmp_path, caplog):
+    p = tmp_path / "t.json"
+    p.write_text('{"version": 1, "entries": [')          # truncated write
+    with caplog.at_level("WARNING", logger="horovod_tpu"):
+        for _ in range(3):
+            assert tile_table.lookup(64, 1024, "bfloat16", "causal",
+                                     path=p) == tile_table.DEFAULT_TILES
+    warned = [r for r in caplog.records if "unreadable" in r.getMessage()]
+    assert len(warned) == 1 and str(p) in warned[0].getMessage()
+
+
+def test_tune_tiles_refuses_to_time_the_interpreter(tmp_path, capsys):
+    # Off-TPU the kernels are interpreted; the tool used to warn and go
+    # on measuring when --out was given.
+    import importlib.util
+    import os
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", "tune_tiles.py")
+    spec = importlib.util.spec_from_file_location("hvd_tune_tiles", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    out = tmp_path / "tiles.json"
+    assert tool.main(["--quick", "--out", str(out)]) == 2
+    assert not out.exists()
+    assert "nothing was measured" in capsys.readouterr().err
+
+
 def test_empty_entries_use_table_default(tmp_path):
     p = tmp_path / "t.json"
     tile_table.save_table({"version": 1, "device": "x",

@@ -64,6 +64,21 @@ class TestTopologyDetection:
         devs = [_FakeDev((x, 0, 0)) for x in range(2)] * 3
         assert hmesh.detect_topology(6, devs) == (6,)
 
+    def test_make_mesh_does_not_hide_a_failed_placement_on_tpu(
+            self, monkeypatch):
+        # On a TPU, create_device_mesh aligns logical axes with the torus;
+        # its failure used to be swallowed into a naive reshape that runs
+        # with tp/sp collectives laid across the fabric at random.
+        def refuse(*a, **kw):
+            raise NotImplementedError("no placement for this slice")
+
+        monkeypatch.setattr(hmesh.mesh_utils, "create_device_mesh", refuse)
+        assert hmesh.make_mesh({"dp": 4, "tp": 2}).shape == \
+            {"dp": 4, "tp": 2}                  # CPU: plain reshape
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(NotImplementedError, match="no placement"):
+            hmesh.make_mesh({"dp": 4, "tp": 2})
+
     def test_torus_groups(self):
         g = hmesh.torus_groups((2, 4))
         # dim 0: columns of the row-major 2x4 grid; dim 1: the rows

@@ -41,14 +41,14 @@ def run_one(B, T, remat, attention, policy="full", steps=8):
     except Exception as e:
         line = f"{tag}: FAILED {type(e).__name__}: {str(e)[:120]}"
     print(line, flush=True)
-    # survive a relay wedge mid-sweep: every finished config is durable
+    # every finished config is durable if the sweep dies midway
     with open(os.path.join(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))), "SWEEP_GPT2.txt"), "a") as f:
         f.write(line + "\n")
 
 if __name__ == "__main__":
-    # priority order: the configs most likely to move MFU come first, so a
-    # relay wedge mid-sweep still answers the main questions.
+    # priority order: the configs most likely to move MFU come first, so
+    # a sweep cut short still answers the main questions.
     for B, remat, att, pol in [
             (8, True, "flash", "dots"),    # selective remat at bench config
             (8, True, "flash", "full"),    # tuned-tile reference point

@@ -20,7 +20,7 @@ compares across meshes.
 Usage::
 
     python tools/mp_bench.py                  # print 4 lines
-    python tools/mp_bench.py --out BENCH_SELF.jsonl
+    python tools/mp_bench.py --out mp_bench.jsonl
 """
 
 import argparse

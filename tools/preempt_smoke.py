@@ -28,7 +28,7 @@ Asserts:
 Exit 0 = all checks pass. Wired as tier-1
 (``tests/test_checkpoint_sharded.py::TestTwoProcessPreemptSmoke``) and
 ``make preempt-smoke``. ``--bench-out FILE`` appends a recovery-time
-JSON line (BENCH_SELF.jsonl format).
+JSON line.
 """
 
 import argparse

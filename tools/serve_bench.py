@@ -11,8 +11,8 @@ clock, prompt lengths and output budgets drawn from seeded ranges, the
 engine serves them under its real continuous-batching scheduler, and
 the record carries p50/p90/p99 of every latency plus goodput.
 
-One JSON line per run to stdout (append with ``--out``); the same
-record shape lands in ``BENCH_SELF.jsonl`` via ``bench.py --serve``.
+One JSON line per run to stdout (append with ``--out``); ``bench.py
+--serve`` prints the same record shape.
 
 Usage::
 
@@ -217,13 +217,18 @@ def run_bench(*, requests: int = 32, rate: float = 50.0,
     pstats = eng.manager.prefix_stats()
     estats = eng.stats()
     ttfts = [o["ttft"] for o in done if o["ttft"] is not None]
+    import jax
+    dev = jax.devices()[0]
     rec = {
         "metric": metric,
         "value": round(tokens / wall, 2),
         "unit": "tokens/sec", "vs_baseline": None,
-        # proxy: bench_sentinel gates this row — a >10% throughput drop
-        # at equal settings (transport included) fails the build
-        "proxy": True,
+        # proxy: a CPU run; bench_sentinel gates such rows — a >10%
+        # throughput drop at equal settings (transport included) fails
+        # the build. The device fields say where any row ran.
+        "proxy": dev.platform == "cpu",
+        "platform": dev.platform, "device_kind": dev.device_kind,
+        "devices": len(jax.devices()),
         "transport": transport,
         "requests": requests, "completed": len(done),
         "rejected": sum(1 for o in outs
@@ -590,6 +595,8 @@ def _build_parser():
 
 def main() -> int:
     args = _build_parser().parse_args()
+    from horovod_tpu.utils import compile_cache
+    compile_cache.enable()
     kw = dict(
         requests=args.requests, rate=args.rate, slots=args.slots,
         max_len=args.max_len, block_size=args.block_size,

@@ -19,7 +19,9 @@ end as subprocesses, so the retry rides along.
 import os
 import re
 import sys
-import tempfile
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from horovod_tpu.utils import compile_cache  # noqa: E402
 
 
 def jit_cache_env(env=None):
@@ -30,14 +32,13 @@ def jit_cache_env(env=None):
     rerun pays a multi-second jit compile for an executable an earlier
     worker already built. Pointing every subprocess at one shared cache
     (entries are keyed on HLO + jax version, so staleness is impossible)
-    makes only the first compile pay. ``setdefault`` keeps an inherited
-    dir — under pytest, tests/conftest.py exports one for the whole
-    suite so the cache is ALSO shared across smokes.
+    makes only the first compile pay. The place follows the one rule in
+    ``horovod_tpu/utils/compile_cache.py``: an inherited
+    ``JAX_COMPILATION_CACHE_DIR`` is kept, else ``<checkout>/.jax_cache``.
     """
     env = dict(os.environ if env is None else env)
-    env.setdefault(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(tempfile.gettempdir(), "hvd_tpu_jit_cache"))
+    if not env.get("JAX_COMPILATION_CACHE_DIR"):
+        env["JAX_COMPILATION_CACHE_DIR"] = compile_cache.cache_dir()
     env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.5")
     return env
 
