@@ -10,7 +10,11 @@ See SURVEY.md for the component inventory mapping every public symbol to its
 upstream equivalent.
 """
 
-from horovod_tpu.core import (  # noqa: F401
+import time as _time
+
+_IMPORT_T0 = _time.perf_counter()   # -> the import_seconds gauge, below
+
+from horovod_tpu.core import (  # noqa: F401,E402
     init, shutdown, is_initialized, rank, size, local_rank, local_size,
     cross_rank, cross_size, mesh, axis_name, build_info, in_spmd_context,
     topology, topology_str,
@@ -101,6 +105,10 @@ from horovod_tpu.timeline import (  # noqa: F401
 )
 
 __version__ = "0.1.0"
+
+# The package's own share of a process's set-up (tracing.NAMES); with
+# core.init's init_seconds it is what the program adds before any jit.
+metrics.gauge("import_seconds").set(_time.perf_counter() - _IMPORT_T0)
 
 
 def mpi_threads_supported() -> bool:

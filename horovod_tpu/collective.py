@@ -31,7 +31,6 @@ from __future__ import annotations
 import functools
 import time as _time_mod
 from collections import deque
-from contextlib import nullcontext
 from typing import Any, List, Optional, Sequence
 
 import jax
@@ -360,6 +359,7 @@ def _allreduce_tree(tree, op, ps, prescale, postscale, compression,
             spans.append((off, flat.shape[0]))
             off += m
         buf = jnp.concatenate(padded)
+        _tracing.note_bucket(_overlap.wire_bytes(int(buf.size), marker_wire))
         # Wire-byte telemetry, same accounting as the algorithm-axis path.
         _metrics.counter(
             "allreduce_wire_bytes_total", algorithm="compression",
@@ -403,6 +403,7 @@ def _allreduce_tree(tree, op, ps, prescale, postscale, compression,
             wire_cast = c.dtype
             c = c.astype(jnp.bfloat16)
         nbytes = int(c.size) * jnp.dtype(c.dtype).itemsize
+        _tracing.note_bucket(nbytes)
         topo = core.topology() if core.is_initialized() else None
         alg = _overlap.resolve_algorithm(
             algorithm, nbytes, op, core.size(), reducible=reducible,
@@ -894,22 +895,6 @@ def _negotiate_inner(kind: str, sig_key: tuple,
     return joined
 
 
-def _maybe_profiler_annotation(kind: str, span):
-    """``HOROVOD_TRACE_JAX_PROFILER=1``: wrap the dispatched program in a
-    ``jax.profiler.TraceAnnotation`` named with the same op-id the host
-    timeline logs, so XLA device traces (``timeline.start_profiler``)
-    correlate with merged host shards. No-op (and never raises) when the
-    knob is off or the profiler is unavailable."""
-    try:
-        from horovod_tpu.config import get_config
-        if not get_config().trace_jax_profiler:
-            return nullcontext()
-        op = span.op_id if span is not None else 0
-        return jax.profiler.TraceAnnotation(f"hvd:{kind}#{op}")
-    except Exception:
-        return nullcontext()
-
-
 def _traced_span(kind: str, name: Optional[str], ps: ProcessSet):
     """Span for an in-jit lowering (negative op-id: trace-time ids are
     per-process — compile caches differ across ranks — so they must never
@@ -1045,11 +1030,11 @@ def _eager_run_inner(kind, tree, params, param_key, negotiate_key,
                                 epoch=core.init_epoch()):
                 placed = [place(x) for x in leaves]
             with _tracing.phase(span, "EXEC", epoch=core.init_epoch()):
-                with _maybe_profiler_annotation(kind, span):
+                with _tracing.span("collective", kind=kind, **sp_args):
                     out_leaves = fn(*placed)
     else:
         placed = [place(x) for x in leaves]
-        with _maybe_profiler_annotation(kind, span):
+        with _tracing.span("collective", kind=kind, **sp_args):
             out_leaves = fn(*placed)
     # Dispatch latency: negotiation + placement + program launch (jax
     # dispatch is async, so this is host-side cost, not device runtime —

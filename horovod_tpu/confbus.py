@@ -254,7 +254,6 @@ _add("HOROVOD_SERVE_AUTH_TOKEN", "serve_auth_token", secret=True,
 _IMMUTABLE_FIELDS: Dict[str, str] = {
     "HOROVOD_TIMELINE": "timeline_path",
     "HOROVOD_TIMELINE_MARK_CYCLES": "timeline_mark_cycles",
-    "HOROVOD_TRACE_JAX_PROFILER": "trace_jax_profiler",
     "HOROVOD_AUTOTUNE": "autotune",
     "HOROVOD_AUTOTUNE_LOG": "autotune_log",
     "HOROVOD_AUTOTUNE_MODE": "autotune_mode",

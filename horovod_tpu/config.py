@@ -79,11 +79,6 @@ class Config:
     # trace at init; HOROVOD_TIMELINE_MARK_CYCLES adds cycle markers.
     timeline_path: Optional[str] = None
     timeline_mark_cycles: bool = False
-    # jax.profiler bridge (trace_merge/tracing): HOROVOD_TRACE_JAX_PROFILER=1
-    # wraps each dispatched collective in a jax.profiler.TraceAnnotation
-    # carrying the same op-id as the host timeline, so device traces
-    # correlate with merged host shards.
-    trace_jax_profiler: bool = False
     # Autotune: HOROVOD_AUTOTUNE enables the online tuner;
     # HOROVOD_AUTOTUNE_LOG mirrors upstream's tuning log path.
     # HOROVOD_AUTOTUNE_MODE picks the search: "ladder" (candidate walk) or
@@ -550,7 +545,6 @@ def refresh() -> Config:
         mp_rules=_env_mp_rules(),
         timeline_path=os.environ.get("HOROVOD_TIMELINE") or None,
         timeline_mark_cycles=_env_bool("HOROVOD_TIMELINE_MARK_CYCLES"),
-        trace_jax_profiler=_env_bool("HOROVOD_TRACE_JAX_PROFILER"),
         autotune=_env_bool("HOROVOD_AUTOTUNE"),
         autotune_log=os.environ.get("HOROVOD_AUTOTUNE_LOG") or None,
         autotune_mode=(os.environ.get("HOROVOD_AUTOTUNE_MODE", "ladder")

@@ -193,13 +193,15 @@ def fuse(leaves: Sequence[Any],
                 [flat, jnp.zeros((padded - n,), flat.dtype)])
         return flat
 
-    buckets = [
-        _segment_slice(segs[0]) if len(segs) == 1
-        else jnp.concatenate([_segment_slice(s) for s in segs])
-        for segs in plan
-    ]
+    with _tracing.scope("hvd/fusion/pack"):
+        buckets = [
+            _segment_slice(segs[0]) if len(segs) == 1
+            else jnp.concatenate([_segment_slice(s) for s in segs])
+            for segs in plan
+        ]
     shapes = [leaves[i].shape for i in range(len(leaves))]
 
+    @_tracing.scope("hvd/fusion/unpack")
     def unpack(new_buckets: List[jnp.ndarray]) -> List[Any]:
         pieces: dict = {}               # leaf -> [(start, piece)]
         for b, segs in enumerate(plan):

@@ -22,6 +22,7 @@ from jax import lax
 
 from jax.sharding import PartitionSpec as P
 
+from horovod_tpu import tracing as _tracing
 from horovod_tpu.parallel.sharding import PartitionRules
 
 
@@ -211,6 +212,7 @@ def partition_rules() -> PartitionRules:
     ])
 
 
+@_tracing.scope("gpt2/loss_head")
 def loss_fn(logits: jnp.ndarray, tokens: jnp.ndarray,
             segment_ids: jnp.ndarray = None) -> jnp.ndarray:
     """Next-token cross entropy. With ``segment_ids`` (sequence packing),

@@ -41,9 +41,17 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from horovod_tpu import tracing as _tracing
+
 __all__ = ["flash_attention"]
 
 _NEG_INF = -1e30
+# Kernel names. A Mosaic custom call is named after the last scope round
+# it, which ``pallas_call(name=)`` sets: flash_fwd, flash_dq, flash_dkv
+# (tracing.NAMES; the per-kernel metrics sum device time by them). jax
+# wraps the outermost scope inside a transformation in the transformation's
+# name (``jvp(flash_fwd)`` would name the call ``jvp_flash_fwd_``), so each
+# call sits in a ``flash_attention`` scope that takes the wrapping instead.
 
 
 def _use_interpret() -> bool:
@@ -204,7 +212,7 @@ def _fwd(q, k, v, bias, seg_q, seg_k, h, scale, causal, block_q, block_k,
         args.append(seg_q)
         args.append(seg_k)
     kernel = _fill_optionals(kernel, bias is not None, seg_q is not None)
-    o, lse = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
@@ -223,7 +231,10 @@ def _fwd(q, k, v, bias, seg_q, seg_k, h, scale, causal, block_q, block_k,
             pltpu.VMEM((bq,), jnp.float32),
         ],
         interpret=_use_interpret(),
-    )(*args)
+        name="flash_fwd",
+    )
+    with _tracing.scope("flash_attention"):
+        o, lse = call(*args)
     return o, lse
 
 
@@ -418,7 +429,7 @@ def _bwd(h, scale, causal, block_q, block_k, res, do, delta=None,
     dkv_kernel = _fill_optionals(dkv_kernel, bias is not None,
                                  seg_q is not None)
 
-    dq = pl.pallas_call(
+    dq_call = pl.pallas_call(
         dq_kernel,
         grid=(bh, pl.cdiv(tq, bq), pl.cdiv(tk, bk)),
         in_specs=specs("dq"),
@@ -426,7 +437,8 @@ def _bwd(h, scale, causal, block_q, block_k, res, do, delta=None,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=_use_interpret(),
-    )(q, k, v, *extra, do, lse, delta)
+        name="flash_dq",
+    )
 
     out_specs = [
         pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
@@ -447,7 +459,7 @@ def _bwd(h, scale, causal, block_q, block_k, res, do, delta=None,
         out_shape.append(jax.ShapeDtypeStruct((bh, tk, 1), jnp.float32))
         scratch.append(pltpu.VMEM((bk,), jnp.float32))
 
-    outs = pl.pallas_call(
+    dkv_call = pl.pallas_call(
         dkv_kernel,
         grid=(bh, pl.cdiv(tk, bk), pl.cdiv(tq, bq)),
         in_specs=specs("dkv"),
@@ -455,7 +467,11 @@ def _bwd(h, scale, causal, block_q, block_k, res, do, delta=None,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=_use_interpret(),
-    )(q, k, v, *extra, do, lse, delta)
+        name="flash_dkv",
+    )
+    with _tracing.scope("flash_attention"):
+        dq = dq_call(q, k, v, *extra, do, lse, delta)
+        outs = dkv_call(q, k, v, *extra, do, lse, delta)
 
     if track_db:
         dk, dv, db = outs

@@ -29,6 +29,7 @@ import optax
 from horovod_tpu import collective as C
 from horovod_tpu import core
 from horovod_tpu import metrics as _metrics
+from horovod_tpu import tracing as _tracing
 from horovod_tpu.compression import Compression
 from horovod_tpu.process_set import ProcessSet
 
@@ -404,6 +405,9 @@ def allreduce_gradients(grads: Any, op: int = C.Average,
                    fusion_threshold_bytes=fusion_threshold_bytes,
                    algorithm=algorithm, overlap_chunks=overlap_chunks,
                    _reverse_issue=overlap)
+    # The sync manifest (tracing.py) counts what this pass hands to
+    # all-reduce, under the caller's scope; trace time only.
+    peers = C._resolve_ps(process_set).size()
     if alive is not None:
         if op not in (C.Average, C.Sum):
             raise ValueError("join-style allreduce supports Sum/Average only")
@@ -412,17 +416,20 @@ def allreduce_gradients(grads: Any, op: int = C.Average,
         n_alive = jnp.maximum(n_alive, 1.0)
         grads = jax.tree_util.tree_map(
             lambda g: g * alivef.astype(g.dtype), grads)
-        summed = C.allreduce(grads, op=C.Sum, process_set=process_set,
-                             prescale_factor=prescale_factor,
-                             postscale_factor=postscale_factor, **comm_kw)
+        with _tracing.sync_pass(peers):
+            summed = C.allreduce(grads, op=C.Sum, process_set=process_set,
+                                 prescale_factor=prescale_factor,
+                                 postscale_factor=postscale_factor,
+                                 **comm_kw)
         if op == C.Average:
             summed = jax.tree_util.tree_map(
                 lambda g: g / n_alive.astype(g.dtype), summed)
         _maybe_record_grad_norm(summed)
         return summed
-    out = C.allreduce(grads, op=op, process_set=process_set,
-                      prescale_factor=prescale_factor,
-                      postscale_factor=postscale_factor, **comm_kw)
+    with _tracing.sync_pass(peers):
+        out = C.allreduce(grads, op=op, process_set=process_set,
+                          prescale_factor=prescale_factor,
+                          postscale_factor=postscale_factor, **comm_kw)
     _maybe_record_grad_norm(out)
     return out
 
@@ -497,27 +504,26 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
         return optimizer.init(params)
 
     def update(grads, state, params=None, **extra):
+        sync_kw = dict(
+            op=op, process_set=process_set, compression=compression,
+            prescale_factor=prescale_factor,
+            postscale_factor=postscale_factor,
+            fusion_threshold_bytes=fusion_threshold_bytes,
+            alive=extra.pop("alive", None), algorithm=algorithm,
+            overlap_chunks=overlap_chunks, overlap=overlap)
         if error_feedback:
             inner_state, residual = state
-            grads, residual = allreduce_gradients(
-                grads, op=op, process_set=process_set,
-                compression=compression, prescale_factor=prescale_factor,
-                postscale_factor=postscale_factor,
-                fusion_threshold_bytes=fusion_threshold_bytes,
-                alive=extra.pop("alive", None), algorithm=algorithm,
-                overlap_chunks=overlap_chunks, overlap=overlap,
-                error_feedback=residual)
-            updates, inner_state = optimizer.update(
-                grads, inner_state, params, **extra)
+            with _tracing.scope("hvd/optimizer/sync"):
+                grads, residual = allreduce_gradients(
+                    grads, error_feedback=residual, **sync_kw)
+            with _tracing.scope("hvd/optimizer/update"):
+                updates, inner_state = optimizer.update(
+                    grads, inner_state, params, **extra)
             return updates, ErrorFeedbackState(inner_state, residual)
-        grads = allreduce_gradients(
-            grads, op=op, process_set=process_set, compression=compression,
-            prescale_factor=prescale_factor, postscale_factor=postscale_factor,
-            fusion_threshold_bytes=fusion_threshold_bytes,
-            alive=extra.pop("alive", None),
-            algorithm=algorithm, overlap_chunks=overlap_chunks,
-            overlap=overlap)
-        return optimizer.update(grads, state, params, **extra)
+        with _tracing.scope("hvd/optimizer/sync"):
+            grads = allreduce_gradients(grads, **sync_kw)
+        with _tracing.scope("hvd/optimizer/update"):
+            return optimizer.update(grads, state, params, **extra)
 
     tx = optax.GradientTransformation(init, update)
     if backward_passes_per_step < 1:
@@ -583,10 +589,11 @@ def grad(fun: Callable, argnums=0, op: int = C.Average,
 
     def wrapped(*args, **kwargs):
         g = gfun(*args, **kwargs)
-        return allreduce_gradients(g, op=op, process_set=process_set,
-                                   compression=compression,
-                                   algorithm=algorithm,
-                                   overlap_chunks=overlap_chunks)
+        with _tracing.scope("hvd/grad/sync"):
+            return allreduce_gradients(g, op=op, process_set=process_set,
+                                       compression=compression,
+                                       algorithm=algorithm,
+                                       overlap_chunks=overlap_chunks)
     return wrapped
 
 
@@ -604,8 +611,9 @@ def value_and_grad(fun: Callable, argnums=0, op: int = C.Average,
             v = jax.tree_util.tree_map(
                 lambda x: C.allreduce(x, op=C.Average,
                                       process_set=process_set), v)
-        g = allreduce_gradients(g, op=op, process_set=process_set,
-                                compression=compression)
+        with _tracing.scope("hvd/value_and_grad/sync"):
+            g = allreduce_gradients(g, op=op, process_set=process_set,
+                                    compression=compression)
         return v, g
     return wrapped
 
@@ -624,8 +632,10 @@ class DistributedGradientTape:
 
     def gradient(self, fun: Callable, params, *args, **kwargs):
         g = jax.grad(fun)(params, *args, **kwargs)
-        return allreduce_gradients(g, op=self._op, process_set=self._ps,
-                                   compression=self._comp)
+        with _tracing.scope("hvd/tape/sync"):
+            return allreduce_gradients(g, op=self._op,
+                                       process_set=self._ps,
+                                       compression=self._comp)
 
 
 def broadcast_parameters(params: Any, root_rank: int = 0,

@@ -341,6 +341,8 @@ class InferenceEngine:
 
         self._decode_pure = _decode_pure
         self._decode_jit = jax.jit(_decode_raw, donate_argnums=donate)
+        tracing.note_program("_decode_raw")     # set-up ledger series
+        tracing.note_program("_prefill_raw")
 
         C, V = self.prefill_chunk, self.cfg.vocab_size
         view_len = self.view_len
@@ -785,10 +787,12 @@ class InferenceEngine:
         """Evict, admit, advance every occupied lane one unit of work
         (one decode token, or one prefill chunk). Returns the number of
         lanes that advanced — 0 means idle."""
-        with self._lock:
+        with self._lock, tracing.span("engine.step", step=self.step_count):
             now = time.monotonic()
-            self._sweep(now)
-            self._admit(now)
+            with self._phase("sweep"):
+                self._sweep(now)
+            with self._phase("admit"):
+                self._admit(now)
             lanes = sorted(self._states.items())
             if not lanes:
                 self._update_gauges()
@@ -811,10 +815,17 @@ class InferenceEngine:
             else:
                 self._run_decode(lanes)
                 self._last_prefill = False
+            with self._phase("sweep"):
+                self._sweep(time.monotonic())
+                self._update_gauges()
             self.step_count += 1
-            self._sweep(time.monotonic())
-            self._update_gauges()
             return len(lanes)
+
+    def _phase(self, phase: str):
+        """One phase of ``step_once``: the span ``hvd:engine.<phase>`` and
+        its host seconds in ``serve_step_phase_seconds_total``."""
+        return tracing.timed("engine." + phase, "serve_step_phase",
+                             self.step_count, engine=self.name)
 
     def _sweep(self, now: float) -> None:
         """Finish lanes that went terminal (deadline, cancel) and free
@@ -1026,6 +1037,29 @@ class InferenceEngine:
                               sampled_every=every)
 
     def _run_decode(self, lanes: List[Tuple[int, _SlotState]]) -> None:
+        with self._phase("build"):
+            tok_seq, counts, proposed, args = self._build_decode(lanes)
+        _rt_t0 = time.time()
+        with self._phase("dispatch"):
+            cache, first, greedy = self._dispatch(
+                "decode", self._decode_jit, self.params, *args,
+                self._extras)
+        if reqtrace.enabled():
+            self._emit_decode_spans(lanes, _rt_t0, time.time() - _rt_t0)
+        self._cache = cache
+        self.manager.set_device_mirror(cache.table)
+        with self._phase("readback"):
+            greedy_np = self._host(greedy)               # (K, slots)
+            logits_np = self._pull_logits_if_sampling(lanes, first)
+        metrics.counter("serve_steps_total", engine=self.name,
+                        phase="decode").inc()
+        with self._phase("commit"):
+            self._commit_decode(lanes, tok_seq, counts, proposed,
+                                greedy_np, logits_np)
+
+    def _build_decode(self, lanes: List[Tuple[int, _SlotState]]):
+        """The decode dispatch's host arrays, and their device copies in
+        argument order after ``params``."""
         K = self.spec_k + 1
         tok_seq = np.zeros((K, self.slots), np.int32)
         pos0 = np.zeros(self.slots, np.int32)
@@ -1066,20 +1100,12 @@ class InferenceEngine:
                                          engine=self.name, request=req.id,
                                          slot=slot, pos=q, phase="decode")
         cache = self._cache.replace(table=self._device_table())
-        _rt_t0 = time.time()
-        cache, first, greedy = self._dispatch(
-            "decode", self._decode_jit, self.params, cache,
-            self._dev(tok_seq), self._dev(pos0), self._dev(counts),
-            self._dev(act), self._dev(cow_src), self._dev(cow_dst),
-            self._extras)
-        if reqtrace.enabled():
-            self._emit_decode_spans(lanes, _rt_t0, time.time() - _rt_t0)
-        self._cache = cache
-        self.manager.set_device_mirror(cache.table)
-        greedy_np = self._host(greedy)                   # (K, slots)
-        logits_np = self._pull_logits_if_sampling(lanes, first)
-        metrics.counter("serve_steps_total", engine=self.name,
-                        phase="decode").inc()
+        return tok_seq, counts, proposed, (
+            cache, self._dev(tok_seq), self._dev(pos0), self._dev(counts),
+            self._dev(act), self._dev(cow_src), self._dev(cow_dst))
+
+    def _commit_decode(self, lanes, tok_seq, counts, proposed, greedy_np,
+                       logits_np) -> None:
         accepted = 0
         for slot, st in lanes:
             req = st.request
@@ -1118,6 +1144,37 @@ class InferenceEngine:
                           proposed=proposed, accepted=accepted)
 
     def _run_prefill(self, lanes: List[Tuple[int, _SlotState]]) -> None:
+        with self._phase("build"):
+            count, args = self._build_prefill(lanes)
+        _rt_t0 = time.time()
+        with self._phase("dispatch"):
+            cache, final, greedy = self._dispatch(
+                "prefill", self._prefill_jit, self.params, *args,
+                self._extras)
+        if reqtrace.enabled():
+            _rt_dur = time.time() - _rt_t0
+            for slot, st in lanes:
+                if st.request.trace is not None:
+                    reqtrace.emit("PREFILL", st.request.trace, _rt_t0,
+                                  _rt_dur, engine=self.name,
+                                  request=st.request.id, slot=slot,
+                                  tokens=int(count[slot]))
+        self._cache = cache
+        self.manager.set_device_mirror(cache.table)
+        with self._phase("readback"):
+            greedy_np = self._host(greedy)
+            logits_np = self._pull_logits_if_sampling(lanes, final)
+        metrics.counter("serve_steps_total", engine=self.name,
+                        phase="prefill").inc()
+        with self._phase("commit"):
+            for slot, st in lanes:
+                st.n_fed += int(count[slot])
+                if st.n_fed >= len(st.request.prompt):
+                    self._commit(st, slot, greedy_np, logits_np)
+
+    def _build_prefill(self, lanes: List[Tuple[int, _SlotState]]):
+        """The prefill dispatch's host arrays, and their device copies in
+        argument order after ``params``."""
         C = self.prefill_chunk
         tok_seq = np.zeros((C, self.slots), np.int32)
         pos0 = np.zeros(self.slots, np.int32)
@@ -1142,30 +1199,9 @@ class InferenceEngine:
                                          request=st.request.id,
                                          slot=slot, pos=q, phase="prefill")
         cache = self._cache.replace(table=self._device_table())
-        _rt_t0 = time.time()
-        cache, final, greedy = self._dispatch(
-            "prefill", self._prefill_jit, self.params, cache,
-            self._dev(tok_seq), self._dev(pos0), self._dev(count),
-            self._dev(act), self._dev(cow_src), self._dev(cow_dst),
-            self._extras)
-        if reqtrace.enabled():
-            _rt_dur = time.time() - _rt_t0
-            for slot, st in lanes:
-                if st.request.trace is not None:
-                    reqtrace.emit("PREFILL", st.request.trace, _rt_t0,
-                                  _rt_dur, engine=self.name,
-                                  request=st.request.id, slot=slot,
-                                  tokens=int(count[slot]))
-        self._cache = cache
-        self.manager.set_device_mirror(cache.table)
-        greedy_np = self._host(greedy)
-        logits_np = self._pull_logits_if_sampling(lanes, final)
-        metrics.counter("serve_steps_total", engine=self.name,
-                        phase="prefill").inc()
-        for slot, st in lanes:
-            st.n_fed += int(count[slot])
-            if st.n_fed >= len(st.request.prompt):
-                self._commit(st, slot, greedy_np, logits_np)
+        return count, (
+            cache, self._dev(tok_seq), self._dev(pos0), self._dev(count),
+            self._dev(act), self._dev(cow_src), self._dev(cow_dst))
 
     def _pull_logits_if_sampling(self, lanes, logits):
         """One bulk device->host transfer when ANY lane will host-sample

@@ -9,12 +9,14 @@ lowering to XLA ops inside.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Optional
 
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from horovod_tpu import core
+from horovod_tpu import tracing as _tracing
 
 __all__ = ["spmd", "spmd_data_sharding"]
 
@@ -35,7 +37,19 @@ def spmd(fn: Callable, *, in_specs: Any = None, out_specs: Any = None,
         in_specs = P()
     if out_specs is None:
         out_specs = P()
-    mapped = jax.shard_map(fn, mesh=m, in_specs=in_specs,
+    # The function's name labels its sync manifest and its set-up ledger
+    # series (tracing.py). ``traced`` runs while jit traces and never per
+    # step: the returned object is still the bare ``jax.jit``, and the
+    # program keeps the function's name (``jit_<name>``).
+    name = getattr(fn, "__name__", "spmd_fn")
+    _tracing.note_program(name)
+
+    @functools.wraps(fn)
+    def traced(*args, **kwargs):
+        with _tracing.program(name):
+            return fn(*args, **kwargs)
+
+    mapped = jax.shard_map(traced, mesh=m, in_specs=in_specs,
                            out_specs=out_specs, check_vma=False)
     return jax.jit(mapped, donate_argnums=donate_argnums,
                    static_argnums=static_argnums)

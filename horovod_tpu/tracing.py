@@ -1,4 +1,27 @@
-"""Span contexts: cross-rank correlation ids for collective operations.
+"""Tracing: what the program says about its own work, in one place.
+
+Three things live here, and nothing else in the package defines a span, a
+scope or a span counter:
+
+* **Profiler spans and scopes** — :func:`span` is a
+  ``jax.profiler.TraceAnnotation`` named ``hvd:<name>`` (a host span on the
+  profiler's clock, nested by thread; with no profiler session it is a flag
+  check in C++, which is "tracing off"); :func:`scope` is a
+  ``jax.named_scope`` for code under ``jit`` (operation metadata only: no
+  operation is added or moved); :func:`timed` is a span that also adds its
+  host seconds to a counter pair of ``metrics.registry``. :data:`NAMES` is
+  the table of every name emitted: its layer, what it covers and which
+  per-layer metric of the benchmark reads it (``tests/test_tracing_spans.py``
+  fails on a name used but not listed; ``PERF.md`` and
+  ``docs/OBSERVABILITY.md`` are written from it).
+* **The sync manifest** — what a program handed to all-reduce for its
+  gradients, counted while the program is traced, from static shapes
+  (:func:`program`, :func:`sync_pass`, :func:`note_bucket`).
+* **The set-up ledger** — one ``jax.monitoring`` listener, registered when
+  this module is imported, that adds jax's own trace / lower / backend /
+  cache-load seconds to ``jax_compile_seconds_total{phase,fun}``.
+
+It also holds the older correlation layer for eager collectives:
 
 Upstream Horovod's ``timeline.cc`` keys every NEGOTIATE / QUEUE / NCCL phase
 event to the tensor being reduced, and because every rank logs the same
@@ -26,11 +49,18 @@ re-mesh) — both count the same submission sequence.
 from __future__ import annotations
 
 import threading
+import time
 from contextlib import contextmanager
-from typing import Any, Dict, Optional
+from typing import Any, Dict, NamedTuple, Optional
+
+import jax
+
+from horovod_tpu import metrics as _metrics
 
 __all__ = ["Span", "mint_span", "current_span", "active_span",
-           "reset_spans", "phase"]
+           "reset_spans", "phase",
+           "NAMES", "Name", "span", "scope", "timed", "current_scope",
+           "program", "note_program", "sync_pass", "note_bucket"]
 
 _LOCK = threading.Lock()
 _SEQ = 0
@@ -143,3 +173,284 @@ def phase(span: Optional[Span], name: str, category: str = "phase",
             cm.__exit__(None, None, None)
         except Exception:
             pass
+
+
+# ---------------------------------------------------------------------------
+# profiler spans and scopes
+# ---------------------------------------------------------------------------
+
+class Name(NamedTuple):
+    """One row of :data:`NAMES`."""
+    kind: str       # span | scope | kernel | counter | gauge
+    layer: str      # the layer as PERF.md section 3 names it
+    covers: str     # what the name stands for, in one line
+    feeds: str      # the per-layer metric that reads it, or "xprof only"
+
+
+_TRAINER = "trainer API (spmd, optimizer, collective, fusion, overlap)"
+_MODELS = "models (models/gpt2, remat)"
+_KERNELS = "kernels (ops/flash_attention)"
+_ENGINE = "engine (serving/engine, scheduler, cache)"
+_COMPILER = "compiler (XLA, Mosaic, persistent cache)"
+
+#: Every span, scope, kernel name and span counter the package emits. The
+#: engine's phases are listed in the order one ``step_once`` runs them.
+NAMES: Dict[str, Name] = {
+    # host spans (jax.profiler.TraceAnnotation "hvd:<name>")
+    "collective": Name(
+        "span", _TRAINER, "the dispatch of one eager collective; args "
+        "kind= and op_id= (the id the host timeline logs)", "xprof only"),
+    "engine.step": Name(
+        "span", _ENGINE, "one step_once (an idle pass holds only its "
+        "sweep and admit); args step=", "xprof only"),
+    "engine.sweep": Name(
+        "span", _ENGINE, "finish and evict terminal lanes (before the "
+        "dispatch and again after it, with the gauges)",
+        "engine_host_ms.serve"),
+    "engine.admit": Name(
+        "span", _ENGINE, "pop ready requests, match prefixes, reserve "
+        "blocks and slots", "engine_host_ms.serve"),
+    "engine.build": Name(
+        "span", _ENGINE, "the dispatch's host arrays, copy-on-write "
+        "bookkeeping and their transfer", "engine_host_ms.serve"),
+    "engine.dispatch": Name(
+        "span", _ENGINE, "the jitted decode or prefill call, blocked "
+        "until the device is done", "xprof only"),
+    "engine.readback": Name(
+        "span", _ENGINE, "device to host: the greedy picks, and the "
+        "logits when a lane samples", "engine_readback_ms.serve"),
+    "engine.commit": Name(
+        "span", _ENGINE, "the per-lane loop: verify drafts, commit "
+        "tokens, push them to the stream", "engine_host_ms.serve"),
+    # scopes under jit (jax.named_scope; operation metadata)
+    "hvd/value_and_grad/sync": Name(
+        "scope", _TRAINER, "hvd.value_and_grad's gradient sync",
+        "grad_sync_mb.train (as the manifest's scope label)"),
+    "hvd/grad/sync": Name(
+        "scope", _TRAINER, "hvd.grad's gradient sync (no overlap taps)",
+        "grad_sync_mb.train (as the manifest's scope label)"),
+    "hvd/tape/sync": Name(
+        "scope", _TRAINER, "DistributedGradientTape.gradient's sync",
+        "grad_sync_mb.train (as the manifest's scope label)"),
+    "hvd/optimizer/sync": Name(
+        "scope", _TRAINER, "DistributedOptimizer.update's gradient sync",
+        "grad_sync_mb.train (as the manifest's scope label)"),
+    "hvd/optimizer/update": Name(
+        "scope", _TRAINER, "the inner optax update of "
+        "DistributedOptimizer", "xprof only"),
+    "hvd/fusion/pack": Name(
+        "scope", _TRAINER, "ravel, slice and concatenate leaves into "
+        "fusion buckets", "xprof only"),
+    "hvd/fusion/unpack": Name(
+        "scope", _TRAINER, "slice the reduced buckets back into leaves",
+        "xprof only"),
+    "gpt2/loss_head": Name(
+        "scope", _MODELS, "models.gpt2.loss_fn: log-softmax over the "
+        "vocabulary and the gather of the targets", "xprof only"),
+    "flash_attention": Name(
+        "scope", _KERNELS, "round each flash kernel call, so that jax's "
+        "jvp()/transpose() wrap this name and not the kernel's",
+        "xprof only"),
+    # kernel names (pallas_call(name=...): the custom call's instruction)
+    "flash_fwd": Name(
+        "kernel", _KERNELS, "flash attention forward (run again in the "
+        "backward under remat)", "flash_fwd_ms.train"),
+    "flash_dq": Name(
+        "kernel", _KERNELS, "flash attention backward, dQ",
+        "flash_dq_ms.train"),
+    "flash_dkv": Name(
+        "kernel", _KERNELS, "flash attention backward, dK and dV",
+        "flash_dkv_ms.train"),
+    # counters and gauges of metrics.registry that this module writes
+    "serve_step_phase_seconds_total": Name(
+        "counter", _ENGINE, "host seconds per engine phase; labels "
+        "engine, phase", "engine_host_ms.serve, engine_readback_ms.serve"),
+    "serve_step_phase_total": Name(
+        "counter", _ENGINE, "times each engine phase ran; labels engine, "
+        "phase", "engine_host_ms.serve, engine_readback_ms.serve"),
+    "grad_sync_bytes": Name(
+        "gauge", _TRAINER, "sync manifest: bytes one device hands to "
+        "all-reduce a step, as last traced; labels program, scope",
+        "grad_sync_mb.train"),
+    "grad_sync_buckets": Name(
+        "gauge", _TRAINER, "sync manifest: fusion buckets reduced a step; "
+        "labels program, scope", "xprof only"),
+    "grad_sync_passes": Name(
+        "gauge", _TRAINER, "sync manifest: calls of allreduce_gradients "
+        "that reached the wire; labels program, scope", "xprof only"),
+    "jax_compile_seconds_total": Name(
+        "counter", _COMPILER, "set-up ledger: seconds jax reports per "
+        "phase (trace, lower, backend, cache_load) and function; an outer "
+        "function's seconds include the functions traced inside it",
+        "step_trace_lower_s.train"),
+    "jax_compile_total": Name(
+        "counter", _COMPILER, "set-up ledger: events behind the seconds",
+        "step_trace_lower_s.train"),
+    "import_seconds": Name(
+        "gauge", _TRAINER, "seconds `import horovod_tpu` took (jax "
+        "imported before it or not)", "program_import_init_s"),
+}
+
+SPAN_PREFIX = "hvd:"
+
+
+def span(name: str, **args):
+    """Host span ``hvd:<name>`` on the profiler's clock. ``args`` carry
+    the identifier that the spans of one request or step share
+    (``step=``, ``request=``). Free when no profiler session is on."""
+    return jax.profiler.TraceAnnotation(SPAN_PREFIX + name, **args)
+
+
+@contextmanager
+def scope(name: str):
+    """``jax.named_scope(name)`` for code under ``jit``, as a context or
+    as a decorator; the innermost open one is :func:`current_scope`. Runs
+    while tracing only."""
+    stack = _TLS.__dict__.setdefault("scopes", [])
+    stack.append(name)
+    try:
+        with jax.named_scope(name):
+            yield
+    finally:
+        stack.pop()
+
+
+def current_scope() -> str:
+    """The innermost open :func:`scope` of this thread, or ``"none"``."""
+    stack = getattr(_TLS, "scopes", None)
+    return stack[-1] if stack else "none"
+
+
+@contextmanager
+def timed(name: str, family: str, step: int, /, **labels):
+    """:func:`span` ``name`` of step ``step`` that also adds its host
+    seconds to ``<family>_seconds_total`` and one to ``<family>_total``,
+    labelled ``labels`` and ``phase=`` the last dotted part of ``name``."""
+    phase_name = name.rsplit(".", 1)[-1]
+    t0 = time.perf_counter()
+    try:
+        with span(name, step=step):
+            yield
+    finally:
+        dt = time.perf_counter() - t0
+        _metrics.counter(family + "_seconds_total", phase=phase_name,
+                         **labels).inc(dt)
+        _metrics.counter(family + "_total", phase=phase_name,
+                         **labels).inc()
+
+
+# ---------------------------------------------------------------------------
+# the sync manifest
+# ---------------------------------------------------------------------------
+
+_PROGRAMS: set = set()          # names the package's own programs carry
+_PUBLISHED: Dict[str, set] = {}  # program -> scopes it has gauges for
+
+
+def note_program(name: str) -> None:
+    """A program the package itself builds: the set-up ledger keeps a
+    series for it instead of counting it under ``other``."""
+    _PROGRAMS.add(name)
+
+
+@contextmanager
+def program(name: str):
+    """The function ``name`` is being traced on this thread: gradient
+    syncs inside belong to its manifest, published when the trace ends
+    as the gauges ``grad_sync_{bytes,buckets,passes}{program,scope}``.
+    The last trace's values: a program lowered twice is not counted
+    twice, and a second program does not add to the first's."""
+    prev = getattr(_TLS, "manifest", None)
+    manifest: Dict[str, list] = {}
+    _TLS.manifest = manifest
+    try:
+        yield
+    finally:
+        _TLS.manifest = prev
+        with _LOCK:
+            stale = _PUBLISHED.get(name, set()) - set(manifest)
+            _PUBLISHED[name] = set(manifest)
+        for sc in stale:
+            manifest[sc] = [0, 0, 0]
+        for sc, (nbytes, buckets, passes) in manifest.items():
+            for what, v in (("bytes", nbytes), ("buckets", buckets),
+                            ("passes", passes)):
+                _metrics.gauge("grad_sync_" + what, program=name,
+                               scope=sc).set(v)
+
+
+@contextmanager
+def sync_pass(peers: int):
+    """One gradient sync over ``peers`` devices is being lowered, called
+    from :func:`current_scope`. With one device nothing reaches the
+    wire and the pass counts nothing (the scope still gets its zeros)."""
+    manifest = getattr(_TLS, "manifest", None)
+    if manifest is None:
+        yield
+        return
+    entry = manifest.setdefault(current_scope(), [0, 0, 0])
+    prev = getattr(_TLS, "sync_entry", None)
+    if peers > 1:
+        entry[2] += 1
+        _TLS.sync_entry = entry
+    try:
+        yield
+    finally:
+        _TLS.sync_entry = prev
+
+
+def note_bucket(nbytes: int) -> None:
+    """A bucket of ``nbytes`` is handed to its collective (the payload as
+    it goes: after compression and wire cast, before any leg split)."""
+    entry = getattr(_TLS, "sync_entry", None)
+    if entry is not None:
+        entry[0] += int(nbytes)
+        entry[1] += 1
+
+
+# ---------------------------------------------------------------------------
+# the set-up ledger
+# ---------------------------------------------------------------------------
+
+_JAX_PHASE = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+
+
+def _ledger(phase_name: str, fun: str, secs: float) -> None:
+    _metrics.counter("jax_compile_seconds_total", phase=phase_name,
+                     fun=fun).inc(secs)
+    _metrics.counter("jax_compile_total", phase=phase_name, fun=fun).inc()
+
+
+def _on_jax_duration(event: str, secs: float, **kw) -> None:
+    """jax's own clock on the four phases of making a program. ``fun`` is
+    the function's name for the package's programs (:func:`note_program`)
+    and ``other`` for the rest, so the series stay bounded. jax reports a
+    cache load without a name, inside the backend event that follows it
+    on the same thread: the load is booked under that event's function
+    and taken out of its ``backend`` seconds, so the phases add up."""
+    phase_name = _JAX_PHASE.get(event)
+    if phase_name is None:
+        return
+    if phase_name == "cache_load":
+        _TLS.cache_load = getattr(_TLS, "cache_load", 0.0) + secs
+        return
+    fun = str(kw.get("fun_name", ""))
+    if fun.startswith("jit(") and fun.endswith(")"):
+        fun = fun[4:-1]                 # lower and backend say jit(<name>)
+    if fun not in _PROGRAMS:
+        fun = "other"
+    if phase_name == "backend":
+        loaded = getattr(_TLS, "cache_load", 0.0)
+        if loaded:
+            _TLS.cache_load = 0.0
+            _ledger("cache_load", fun, loaded)
+            secs = max(0.0, secs - loaded)
+    _ledger(phase_name, fun, secs)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)

@@ -7,6 +7,7 @@ flash kernel is patched by the test; the product has no switch for it."""
 import dataclasses
 import importlib
 import os
+import re
 import sys
 
 import jax
@@ -64,6 +65,15 @@ def _shapes(tree, sharding):
         tree)
 
 
+def _assert_kernels_named(hlo):
+    """The Mosaic custom calls carry the names the per-kernel metrics sum
+    by (``pallas_call(name=)``, ``tracing.NAMES``): a refactor that loses
+    one would leave ``flash_*_ms.train`` without a reading."""
+    for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert re.search(rf"%{kernel}[.\d]* = [^\n]*custom-call\(", hlo), \
+            kernel
+
+
 @pytest.mark.parametrize("variant", _FLASH, ids=[v[0] for v in _FLASH])
 def test_flash_variant_compiles_for_v5e(v5e, mosaic, variant):
     _, B, T, H, D, dtype, causal, packed, masked, offset = variant
@@ -84,7 +94,7 @@ def test_flash_variant_compiles_for_v5e(v5e, mosaic, variant):
 
     lowered = jax.jit(fwd_bwd).lower(x, x, x, seg, mask)
     assert "tpu_custom_call" in lowered.as_text()
-    lowered.compile()
+    _assert_kernels_named(lowered.compile().as_text())
 
 
 @pytest.mark.parametrize("n_dev", [1, 4])
@@ -106,6 +116,14 @@ def test_spmd_train_step_compiles_for_v5e(v5e, mosaic, restore_world, n_dev):
     hlo = lowered.compile().as_text()
     # the gradient sync is in the program exactly when there is a peer
     assert (" all-reduce(" in hlo) == (n_dev > 1)
+    # the program keeps its name, the kernels theirs, and the trainer's
+    # scopes reach the chip's program as operation metadata
+    assert hlo.startswith("HloModule jit_train_step")
+    _assert_kernels_named(hlo)
+    for scope in ("hvd/value_and_grad/sync", "hvd/optimizer/sync",
+                  "hvd/optimizer/update", "hvd/fusion/pack",
+                  "hvd/fusion/unpack", "gpt2/loss_head"):
+        assert scope in hlo, scope
 
 
 def test_engine_programs_compile_for_v5e_with_cache_donation(

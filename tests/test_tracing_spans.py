@@ -1,0 +1,340 @@
+"""The program's own spans, scopes, sync manifest and set-up ledger
+(``horovod_tpu/tracing.py``), on the CPU: every name emitted is in the
+table, the lowered README step carries the trainer's scopes and the three
+kernel names, the manifest counts what the step hands to all-reduce, the
+ledger books jax's trace / lower / backend seconds under the function's
+name, and one ``step_once`` leaves its phase spans on the profiler's host
+plane in the table's order."""
+
+import ast
+import glob
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax.sharding import PartitionSpec as P
+
+import horovod_tpu as hvd
+from horovod_tpu import tracing
+from horovod_tpu.models.gpt2 import GPT2, GPT2Config, loss_fn
+
+PKG = os.path.dirname(os.path.abspath(hvd.__file__))
+TRAINER_SCOPES = ["hvd/value_and_grad/sync", "hvd/optimizer/sync",
+                  "hvd/optimizer/update", "hvd/fusion/pack",
+                  "hvd/fusion/unpack", "gpt2/loss_head"]
+KERNELS = ["flash_fwd", "flash_dq", "flash_dkv"]
+ENGINE_PHASES = ["sweep", "admit", "build", "dispatch", "readback", "commit"]
+
+
+def _gauges(name, **labels):
+    """{scope: value} of the manifest gauge ``name`` with these labels."""
+    return {s["labels"]["scope"]: s["value"]
+            for s in hvd.metrics.snapshot()["gauges"].get(name, ())
+            if all(s["labels"].get(k) == v for k, v in labels.items())}
+
+
+def _counter(name, **labels):
+    return sum(s["value"]
+               for s in hvd.metrics.snapshot()["counters"].get(name, ())
+               if all(s["labels"].get(k) == v for k, v in labels.items()))
+
+
+# ---------------------------------------------------------------------------
+# the table
+# ---------------------------------------------------------------------------
+
+def _calls(tree):
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Call):
+            f = node.func
+            yield node, (f.attr if isinstance(f, ast.Attribute)
+                         else getattr(f, "id", ""))
+
+
+def _emitted_names():
+    """(file, line, name) of every span, scope, kernel name and tracing
+    counter in the package's source, and the places that go round the
+    facility."""
+    used, strays = [], []
+    for path in glob.glob(os.path.join(PKG, "**", "*.py"), recursive=True):
+        rel = os.path.relpath(path, PKG)
+        tree = ast.parse(open(path).read())
+        for node, fn in _calls(tree):
+            owner = getattr(getattr(node.func, "value", None), "id", "")
+            if fn in ("TraceAnnotation", "named_scope") \
+                    and rel != "tracing.py":
+                strays.append((rel, node.lineno, fn))
+            if fn in ("span", "scope", "timed") \
+                    and owner in ("tracing", "_tracing"):
+                arg = node.args[0]
+                if isinstance(arg, ast.BinOp):      # "engine." + phase
+                    assert rel == os.path.join("serving", "engine.py")
+                    used += [(rel, node.lineno, arg.left.value + p)
+                             for p in ENGINE_PHASES]
+                else:
+                    assert isinstance(arg, ast.Constant), (rel, node.lineno)
+                    used.append((rel, node.lineno, arg.value))
+                if fn == "timed":
+                    family = node.args[1].value
+                    used += [(rel, node.lineno, family + "_seconds_total"),
+                             (rel, node.lineno, family + "_total")]
+            if fn == "pallas_call":
+                used += [(rel, node.lineno, kw.value.value)
+                         for kw in node.keywords if kw.arg == "name"]
+            if rel == "tracing.py" and owner == "_metrics" \
+                    and fn in ("counter", "gauge"):
+                arg = node.args[0]
+                if isinstance(arg, ast.Constant):
+                    used.append((rel, node.lineno, arg.value))
+                else:                               # "grad_sync_" + what
+                    used += [(rel, node.lineno, arg.left.value + w)
+                             for w in ("bytes", "buckets", "passes")] \
+                        if isinstance(arg.left, ast.Constant) else []
+        if rel == "__init__.py":
+            used.append((rel, 0, "import_seconds"))
+    return used, strays
+
+
+def test_every_name_emitted_is_in_the_table():
+    used, strays = _emitted_names()
+    assert not strays, f"spans or scopes that go round tracing.py: {strays}"
+    unlisted = [u for u in used if u[2] not in tracing.NAMES]
+    assert not unlisted, f"emitted but not in tracing.NAMES: {unlisted}"
+    names = {u[2] for u in used}
+    assert set(TRAINER_SCOPES) | set(KERNELS) <= names
+    assert {"engine." + p for p in ENGINE_PHASES} <= names
+    # and the table lists nothing that is not emitted
+    assert set(tracing.NAMES) - names == set(), set(tracing.NAMES) - names
+
+
+def test_the_table_names_a_layer_and_a_reader_for_every_row():
+    for name, row in tracing.NAMES.items():
+        assert row.kind in ("span", "scope", "kernel", "counter", "gauge")
+        assert row.layer and row.covers and row.feeds, name
+        assert "\n" not in row.covers
+    phases = [n for n in tracing.NAMES if n.startswith("engine.")]
+    assert phases == ["engine.step"] + ["engine." + p for p in ENGINE_PHASES]
+
+
+def test_the_profiler_knob_is_gone():
+    from horovod_tpu import confbus, config
+    assert not hasattr(config.Config(), "trace_jax_profiler")
+    assert "HOROVOD_TRACE_JAX_PROFILER" not in confbus._IMMUTABLE_FIELDS
+
+
+# ---------------------------------------------------------------------------
+# the README step, lowered
+# ---------------------------------------------------------------------------
+
+def _step(devices, readme=True):
+    """The README train step (or the same with plain jax.value_and_grad)
+    lowered on ``devices``: its text, its program name, the manifest it
+    left, and the bytes of its parameter tree."""
+    hvd.init(devices=devices)
+    try:
+        cfg = GPT2Config.tiny(attention="flash", remat=True,
+                              remat_policy="dots")
+        model = GPT2(cfg)
+        tokens = jnp.zeros((2 * len(devices), 128), jnp.int32)
+        params = model.init(jax.random.PRNGKey(0), tokens[:1])
+        opt = hvd.DistributedOptimizer(optax.adamw(1e-3))
+        vg = hvd.value_and_grad if readme else jax.value_and_grad
+
+        def train_step(params, opt_state, tokens):
+            loss, grads = vg(
+                lambda p: loss_fn(model.apply(p, tokens), tokens))(params)
+            updates, opt_state = opt.update(grads, opt_state, params)
+            return optax.apply_updates(params, updates), opt_state, loss
+
+        step = hvd.spmd(train_step, in_specs=(P(), P(), P("hvd")),
+                        out_specs=(P(), P(), P()))
+        assert type(step) is type(jax.jit(lambda: 0))   # the bare jit
+        lowered = step.lower(params, opt.init(params), tokens)
+        return {
+            "text": lowered.as_text(debug_info=True),
+            "module": lowered.as_text().split("{", 1)[0],
+            "tree_bytes": sum(x.size * x.dtype.itemsize for x in
+                              jax.tree_util.tree_leaves(params)),
+            "bytes": _gauges("grad_sync_bytes", program="train_step"),
+            "buckets": _gauges("grad_sync_buckets", program="train_step"),
+            "passes": _gauges("grad_sync_passes", program="train_step"),
+        }
+    finally:
+        hvd.init()          # back onto the session's 8 CPU devices
+
+
+@pytest.fixture(scope="module")
+def readme_step():
+    return _step(jax.devices()[:2])
+
+
+@pytest.mark.parametrize("name", TRAINER_SCOPES + KERNELS)
+def test_lowered_readme_step_carries_the_name(readme_step, name):
+    assert name in readme_step["text"]
+
+
+def test_the_program_keeps_its_name(readme_step):
+    assert "@jit_train_step" in readme_step["module"]
+
+
+def test_manifest_of_the_readme_step_is_two_passes(readme_step):
+    tree = readme_step["tree_bytes"]
+    assert readme_step["passes"] == {"hvd/value_and_grad/sync": 1,
+                                     "hvd/optimizer/sync": 1}
+    assert readme_step["bytes"] == {"hvd/value_and_grad/sync": tree,
+                                    "hvd/optimizer/sync": tree}
+    assert sum(readme_step["bytes"].values()) == 2 * tree
+    assert all(b >= 1 for b in readme_step["buckets"].values())
+
+
+def test_manifest_is_the_last_lowering_not_a_sum():
+    """jax.value_and_grad + DistributedOptimizer syncs once; lowering that
+    step after the README one (same program name) replaces its manifest,
+    the scope that fell away reading 0."""
+    _step(jax.devices()[:2])
+    one = _step(jax.devices()[:2], readme=False)
+    assert one["passes"] == {"hvd/value_and_grad/sync": 0,
+                             "hvd/optimizer/sync": 1}
+    assert sum(one["bytes"].values()) == one["tree_bytes"]
+
+
+def test_manifest_counts_nothing_on_one_device():
+    alone = _step(jax.devices()[:1])
+    assert set(alone["bytes"]) == {"hvd/value_and_grad/sync",
+                                   "hvd/optimizer/sync"}
+    assert not any(alone["bytes"].values())
+    assert not any(alone["passes"].values())
+    assert not any(alone["buckets"].values())
+
+
+def test_a_sync_outside_hvd_spmd_leaves_no_manifest():
+    before = hvd.metrics.snapshot()["gauges"].get("grad_sync_bytes", [])
+    mapped = jax.shard_map(
+        lambda g: hvd.allreduce_gradients(g), mesh=hvd.mesh(),
+        in_specs=P(), out_specs=P(), check_vma=False)
+    jax.jit(mapped).lower(jnp.ones((4, 4)))
+    after = hvd.metrics.snapshot()["gauges"].get("grad_sync_bytes", [])
+    assert after == before
+
+
+# ---------------------------------------------------------------------------
+# the set-up ledger
+# ---------------------------------------------------------------------------
+
+def test_set_up_ledger_books_a_programs_phases_under_its_name():
+    def foo(x):
+        return hvd.allreduce(jnp.sin(x) * 2.0)
+
+    def bar(x):
+        return jnp.cos(x) * 3.0
+
+    hvd.spmd(foo)(jnp.ones((8, 16)))
+    jax.jit(bar)(jnp.ones((3, 5)))
+    for phase in ("trace", "lower", "backend"):
+        assert _counter("jax_compile_seconds_total", phase=phase,
+                        fun="foo") > 0, phase
+        assert _counter("jax_compile_total", phase=phase, fun="foo") >= 1
+        assert _counter("jax_compile_total", phase=phase, fun="other") >= 1
+    # a function the package did not build has no series of its own
+    assert _counter("jax_compile_total", fun="bar") == 0
+
+
+def test_cache_load_is_taken_out_of_the_backend_seconds():
+    tracing.note_program("baz")
+    event = "/jax/compilation_cache/cache_retrieval_time_sec"
+    tracing._on_jax_duration(event, 2.0)
+    tracing._on_jax_duration("/jax/core/compile/backend_compile_duration",
+                             2.5, fun_name="jit(baz)")
+    tracing._on_jax_duration("/jax/core/compile/backend_compile_duration",
+                             1.0, fun_name="jit(baz)")
+    tracing._on_jax_duration("/jax/some/other_duration", 9.0)
+    assert _counter("jax_compile_seconds_total", phase="cache_load",
+                    fun="baz") == 2.0
+    assert _counter("jax_compile_seconds_total", phase="backend",
+                    fun="baz") == 1.5
+    assert _counter("jax_compile_total", phase="backend", fun="baz") == 2
+    assert _counter("jax_compile_total", phase="cache_load", fun="baz") == 1
+
+
+def test_import_and_init_seconds_are_stamped():
+    import importlib
+    importlib.reload(hvd)       # the gauge is set when the package loads
+    hvd.init()
+    snap = hvd.metrics.snapshot()
+    assert snap["gauges"]["import_seconds"][0]["value"] > 0
+    assert snap["histograms"]["init_seconds"][0]["count"] >= 1
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's host plane
+# ---------------------------------------------------------------------------
+
+def _host_spans(trace_dir):
+    """[(name, start_ns, end_ns)] of the hvd: spans of a profiler trace."""
+    from jax.profiler import ProfileData
+    path = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                     recursive=True)[0]
+    spans = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:CPU"):
+            for line in plane.lines:
+                spans += [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                          for e in line.events
+                          if e.name.startswith(tracing.SPAN_PREFIX)]
+    return sorted(spans, key=lambda s: s[1])
+
+
+@pytest.fixture(scope="module")
+def traced_step(tmp_path_factory):
+    """One ``step_once`` of a warm tiny engine, and one eager collective,
+    under a profiler session."""
+    from horovod_tpu.serving import InferenceEngine
+    cfg = GPT2Config.tiny(dtype=jnp.float32)
+    model = GPT2(cfg)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.ones((1, 4), jnp.int32))["params"]
+    eng = InferenceEngine(model, params, slots=2, max_len=32, block_size=4,
+                          prefill_chunk=1, name="spans")
+    req = eng.submit(prompt=[1, 2, 3], max_new_tokens=8)
+    for _ in range(4):                      # compile, and start generating
+        eng.step_once()
+    out = str(tmp_path_factory.mktemp("trace"))
+    step, tokens = eng.step_count, len(req.tokens)
+    with jax.profiler.trace(out):
+        advanced = eng.step_once()
+        hvd.allreduce(np.ones((hvd.size(), 4), np.float32), name="probe")
+    assert advanced == 1 and len(req.tokens) == tokens + 1
+    assert eng.decode_compiles == 1
+    return {"spans": _host_spans(out), "step": step, "engine": eng}
+
+
+def test_step_once_leaves_its_phases_inside_one_step_span(traced_step):
+    spans = traced_step["spans"]
+    steps = [s for s in spans if s[0] == "hvd:engine.step"]
+    assert len(steps) == 1
+    _, lo, hi = steps[0]
+    inside = [s for s in spans
+              if s[0].startswith("hvd:engine.") and s is not steps[0]]
+    assert all(lo <= a and b <= hi for _, a, b in inside)
+    first_seen = list(dict.fromkeys(n for n, _, _ in inside))
+    assert first_seen == ["hvd:engine." + p for p in ENGINE_PHASES]
+    # no two phases overlap: the enclosing span is the cause, not a sibling
+    for (_, _, end), (_, start, _) in zip(inside, inside[1:]):
+        assert end <= start
+
+
+def test_an_eager_collective_is_one_collective_span(traced_step):
+    names = [n for n, _, _ in traced_step["spans"]]
+    assert names.count("hvd:collective") == 1
+
+
+@pytest.mark.parametrize("phase", ENGINE_PHASES)
+def test_each_engine_phase_has_its_counter_pair(traced_step, phase):
+    assert traced_step["engine"].step_count > traced_step["step"]
+    seconds = _counter("serve_step_phase_seconds_total", engine="spans",
+                       phase=phase)
+    count = _counter("serve_step_phase_total", engine="spans", phase=phase)
+    assert seconds > 0 and count >= 1

@@ -27,6 +27,7 @@ MODULES = [
     "horovod_tpu.config",
     "horovod_tpu.callbacks",
     "horovod_tpu.timeline",
+    "horovod_tpu.tracing",
     "horovod_tpu.autotune",
     "horovod_tpu.checkpoint",
     "horovod_tpu.checkpoint_sharded",
