@@ -37,8 +37,7 @@ def main(epochs: int = 2, steps_per_epoch: int = 10, batch: int = 32):
     # Horovod recipe: scale LR by size with warmup, then wrap the optimizer.
     sched = warmup_schedule(1e-3, warmup_epochs=1,
                             steps_per_epoch=steps_per_epoch)
-    opt = hvd.DistributedOptimizer(optax.adam(sched),
-                                   compression=hvd.Compression.bf16)
+    opt = hvd.DistributedOptimizer(optax.adam(sched))
     opt_state = opt.init(params)
 
     def train_step(params, opt_state, images, labels):
@@ -49,7 +48,10 @@ def main(epochs: int = 2, steps_per_epoch: int = 10, batch: int = 32):
             return -jnp.mean(jnp.take_along_axis(
                 jax.nn.log_softmax(logits), labels[:, None], 1))
 
-        loss, grads = hvd.value_and_grad(loss_fn)(params)
+        # The one gradient sync of the step (the optimizer is handed what
+        # it averaged and lowers no second pass), so the wire option is here.
+        loss, grads = hvd.value_and_grad(
+            loss_fn, compression=hvd.Compression.bf16)(params)
         updates, opt_state = opt.update(grads, opt_state, params)
         return optax.apply_updates(params, updates), opt_state, loss
 

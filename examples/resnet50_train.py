@@ -50,8 +50,7 @@ def main():
     params, batch_stats = variables["params"], variables["batch_stats"]
 
     sched = warmup_schedule(0.1, warmup_epochs=5, steps_per_epoch=args.steps)
-    opt = hvd.DistributedOptimizer(optax.sgd(sched, momentum=0.9),
-                                   compression=hvd.Compression.bf16)
+    opt = hvd.DistributedOptimizer(optax.sgd(sched, momentum=0.9))
     opt_state = opt.init(params)
 
     def train_step(params, batch_stats, opt_state, images, labels):
@@ -65,7 +64,10 @@ def main():
 
         (loss, batch_stats), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params, batch_stats)
-        grads = hvd.allreduce_gradients(grads)
+        # The one gradient sync of the step (the optimizer is handed what
+        # it averaged and lowers no second pass), so the wire option is here.
+        grads = hvd.allreduce_gradients(
+            grads, compression=hvd.Compression.bf16)
         updates, opt_state = opt.update(grads, opt_state, params)
         return optax.apply_updates(params, updates), batch_stats, \
             opt_state, loss

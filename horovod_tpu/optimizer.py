@@ -343,7 +343,28 @@ def allreduce_gradients(grads: Any, op: int = C.Average,
                         overlap_chunks: Optional[int] = None,
                         overlap: bool = False,
                         error_feedback: Any = None) -> Any:
-    """Fused allreduce of a gradient pytree (in-trace).
+    """Fused in-trace allreduce of gradients; skipped if already averaged.
+
+    **Synchronised once.** Under ``hvd.spmd`` every plain pass marks the
+    leaves it returns with what it gave (its op and process set), as does
+    ``hvd.grad(overlap=True)``. A later pass is skipped, returning
+    ``grads`` as they are, when *every* leaf of ``grads`` is such a
+    marked object (the very object: anything computed from it, a clip, a
+    scale, ``g + r``, a ``jit`` or ``cond`` boundary, is a new one), the
+    mark and this pass both say ``Average`` over the same process set,
+    and ``alive``, ``prescale_factor`` and ``postscale_factor`` are at
+    their defaults: the average of equal values is that value. This is
+    the README step, ``hvd.value_and_grad`` then
+    ``DistributedOptimizer.update``, which used to reduce every gradient
+    twice. ``Sum``, ``Adasum``, ``Min`` and ``Max`` are never skipped,
+    nor is the result of an ``alive`` pass ever marked. ``compression``,
+    ``algorithm``, ``overlap_chunks``, ``overlap`` and
+    ``fusion_threshold_bytes`` say how a pass travels, not what it
+    returns, so they do not keep a pass from being skipped: give them to
+    the call that synchronises. The gauge
+    ``grad_sync_skipped{program,scope}`` counts the passes skipped.
+    Outside ``hvd.spmd`` (a ``shard_map`` of your own) nothing is marked
+    and every pass lowers.
 
     ``alive`` implements the Join op for uneven data (upstream
     ``horovod/common/ops/../join``): pass a 0/1 scalar per device; dead
@@ -407,7 +428,8 @@ def allreduce_gradients(grads: Any, op: int = C.Average,
                    _reverse_issue=overlap)
     # The sync manifest (tracing.py) counts what this pass hands to
     # all-reduce, under the caller's scope; trace time only.
-    peers = C._resolve_ps(process_set).size()
+    ps = C._resolve_ps(process_set)
+    peers = ps.size()
     if alive is not None:
         if op not in (C.Average, C.Sum):
             raise ValueError("join-style allreduce supports Sum/Average only")
@@ -426,10 +448,22 @@ def allreduce_gradients(grads: Any, op: int = C.Average,
                 lambda g: g / n_alive.astype(g.dtype), summed)
         _maybe_record_grad_norm(summed)
         return summed
+    if (op == C.Average and prescale_factor == 1.0
+            and postscale_factor == 1.0
+            and _tracing.synced_as(grads) == (C.Average, ps)):
+        # Every leaf is the very object an earlier pass of this trace
+        # returned as the average over this process set: the average of
+        # equal values is that value, so this pass lowers nothing. The
+        # scope keeps its manifest entry, counted as skipped.
+        with _tracing.sync_pass(peers, skipped=True):
+            pass
+        _maybe_record_grad_norm(grads)
+        return grads
     with _tracing.sync_pass(peers):
         out = C.allreduce(grads, op=op, process_set=process_set,
                           prescale_factor=prescale_factor,
                           postscale_factor=postscale_factor, **comm_kw)
+    _tracing.mark_synced(out, (op, ps))
     _maybe_record_grad_norm(out)
     return out
 
@@ -447,11 +481,21 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
                          overlap: bool = False,
                          error_feedback: Optional[bool] = None,
                          ) -> optax.GradientTransformation:
-    """Wrap an optax optimizer so gradients are synchronized before the update
-    (``hvd.DistributedOptimizer``).
+    """Wrap an optax optimizer: ``update`` syncs gradients not yet averaged.
 
-    Use inside the jitted, shard_mapped train step; with jit auto-sharding it
-    degrades to the inner optimizer unchanged.
+    ``hvd.DistributedOptimizer``: gradients are synchronized before the
+    inner update. Use inside the jitted, shard_mapped train step; with jit
+    auto-sharding it degrades to the inner optimizer unchanged.
+
+    Handed the gradients ``hvd.value_and_grad`` / ``hvd.grad`` /
+    ``hvd.allreduce_gradients`` returned in the same ``hvd.spmd`` step,
+    untouched, an ``op=Average`` optimizer lowers no second pass (see
+    :func:`allreduce_gradients`, "Synchronised once"): the update runs on
+    the first pass's values, and this wrapper's ``compression``,
+    ``algorithm``, ``overlap_chunks``, ``overlap`` and
+    ``fusion_threshold_bytes`` then shape nothing. Wire options belong on
+    the call that synchronises; to have them apply here, take the
+    gradients from plain ``jax.value_and_grad``.
 
     ``backward_passes_per_step=k`` mirrors the upstream argument (local
     gradient accumulation: one allreduce per k backward passes, the
@@ -580,7 +624,10 @@ def grad(fun: Callable, argnums=0, op: int = C.Average,
         def wrapped(*args, **kwargs):
             g = gfun(*args, **kwargs)
             # The taps already synchronized every group; only telemetry
-            # remains.
+            # remains, and the mark that lets a later pass see it
+            # (outside an SPMD context the taps are identities).
+            if core.in_spmd_context():
+                _tracing.mark_synced(g, (op, C._resolve_ps(process_set)))
             _maybe_record_grad_norm(g)
             return g
         return wrapped
@@ -600,9 +647,15 @@ def grad(fun: Callable, argnums=0, op: int = C.Average,
 def value_and_grad(fun: Callable, argnums=0, op: int = C.Average,
                    process_set: Optional[ProcessSet] = None,
                    compression=Compression.none, **gradkw) -> Callable:
-    """Distributed ``jax.value_and_grad``; the value is also averaged so every
-    device reports the global loss (matches DistributedGradientTape +
-    MetricAverageCallback behaviour)."""
+    """Distributed ``jax.value_and_grad``: the README step's one gradient sync.
+
+    The value is also averaged so every device reports the global loss
+    (matches DistributedGradientTape + MetricAverageCallback behaviour).
+
+    This is the pass that travels when its gradients go on, untouched, to
+    an ``op=Average`` :func:`DistributedOptimizer` in the same ``hvd.spmd``
+    step: the optimizer's own pass is then skipped
+    (:func:`allreduce_gradients`), so ``compression`` belongs here."""
     vgfun = jax.value_and_grad(fun, argnums=argnums, **gradkw)
 
     def wrapped(*args, **kwargs):

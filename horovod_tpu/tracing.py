@@ -16,7 +16,10 @@ scope or a span counter:
   ``docs/OBSERVABILITY.md`` are written from it).
 * **The sync manifest** — what a program handed to all-reduce for its
   gradients, counted while the program is traced, from static shapes
-  (:func:`program`, :func:`sync_pass`, :func:`note_bucket`).
+  (:func:`program`, :func:`sync_pass`, :func:`note_bucket`), and which
+  leaves a finished pass of the same trace returned
+  (:func:`mark_synced`, :func:`synced_as`), so that a second pass over
+  them lowers nothing.
 * **The set-up ledger** — one ``jax.monitoring`` listener, registered when
   this module is imported, that adds jax's own trace / lower / backend /
   cache-load seconds to ``jax_compile_seconds_total{phase,fun}``.
@@ -60,7 +63,8 @@ from horovod_tpu import metrics as _metrics
 __all__ = ["Span", "mint_span", "current_span", "active_span",
            "reset_spans", "phase",
            "NAMES", "Name", "span", "scope", "timed", "current_scope",
-           "program", "note_program", "sync_pass", "note_bucket"]
+           "program", "note_program", "sync_pass", "note_bucket",
+           "mark_synced", "synced_as"]
 
 _LOCK = threading.Lock()
 _SEQ = 0
@@ -278,6 +282,10 @@ NAMES: Dict[str, Name] = {
     "grad_sync_passes": Name(
         "gauge", _TRAINER, "sync manifest: calls of allreduce_gradients "
         "that reached the wire; labels program, scope", "xprof only"),
+    "grad_sync_skipped": Name(
+        "gauge", _TRAINER, "sync manifest: calls of allreduce_gradients "
+        "that found every leaf averaged by an earlier pass of the trace "
+        "and lowered nothing; labels program, scope", "xprof only"),
     "jax_compile_seconds_total": Name(
         "counter", _COMPILER, "set-up ledger: seconds jax reports per "
         "phase (trace, lower, backend, cache_load) and function; an outer "
@@ -353,44 +361,52 @@ def note_program(name: str) -> None:
     _PROGRAMS.add(name)
 
 
+_COUNTS = ("bytes", "buckets", "passes", "skipped")
+
+
 @contextmanager
 def program(name: str):
     """The function ``name`` is being traced on this thread: gradient
     syncs inside belong to its manifest, published when the trace ends
-    as the gauges ``grad_sync_{bytes,buckets,passes}{program,scope}``.
-    The last trace's values: a program lowered twice is not counted
-    twice, and a second program does not add to the first's."""
-    prev = getattr(_TLS, "manifest", None)
+    as the gauges ``grad_sync_{bytes,buckets,passes,skipped}{program,
+    scope}``. The last trace's values: a program lowered twice is not
+    counted twice, and a second program does not add to the first's.
+    The leaves marked by :func:`mark_synced` are kept until the trace
+    ends and no longer, so that no tracer outlives its trace."""
+    prev = getattr(_TLS, "manifest", None), getattr(_TLS, "synced", None)
     manifest: Dict[str, list] = {}
-    _TLS.manifest = manifest
+    _TLS.manifest, _TLS.synced = manifest, {}
     try:
         yield
     finally:
-        _TLS.manifest = prev
+        _TLS.manifest, _TLS.synced = prev
         with _LOCK:
             stale = _PUBLISHED.get(name, set()) - set(manifest)
             _PUBLISHED[name] = set(manifest)
         for sc in stale:
-            manifest[sc] = [0, 0, 0]
-        for sc, (nbytes, buckets, passes) in manifest.items():
-            for what, v in (("bytes", nbytes), ("buckets", buckets),
-                            ("passes", passes)):
+            manifest[sc] = [0] * len(_COUNTS)
+        for sc, counts in manifest.items():
+            for what, v in zip(_COUNTS, counts):
                 _metrics.gauge("grad_sync_" + what, program=name,
                                scope=sc).set(v)
 
 
 @contextmanager
-def sync_pass(peers: int):
+def sync_pass(peers: int, skipped: bool = False):
     """One gradient sync over ``peers`` devices is being lowered, called
     from :func:`current_scope`. With one device nothing reaches the
-    wire and the pass counts nothing (the scope still gets its zeros)."""
+    wire and the pass counts nothing (the scope still gets its zeros).
+    ``skipped``: the pass found its gradients synchronised already
+    (:func:`synced_as`) and lowers nothing; it counts as that."""
     manifest = getattr(_TLS, "manifest", None)
     if manifest is None:
         yield
         return
-    entry = manifest.setdefault(current_scope(), [0, 0, 0])
+    entry = manifest.setdefault(current_scope(), [0] * len(_COUNTS))
     prev = getattr(_TLS, "sync_entry", None)
-    if peers > 1:
+    if skipped:
+        entry[3] += 1
+    elif peers > 1:
         entry[2] += 1
         _TLS.sync_entry = entry
     try:
@@ -406,6 +422,39 @@ def note_bucket(nbytes: int) -> None:
     if entry is not None:
         entry[0] += int(nbytes)
         entry[1] += 1
+
+
+def mark_synced(tree: Any, what: Any) -> None:
+    """Every leaf of ``tree`` is what a finished gradient sync of this
+    trace returned; ``what`` says what the sync gave (its op and its
+    resolved process set). Kept by the leaf object itself (a strong
+    reference keyed by ``id``, so that the ``id`` cannot be re-used while
+    it is a key) until :func:`program` exits. Outside a program nothing
+    is kept."""
+    synced = getattr(_TLS, "synced", None)
+    if synced is not None:
+        for leaf in jax.tree_util.tree_leaves(tree):
+            synced[id(leaf)] = (leaf, what)
+
+
+def synced_as(tree: Any) -> Any:
+    """What :func:`mark_synced` said of the leaves of ``tree``, if it said
+    the same of every one of them (the very objects, compared with
+    ``is``: anything computed from a marked leaf is a new object and not
+    marked); else None. All or nothing: one new leaf, and the tree is not
+    synchronised."""
+    synced = getattr(_TLS, "synced", None)
+    if not synced:
+        return None
+    said = []
+    for leaf in jax.tree_util.tree_leaves(tree):
+        kept = synced.get(id(leaf))
+        if kept is None or kept[0] is not leaf:
+            return None
+        said.append(kept[1])
+    if not said or any(what != said[0] for what in said):
+        return None
+    return said[0]
 
 
 # ---------------------------------------------------------------------------

@@ -91,7 +91,7 @@ def _emitted_names():
                     used.append((rel, node.lineno, arg.value))
                 else:                               # "grad_sync_" + what
                     used += [(rel, node.lineno, arg.left.value + w)
-                             for w in ("bytes", "buckets", "passes")] \
+                             for w in tracing._COUNTS] \
                         if isinstance(arg.left, ast.Constant) else []
         if rel == "__init__.py":
             used.append((rel, 0, "import_seconds"))
@@ -129,10 +129,12 @@ def test_the_profiler_knob_is_gone():
 # the README step, lowered
 # ---------------------------------------------------------------------------
 
-def _step(devices, readme=True):
+def _step(devices, readme=True, touch=False):
     """The README train step (or the same with plain jax.value_and_grad)
     lowered on ``devices``: its text, its program name, the manifest it
-    left, and the bytes of its parameter tree."""
+    left, and the bytes of its parameter tree. ``touch`` multiplies the
+    gradients by one between the two syncs: new objects, so that both
+    passes lower, as they did before a pass could be skipped."""
     hvd.init(devices=devices)
     try:
         cfg = GPT2Config.tiny(attention="flash", remat=True,
@@ -146,6 +148,8 @@ def _step(devices, readme=True):
         def train_step(params, opt_state, tokens):
             loss, grads = vg(
                 lambda p: loss_fn(model.apply(p, tokens), tokens))(params)
+            if touch:
+                grads = jax.tree_util.tree_map(lambda g: g * 1, grads)
             updates, opt_state = opt.update(grads, opt_state, params)
             return optax.apply_updates(params, updates), opt_state, loss
 
@@ -161,6 +165,8 @@ def _step(devices, readme=True):
             "bytes": _gauges("grad_sync_bytes", program="train_step"),
             "buckets": _gauges("grad_sync_buckets", program="train_step"),
             "passes": _gauges("grad_sync_passes", program="train_step"),
+            "skipped": _gauges("grad_sync_skipped", program="train_step"),
+            "all_reduces": lowered.as_text().count("stablehlo.all_reduce"),
         }
     finally:
         hvd.init()          # back onto the session's 8 CPU devices
@@ -171,23 +177,50 @@ def readme_step():
     return _step(jax.devices()[:2])
 
 
+@pytest.fixture(scope="module")
+def twice_step():
+    return _step(jax.devices()[:2], touch=True)
+
+
 @pytest.mark.parametrize("name", TRAINER_SCOPES + KERNELS)
-def test_lowered_readme_step_carries_the_name(readme_step, name):
-    assert name in readme_step["text"]
+def test_lowered_readme_step_carries_the_name(readme_step, twice_step, name):
+    assert name in twice_step["text"]
+    # the optimizer's pass is skipped on the README path: nothing is
+    # lowered in its scope, so the text has no operation to carry it
+    assert (name in readme_step["text"]) == (name != "hvd/optimizer/sync")
 
 
 def test_the_program_keeps_its_name(readme_step):
     assert "@jit_train_step" in readme_step["module"]
 
 
-def test_manifest_of_the_readme_step_is_two_passes(readme_step):
+def test_manifest_of_the_readme_step_is_one_pass(readme_step):
+    """hvd.value_and_grad averages; DistributedOptimizer.update is handed
+    the very leaves it returned and lowers nothing: both scopes keep their
+    entry, the second with zeros and one skipped pass."""
     tree = readme_step["tree_bytes"]
     assert readme_step["passes"] == {"hvd/value_and_grad/sync": 1,
-                                     "hvd/optimizer/sync": 1}
+                                     "hvd/optimizer/sync": 0}
     assert readme_step["bytes"] == {"hvd/value_and_grad/sync": tree,
-                                    "hvd/optimizer/sync": tree}
-    assert sum(readme_step["bytes"].values()) == 2 * tree
-    assert all(b >= 1 for b in readme_step["buckets"].values())
+                                    "hvd/optimizer/sync": 0}
+    assert readme_step["skipped"] == {"hvd/value_and_grad/sync": 0,
+                                      "hvd/optimizer/sync": 1}
+    assert readme_step["buckets"]["hvd/value_and_grad/sync"] >= 1
+    assert readme_step["buckets"]["hvd/optimizer/sync"] == 0
+
+
+def test_lowered_readme_step_holds_half_the_all_reduces(readme_step,
+                                                        twice_step):
+    """Beside the same step with its gradients touched in between (two
+    passes, what every README step lowered before): the loss's one
+    all-reduce apart, half as many."""
+    twice = twice_step
+    assert twice["passes"] == {"hvd/value_and_grad/sync": 1,
+                               "hvd/optimizer/sync": 1}
+    assert not any(twice["skipped"].values())
+    assert sum(twice["bytes"].values()) == 2 * twice["tree_bytes"]
+    once = readme_step["all_reduces"] - 1
+    assert once >= 1 and twice["all_reduces"] - 1 == 2 * once
 
 
 def test_manifest_is_the_last_lowering_not_a_sum():
@@ -198,6 +231,7 @@ def test_manifest_is_the_last_lowering_not_a_sum():
     one = _step(jax.devices()[:2], readme=False)
     assert one["passes"] == {"hvd/value_and_grad/sync": 0,
                              "hvd/optimizer/sync": 1}
+    assert not any(one["skipped"].values())
     assert sum(one["bytes"].values()) == one["tree_bytes"]
 
 
@@ -208,6 +242,9 @@ def test_manifest_counts_nothing_on_one_device():
     assert not any(alone["bytes"].values())
     assert not any(alone["passes"].values())
     assert not any(alone["buckets"].values())
+    # the skip sees objects, not the wire: it engages on one device too
+    assert alone["skipped"] == {"hvd/value_and_grad/sync": 0,
+                                "hvd/optimizer/sync": 1}
 
 
 def test_a_sync_outside_hvd_spmd_leaves_no_manifest():
