@@ -4,7 +4,9 @@ flax for TPU (bf16 compute, MXU-friendly shapes), not ported from the
 reference's TF/torch example scripts. Plus the Llama family (RoPE +
 RMSNorm + SwiGLU + GQA, optional Mixtral-style MoE) and the T5
 encoder-decoder family for modern-LLM migrations — all three
-architecture classes (decoder-only, encoder-only, encoder-decoder).
+architecture classes (decoder-only, encoder-only, encoder-decoder) — and a
+block-diffusion decoder with dropless routed experts (``models/sdar.py``,
+training only).
 """
 
 from horovod_tpu.models.mnist import MnistCNN  # noqa: F401

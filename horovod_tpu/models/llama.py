@@ -35,7 +35,7 @@ from horovod_tpu.parallel.sharding import PartitionRules
 
 
 __all__ = ["Llama", "LlamaConfig", "loss_fn", "loss_fn_moe",
-           "partition_rules", "apply_rope"]
+           "partition_rules", "apply_rope", "repeat_kv"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -109,6 +109,16 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
 
 
+def repeat_kv(k: jnp.ndarray, v: jnp.ndarray, num_heads: int):
+    """Grouped-query attention: expand the key/value heads of (B, T, Hkv, D)
+    to ``num_heads``, each serving ``num_heads // Hkv`` query heads in
+    order, so that the attention ops see plain multi-head shapes."""
+    q_per_kv = num_heads // k.shape[2]
+    if q_per_kv == 1:
+        return k, v
+    return jnp.repeat(k, q_per_kv, axis=2), jnp.repeat(v, q_per_kv, axis=2)
+
+
 class RMSNorm(nn.Module):
     """fp32 root-mean-square norm with a learned scale (no mean removal)."""
     eps: float = 1e-6
@@ -140,10 +150,7 @@ class Attention(nn.Module):
                      name="wv")(x).reshape(B, T, Hkv, hd)
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
-        if Hkv != H:                 # GQA: expand kv heads to MHA shapes
-            q_per_kv = H // Hkv
-            k = jnp.repeat(k, q_per_kv, axis=2)
-            v = jnp.repeat(v, q_per_kv, axis=2)
+        k, v = repeat_kv(k, v, H)    # GQA: expand kv heads to MHA shapes
         from horovod_tpu.ops.attention import sp_attention
         o = sp_attention(q, k, v, cfg, segment_ids=segment_ids)
         return nn.Dense(D, use_bias=False, dtype=cfg.dtype,

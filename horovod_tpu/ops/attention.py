@@ -16,7 +16,7 @@ import jax.numpy as jnp
 
 __all__ = ["multihead_attention", "ATTENTION_IMPLS", "validate_sp_config",
            "sp_global_positions", "sp_attention", "packed_positions",
-           "segment_mask"]
+           "segment_mask", "block_diffusion_mask"]
 
 ATTENTION_IMPLS = ("dense", "flash")
 
@@ -30,7 +30,9 @@ def multihead_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         out_dtype: Optional[jnp.dtype] = None,
                         flash_blocks: Optional[tuple] = None,
                         bias: Optional[jnp.ndarray] = None,
-                        scale: Optional[float] = None) -> jnp.ndarray:
+                        scale: Optional[float] = None,
+                        block_diffusion: Optional[tuple] = None
+                        ) -> jnp.ndarray:
     """softmax(q k^T * scale [+ bias + masks]) v over (B, T, H, D).
 
     Args:
@@ -55,6 +57,10 @@ def multihead_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         passing one with impl="flash" raises.
       scale: logit scale override; default ``1/sqrt(head_dim)`` (T5
         famously uses 1.0 — folded into its initializer).
+      block_diffusion: optional static ``(seq_len, block_len)``: the rows
+        are ``[noisy ; clean]``, ``2 * seq_len`` positions, masked by
+        :func:`block_diffusion_mask`. Both impls; the flash kernels skip
+        the tiles that hold no visible pair.
 
     Returns (B, T_q, H, D).
     """
@@ -81,6 +87,7 @@ def multihead_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         return flash_attention(q, k, v, causal=causal, scale=scale,
                                key_bias=key_bias,
                                segment_ids=segment_ids,
+                               block_diffusion=block_diffusion,
                                **blocks).astype(out_dtype)
 
     scale = d ** -0.5 if scale is None else scale
@@ -96,6 +103,16 @@ def multihead_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if causal:
         tq, tk = q.shape[1], k.shape[1]
         mask = jnp.tril(jnp.ones((tq, tk), bool))
+        s = jnp.where(mask[None, None], s, _NEG_INF)
+    if block_diffusion is not None:
+        seq_len, block_len = block_diffusion
+        if q.shape[1] != 2 * seq_len or k.shape[1] != 2 * seq_len:
+            raise ValueError(
+                f"block_diffusion=({seq_len}, {block_len}) needs "
+                f"{2 * seq_len} positions, got {q.shape[1]} x {k.shape[1]}")
+        pos = jnp.arange(2 * seq_len, dtype=jnp.int32)
+        mask = block_diffusion_mask(pos[:, None], pos[None, :], seq_len,
+                                    block_len)
         s = jnp.where(mask[None, None], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(out_dtype)
     if key_mask is not None or segment_ids is not None:
@@ -161,6 +178,36 @@ def segment_mask(seg_q: jnp.ndarray, seg_k: jnp.ndarray) -> jnp.ndarray:
     segment. THE definition of cross-document blocking; every dense path
     (local, ring step, ulysses) masks through this one helper."""
     return seg_q[:, :, None] == seg_k[:, None, :]
+
+
+def block_diffusion_mask(q_pos, k_pos, seq_len: int, block_len: int):
+    """Visibility under block-diffusion training, where a row is
+    ``[noisy ; clean]``: positions ``0 .. seq_len-1`` are the noised copy,
+    ``seq_len .. 2*seq_len-1`` the clean one, and both count their blocks of
+    ``block_len`` from their own start. A noisy query sees the noisy keys of
+    its own block and the clean keys of the blocks before it; a clean query
+    sees the clean keys of its own block and of those before it, and no
+    noisy key. THE definition: the dense path and the three flash kernels
+    mask through it. ``q_pos`` ``(Tq, 1)`` and ``k_pos`` ``(1, Tk)`` int32
+    (or any shapes that broadcast); returns their broadcast, bool."""
+    if block_len & (block_len - 1):
+        block_of = lambda x: jax.lax.div(x, jnp.int32(block_len))
+    else:       # a shift: no vector division inside a kernel
+        shift = block_len.bit_length() - 1
+        block_of = lambda x: jax.lax.shift_right_logical(x, jnp.int32(shift))
+    q_noisy, k_noisy = q_pos < seq_len, k_pos < seq_len
+    q_blk = block_of(jnp.where(q_noisy, q_pos, q_pos - seq_len))
+    k_blk = block_of(jnp.where(k_noisy, k_pos, k_pos - seq_len))
+    # Two comparisons of a column with a row, in integers (a select between
+    # booleans does not lower in a kernel). A noisy key is seen by the noisy
+    # queries of its own block: a clean query's block reads -1 and a clean
+    # key's -2 here, which match nothing. A clean key is seen from the
+    # blocks after it, and from its own by a clean query: a noisy key's
+    # block reads as past every block.
+    same = jnp.where(q_noisy, q_blk, -1) == jnp.where(k_noisy, k_blk, -2)
+    before = (jnp.where(k_noisy, jnp.int32(2 ** 30), k_blk)
+              < q_blk + jnp.where(q_noisy, 0, 1))
+    return same | before
 
 
 def packed_positions(segment_ids: jnp.ndarray) -> jnp.ndarray:

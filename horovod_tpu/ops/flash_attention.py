@@ -42,6 +42,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from horovod_tpu import tracing as _tracing
+from horovod_tpu.ops.attention import block_diffusion_mask
 
 __all__ = ["flash_attention"]
 
@@ -63,14 +64,17 @@ def _block_sizes(tq: int, tk: int, block_q: int, block_k: int):
 
 
 def _mask_scores(s, q_blk, kv_blk, *, block_q, block_k, tq, tk, causal,
-                 offset=0, bias=None, seg_q=None, seg_k=None):
-    """Apply causal / ragged-edge / key-bias masking to a score block.
+                 offset=0, bias=None, seg_q=None, seg_k=None, bd=None):
+    """Apply causal / block-diffusion / ragged-edge / key-bias masking to a
+    score block.
 
     Shared by the forward and both backward kernels so the mask definition
     cannot diverge between passes. ``s`` is (block_q, block_k) fp32.
     ``offset`` shifts the causal diagonal: visible iff
     ``q_pos + offset >= k_pos`` (offset -1 = strict causal — what striped
-    ring layouts need for the src > rank blocks).
+    ring layouts need for the src > rank blocks). ``bd`` is the static
+    ``(seq_len, block_len)`` of a block-diffusion row ``[noisy ; clean]``
+    (``ops.attention.block_diffusion_mask``).
     """
     need_pos = causal or tq % block_q or tk % block_k
     if bias is not None:
@@ -87,6 +91,14 @@ def _mask_scores(s, q_blk, kv_blk, *, block_q, block_k, tq, tk, causal,
         if causal:
             ok = jnp.logical_and(ok, q_pos + offset >= k_pos)
         s = jnp.where(ok, s, _NEG_INF)
+    if bd is not None:
+        # positions past the ragged edge were masked above; here a column of
+        # query positions against a row of key positions
+        q_pos = (q_blk * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (s.shape[0], 1), 0))
+        k_pos = (kv_blk * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (1, s.shape[1]), 1))
+        s = jnp.where(block_diffusion_mask(q_pos, k_pos, *bd), s, _NEG_INF)
     return s
 
 
@@ -111,6 +123,47 @@ def _causal_skip(causal: bool, q_blk, kv_idx, block_q: int, block_k: int,
         kv_idx * block_k < (q_blk + 1) * block_q + offset)
 
 
+def _bd_skip(q_blk, kv_idx, block_q: int, block_k: int, seq: int,
+             blk: int, xp=jnp):
+    """True when this (q, kv) block pair of a block-diffusion row
+    ``[noisy ; clean]`` holds a visible pair: the noisy parts of both share
+    a block, or a clean key lies in a block before a noisy query's, or not
+    after a clean query's. Scalars in a kernel; arrays of tile indices and
+    ``xp=np`` in :func:`bd_tiles`."""
+    q0, k0 = q_blk * block_q, kv_idx * block_k
+    q1 = xp.minimum(q0 + block_q, 2 * seq) - 1        # last position held
+    k1 = xp.minimum(k0 + block_k, 2 * seq) - 1
+    q_noisy, k_noisy = q0 < seq, k0 < seq               # holds a noisy part
+    q_clean, k_clean = q1 >= seq, k1 >= seq             # holds a clean part
+    qn_lo, qn_hi = q0 // blk, xp.minimum(q1, seq - 1) // blk
+    kn_lo, kn_hi = k0 // blk, xp.minimum(k1, seq - 1) // blk
+    qc_hi = (xp.maximum(q1, seq) - seq) // blk
+    kc_lo = (xp.maximum(k0, seq) - seq) // blk
+    return ((q_noisy & k_noisy & (qn_lo <= kn_hi) & (kn_lo <= qn_hi))
+            | (q_noisy & k_clean & (kc_lo < qn_hi))
+            | (q_clean & k_clean & (kc_lo <= qc_hi)))
+
+
+def _tile_visible(causal: bool, bd, q_blk, kv_idx, block_q: int,
+                  block_k: int, offset: int = 0):
+    """The tile skip of the three kernels: the block-diffusion one for a
+    ``bd`` row, else the causal one."""
+    if bd is not None:
+        return _bd_skip(q_blk, kv_idx, block_q, block_k, *bd)
+    return _causal_skip(causal, q_blk, kv_idx, block_q, block_k, offset)
+
+
+def bd_tiles(seq: int, blk: int, block_q: int, block_k: int):
+    """``(visited, total)`` tiles of one head's forward grid over a
+    block-diffusion row of ``2 * seq`` positions, from shapes alone (the
+    routing manifest's ``bd_tiles_visited`` / ``bd_tiles_total``)."""
+    bq, bk = _block_sizes(2 * seq, 2 * seq, block_q, block_k)
+    nq, nk = -(-2 * seq // bq), -(-2 * seq // bk)
+    hit = _bd_skip(np.arange(nq)[:, None], np.arange(nk)[None, :], bq, bk,
+                   seq, blk, xp=np)
+    return int(np.sum(hit)), nq * nk
+
+
 # ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
@@ -118,7 +171,7 @@ def _causal_skip(causal: bool, q_blk, kv_idx, block_q: int, block_k: int,
 def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref, o_ref,
                 lse_ref, acc_ref, m_ref, l_ref, *, scale: float,
                 causal: bool, offset: int, block_q: int, block_k: int,
-                tq: int, tk: int):
+                tq: int, tk: int, bd=None):
     kv_idx = pl.program_id(2)
     num_kv = pl.num_programs(2)
 
@@ -130,7 +183,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref, o_ref,
 
     q_blk = pl.program_id(1)
 
-    @pl.when(_causal_skip(causal, q_blk, kv_idx, block_q, block_k, offset))
+    @pl.when(_tile_visible(causal, bd, q_blk, kv_idx, block_q, block_k,
+                           offset))
     def _():
         q = _zero_oob_rows(q_ref[0].astype(jnp.float32) * scale,
                            q_blk, block_q, tq)
@@ -141,7 +195,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref, o_ref,
         seg_k = None if segk_ref is None else segk_ref[0]
         s = _mask_scores(s, q_blk, kv_idx, block_q=block_q, block_k=block_k,
                          tq=tq, tk=tk, causal=causal, offset=offset,
-                         bias=bias, seg_q=seg_q, seg_k=seg_k)
+                         bias=bias, seg_q=seg_q, seg_k=seg_k, bd=bd)
 
         m_prev = m_ref[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
@@ -185,7 +239,7 @@ _seg_k_spec = _per_key_spec
 
 
 def _fwd(q, k, v, bias, seg_q, seg_k, h, scale, causal, block_q, block_k,
-         offset=0):
+         offset=0, bd=None):
     bh, tq, d = q.shape
     tk = k.shape[1]
     bq, bk = _block_sizes(tq, tk, block_q, block_k)
@@ -193,7 +247,7 @@ def _fwd(q, k, v, bias, seg_q, seg_k, h, scale, causal, block_q, block_k,
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, offset=offset, block_q=bq,
-        block_k=bk, tq=tq, tk=tk)
+        block_k=bk, tq=tq, tk=tk, bd=bd)
     in_specs = [
         pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
@@ -267,7 +321,7 @@ def _fill_optionals(kernel, has_bias, has_seg):
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
                    do_ref, lse_ref, delta_ref, dq_ref, acc_ref, *,
                    scale: float, causal: bool, offset: int, block_q: int,
-                   block_k: int, tq: int, tk: int):
+                   block_k: int, tq: int, tk: int, bd=None):
     kv_idx = pl.program_id(2)
     num_kv = pl.num_programs(2)
 
@@ -277,7 +331,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
 
     q_blk = pl.program_id(1)
 
-    @pl.when(_causal_skip(causal, q_blk, kv_idx, block_q, block_k, offset))
+    @pl.when(_tile_visible(causal, bd, q_blk, kv_idx, block_q, block_k,
+                           offset))
     def _():
         q = _zero_oob_rows(q_ref[0].astype(jnp.float32) * scale,
                            q_blk, block_q, tq)
@@ -288,7 +343,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
         seg_k = None if segk_ref is None else segk_ref[0]
         s = _mask_scores(s, q_blk, kv_idx, block_q=block_q, block_k=block_k,
                          tq=tq, tk=tk, causal=causal, offset=offset,
-                         bias=bias, seg_q=seg_q, seg_k=seg_k)
+                         bias=bias, seg_q=seg_q, seg_k=seg_k, bd=bd)
         p = jnp.exp(s - lse_ref[0])
         p = jnp.where(s > _NEG_INF / 2, p, 0.0)
         do = _zero_oob_rows(do_ref[0].astype(jnp.float32), q_blk, block_q, tq)
@@ -308,7 +363,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
                     do_ref, lse_ref, delta_ref, dk_ref, dv_ref, db_ref,
                     dk_acc, dv_acc, db_acc, *, scale: float, causal: bool,
                     offset: int, block_q: int, block_k: int, tq: int,
-                    tk: int):
+                    tk: int, bd=None):
     q_idx = pl.program_id(2)
     num_q = pl.num_programs(2)
 
@@ -321,7 +376,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
 
     kv_blk = pl.program_id(1)
 
-    @pl.when(_causal_skip(causal, q_idx, kv_blk, block_q, block_k, offset))
+    @pl.when(_tile_visible(causal, bd, q_idx, kv_blk, block_q, block_k,
+                           offset))
     def _():
         q = _zero_oob_rows(q_ref[0].astype(jnp.float32) * scale,
                            q_idx, block_q, tq)
@@ -332,7 +388,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
         seg_k = None if segk_ref is None else segk_ref[0]
         s = _mask_scores(s, q_idx, kv_blk, block_q=block_q, block_k=block_k,
                          tq=tq, tk=tk, causal=causal, offset=offset,
-                         bias=bias, seg_q=seg_q, seg_k=seg_k)
+                         bias=bias, seg_q=seg_q, seg_k=seg_k, bd=bd)
         p = jnp.exp(s - lse_ref[0])
         p = jnp.where(s > _NEG_INF / 2, p, 0.0)
         do = _zero_oob_rows(do_ref[0].astype(jnp.float32), q_idx, block_q, tq)
@@ -357,7 +413,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
 
 
 def _bwd(h, scale, causal, block_q, block_k, res, do, delta=None,
-         offset=0, want_db=True):
+         offset=0, want_db=True, bd=None):
     q, k, v, bias, seg_q, seg_k, o, lse = res
     bh, tq, d = q.shape
     tk = k.shape[1]
@@ -370,7 +426,7 @@ def _bwd(h, scale, causal, block_q, block_k, res, do, delta=None,
                         axis=-1, keepdims=True)
 
     common = dict(scale=scale, causal=causal, offset=offset, block_q=bq,
-                  block_k=bk, tq=tq, tk=tk)
+                  block_k=bk, tq=tq, tk=tk, bd=bd)
 
     dq_kernel = functools.partial(_bwd_dq_kernel, **common)
     dkv_kernel = functools.partial(_bwd_dkv_kernel, **common)
@@ -487,28 +543,28 @@ def _bwd(h, scale, causal, block_q, block_k, res, do, delta=None,
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11,
-                                                    12))
+                                                    12, 13))
 def _flash(q, k, v, bias, seg, h, scale, causal, block_q, block_k,
-           block_q_bwd, block_k_bwd, offset):
+           block_q_bwd, block_k_bwd, offset, bd):
     o, _ = _fwd(q, k, v, bias, seg, seg, h, scale, causal, block_q,
-                block_k, offset=offset)
+                block_k, offset=offset, bd=bd)
     return o
 
 
 def _flash_fwd(q, k, v, bias, seg, h, scale, causal, block_q, block_k,
-               block_q_bwd, block_k_bwd, offset):
+               block_q_bwd, block_k_bwd, offset, bd):
     o, lse = _fwd(q, k, v, bias, seg, seg, h, scale, causal, block_q,
-                  block_k, offset=offset)
+                  block_k, offset=offset, bd=bd)
     return o, (q, k, v, bias, seg, seg, o, lse)
 
 
 def _flash_bwd(h, scale, causal, block_q, block_k, block_q_bwd,
-               block_k_bwd, offset, res, do):
+               block_k_bwd, offset, bd, res, do):
     # The backward kernels' VMEM profile differs from the forward's (two
     # extra fp32 accumulators per tile), so they may want their own tiles
     # — measured entries carry them (tile_table "tuned-*-fwdbwd").
     dq, dk, dv, dbias = _bwd(h, scale, causal, block_q_bwd, block_k_bwd,
-                             res, do, offset=offset)
+                             res, do, offset=offset, bd=bd)
     seg = res[4]  # res = (q, k, v, bias, seg, seg, o, lse)
     # Integer segment ids take a symbolic-zero (float0) cotangent.
     dseg = (None if seg is None
@@ -527,7 +583,8 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     block_k: Optional[int] = None,
                     block_q_bwd: Optional[int] = None,
                     block_k_bwd: Optional[int] = None,
-                    causal_offset: int = 0) -> jnp.ndarray:
+                    causal_offset: int = 0,
+                    block_diffusion: Optional[tuple] = None) -> jnp.ndarray:
     """Fused attention ``softmax(q k^T * scale + key_bias [+ mask]) v``.
 
     Args:
@@ -547,6 +604,11 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
       causal_offset: shifts the causal diagonal — visible iff
         ``i + causal_offset >= j`` (−1 = strict causal; used by striped
         ring layouts). Only meaningful with ``causal=True``.
+      block_diffusion: optional static ``(seq_len, block_len)``: the rows
+        are block-diffusion training rows ``[noisy ; clean]`` of
+        ``2 * seq_len`` positions (``ops.attention.block_diffusion_mask``
+        says who sees whom); the kernels mask score tiles by position and
+        skip the tiles that hold no visible pair. Not with ``causal``.
       block_q, block_k: tile sizes (clamped to the sequence lengths).
         ``None`` (default) consults the checked-in tile table
         (``ops/tile_table.py``, regenerated by ``autotune_flash_blocks``)
@@ -568,12 +630,22 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if causal and tq != tk:
         raise ValueError(f"causal flash attention needs t_q == t_kv, "
                          f"got {tq} != {tk}")
+    bd = None
+    if block_diffusion is not None:
+        bd = (int(block_diffusion[0]), int(block_diffusion[1]))
+        if causal or tq != tk or tq != 2 * bd[0] or bd[0] % bd[1]:
+            raise ValueError(
+                f"block_diffusion={bd} needs non-causal self-attention "
+                f"over 2 * seq_len positions in whole blocks, got "
+                f"causal={causal}, t_q={tq}, t_kv={tk}")
     scale = d ** -0.5 if scale is None else scale
 
     if None in (block_q, block_k, block_q_bwd, block_k_bwd):
         from horovod_tpu.ops import tile_table
+        kind = ("block_diffusion" if bd is not None
+                else "causal" if causal else "full")
         tq_, tk_, tqb_, tkb_ = tile_table.lookup_full(
-            d, max(tq, tk), q.dtype, "causal" if causal else "full")
+            d, max(tq, tk), q.dtype, kind)
         block_q = tq_ if block_q is None else block_q
         block_k = tk_ if block_k is None else block_k
         # Explicit fwd tiles with no explicit bwd tiles: share the fwd
@@ -608,5 +680,9 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
     o = _flash(pack(q), pack(k), pack(v), key_bias, seg, h, float(scale),
                bool(causal), int(block_q), int(block_k),
-               int(block_q_bwd), int(block_k_bwd), int(causal_offset))
+               int(block_q_bwd), int(block_k_bwd), int(causal_offset), bd)
+    if bd is not None:
+        visited, total = bd_tiles(bd[0], bd[1], int(block_q), int(block_k))
+        _tracing.note_routing(bd_tiles_visited=visited,
+                              bd_tiles_total=total)
     return o.reshape(b, h, tq, d).transpose(0, 2, 1, 3)

@@ -17,18 +17,35 @@ PAPERS.md):
   in bf16 on the MXU.
 - Auxiliary load-balance loss (Switch eq. 4) keeps routing uniform; it is
   returned so the model can add it to the objective.
+
+Beside the capacity routers stands the dropless layer, ``RoutedExperts``
+(``routed_share`` is its arithmetic): top-k of many experts, no capacity
+and no dropped assignment, for models whose published routing drops nothing
+and whose references therefore drop nothing. It is told which experts it
+holds, routes over all of them and computes the part of the result that its
+own experts give: the local assignments are sorted by expert and go through
+three grouped products (``jax.lax.ragged_dot``: XLA's own grouped kernel on
+a TPU) shaped for the worst case, ``positions x min(top_k, held)`` rows, of
+which the kernel visits those the groups cover. Under an ``ep`` mesh axis
+the positions of all peers are gathered, every peer computes its experts'
+share of all of them, and a reduce-scatter sums the shares; on one device it
+runs without that exchange.
 """
 
 from __future__ import annotations
 
-from typing import Tuple
+import functools
+from typing import Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from horovod_tpu import tracing as _tracing
 
 __all__ = ["Top1Router", "Top2Router", "MoEMLP",
-           "switch_load_balance_loss"]
+           "switch_load_balance_loss", "RoutedExperts", "routed_share"]
 
 
 def switch_load_balance_loss(router_probs: jnp.ndarray,
@@ -222,3 +239,171 @@ class MoEMLP(nn.Module):
         out = jnp.einsum("nec,ecd->nd", combine.astype(self.dtype),
                          expert_out)
         return out.reshape(b, t, d), aux_loss
+
+
+# ---------------------------------------------------------------------------
+# the dropless layer
+# ---------------------------------------------------------------------------
+
+def _int_zero(x):
+    """The cotangent of an integer or boolean argument."""
+    return np.zeros(x.shape, dtype=jax.dtypes.float0)
+
+
+@jax.custom_vjp
+def _dispatch(x, src_token, dst, mine):
+    """Rows of ``x`` (n, d) in sorted order: row ``r`` is position
+    ``src_token[r]``. ``dst`` (n, k) is the row of each assignment and
+    ``mine`` (n, k) whether it has one: the transpose is a gather too."""
+    return x[src_token]
+
+
+def _dispatch_fwd(x, src_token, dst, mine):
+    return x[src_token], (src_token, dst, mine)
+
+
+def _dispatch_bwd(res, g):
+    src_token, dst, mine = res
+    dx = jnp.sum(jnp.where(mine[..., None], g[dst], 0), axis=1,
+                 dtype=jnp.float32).astype(g.dtype)
+    return dx, _int_zero(src_token), _int_zero(dst), _int_zero(mine)
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(ys, w, src, dst):
+    """``out[p] = sum_j w[p, j] * ys[dst[p, j]]`` over the sorted rows
+    ``ys`` (rows, d); ``w`` (n, k) fp32 is zero where an assignment has no
+    row. ``src`` (rows,) is the flat assignment of each row, so that the
+    transpose is a gather and not a scatter."""
+    return jnp.einsum("nk,nkd->nd", w, ys[dst].astype(jnp.float32)
+                      ).astype(ys.dtype)
+
+
+def _combine_fwd(ys, w, src, dst):
+    return _combine(ys, w, src, dst), (ys, w, src, dst)
+
+
+def _combine_bwd(res, g):
+    ys, w, src, dst = res
+    k = w.shape[1]
+    dys = (g[src // k].astype(jnp.float32)
+           * w.reshape(-1)[src][:, None]).astype(ys.dtype)
+    dw = jnp.einsum("nd,nkd->nk", g.astype(jnp.float32),
+                    ys[dst].astype(jnp.float32))
+    return dys, dw, _int_zero(src), _int_zero(dst)
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def routed_share(tokens, router, w_gate, w_up, w_down, *, first, top_k: int,
+                 norm_topk: bool = True, dtype=jnp.bfloat16):
+    """The part of a dropless top-k expert layer that the experts held here
+    give, for ``tokens`` (n, d).
+
+    ``router`` (d, experts_total) scores every expert in fp32 (softmax over
+    all of them, the top ``top_k``, renormalised over the chosen ones when
+    ``norm_topk``); ``w_gate``/``w_up`` (held, d, f) and ``w_down`` (held, f,
+    d) are experts ``first .. first + held - 1`` (``first`` may be traced:
+    an ``ep`` peer's index times ``held``). Returns ``(out, aux)``:
+    ``out[p] = sum_{e chosen by p and held} gate[p, e] * down_e(silu(gate_e
+    x) * up_e x)`` in ``dtype``; the normalisation stays over all chosen
+    experts, held or not, so that the shares of all holders add up to the
+    whole layer. ``aux`` holds ``group_sizes`` (held,), the rows each held
+    expert was given, and ``choice`` (n, top_k), the experts chosen.
+    No assignment is dropped: the grouped products are shaped for
+    ``n * min(top_k, held)`` rows.
+    """
+    n, d = tokens.shape
+    held = w_gate.shape[0]
+    rows = n * min(top_k, held)
+    with _tracing.scope("moe/route"):
+        logits = jnp.dot(tokens.astype(jnp.float32), router,
+                         precision=jax.lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate, choice = jax.lax.top_k(probs, top_k)              # (n, k)
+        if norm_topk:
+            gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+        local = choice - first
+        mine = (local >= 0) & (local < held)
+        # the assignments sorted by held expert, those of other holders
+        # last: every local one lies inside the first ``rows``
+        key = jnp.where(mine, local, held).reshape(-1).astype(jnp.int32)
+        order = jnp.argsort(key, stable=True)
+        dst = jnp.argsort(order).reshape(n, top_k).astype(jnp.int32)
+        src = order[:rows].astype(jnp.int32)
+        group_sizes = jnp.sum(
+            key[:, None] == jnp.arange(held, dtype=jnp.int32)[None, :],
+            axis=0, dtype=jnp.int32)
+        w = jnp.where(mine, gate, 0.0)
+        dst = jnp.where(mine, dst, 0)
+    with _tracing.scope("moe/experts"):
+        xs = _dispatch(tokens.astype(dtype), src // top_k, dst, mine)
+        g = jax.lax.ragged_dot(xs, w_gate.astype(dtype), group_sizes)
+        u = jax.lax.ragged_dot(xs, w_up.astype(dtype), group_sizes)
+        ys = jax.lax.ragged_dot(nn.silu(g) * u, w_down.astype(dtype),
+                                group_sizes)
+        out = _combine(ys, w, src, dst)
+    return out, {"group_sizes": group_sizes, "choice": choice}
+
+
+class RoutedExperts(nn.Module):
+    """Dropless top-k routed SwiGLU experts (no bias, no shared expert, no
+    auxiliary loss), told which experts it holds: ``experts_held = (first,
+    count)`` of ``experts_total``. The router keeps its full width. With
+    ``ep_axis`` (inside ``shard_map`` over that mesh axis, positions and
+    experts sharded over it) peer ``i`` holds experts ``i * count ..``, the
+    positions of all peers are gathered, each peer computes its share of
+    all of them and a reduce-scatter sums the shares into each peer's own
+    positions. Without it nothing is exchanged and the result is this
+    holder's share alone: what the absent experts would add is left out.
+
+    Returns ``out`` (B, T, D); ``group_sizes`` and ``choice`` are sown into
+    the ``"intermediates"`` collection.
+    """
+    experts_total: int
+    experts_held: Tuple[int, int]
+    top_k: int
+    d_ff: int
+    norm_topk: bool = True
+    dtype: jnp.dtype = jnp.bfloat16
+    ep_axis: Optional[str] = None
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+        b, t, d = x.shape
+        first, held = self.experts_held
+        if not 0 <= first <= first + held <= self.experts_total or held < 1:
+            raise ValueError(
+                f"experts_held={self.experts_held} is no range of the "
+                f"{self.experts_total} experts")
+        init = nn.initializers.lecun_normal(batch_axis=(0,))
+        router = self.param("router", nn.initializers.normal(0.02),
+                            (d, self.experts_total), jnp.float32)
+        w_gate = self.param("w_gate", init, (held, d, self.d_ff),
+                            jnp.float32)
+        w_up = self.param("w_up", init, (held, d, self.d_ff), jnp.float32)
+        w_down = self.param("w_down", init, (held, self.d_ff, d),
+                            jnp.float32)
+        share = functools.partial(
+            routed_share, router=router, w_gate=w_gate, w_up=w_up,
+            w_down=w_down, top_k=self.top_k, norm_topk=self.norm_topk,
+            dtype=self.dtype)
+        tokens = x.reshape(b * t, d)
+        if self.ep_axis is None:
+            out, aux = share(tokens, first=first)
+        else:
+            everyone = jax.lax.all_gather(tokens, self.ep_axis, axis=0,
+                                          tiled=True)
+            out, aux = share(
+                everyone, first=jax.lax.axis_index(self.ep_axis) * held)
+            out = jax.lax.psum_scatter(out, self.ep_axis,
+                                       scatter_dimension=0, tiled=True)
+        _tracing.note_routing(
+            moe_rows_bound=aux["choice"].shape[0] * min(self.top_k, held))
+        self.sow("intermediates", "group_sizes", aux["group_sizes"])
+        self.sow("intermediates", "choice", aux["choice"])
+        return out.reshape(b, t, d)

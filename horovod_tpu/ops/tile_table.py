@@ -20,9 +20,11 @@ Table file: ``flash_tiles.json`` next to this module (override with
                   "kind": "causal", "block_q": 256, "block_k": 512,
                   "us_per_call": 950.0, "source": "tuned-v5e"}, ...]}
 
-``kind`` is one of "causal" | "full" | "ring" (the ring kernel's VMEM
-profile differs: its per-hop seq is the local shard and the backward is an
-explicit second ring). Lookup is nearest-match: exact kind and dtype
+``kind`` is one of "causal" | "full" | "ring" | "block_diffusion" (the ring
+kernel's VMEM profile differs: its per-hop seq is the local shard and the
+backward is an explicit second ring; a block-diffusion row is ``[noisy ;
+clean]``, ``seq`` counts both halves, and its tiles are skipped along three
+diagonals). Lookup is nearest-match: exact kind and dtype
 preferred, then closest head_dim and seq in log space — so one measured
 point generalises to neighbouring shapes until the tuner fills them in.
 """
@@ -43,7 +45,7 @@ __all__ = ["lookup", "lookup_full", "record", "load_table", "save_table",
            "table_path", "DEFAULT_TILES", "KINDS"]
 
 DEFAULT_TILES = (256, 512)   # measured fastest on v5e for fwd+bwd (round 1)
-KINDS = ("causal", "full", "ring")
+KINDS = ("causal", "full", "ring", "block_diffusion")
 
 _lock = threading.Lock()
 # path -> (mtime_ns, parsed table); one live version per path, so tuner

@@ -20,6 +20,10 @@ scope or a span counter:
   leaves a finished pass of the same trace returned
   (:func:`mark_synced`, :func:`synced_as`), so that a second pass over
   them lowers nothing.
+* **The routing manifest** — what the routed expert layers and the
+  block-diffusion attention of a program are shaped for, noted while the
+  program is traced (:func:`note_routing`), and how the routing of one
+  batch loaded the experts held (:func:`routing_load`).
 * **The set-up ledger** — one ``jax.monitoring`` listener, registered when
   this module is imported, that adds jax's own trace / lower / backend /
   cache-load seconds to ``jax_compile_seconds_total{phase,fun}``.
@@ -64,7 +68,7 @@ __all__ = ["Span", "mint_span", "current_span", "active_span",
            "reset_spans", "phase",
            "NAMES", "Name", "span", "scope", "timed", "current_scope",
            "program", "note_program", "sync_pass", "note_bucket",
-           "mark_synced", "synced_as"]
+           "mark_synced", "synced_as", "note_routing", "routing_load"]
 
 _LOCK = threading.Lock()
 _SEQ = 0
@@ -193,6 +197,8 @@ class Name(NamedTuple):
 
 _TRAINER = "trainer API (spmd, optimizer, collective, fusion, overlap)"
 _MODELS = "models (models/gpt2, remat)"
+_SDAR = "models (models/sdar, remat)"
+_EXPERTS = "expert layer (ops/moe)"
 _KERNELS = "kernels (ops/flash_attention)"
 _ENGINE = "engine (serving/engine, scheduler, cache)"
 _COMPILER = "compiler (XLA, Mosaic, persistent cache)"
@@ -251,6 +257,25 @@ NAMES: Dict[str, Name] = {
     "gpt2/loss_head": Name(
         "scope", _MODELS, "models.gpt2.loss_fn: log-softmax over the "
         "vocabulary and the gather of the targets", "xprof only"),
+    "sdar/attn": Name(
+        "scope", _SDAR, "models.sdar: the projections, QK-norm, RoPE, the "
+        "attention call and the output projection of one layer",
+        "xprof only"),
+    "moe/route": Name(
+        "scope", _EXPERTS, "ops.moe.routed_share: router logits and "
+        "softmax in fp32, top-k, the sort of the local assignments by "
+        "expert; flax puts the model's own module path before it "
+        "(SDAR/h<i>/moe/moe/route)", "xprof only"),
+    "moe/experts": Name(
+        "scope", _EXPERTS, "ops.moe.routed_share: gather, the grouped "
+        "products over the experts held (ragged-dot custom calls), the "
+        "weighted sum back into positions",
+        "moe_expert_time_share.train (the grouped products, by "
+        "instruction name)"),
+    "sdar/loss_head": Name(
+        "scope", _SDAR, "models.sdar.loss_fn: the head over the noisy "
+        "half, log-softmax over the vocabulary slice, the masked 1/t "
+        "weighting", "xprof only"),
     "flash_attention": Name(
         "scope", _KERNELS, "round each flash kernel call, so that jax's "
         "jvp()/transpose() wrap this name and not the kernel's",
@@ -286,6 +311,27 @@ NAMES: Dict[str, Name] = {
         "gauge", _TRAINER, "sync manifest: calls of allreduce_gradients "
         "that found every leaf averaged by an earlier pass of the trace "
         "and lowered nothing; labels program, scope", "xprof only"),
+    "moe_rows_bound": Name(
+        "gauge", _EXPERTS, "routing manifest: rows the grouped products "
+        "are shaped for (positions x min(top_k, held): no assignment is "
+        "ever dropped); label program", "registry only: what "
+        "moe_local_assignments is a part of (12 % on the benchmark's cell)"),
+    "bd_tiles_visited": Name(
+        "gauge", _KERNELS, "routing manifest: tiles of one head's forward "
+        "grid that hold a visible pair; label program",
+        "bd_tiles_visited_share.train"),
+    "bd_tiles_total": Name(
+        "gauge", _KERNELS, "routing manifest: tiles of one head's forward "
+        "grid; label program", "bd_tiles_visited_share.train"),
+    "moe_local_assignments": Name(
+        "gauge", _EXPERTS, "routing of one batch (routing_load): "
+        "(position, expert) choices that fell on the experts held, a "
+        "layer; label program", "moe_local_assignments.train"),
+    "moe_load_max_over_mean": Name(
+        "gauge", _EXPERTS, "routing of one batch: the busiest held "
+        "expert's rows over the mean, over layers; label program",
+        "registry only: the imbalance an operator looks at before blaming "
+        "the grouped products, whose time follows the rows they are given"),
     "jax_compile_seconds_total": Name(
         "counter", _COMPILER, "set-up ledger: seconds jax reports per "
         "phase (trace, lower, backend, cache_load) and function; an outer "
@@ -371,15 +417,20 @@ def program(name: str):
     as the gauges ``grad_sync_{bytes,buckets,passes,skipped}{program,
     scope}``. The last trace's values: a program lowered twice is not
     counted twice, and a second program does not add to the first's.
-    The leaves marked by :func:`mark_synced` are kept until the trace
+    What :func:`note_routing` was told inside is published the same way,
+    as gauges ``{program}``. The leaves marked by :func:`mark_synced` are kept until the trace
     ends and no longer, so that no tracer outlives its trace."""
-    prev = getattr(_TLS, "manifest", None), getattr(_TLS, "synced", None)
+    prev = (getattr(_TLS, "manifest", None), getattr(_TLS, "synced", None),
+            getattr(_TLS, "routing", None))
     manifest: Dict[str, list] = {}
-    _TLS.manifest, _TLS.synced = manifest, {}
+    routing: Dict[str, float] = {}
+    _TLS.manifest, _TLS.synced, _TLS.routing = manifest, {}, routing
     try:
         yield
     finally:
-        _TLS.manifest, _TLS.synced = prev
+        _TLS.manifest, _TLS.synced, _TLS.routing = prev
+        for key, v in routing.items():
+            _metrics.gauge(key, program=name).set(v)
         with _LOCK:
             stale = _PUBLISHED.get(name, set()) - set(manifest)
             _PUBLISHED[name] = set(manifest)
@@ -455,6 +506,43 @@ def synced_as(tree: Any) -> Any:
     if not said or any(what != said[0] for what in said):
         return None
     return said[0]
+
+
+# ---------------------------------------------------------------------------
+# the routing manifest
+# ---------------------------------------------------------------------------
+
+_ROUTING = ("moe_rows_bound", "bd_tiles_visited", "bd_tiles_total")
+
+
+def note_routing(**shapes) -> None:
+    """A routed expert layer or a block-diffusion attention call is being
+    traced: what it is shaped for, from static values (:data:`_ROUTING`
+    names them). Published as gauges ``{program}`` when :func:`program`
+    exits; every layer of a program says the same, and the last one
+    stands. Outside a program nothing is kept."""
+    unknown = set(shapes) - set(_ROUTING)
+    if unknown:
+        raise ValueError(f"not in the routing manifest: {sorted(unknown)}")
+    routing = getattr(_TLS, "routing", None)
+    if routing is not None:
+        routing.update(shapes)
+
+
+def routing_load(program_name: str, group_sizes) -> None:
+    """How the routing of one batch loaded the experts held:
+    ``group_sizes`` ``(layers, held)`` are the rows each held expert was
+    given, an auxiliary output of a forward pass (a check step, never a
+    timed one). Sets ``moe_local_assignments`` (rows a layer, mean over
+    layers) and ``moe_load_max_over_mean``."""
+    import numpy as np
+    sizes = np.asarray(group_sizes, dtype=np.float64)
+    sizes = sizes.reshape(-1, sizes.shape[-1])
+    _metrics.gauge("moe_local_assignments", program=program_name).set(
+        float(sizes.sum(axis=1).mean()))
+    mean = sizes.mean()
+    _metrics.gauge("moe_load_max_over_mean", program=program_name).set(
+        float(sizes.max() / mean) if mean else 0.0)
 
 
 # ---------------------------------------------------------------------------

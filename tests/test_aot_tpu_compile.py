@@ -128,6 +128,67 @@ def test_spmd_train_step_compiles_for_v5e(v5e, mosaic, restore_world, n_dev):
     assert "hvd/optimizer/sync" not in hlo
 
 
+def test_block_diffusion_flash_compiles_for_v5e_at_the_cells_shape(v5e,
+                                                                   mosaic):
+    """Head size 128, 8,192 positions ``[noisy ; clean]``, the tiles of the
+    table's row: the mask's integer arithmetic and the tile skip lower for
+    the chip in all three kernels (a select between booleans did not)."""
+    from horovod_tpu.ops.flash_attention import flash_attention
+    on = SingleDeviceSharding(v5e[0])
+    x = jax.ShapeDtypeStruct((1, 8192, 8, 128), jnp.bfloat16, sharding=on)
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, block_diffusion=(4096, 4)).astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    _assert_kernels_named(jax.jit(fwd_bwd).lower(x, x, x).compile()
+                          .as_text())
+
+
+def test_block_diffusion_step_compiles_for_v5e_at_published_widths(
+        v5e, mosaic, restore_world):
+    """One layer of the second family's step at its published widths and
+    the cell's 2 x 4,096 clean tokens: the flash kernels under the mask,
+    XLA's grouped kernel for the experts held, the four scopes."""
+    import optax
+    from horovod_tpu.models import sdar
+    hvd.init(devices=v5e[:1])
+    cfg = sdar.SDARConfig(vocab_size=18992, num_layers=1,
+                          experts_held=(0, 16), attention="flash",
+                          remat=True)
+    model = sdar.SDAR(cfg)
+    opt = hvd.DistributedOptimizer(optax.adamw(3e-4))
+
+    def train_step(params, opt_state, tokens):
+        noise = sdar.block_noise(
+            jax.random.split(jax.random.PRNGKey(0), tokens.shape[0]),
+            tokens.shape[1], cfg.block_len)
+        loss, grads = hvd.value_and_grad(
+            lambda p: sdar.loss_fn(model, p, tokens, noise))(params)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, loss
+
+    step = hvd.spmd(train_step, in_specs=(P(), P(), P("hvd")),
+                    out_specs=(P(), P(), P()), donate_argnums=(0, 1))
+    replicated = NamedSharding(hvd.mesh(), P())
+    twin = sdar.SDAR(dataclasses.replace(cfg, attention="dense",
+                                         remat=False))
+    row = jnp.zeros((1, 8), jnp.int32)
+    params = jax.eval_shape(
+        lambda: twin.init(jax.random.PRNGKey(0), row, row)["params"])
+    tokens = jax.ShapeDtypeStruct((2, 4096), jnp.int32,
+                                  sharding=hvd.spmd_data_sharding())
+    hlo = step.lower(_shapes(params, replicated),
+                     _shapes(jax.eval_shape(opt.init, params), replicated),
+                     tokens).compile().as_text()
+    _assert_kernels_named(hlo)
+    assert re.search(r"%ragged-dot[^\n]* = [^\n]*custom-call\(", hlo)
+    for scope in ("sdar/attn", "moe/route", "moe/experts",
+                  "sdar/loss_head"):
+        assert scope in hlo, scope
+
+
 def test_engine_programs_compile_for_v5e_with_cache_donation(
         v5e, restore_world, monkeypatch):
     from horovod_tpu.serving import InferenceEngine

@@ -26,6 +26,9 @@ TRAINER_SCOPES = ["hvd/value_and_grad/sync", "hvd/optimizer/sync",
                   "hvd/optimizer/update", "hvd/fusion/pack",
                   "hvd/fusion/unpack", "gpt2/loss_head"]
 KERNELS = ["flash_fwd", "flash_dq", "flash_dkv"]
+SDAR_SCOPES = ["sdar/attn", "moe/route", "moe/experts", "sdar/loss_head"]
+ROUTING = ["moe_rows_bound", "bd_tiles_visited", "bd_tiles_total",
+           "moe_local_assignments", "moe_load_max_over_mean"]
 ENGINE_PHASES = ["sweep", "admit", "build", "dispatch", "readback", "commit"]
 
 
@@ -89,6 +92,9 @@ def _emitted_names():
                 arg = node.args[0]
                 if isinstance(arg, ast.Constant):
                     used.append((rel, node.lineno, arg.value))
+                elif isinstance(arg, ast.Name):     # a key of the routing
+                    assert arg.id == "key"          # manifest, as noted
+                    used += [(rel, node.lineno, k) for k in tracing._ROUTING]
                 else:                               # "grad_sync_" + what
                     used += [(rel, node.lineno, arg.left.value + w)
                              for w in tracing._COUNTS] \
@@ -105,6 +111,7 @@ def test_every_name_emitted_is_in_the_table():
     assert not unlisted, f"emitted but not in tracing.NAMES: {unlisted}"
     names = {u[2] for u in used}
     assert set(TRAINER_SCOPES) | set(KERNELS) <= names
+    assert set(SDAR_SCOPES) | set(ROUTING) <= names
     assert {"engine." + p for p in ENGINE_PHASES} <= names
     # and the table lists nothing that is not emitted
     assert set(tracing.NAMES) - names == set(), set(tracing.NAMES) - names
@@ -375,3 +382,47 @@ def test_each_engine_phase_has_its_counter_pair(traced_step, phase):
                        phase=phase)
     count = _counter("serve_step_phase_total", engine="spans", phase=phase)
     assert seconds > 0 and count >= 1
+
+
+# ---------------------------------------------------------------------------
+# the block-diffusion decoder's names
+# ---------------------------------------------------------------------------
+
+def test_lowered_block_diffusion_step_carries_its_scopes_and_manifest():
+    """The step of the second model family, lowered: its four scopes and
+    the three kernel names in the text, and the routing manifest published
+    under the program's name when the trace ends."""
+    from horovod_tpu.models import sdar
+    hvd.init(devices=jax.devices()[:1])
+    try:
+        cfg = sdar.SDARConfig.tiny(experts_held=(2, 2), top_k=4,
+                                   attention="flash", remat=True,
+                                   flash_blocks=(16, 32))
+        model = sdar.SDAR(cfg)
+        tokens = jnp.zeros((2, 32), jnp.int32)
+        params = model.init(jax.random.PRNGKey(0), tokens, tokens)["params"]
+        opt = hvd.DistributedOptimizer(optax.sgd(0.1))
+
+        def bd_step(params, opt_state, tokens):
+            noise = sdar.block_noise(
+                jax.random.split(jax.random.PRNGKey(1), tokens.shape[0]),
+                tokens.shape[1], cfg.block_len)
+            loss, grads = hvd.value_and_grad(
+                lambda p: sdar.loss_fn(model, p, tokens, noise))(params)
+            updates, opt_state = opt.update(grads, opt_state, params)
+            return optax.apply_updates(params, updates), opt_state, loss
+
+        step = hvd.spmd(bd_step, in_specs=(P(), P(), P("hvd")),
+                        out_specs=(P(), P(), P()))
+        text = step.lower(params, opt.init(params), tokens).as_text(
+            debug_info=True)
+    finally:
+        hvd.shutdown()
+    for name in SDAR_SCOPES + KERNELS:
+        assert name in text, name
+    gauges = hvd.metrics.snapshot()["gauges"]
+    read = {name: [s["value"] for s in gauges.get(name, ())
+                   if s["labels"].get("program") == "bd_step"]
+            for name in tracing._ROUTING}
+    assert read["moe_rows_bound"] == [2 * 64 * 2]
+    assert 0 < read["bd_tiles_visited"][0] < read["bd_tiles_total"][0]

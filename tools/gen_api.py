@@ -46,6 +46,7 @@ MODULES = [
     "horovod_tpu.models",
     "horovod_tpu.models.gpt2_pipeline",
     "horovod_tpu.models.llama",
+    "horovod_tpu.models.sdar",
     "horovod_tpu.models.t5",
     "horovod_tpu.models.convert",
     "horovod_tpu.models.generate",
