@@ -15,6 +15,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from horovod_tpu.models.remat import remat_block
 
 
 @dataclasses.dataclass(frozen=True)
@@ -134,19 +135,7 @@ class Bert(nn.Module):
             pe = pe[None]
         x = (wte[tokens] + pe + wtt[token_types]).astype(cfg.dtype)
         x = nn.LayerNorm(dtype=jnp.float32, name="ln_emb")(x)
-        layer = EncoderLayer
-        if cfg.remat:
-            if cfg.remat_policy == "dots":
-                layer = nn.remat(
-                    EncoderLayer,
-                    policy=(jax.checkpoint_policies
-                            .dots_with_no_batch_dims_saveable))
-            elif cfg.remat_policy == "full":
-                layer = nn.remat(EncoderLayer)
-            else:
-                raise ValueError(
-                    f"unknown remat_policy {cfg.remat_policy!r}: "
-                    "expected 'full' or 'dots'")
+        layer = remat_block(EncoderLayer, cfg)
         for i in range(cfg.num_layers):
             x = layer(cfg, name=f"layer{i}")(x, attention_mask,
                                              segment_ids)

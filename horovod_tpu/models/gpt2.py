@@ -23,6 +23,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from horovod_tpu import tracing as _tracing
+from horovod_tpu.models.remat import remat_block
 from horovod_tpu.parallel.sharding import PartitionRules
 
 
@@ -38,12 +39,17 @@ class GPT2Config:
     ln_eps: float = 1e-6             # HF checkpoints use 1e-5 (convert.py)
     dtype: jnp.dtype = jnp.bfloat16
     remat: bool = False
-    # Rematerialization policy when remat=True. "full" recomputes the whole
-    # block in backward (minimum memory, ~33% extra FLOPs). "dots" applies
-    # jax.checkpoint_policies.dots_with_no_batch_dims_saveable: MXU outputs
-    # (qkv/attn/mlp matmuls) are SAVED and only cheap elementwise/norm work
-    # recomputes — the standard XLA lever for trading a little HBM back for
-    # the recompute FLOPs when the batch fits anyway.
+    # Rematerialization policy when remat=True (models/remat.py). "full"
+    # recomputes the whole block in backward (minimum memory, ~33% extra
+    # FLOPs, the flash forward kernel run twice). "dots" SAVES what the MXU
+    # produced and recomputes only the cheap elementwise/norm work: the
+    # weight products (jax.checkpoint_policies.
+    # dots_with_no_batch_dims_saveable: qkv/out/fc/proj) and, with
+    # attention="flash", the kernel's output and row log-sum-exp, which its
+    # forward rule names (flash_out, flash_lse). In bytes a layer at
+    # B x T x d_model in bf16: 9 x B*T*d for the products (151 MB at
+    # 8 x 1024 x 1024) and 1 x B*T*d (17 MB) + B*H*T fp32 for the kernel's —
+    # the lever for trading HBM back for recompute when the batch fits.
     remat_policy: str = "full"
     use_ring_attention: bool = False  # sequence-parallel attention (ops/)
     # "contiguous" | "striped": how sequence positions map to sp shards.
@@ -167,19 +173,7 @@ class GPT2(nn.Module):
             # *global* positions.
             pos = sp_global_positions(T, cfg)
         x = wte[tokens].astype(cfg.dtype) + wpe[pos].astype(cfg.dtype)
-        block = Block
-        if cfg.remat:
-            if cfg.remat_policy == "dots":
-                block = nn.remat(
-                    Block, static_argnums=(3,),
-                    policy=(jax.checkpoint_policies
-                            .dots_with_no_batch_dims_saveable))
-            elif cfg.remat_policy == "full":
-                block = nn.remat(Block, static_argnums=(3,))
-            else:
-                raise ValueError(
-                    f"unknown remat_policy {cfg.remat_policy!r}: "
-                    "expected 'full' or 'dots'")
+        block = remat_block(Block, cfg, static_argnums=(3,))
         for i in range(cfg.num_layers):
             x = block(cfg, name=f"h{i}")(x, segment_ids, deterministic)
         x = nn.LayerNorm(epsilon=cfg.ln_eps, dtype=jnp.float32,

@@ -31,6 +31,7 @@ from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.models.gpt2 import loss_fn  # same next-token CE  # noqa: F401
 from horovod_tpu.models.gpt2 import loss_fn_moe  # CE + aux  # noqa: F401
+from horovod_tpu.models.remat import remat_block
 from horovod_tpu.parallel.sharding import PartitionRules
 
 
@@ -233,19 +234,7 @@ class Llama(nn.Module):
             # position input (the same role as gpt2's wpe indexing).
             pos = sp_global_positions(T, cfg)
         x = wte[tokens].astype(cfg.dtype)
-        block = Block
-        if cfg.remat:
-            if cfg.remat_policy == "dots":
-                block = nn.remat(
-                    Block, static_argnums=(4,),
-                    policy=(jax.checkpoint_policies
-                            .dots_with_no_batch_dims_saveable))
-            elif cfg.remat_policy == "full":
-                block = nn.remat(Block, static_argnums=(4,))
-            else:
-                raise ValueError(
-                    f"unknown remat_policy {cfg.remat_policy!r}: "
-                    "expected 'full' or 'dots'")
+        block = remat_block(Block, cfg, static_argnums=(4,))
         for i in range(cfg.num_layers):
             x = block(cfg, name=f"h{i}")(x, pos, segment_ids,
                                          deterministic)

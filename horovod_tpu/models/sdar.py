@@ -30,6 +30,7 @@ import jax.numpy as jnp
 
 from horovod_tpu import tracing as _tracing
 from horovod_tpu.models.llama import RMSNorm, apply_rope, repeat_kv
+from horovod_tpu.models.remat import remat_block
 
 __all__ = ["SDAR", "SDARConfig", "block_noise", "loss_fn"]
 
@@ -147,18 +148,7 @@ class SDAR(nn.Module):
                    (cfg.vocab_size, cfg.d_model), jnp.float32)
         pos = jnp.concatenate([jnp.arange(T), jnp.arange(T)])
         x = wte[jnp.concatenate([noisy, clean], axis=1)].astype(cfg.dtype)
-        block = Block
-        if cfg.remat:
-            if cfg.remat_policy == "dots":
-                block = nn.remat(
-                    Block, policy=(jax.checkpoint_policies
-                                   .dots_with_no_batch_dims_saveable))
-            elif cfg.remat_policy == "full":
-                block = nn.remat(Block)
-            else:
-                raise ValueError(
-                    f"unknown remat_policy {cfg.remat_policy!r}: "
-                    "expected 'full' or 'dots'")
+        block = remat_block(Block, cfg)
         for i in range(cfg.num_layers):
             x = block(cfg, name=f"h{i}")(x, pos)
         return RMSNorm(cfg.rms_eps, name="norm_f")(x[:, :T])

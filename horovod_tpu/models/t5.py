@@ -35,6 +35,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.models.llama import RMSNorm
+from horovod_tpu.models.remat import remat_block
 from horovod_tpu.parallel.sharding import PartitionRules
 
 
@@ -206,19 +207,6 @@ class DecoderLayer(nn.Module):
             RMSNorm(eps=cfg.ln_eps, name="ln3")(x))
 
 
-def _maybe_remat(cfg: T5Config, layer_cls):
-    if not cfg.remat:
-        return layer_cls
-    if cfg.remat_policy == "dots":
-        return nn.remat(layer_cls,
-                        policy=(jax.checkpoint_policies
-                                .dots_with_no_batch_dims_saveable))
-    if cfg.remat_policy == "full":
-        return nn.remat(layer_cls)
-    raise ValueError(f"unknown remat_policy {cfg.remat_policy!r}: "
-                     "expected 'full' or 'dots'")
-
-
 class T5(nn.Module):
     cfg: T5Config
 
@@ -245,8 +233,8 @@ class T5(nn.Module):
         emb = self.param("embedding", nn.initializers.normal(1.0),
                          (cfg.vocab_size, cfg.d_model), jnp.float32)
 
-        enc_layer = _maybe_remat(cfg, EncoderLayer)
-        dec_layer = _maybe_remat(cfg, DecoderLayer)
+        enc_layer = remat_block(EncoderLayer, cfg)
+        dec_layer = remat_block(DecoderLayer, cfg)
 
         # Encoder: bidirectional rel bias, one table for the stack.
         x = emb[enc_tokens].astype(cfg.dtype)

@@ -38,6 +38,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -47,6 +48,10 @@ from horovod_tpu.ops.attention import block_diffusion_mask
 __all__ = ["flash_attention"]
 
 _NEG_INF = -1e30
+# What the forward rule names of its own outputs (``checkpoint_name``): the
+# attention output and the row log-sum-exp, both O(T) and all the backward
+# kernels need of the forward besides its inputs.
+RESIDUAL_NAMES = ("flash_out", "flash_lse")
 # Kernel names. A Mosaic custom call is named after the last scope round
 # it, which ``pallas_call(name=)`` sets: flash_fwd, flash_dq, flash_dkv
 # (tracing.NAMES; the per-kernel metrics sum device time by them). jax
@@ -555,7 +560,14 @@ def _flash_fwd(q, k, v, bias, seg, h, scale, causal, block_q, block_k,
                block_q_bwd, block_k_bwd, offset, bd):
     o, lse = _fwd(q, k, v, bias, seg, seg, h, scale, causal, block_q,
                   block_k, offset=offset, bd=bd)
-    return o, (q, k, v, bias, seg, seg, o, lse)
+    # Named, so that a remat policy can keep them (models/remat.py) and
+    # the backward does not run the forward kernel again to get them back.
+    # The log-sum-exp is kept without its last dimension of 1, which the
+    # chip's tiling pads to 128 lanes in HBM: 67 MB a call at 128 x 1024 for
+    # 0.5 MB of numbers, and a step that holds 24 of them is slower for it.
+    o = checkpoint_name(o, RESIDUAL_NAMES[0])
+    lse = checkpoint_name(lse[..., 0], RESIDUAL_NAMES[1])
+    return o, (q, k, v, bias, seg, o, lse)
 
 
 def _flash_bwd(h, scale, causal, block_q, block_k, block_q_bwd,
@@ -563,9 +575,10 @@ def _flash_bwd(h, scale, causal, block_q, block_k, block_q_bwd,
     # The backward kernels' VMEM profile differs from the forward's (two
     # extra fp32 accumulators per tile), so they may want their own tiles
     # — measured entries carry them (tile_table "tuned-*-fwdbwd").
+    q, k, v, bias, seg, o, lse = res
     dq, dk, dv, dbias = _bwd(h, scale, causal, block_q_bwd, block_k_bwd,
-                             res, do, offset=offset, bd=bd)
-    seg = res[4]  # res = (q, k, v, bias, seg, seg, o, lse)
+                             (q, k, v, bias, seg, seg, o, lse[..., None]),
+                             do, offset=offset, bd=bd)
     # Integer segment ids take a symbolic-zero (float0) cotangent.
     dseg = (None if seg is None
             else np.zeros(seg.shape, dtype=jax.dtypes.float0))

@@ -24,6 +24,9 @@ scope or a span counter:
   block-diffusion attention of a program are shaped for, noted while the
   program is traced (:func:`note_routing`), and how the routing of one
   batch loaded the experts held (:func:`routing_load`).
+* **The remat count** — how often a block's remat policy kept a residual
+  that the flash forward named, while the program is traced
+  (:func:`note_residual_saved`).
 * **The set-up ledger** — one ``jax.monitoring`` listener, registered when
   this module is imported, that adds jax's own trace / lower / backend /
   cache-load seconds to ``jax_compile_seconds_total{phase,fun}``.
@@ -68,7 +71,8 @@ __all__ = ["Span", "mint_span", "current_span", "active_span",
            "reset_spans", "phase",
            "NAMES", "Name", "span", "scope", "timed", "current_scope",
            "program", "note_program", "sync_pass", "note_bucket",
-           "mark_synced", "synced_as", "note_routing", "routing_load"]
+           "mark_synced", "synced_as", "note_routing", "routing_load",
+           "note_residual_saved"]
 
 _LOCK = threading.Lock()
 _SEQ = 0
@@ -283,7 +287,8 @@ NAMES: Dict[str, Name] = {
     # kernel names (pallas_call(name=...): the custom call's instruction)
     "flash_fwd": Name(
         "kernel", _KERNELS, "flash attention forward (run again in the "
-        "backward under remat)", "flash_fwd_ms.train"),
+        "backward under remat=full; under dots its named output and "
+        "log-sum-exp are kept)", "flash_fwd_ms.train"),
     "flash_dq": Name(
         "kernel", _KERNELS, "flash attention backward, dQ",
         "flash_dq_ms.train"),
@@ -323,6 +328,14 @@ NAMES: Dict[str, Name] = {
     "bd_tiles_total": Name(
         "gauge", _KERNELS, "routing manifest: tiles of one head's forward "
         "grid; label program", "bd_tiles_visited_share.train"),
+    "flash_residuals_saved": Name(
+        "gauge", _MODELS, "remat count: times a block's dots policy "
+        "answered save for the flash forward's named output or row "
+        "log-sum-exp while the program was last traced (a count of "
+        "answers, a multiple of the layers: two a layer with jax 0.9; 0 "
+        "under full, without remat, with dense attention); label program",
+        "registry only: flash_fwd_ms.train reads the effect on the device "
+        "(one forward call a layer, not two)"),
     "moe_local_assignments": Name(
         "gauge", _EXPERTS, "routing of one batch (routing_load): "
         "(position, expert) choices that fell on the experts held, a "
@@ -418,19 +431,24 @@ def program(name: str):
     scope}``. The last trace's values: a program lowered twice is not
     counted twice, and a second program does not add to the first's.
     What :func:`note_routing` was told inside is published the same way,
-    as gauges ``{program}``. The leaves marked by :func:`mark_synced` are kept until the trace
-    ends and no longer, so that no tracer outlives its trace."""
+    as gauges ``{program}``, and so is what :func:`note_residual_saved`
+    counted (every program says it, 0 included). The leaves marked by
+    :func:`mark_synced` are kept until the trace ends and no longer, so
+    that no tracer outlives its trace."""
     prev = (getattr(_TLS, "manifest", None), getattr(_TLS, "synced", None),
-            getattr(_TLS, "routing", None))
+            getattr(_TLS, "routing", None), getattr(_TLS, "saved", None))
     manifest: Dict[str, list] = {}
     routing: Dict[str, float] = {}
-    _TLS.manifest, _TLS.synced, _TLS.routing = manifest, {}, routing
+    saved = [0]
+    _TLS.manifest, _TLS.synced, _TLS.routing, _TLS.saved = (
+        manifest, {}, routing, saved)
     try:
         yield
     finally:
-        _TLS.manifest, _TLS.synced, _TLS.routing = prev
+        _TLS.manifest, _TLS.synced, _TLS.routing, _TLS.saved = prev
         for key, v in routing.items():
             _metrics.gauge(key, program=name).set(v)
+        _metrics.gauge("flash_residuals_saved", program=name).set(saved[0])
         with _LOCK:
             stale = _PUBLISHED.get(name, set()) - set(manifest)
             _PUBLISHED[name] = set(manifest)
@@ -543,6 +561,26 @@ def routing_load(program_name: str, group_sizes) -> None:
     mean = sizes.mean()
     _metrics.gauge("moe_load_max_over_mean", program=program_name).set(
         float(sizes.max() / mean) if mean else 0.0)
+
+
+# ---------------------------------------------------------------------------
+# the remat count
+# ---------------------------------------------------------------------------
+
+def note_residual_saved() -> None:
+    """The remat policy of a block answered "save" for a residual that the
+    flash forward named (``models/remat.py``; ``ops/flash_attention.
+    RESIDUAL_NAMES``). How often jax asks a policy about one equation
+    while it splits a block into what is kept and what is run again is
+    jax's own business (0.9 asks once: two answers a layer), so the gauge
+    ``flash_residuals_saved{program}`` that :func:`program` publishes is
+    a count of answers and not of arrays: positive and proportional to
+    the layers under ``dots`` with flash attention, 0 under ``full``,
+    without remat and with dense attention. Outside a program nothing is
+    kept."""
+    saved = getattr(_TLS, "saved", None)
+    if saved is not None:
+        saved[0] += 1
 
 
 # ---------------------------------------------------------------------------

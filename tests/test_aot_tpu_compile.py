@@ -120,6 +120,11 @@ def test_spmd_train_step_compiles_for_v5e(v5e, mosaic, restore_world, n_dev):
     # scopes reach the chip's program as operation metadata
     assert hlo.startswith("HloModule jit_train_step")
     _assert_kernels_named(hlo)
+    # remat=dots keeps what the forward kernel wrote: one forward call a
+    # layer in the chip's program, not a second one in the backward
+    for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
+        calls = re.findall(rf"%{kernel}[.\d]* = [^\n]*custom-call\(", hlo)
+        assert len(calls) == cfg.num_layers, (kernel, calls)
     for scope in ("hvd/value_and_grad/sync", "hvd/optimizer/update",
                   "hvd/fusion/pack", "hvd/fusion/unpack", "gpt2/loss_head"):
         assert scope in hlo, scope

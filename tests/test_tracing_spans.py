@@ -7,6 +7,7 @@ name, and one ``step_once`` leaves its phase spans on the profiler's host
 plane in the table's order."""
 
 import ast
+import dataclasses
 import glob
 import os
 
@@ -37,6 +38,13 @@ def _gauges(name, **labels):
     return {s["labels"]["scope"]: s["value"]
             for s in hvd.metrics.snapshot()["gauges"].get(name, ())
             if all(s["labels"].get(k) == v for k, v in labels.items())}
+
+
+def _program_gauge(name, program):
+    """The values of the gauge ``name{program}`` (one, or none yet)."""
+    return [s["value"]
+            for s in hvd.metrics.snapshot()["gauges"].get(name, ())
+            if s["labels"] == {"program": program}]
 
 
 def _counter(name, **labels):
@@ -136,16 +144,19 @@ def test_the_profiler_knob_is_gone():
 # the README step, lowered
 # ---------------------------------------------------------------------------
 
-def _step(devices, readme=True, touch=False):
+def _step(devices, readme=True, touch=False, **changes):
     """The README train step (or the same with plain jax.value_and_grad)
     lowered on ``devices``: its text, its program name, the manifest it
     left, and the bytes of its parameter tree. ``touch`` multiplies the
     gradients by one between the two syncs: new objects, so that both
-    passes lower, as they did before a pass could be skipped."""
+    passes lower, as they did before a pass could be skipped. ``changes``
+    are to the model's configuration (flash attention under
+    ``remat=dots``, two layers)."""
     hvd.init(devices=devices)
     try:
-        cfg = GPT2Config.tiny(attention="flash", remat=True,
-                              remat_policy="dots")
+        cfg = dataclasses.replace(
+            GPT2Config.tiny(attention="flash", remat=True,
+                            remat_policy="dots"), **changes)
         model = GPT2(cfg)
         tokens = jnp.zeros((2 * len(devices), 128), jnp.int32)
         params = model.init(jax.random.PRNGKey(0), tokens[:1])
@@ -174,6 +185,7 @@ def _step(devices, readme=True, touch=False):
             "passes": _gauges("grad_sync_passes", program="train_step"),
             "skipped": _gauges("grad_sync_skipped", program="train_step"),
             "all_reduces": lowered.as_text().count("stablehlo.all_reduce"),
+            "saved": _program_gauge("flash_residuals_saved", "train_step"),
         }
     finally:
         hvd.init()          # back onto the session's 8 CPU devices
@@ -240,6 +252,30 @@ def test_manifest_is_the_last_lowering_not_a_sum():
                              "hvd/optimizer/sync": 1}
     assert not any(one["skipped"].values())
     assert sum(one["bytes"].values()) == one["tree_bytes"]
+
+
+def test_remat_count_follows_the_layers(readme_step):
+    """Under ``dots`` with flash attention the policy keeps the forward
+    kernel's two named outputs in every layer: a count of the policy's
+    answers (how often jax asks is its own business), so positive and
+    twice as large for two layers as for one."""
+    one = _step(jax.devices()[:2], num_layers=1)["saved"]
+    assert readme_step["saved"][0] > 0
+    assert readme_step["saved"] == [2 * one[0]]
+
+
+@pytest.mark.parametrize("changes", [
+    dict(remat_policy="full"), dict(remat=False), dict(attention="dense")],
+    ids=["full", "no-remat", "dense"])
+def test_remat_count_is_zero_where_nothing_named_is_kept(changes):
+    assert _step(jax.devices()[:2], **changes)["saved"] == [0]
+
+
+def test_remat_count_is_the_last_trace_not_a_sum(readme_step):
+    """The same program traced again says the same, and one that keeps
+    nothing (same program name) takes the count back to 0."""
+    assert _step(jax.devices()[:2])["saved"] == readme_step["saved"]
+    assert _step(jax.devices()[:2], remat=False)["saved"] == [0]
 
 
 def test_manifest_counts_nothing_on_one_device():
@@ -420,9 +456,7 @@ def test_lowered_block_diffusion_step_carries_its_scopes_and_manifest():
         hvd.shutdown()
     for name in SDAR_SCOPES + KERNELS:
         assert name in text, name
-    gauges = hvd.metrics.snapshot()["gauges"]
-    read = {name: [s["value"] for s in gauges.get(name, ())
-                   if s["labels"].get("program") == "bd_step"]
+    read = {name: _program_gauge(name, "bd_step")
             for name in tracing._ROUTING}
     assert read["moe_rows_bound"] == [2 * 64 * 2]
     assert 0 < read["bd_tiles_visited"][0] < read["bd_tiles_total"][0]
