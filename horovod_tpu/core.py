@@ -233,7 +233,7 @@ def init(devices: Optional[Sequence] = None, axis_name: str = AXIS_NAME,
         # Resolved comm-knob gauges (hvd.metrics()-visible): the algorithm
         # as an info-style labeled gauge and the chunk depth. Inactive
         # algorithm labels are zeroed so a re-init with a different knob
-        # (bench --sweep-comm) leaves exactly one label at 1.
+        # leaves exactly one label at 1.
         from horovod_tpu.overlap import ALGORITHMS as _algs
         from horovod_tpu.overlap import WIRES as _wires
         for _a in _algs:
@@ -246,7 +246,7 @@ def init(devices: Optional[Sequence] = None, axis_name: str = AXIS_NAME,
         _metrics.gauge("config_overlap_chunks").set(cfg.overlap_chunks)
         # Detected torus dims, one gauge per dim index. Slots beyond the
         # detected rank are zeroed so a re-init onto a flatter fabric
-        # (elastic re-mesh, bench sweeps) does not leave stale dims —
+        # (elastic re-mesh) does not leave stale dims —
         # hvd.doctor()'s offline _check_topology counts dims > 1 from
         # exactly these series.
         for _i in range(max(len(topo), 4)):

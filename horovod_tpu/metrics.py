@@ -510,7 +510,7 @@ def to_json(snap: Optional[Dict[str, Any]] = None) -> str:
 
 
 def collective_summary() -> Dict[str, Dict[str, Any]]:
-    """Compact per-kind collective counters for bench/report embedding:
+    """Compact per-kind collective counters for report embedding:
     ``{kind: {"calls": n, "bytes": b}}``."""
     snap = registry.snapshot()
     out: Dict[str, Dict[str, Any]] = {}

@@ -75,27 +75,11 @@ class ResNet(nn.Module):
     # are pmean-ed over this axis (upstream horovod/torch/sync_batch_norm.py
     # semantics) — use inside shard_map with the axis bound. None = local BN.
     bn_cross_replica_axis: str | None = None
-    # BN moment-accumulation dtype experiment (measured negative on chip):
-    # None keeps flax's fp32-stats BatchNorm; jnp.bfloat16 halves the HBM
-    # traffic of the statistics passes via ops.batch_norm.TunableBatchNorm
-    # (checkpoint-compatible variable layout either way).
-    bn_stats_dtype: Any = None
-    # "conv" = plain 7x7/s2 stem; "s2d" = MLPerf space-to-depth stem (the
-    # same math re-laid as a 4x4/s1 conv on 12 channels so the C=3 input
-    # stops padding the MXU tile — see convert_stem_weights).
-    stem: str = "conv"
 
     @nn.compact
     def __call__(self, x, train: bool = True):
         conv = partial(nn.Conv, use_bias=False, dtype=self.dtype)
-        if self.bn_stats_dtype is not None:
-            from horovod_tpu.ops.batch_norm import TunableBatchNorm
-            norm = partial(TunableBatchNorm, use_running_average=not train,
-                           momentum=0.9, epsilon=1e-5, dtype=self.dtype,
-                           param_dtype=jnp.float32,
-                           stats_dtype=self.bn_stats_dtype,
-                           axis_name=self.bn_cross_replica_axis)
-        elif self.bn_cross_replica_axis is not None:
+        if self.bn_cross_replica_axis is not None:
             from horovod_tpu.ops.sync_batch_norm import SyncBatchNorm
             norm = partial(SyncBatchNorm, use_running_average=not train,
                            momentum=0.9, epsilon=1e-5, dtype=self.dtype,
@@ -106,17 +90,8 @@ class ResNet(nn.Module):
                            momentum=0.9, epsilon=1e-5, dtype=self.dtype,
                            param_dtype=jnp.float32)
         x = x.astype(self.dtype)
-        if self.stem == "s2d":
-            from horovod_tpu.ops.batch_norm import space_to_depth
-            x = space_to_depth(x, 2)
-            x = conv(self.num_filters, (4, 4), (1, 1),
-                     padding=[(2, 1), (2, 1)], name="conv_init")(x)
-        elif self.stem == "conv":
-            x = conv(self.num_filters, (7, 7), (2, 2),
-                     padding=[(3, 3), (3, 3)], name="conv_init")(x)
-        else:
-            raise ValueError(f"unknown stem {self.stem!r}; expected "
-                             "'conv' or 's2d'")
+        x = conv(self.num_filters, (7, 7), (2, 2),
+                 padding=[(3, 3), (3, 3)], name="conv_init")(x)
         x = norm(name="bn_init")(x)
         x = nn.relu(x)
         x = nn.max_pool(x, (3, 3), strides=(2, 2), padding=((1, 1), (1, 1)))
@@ -129,39 +104,6 @@ class ResNet(nn.Module):
         x = nn.Dense(self.num_classes, dtype=jnp.float32,
                      param_dtype=jnp.float32)(x)
         return x
-
-
-def convert_stem_weights(w7):
-    """Re-lay a (7, 7, C, F) stride-2 stem kernel for the space-to-depth
-    stem: returns the (4, 4, 4C, F) kernel that computes the IDENTICAL
-    convolution on ``space_to_depth(x, 2)`` with stride 1 and padding
-    ((2, 1), (2, 1)).
-
-    Derivation: the original output is ``sum_{di,dj,c} x[2i+di-3, 2j+dj-3,
-    c] * W[di, dj, c]``; with ``z[p, q, (a,b,c)] = x[2p+a, 2q+b, c]`` and
-    pad-lo 2, the s2d conv reads ``x[2i + (2u+a-1) - 2, ...]``, so tap
-    ``(u, a)`` maps to ``di = 2u + a - 1`` (di = -1 gets zero weight).
-    Train either layout and move checkpoints through this transform.
-    """
-    import numpy as np
-    kh, kw, c, f = w7.shape
-    if (kh, kw) != (7, 7):
-        raise ValueError(f"expected a 7x7 stem kernel, got {(kh, kw)}")
-    w7 = np.asarray(w7)
-    v = np.zeros((4, 4, 4 * c, f), w7.dtype)
-    for u in range(4):
-        for a in range(2):
-            di = 2 * u + a - 1
-            if not 0 <= di < 7:
-                continue
-            for vv in range(4):
-                for b in range(2):
-                    dj = 2 * vv + b - 1
-                    if not 0 <= dj < 7:
-                        continue
-                    v[u, vv, (a * 2 + b) * c:(a * 2 + b + 1) * c] = \
-                        w7[di, dj]
-    return v
 
 
 ResNet18 = partial(ResNet, stage_sizes=[2, 2, 2, 2], block_cls=BasicBlock)

@@ -9,13 +9,12 @@ subsystem — the third observability layer on top of metrics (aggregates)
 and tracing (timelines):
 
 * **Program registry** (:class:`ProgramRegistry` / :func:`instrument`):
-  every jitted step we own — train steps, serving decode/prefill, bench
-  programs — registers its compiled cost analysis (flops, bytes accessed,
+  every jitted step we own — train steps, serving decode/prefill —
+  registers its compiled cost analysis (flops, bytes accessed,
   peak HBM) once per compilation, and every honest step timing fed to
   :func:`observe_step` updates live ``program_mfu`` / ``program_hfu`` /
-  ``hbm_bandwidth_utilization`` gauges. The MFU/HFU split follows the
-  bench.py r5 convention: **hfu** divides XLA's *executed* FLOPs (counts
-  remat recompute) by the device peak, **mfu** divides the analytic,
+  ``hbm_bandwidth_utilization`` gauges. **hfu** divides XLA's *executed*
+  FLOPs (counts remat recompute) by the device peak, **mfu** the analytic,
   remat-invariant model FLOPs (PaLM App-B for LMs) by the same peak —
   configs compare on mfu, hfu explains where the step time went.
 * **Recompile detector** (:meth:`ProgramRegistry.note_trace`): fingerprints
@@ -503,7 +502,7 @@ def cost_from(compiled) -> Dict[str, float]:
 
 def _cost_capture_enabled(default: bool = True) -> bool:
     """Compiled-cost capture re-lowers the program once per new signature
-    (the same lower+compile bench.py always paid). ``HOROVOD_PROFILER_COST``
+    (one more lower + compile). ``HOROVOD_PROFILER_COST``
     forces it on (``1``) or off (``0``) for every call site; unset falls
     back to ``default`` — True for instrumented steps, False for the
     serving engine (whose capture compiles each phase a second time

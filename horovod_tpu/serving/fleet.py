@@ -611,6 +611,8 @@ class FleetSupervisor:
         slot.state = LIVE
         if slot.role == "serving":
             self._member_add(slot)
+        else:
+            self._heal_quarantined()
         metrics._timeline_marker("FLEET", category="fleet",
                                  event="live", replica=slot.name,
                                  attempt=slot.attempt, was=was)
@@ -716,10 +718,22 @@ class FleetSupervisor:
                         pool=spare.serve_role)
             return
 
+    def _heal_quarantined(self) -> None:
+        """A parked serving slot never comes back, so a warm spare takes
+        its place whenever one is live: at the quarantine, or when the
+        spare is admitted later. ``_on_death`` promotes only at the death
+        of a LIVE serving replica; one that is parked while restarting
+        (or while the spare is itself rebuilding) would otherwise leave
+        the fleet under its target beside an idle spare."""
+        for slot in self._slots:
+            if slot.role == "serving" and slot.state == QUARANTINED:
+                self._promote_spare(slot)
+
     def _quarantine(self, slot: ReplicaSlot, reason: str) -> None:
         slot.state = QUARANTINED
         slot.quarantine_reason = reason
         slot.next_restart_at = float("inf")
+        self._heal_quarantined()
         # Event counter next to the sticky state gauge: the continuous
         # doctor's windowed availability check alerts on the *event*
         # (which ages out of the window and clears) rather than the

@@ -193,7 +193,7 @@ def span(name: str, tr: Any, **args: Any):
 
 def events() -> List[Dict[str, Any]]:
     """Snapshot of the live span buffer (what ``/trace`` serves and what
-    ``serve_bench`` feeds into ``trace_merge.request_report``)."""
+    ``tools/serve_bench.py`` feeds into ``trace_merge.request_report``)."""
     with _LOCK:
         return list(_BUF)
 

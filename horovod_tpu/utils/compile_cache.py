@@ -1,7 +1,7 @@
 """Where the persistent XLA compilation cache lives.
 
-One rule for every entry point (``chip_smoke.py``, ``bench.py``, the
-tools, the test harness): if ``JAX_COMPILATION_CACHE_DIR`` is set, jax
+One rule for every entry point (``chip_smoke.py``, ``benchmark/run.py``,
+the tools, the test harness): if ``JAX_COMPILATION_CACHE_DIR`` is set, jax
 reads it itself and nothing is set in code; otherwise the cache is
 ``<checkout>/.jax_cache``. The path is part of how a run finds an earlier
 run's entries, so it never derives from a temp dir, a pid or a clock.

@@ -35,6 +35,46 @@ def _init_hvd():
     yield
 
 
+def session_state_faults():
+    """What of the session's global state is not as ``_init_hvd`` left it:
+    one line a kind, empty when a file put back all it changed."""
+    from horovod_tpu import process_set, timeline
+    if not hvd.is_initialized():
+        return ["hvd left shut down"]
+    faults = []
+    if hvd.size() != 8:
+        faults.append(f"world left at {hvd.size()} devices, not 8")
+    if timeline.get_timeline() is not None:
+        faults.append(f"timeline left started "
+                      f"({timeline.get_timeline().path})")
+    extra = sorted(set(process_set.get_process_set_ids_and_ranks()) - {0})
+    if extra:
+        faults.append(f"process sets {extra} left registered")
+    return faults
+
+
+def hold_to_session_state(file):
+    """Fail, naming ``file``, if the session's state is not as it was, and
+    put it back first so that no stranger fails for it: ``hvd.init``
+    resets the process sets itself; a timeline is stopped before it, or
+    init keeps it."""
+    faults = session_state_faults()
+    if faults:
+        hvd.stop_timeline()
+        hvd.init()
+        pytest.fail(f"{file} left the session's hvd state changed: "
+                    f"{'; '.join(faults)} (restored)", pytrace=False)
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _session_state_guard(request):
+    """Every file shares one process's ``hvd`` state with the files its
+    xdist worker runs next. A file that changes it puts it back; one that
+    does not is named here, on its own last test."""
+    yield
+    hold_to_session_state(request.module.__file__)
+
+
 @pytest.fixture
 def rng():
     import numpy as np

@@ -463,7 +463,7 @@ class TestPerfDoctorCLI:
 
 class TestTwoProcessSmoke:
     def test_doctor_smoke_two_process(self, tmp_path):
-        """Acceptance drive: 2 real processes, a manufactured 250ms
+        """Acceptance drive: 2 real processes, a manufactured 750ms
         straggler and a forced recompile; hvd.doctor() must rank both and
         name the blamed argument (tools/doctor_smoke.py, also
         `make doctor-smoke`)."""

@@ -353,6 +353,44 @@ class TestSupervision:
         for h in handles.values():
             h.stop()
 
+    @pytest.mark.parametrize("spare_ready_first", [True, False])
+    def test_spare_takes_a_quarantined_serving_slot(self,
+                                                    spare_ready_first):
+        """A serving replica parked while it was restarting (never LIVE
+        at a death, so no promotion then) must not leave the fleet under
+        its target beside a warm spare, whichever of the quarantine and
+        the spare's admission comes first (the order the fleet smoke's
+        two crash loops take under a loaded machine)."""
+        spare = []
+
+        def launcher(name, rank, attempt):
+            if name == "r0":
+                return DeadOnArrivalHandle()
+            spare.append(InProcReplica(rank))
+            return spare[-1]
+
+        fleet = self._fleet(launcher, target=1, spares=1, crash_loop_k=3,
+                            crash_loop_window_seconds=60.0,
+                            restart_budget=99)
+        r0, s0 = fleet.slot("r0"), fleet.slot("s0")
+        if spare_ready_first:
+            fleet._launch(s0)
+            assert _poll_until(fleet, lambda: s0.state == "live")
+            fleet._launch(r0)
+            assert _poll_until(fleet, lambda: r0.state == "quarantined")
+        else:
+            fleet._launch(r0)
+            s0.next_restart_at = float("inf")     # not launched yet
+            assert _poll_until(fleet, lambda: r0.state == "quarantined")
+            assert fleet.live_serving_count() == 0
+            fleet._launch(s0)
+            assert _poll_until(fleet, lambda: s0.state == "live")
+        assert (s0.role, r0.role) == ("serving", "spare")
+        assert fleet.live_serving_count() == 1
+        assert r0.state == "quarantined"          # still parked
+        for h in spare:
+            h.stop()
+
     def test_rolling_restart_replaces_every_serving_replica(self,
                                                            tmp_path):
         member = str(tmp_path / "members.json")

@@ -595,21 +595,6 @@ class TestTimelineSatelliteFixes:
         assert [e["name"] for e in events2] == ["second"]
 
 
-class TestBenchWiring:
-    def test_report_carries_negotiation_and_collective_counters(self):
-        """Satellite: BENCH_*.json lines embed negotiation_stats() and the
-        metrics snapshot's collective counters."""
-        import importlib.util
-        spec = importlib.util.spec_from_file_location(
-            "bench_for_metrics_test", "bench.py")
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
-        hvd.allreduce(np.ones((hvd.size(), 2), np.float32))
-        rec = bench._report("m", "u", 1.0, 0.5, 2e12)
-        assert rec["negotiation"] == {"full": 0, "fast": 0}  # single process
-        assert rec["collectives"]["allreduce"]["calls"] >= 1
-
-
 class TestServeLatencyBuckets:
     """ISSUE 15 satellite: sub-ms histogram resolution for the serving
     latency families, and the live scrape endpoint round-tripping

@@ -1,6 +1,10 @@
 """Native runtime core tests (cpp/libhvdtpu.so via ctypes)."""
 
 import json
+import os
+import shutil
+import subprocess
+import threading
 import time
 
 import numpy as np
@@ -98,3 +102,37 @@ class TestNativeTimeline:
         assert [e["name"] for e in data["traceEvents"]] == [
             "allreduce", "broadcast"]
         assert data["traceEvents"][0]["dur"] == 120.0
+
+
+def test_build_puts_a_whole_library_in_place_or_none(tmp_path):
+    """Six xdist workers of a fresh checkout call ``native.load()`` at
+    once, and each builds: a reader must find no file or all of it. The
+    stand-in compiler writes its output in two halves, slowly."""
+    cpp = tmp_path / "cpp"
+    shutil.copytree(os.path.join(native._REPO, "cpp"), cpp,
+                    ignore=shutil.ignore_patterns("*.so"))
+    slow = tmp_path / "slow_cxx.sh"
+    slow.write_text('#!/bin/sh\nwhile [ "$1" != "-o" ]; do shift; done\n'
+                    'printf half > "$2"; sleep 0.4; printf whole > "$2"\n')
+    slow.chmod(0o755)
+    seen = set()
+    done = threading.Event()
+
+    def reader():
+        while not done.is_set():
+            try:
+                seen.add((cpp / "libhvdtpu.so").read_text())
+            except FileNotFoundError:
+                pass
+
+    t = threading.Thread(target=reader)
+    t.start()
+    try:
+        subprocess.run(["make", "-C", str(cpp), f"CXX={slow}"], check=True,
+                       capture_output=True, timeout=60)
+    finally:
+        done.set()
+        t.join()
+    assert (cpp / "libhvdtpu.so").read_text() == "whole"
+    assert seen <= {"whole"}, seen
+    assert not [n for n in os.listdir(cpp) if ".tmp." in n]

@@ -22,6 +22,11 @@ from horovod_tpu import overlap
 ALGS = ("psum", "rs_ag", "chunked_rs_ag")
 
 
+def _counter(name, **labels):
+    return sum(c["value"] for c in hvd.metrics()["counters"].get(name, [])
+               if all(c["labels"].get(k) == v for k, v in labels.items()))
+
+
 def _tol(dtype):
     if dtype == jnp.bfloat16:
         return dict(rtol=2e-2, atol=2e-2)
@@ -529,15 +534,53 @@ class TestOverlapModes:
 
 
 class TestConfigKnobs:
-    def test_env_plumbing_and_gauges(self, monkeypatch):
+    # What the lowered README step holds for each schedule: the way to
+    # time one is the variable around ``benchmark/run.py`` (PERFORMANCE.md),
+    # so the variable has to reach the step's collectives.
+    @pytest.mark.parametrize("alg, has, has_not", [
+        ("psum", ["all_reduce"],
+         ["reduce_scatter", "collective_permute"]),
+        ("rs_ag", ["reduce_scatter", "all_gather"], ["collective_permute"]),
+        ("chunked_rs_ag", ["reduce_scatter", "all_gather"],
+         ["collective_permute"]),
+        ("swing", ["collective_permute"], ["reduce_scatter"]),
+    ], ids=["psum", "rs_ag", "chunked_rs_ag", "swing"])
+    def test_env_plumbing_and_gauges(self, monkeypatch, rng, alg, has,
+                                     has_not):
+        import optax
         from horovod_tpu import config as hconfig
-        monkeypatch.setenv("HOROVOD_ALLREDUCE_ALGORITHM", "rs_ag")
+        monkeypatch.setenv("HOROVOD_ALLREDUCE_ALGORITHM", alg)
         monkeypatch.setenv("HOROVOD_OVERLAP_CHUNKS", "7")
         cfg = hconfig.refresh()
         try:
-            assert cfg.allreduce_algorithm == "rs_ag"
+            assert cfg.allreduce_algorithm == alg
             assert cfg.overlap_chunks == 7
-            assert hvd.build_info()["allreduce_algorithm"] == "rs_ag"
+            assert hvd.build_info()["allreduce_algorithm"] == alg
+            W, X, loss = TestOverlapModes()._problem(rng)
+            opt = hvd.DistributedOptimizer(optax.sgd(0.1))
+
+            def train_step(w, opt_state, x):
+                value, grads = hvd.value_and_grad(loss)(w, x)
+                updates, opt_state = opt.update(grads, opt_state, w)
+                return optax.apply_updates(w, updates), opt_state, value
+
+            step = hvd.spmd(train_step, in_specs=(P(), P(), P("hvd")),
+                            out_specs=(P(), P(), P()))
+            lowered_as = _counter("allreduce_algorithm_total", algorithm=alg)
+            text = step.lower(W, opt.init(W), X).as_text()
+            buckets = _counter("allreduce_algorithm_total",
+                               algorithm=alg) - lowered_as
+            assert buckets >= 1
+            for op in has:
+                assert f"stablehlo.{op}" in text, (alg, op)
+            for op in has_not:
+                assert f"stablehlo.{op}" not in text, (alg, op)
+            # a pair a bucket, or a pair for each chunk of each bucket
+            pairs = text.count("stablehlo.reduce_scatter")
+            if alg == "rs_ag":
+                assert pairs == buckets
+            elif alg == "chunked_rs_ag":
+                assert pairs > buckets
         finally:
             monkeypatch.delenv("HOROVOD_ALLREDUCE_ALGORITHM")
             monkeypatch.delenv("HOROVOD_OVERLAP_CHUNKS")

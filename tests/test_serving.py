@@ -843,3 +843,22 @@ class TestTwoProcessSmoke:
         # rendezvous-flake retry in tools/smoke_util.py.
         rc, text = serve_smoke.run_smoke(str(tmp_path))
         assert rc == 0, text
+
+
+# ---------------------------------------------------------------------------
+# the load generator's command line (tools/serve_bench.py, loaded by path:
+# tools/ is not a package)
+# ---------------------------------------------------------------------------
+
+def test_serve_bench_tool_parser():
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "hvd_serve_bench", os.path.join(_REPO, "tools", "serve_bench.py"))
+    sb = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(sb)
+    args = sb._build_parser().parse_args(
+        ["--requests", "4", "--rate", "9", "--kv-quant", "int8"])
+    assert args.requests == 4 and args.rate == 9.0
+    assert args.kv_quant == "int8"
+    with pytest.raises(SystemExit):
+        sb._build_parser().parse_args(["--kv-quant", "int4"])

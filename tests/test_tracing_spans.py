@@ -454,6 +454,7 @@ def test_lowered_block_diffusion_step_carries_its_scopes_and_manifest():
             debug_info=True)
     finally:
         hvd.shutdown()
+        hvd.init()          # back onto the session's 8 CPU devices
     for name in SDAR_SCOPES + KERNELS:
         assert name in text, name
     read = {name: _program_gauge(name, "bd_step")

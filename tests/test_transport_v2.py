@@ -318,10 +318,17 @@ class TestStreamWire:
             assert h.tokens == [0, 2, 4, 6, 8, 10]
             assert pushed == [(i, i * 2) for i in range(6)]
             assert h.ttft_client is not None
-            # push lag histogram saw the token frames
-            snap = metrics.snapshot()
-            lag = snap["histograms"].get(
-                "transport_stream_push_lag_seconds", [])
+            # push lag histogram saw the token frames (the pump counts a
+            # batch after its sendall: the terminal can reach the client
+            # before the last token's lag is observed, so wait for it)
+            bound = time.monotonic() + 10.0
+            while True:
+                lag = metrics.snapshot()["histograms"].get(
+                    "transport_stream_push_lag_seconds", [])
+                if (lag and lag[0]["count"] >= 6) \
+                        or time.monotonic() > bound:
+                    break
+                time.sleep(0.01)
             assert lag and lag[0]["count"] >= 6
         finally:
             disp.close()

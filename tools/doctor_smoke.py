@@ -3,11 +3,13 @@
 recompile, one ranked diagnosis.
 
 Spawns two real processes that rendezvous over ``jax.distributed`` with
-``HOROVOD_TIMELINE`` shards on. Rank 1 sleeps 250ms before one allreduce
-(manufactured straggler); both ranks run a profiled step twice with a
-changed static argument (forced recompile, blamed on ``seq_len``); each
-rank writes its metrics snapshot. The parent merges the trace shards,
-fuses the snapshots, runs ``hvd.doctor()``, and verifies:
+``HOROVOD_TIMELINE`` shards on. Rank 1 sleeps 750ms before one allreduce
+(manufactured straggler: long enough that the scheduling noise of a
+loaded machine, which has eaten 120ms of it, leaves the 200ms floor);
+both ranks run a profiled step twice with a changed static argument
+(forced recompile, blamed on ``seq_len``); each rank writes its metrics
+snapshot. The parent merges the trace shards, fuses the snapshots, runs
+``hvd.doctor()``, and verifies:
 
 * a ``straggler`` finding names rank 1 with >= 200ms of blame,
 * a ``recompile`` finding names the blamed argument ``seq_len``,
@@ -44,7 +46,7 @@ WORKER = textwrap.dedent("""
     n = hvd.size()
     for step in range(3):
         if pid == 1 and step == 1:
-            time.sleep(0.25)   # manufactured straggler: rank 1 arrives late
+            time.sleep(0.75)   # manufactured straggler: rank 1 arrives late
         hvd.allreduce(np.full((n, 4), float(pid + 1), np.float32),
                       name=f"grads_step{{step}}")
     # Forced recompile: the static seq_len changes between calls, so the
@@ -115,7 +117,7 @@ def run_smoke(workdir: str, timeout_s: float = 240.0):
     s = stragglers[0]
     if s["evidence"].get("blamed_rank") != 1 \
             or s["evidence"].get("blame_seconds", 0) < 0.2:
-        print(f"straggler finding does not blame rank 1 for the 250ms "
+        print(f"straggler finding does not blame rank 1 for the 750ms "
               f"sleep: {s['evidence']}", file=sys.stderr)
         return 1, ""
 

@@ -71,7 +71,6 @@ MODULES = [
     "horovod_tpu.ops.sequence",
     "horovod_tpu.ops.moe",
     "horovod_tpu.ops.sync_batch_norm",
-    "horovod_tpu.ops.batch_norm",
     "horovod_tpu.ops.quantized",
     "horovod_tpu.ops.tile_table",
     "horovod_tpu.data.store",

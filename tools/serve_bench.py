@@ -2,17 +2,15 @@
 """Serving load generator: Poisson arrivals against one InferenceEngine,
 TTFT / TPOT / throughput percentiles as JSON lines.
 
-Offline bench numbers (``bench.py --model gpt2_decode``) measure the
-decode program's raw token rate; what users feel is different — time to
-*first* token under contention (TTFT), steady-state time per output
+A decode program's raw token rate is not what users feel: that is time
+to *first* token under contention (TTFT), steady-state time per output
 token (TPOT), and how both degrade as the arrival rate climbs. This
 tool measures exactly that: requests arrive on a seeded exponential
 clock, prompt lengths and output budgets drawn from seeded ranges, the
 engine serves them under its real continuous-batching scheduler, and
 the record carries p50/p90/p99 of every latency plus goodput.
 
-One JSON line per run to stdout (append with ``--out``); ``bench.py
---serve`` prints the same record shape.
+One JSON line per run to stdout (append with ``--out``).
 
 Usage::
 
@@ -81,9 +79,9 @@ def run_bench(*, requests: int = 32, rate: float = 50.0,
     from horovod_tpu.serving.engine import InferenceEngine
 
     # With HOROVOD_REQUEST_TRACE=1 every benched request is span-traced
-    # and the record carries the mean TTFT component breakdown; the
-    # request_trace flag is part of the sentinel identity, so traced
-    # rows never gate against untraced ones.
+    # and the record carries the mean TTFT component breakdown (and
+    # ``request_trace``, so a traced row is never read beside an
+    # untraced one).
     trace_on = reqtrace.enabled()
     if trace_on:
         reqtrace.reset()
@@ -92,7 +90,7 @@ def run_bench(*, requests: int = 32, rate: float = 50.0,
         cfg = GPT2Config.tiny(dtype=jnp.float32)
         max_len = min(max_len, cfg.max_seq_len)
     else:
-        # gpt2-medium geometry, the family bench.py's decode bench uses.
+        # gpt2-medium geometry.
         cfg = GPT2Config(vocab_size=50257, max_seq_len=max(max_len, 1024),
                          num_layers=24, num_heads=16, d_model=1024,
                          dtype=jnp.bfloat16)
@@ -223,9 +221,8 @@ def run_bench(*, requests: int = 32, rate: float = 50.0,
         "metric": metric,
         "value": round(tokens / wall, 2),
         "unit": "tokens/sec", "vs_baseline": None,
-        # proxy: a CPU run; bench_sentinel gates such rows — a >10%
-        # throughput drop at equal settings (transport included) fails
-        # the build. The device fields say where any row ran.
+        # proxy: a CPU run, which says what the engine counts and never
+        # how fast it is. The device fields say where any row ran.
         "proxy": dev.platform == "cpu",
         "platform": dev.platform, "device_kind": dev.device_kind,
         "devices": len(jax.devices()),
@@ -321,7 +318,7 @@ def run_storm_bench(*, roles: str = "1x2", requests: int = 32,
 
     Emits three records: one per mode (``serve_storm_tokens_per_sec``
     with TTFT/TPOT percentile summaries, distinguished by the
-    ``serve_role`` settings field the sentinel keys on) plus a
+    ``serve_role`` settings field) plus a
     mono-over-disagg p99-TPOT ratio line (higher is better; >= 1.0
     means the decode tail was no worse under disaggregation). The
     disagg record also carries the prefix-cache hit rates: ``local``
@@ -624,8 +621,8 @@ def main() -> int:
         off = run_bench(prefix_cache=False, **kw)
         on = run_bench(prefix_cache=True, **kw)
         recs += [off, on]
-        # Gated proxies for the sentinel: both are higher-is-better, so
-        # a regression in either shows up as a drop in "value".
+        # Both are higher-is-better: a regression in either shows up as
+        # a drop in "value".
         common = {k: on[k] for k in
                   ("transport", "requests", "arrival_rate_hz", "slots",
                    "max_len", "block_size", "prefill_chunk", "kv_quant",
