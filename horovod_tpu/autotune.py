@@ -62,6 +62,11 @@ def autotune_fusion_threshold(
     return AutotuneResult(best_threshold_bytes=best, trials=trials)
 
 
+# The plain (block_q, block_k) grid of a flash tile sweep, shaped for a v5e.
+FLASH_TILE_CANDIDATES = [(128, 128), (128, 512), (256, 256), (256, 512),
+                         (256, 1024), (512, 512), (512, 1024)]
+
+
 def autotune_flash_blocks(q_shape, dtype="bfloat16", causal: bool = True,
                           candidates: Optional[List[tuple]] = None,
                           steps_per_trial: int = 5,
@@ -89,6 +94,17 @@ def autotune_flash_blocks(q_shape, dtype="bfloat16", causal: bool = True,
     candidate count and every differentiated pallas candidate is its own
     compile, so pinned-then-sweep is the practical shape.
 
+    A candidate may be ``(block_q, block_k, chunk)`` with ``block_k`` the
+    whole sequence: the causal kernels then loop inside each grid step
+    over compute chunks of ``chunk`` keys of the resident K tile, as far
+    as the diagonal
+    (``ops/flash_attention._chunk_loop``). Such a winner is returned as
+    the candidate it was (with ``tune_backward``: ``(bq, bk, bq_bwd,
+    bk_bwd, chunk, chunk_bwd)``, ``tile_table.lookup_full``'s order) and
+    recorded with its chunk. A chunk is the table's to give, not an
+    argument of ``flash_attention``, so the probes enter by the private
+    ``_attend``.
+
     ``chain`` kernel invocations are scanned inside ONE jit (each step's
     output feeds the next step's queries), so a single dispatch carries
     ``chain``x the device work — per-dispatch host latency is amortized
@@ -98,7 +114,8 @@ def autotune_flash_blocks(q_shape, dtype="bfloat16", causal: bool = True,
       q_shape: (batch, seq, heads, head_dim) to tune for.
       dtype: array dtype for the probe tensors.
       causal: tune the causal or full-attention variant.
-      candidates: (block_q, block_k) pairs; defaults to a v5e-shaped grid.
+      candidates: (block_q, block_k) pairs or (block_q, block_k, chunk)
+        triples; defaults to a v5e-shaped grid of pairs.
       include_backward: time fwd+bwd (the training shape) vs fwd only.
       chain: attention invocations chained per dispatch. Compile time per
         candidate grows with ``chain`` (the backward scan differentiates
@@ -117,7 +134,7 @@ def autotune_flash_blocks(q_shape, dtype="bfloat16", causal: bool = True,
     import numpy as np
     from jax import lax
 
-    from horovod_tpu.ops.flash_attention import flash_attention
+    from horovod_tpu.ops.flash_attention import _attend
 
     if record:
         # Validate the destination BEFORE the sweep — a typo'd kind or
@@ -139,18 +156,23 @@ def autotune_flash_blocks(q_shape, dtype="bfloat16", causal: bool = True,
                 f"tile table directory {dp.parent} is not writable")
 
     if candidates is None:
-        candidates = [(128, 128), (128, 512), (256, 256), (256, 512),
-                      (256, 1024), (512, 512), (512, 1024)]
+        candidates = FLASH_TILE_CANDIDATES
     rng = np.random.default_rng(0)
     q, k, v = (jnp.asarray(rng.standard_normal(q_shape), dtype)
                for _ in range(3))
 
-    def make_fn(bq, bk, bqb, bkb, backward):
+    def tiling(cand):
+        """(block_q, block_k, chunk or None) of a candidate."""
+        return tuple(cand) + (None,) * (3 - len(cand))
+
+    def make_fn(fwd, bwd, backward):
+        (bq, bk, chunk), (bqb, bkb, chunk_bwd) = tiling(fwd), tiling(bwd)
+        tiles = (bq, bk, bqb, bkb, chunk, chunk_bwd)
+
         def chained(q, k, v):
             def body(c, _):
-                o = flash_attention(c, k, v, causal=causal, block_q=bq,
-                                    block_k=bk, block_q_bwd=bqb,
-                                    block_k_bwd=bkb)
+                o = _attend(c, k, v, causal, q_shape[-1] ** -0.5, None,
+                            None, tiles)
                 return o.astype(c.dtype), None
             out, _ = lax.scan(body, q, None, length=chain)
             return out
@@ -179,10 +201,10 @@ def autotune_flash_blocks(q_shape, dtype="bfloat16", causal: bool = True,
         return (time.perf_counter() - t0) / steps_per_trial / max(chain, 1)
 
     trials: Dict[tuple, float] = {}
-    for bq, bk in candidates:
-        t = time_candidate(make_fn(bq, bk, bq, bk, include_backward))
+    for cand in candidates:
+        t = time_candidate(make_fn(cand, cand, include_backward))
         if t is not None:
-            trials[(bq, bk)] = t
+            trials[tuple(cand)] = t
     if not trials:
         raise RuntimeError(
             f"no flash tiling compiled for shape {q_shape}") from last_error
@@ -192,28 +214,38 @@ def autotune_flash_blocks(q_shape, dtype="bfloat16", causal: bool = True,
     if tune_backward:
         # Phase 2: forward tiles pinned at the winner; each candidate now
         # times the BACKWARD kernels' tiling on full fwd+bwd probes.
-        fq, fk = best
+        fwd_best = best
         bwd_trials: Dict[tuple, float] = {}
-        for bq, bk in candidates:
-            t = time_candidate(make_fn(fq, fk, bq, bk, True))
+        for cand in candidates:
+            t = time_candidate(make_fn(fwd_best, cand, True))
             if t is not None:
-                bwd_trials[(bq, bk)] = t
-                trials[("bwd", bq, bk)] = t
+                bwd_trials[tuple(cand)] = t
+                trials[("bwd",) + tuple(cand)] = t
         if bwd_trials:
             bwd_best = min(bwd_trials, key=bwd_trials.get)
-            best = (fq, fk) + bwd_best
+            (fq, fk, chunk), (bq, bk, chunk_bwd) = (tiling(fwd_best),
+                                                    tiling(bwd_best))
+            best = (fq, fk, bq, bk)
+            if chunk or chunk_bwd:
+                best += (chunk or fk, chunk_bwd or bk)
 
     if record:
-        extra = {}
+        fwd = tiling(best if bwd_best is None else fwd_best)
+        extra = {} if fwd[2] is None else dict(chunk=fwd[2])
         us = trials[best] if bwd_best is None else bwd_trials[bwd_best]
         if bwd_best is not None:
-            extra = dict(block_q_bwd=bwd_best[0], block_k_bwd=bwd_best[1])
+            bwd = tiling(bwd_best)
+            extra.update(block_q_bwd=bwd[0], block_k_bwd=bwd[1])
+            if fwd[2] is not None or bwd[2] is not None:
+                # said even where it is the whole tile: left out, the
+                # table would hand the backward the forward's
+                extra.update(chunk_bwd=bwd[2] or bwd[1])
             suffix = "-fwdbwd"
         else:
             suffix = "" if include_backward else "-fwdonly"
         tile_table.record(
             head_dim=q_shape[-1], seq=q_shape[1], dtype=dtype, kind=kind,
-            block_q=best[0], block_k=best[1],
+            block_q=fwd[0], block_k=fwd[1],
             us_per_call=us * 1e6,
             source=f"tuned-{jax.default_backend()}" + suffix,
             device=jax.devices()[0].device_kind,

@@ -14,7 +14,19 @@ Design (tpu-first):
 - QK^T and PV ride the MXU via ``jnp.dot(..., preferred_element_type=f32)``;
   the online-softmax rescale is VPU work fused in between.
 - Causal masking skips whole K blocks past the diagonal with ``@pl.when``
-  (no FLOPs burned above the diagonal beyond one partial block per row).
+  (no FLOPs burned above the diagonal beyond one partial block per row) —
+  where the grid has K blocks to skip. A shape whose tile-table entry
+  carries a compute ``chunk`` (head 64 / T 1024, swept forward and backward
+  on the v5e) is tiled at two levels instead: the K tile is the whole key
+  axis, resident for the head, the grid's K axis has one step, and all
+  three kernels loop inside the step over chunks of the keys, as many as
+  the diagonal leaves visible to this Q tile (``_causal_chunks``,
+  ``_chunk_loop``). Chunks wholly under the diagonal take no positional
+  mask; only those it crosses go through ``_mask_scores``. The forward
+  carries its softmax state round the loop as values (a chunk's
+  read-modify-write of the 1-D scratch costs more than the chunk's
+  scores), the backward kernels keep their sums in scratch. An entry
+  without a chunk runs the kernels as they always were.
 - Sequence lengths need not divide the block size: the grid is ``cdiv`` and
   the ragged edge blocks are position-masked (ViT's 197 tokens, odd context
   lengths). Tiling — and the VMEM bound — is preserved.
@@ -26,8 +38,14 @@ Design (tpu-first):
 - Off-TPU (the virtual CPU test mesh) the same kernels run in Pallas
   interpreter mode, so tests exercise the real kernel code path.
 
-Block sizes default to (256, 512) — measured fastest on v5e — and are
-clamped to the sequence length for small inputs.
+Tiles come from the checked-in tile table (``ops/tile_table.py``,
+``flash_tiles.json``), by (head_dim, seq, dtype, kind of mask), measured on
+the chip by ``tools/tune_tiles.py``; a shape with no entry near it takes the
+table's default (256, 512). They are clamped to the sequence length for
+small inputs. What a visit of a tile costs on the v5e is mostly fixed (the
+chain matmul, softmax, matmul of one pair does not overlap the next pair's),
+so larger tiles and chunks win until the scores they waste above the
+diagonal outweigh it: 128-wide ones lose everywhere.
 """
 
 from __future__ import annotations
@@ -121,10 +139,12 @@ def _zero_oob_rows(x, blk, block: int, t: int):
 
 
 def _causal_skip(causal: bool, q_blk, kv_idx, block_q: int, block_k: int,
-                 offset: int = 0):
-    """True when this (q, kv) block pair has any visible entries."""
-    return jnp.logical_or(
-        jnp.logical_not(causal),
+                 offset: int = 0, xp=jnp):
+    """True when this (q, kv) block pair has any visible entries. Scalars
+    in a kernel; arrays of tile indices and ``xp=np`` in
+    :func:`causal_tiles`."""
+    return xp.logical_or(
+        xp.logical_not(causal),
         kv_idx * block_k < (q_blk + 1) * block_q + offset)
 
 
@@ -158,6 +178,73 @@ def _tile_visible(causal: bool, bd, q_blk, kv_idx, block_q: int,
     return _causal_skip(causal, q_blk, kv_idx, block_q, block_k, offset)
 
 
+def _tiling(tq: int, tk: int, block_q: int, block_k: int, chunk,
+            causal: bool, bd):
+    """``(block_q, block_k, chunk)`` as the kernels run them. Without a
+    compute chunk (``None``) the tiles are the grid's, clamped to the
+    lengths. With one (the tile table gave it, the mask is the causal one,
+    the table's K tile holds every key, and they make more than one chunk)
+    the K tile is the whole key axis, in whole chunks: K and V of a head
+    stay resident, the grid's K axis has one step, and a loop inside the
+    step takes its place (:func:`_chunk_loop`)."""
+    bq, bk = _block_sizes(tq, tk, block_q, block_k)
+    if causal and bd is None and chunk and block_k >= tk and 0 < chunk < tk:
+        return bq, -(-tk // chunk) * chunk, int(chunk)
+    return bq, bk, None
+
+
+def _causal_chunks(q_blk, block_q: int, chunk: int, offset: int, tk: int,
+                   xp=jnp):
+    """``(clear, visible)`` of the compute chunks of the key axis against Q
+    tile ``q_blk`` under the causal mask: the first ``clear`` chunks lie
+    wholly at or under the diagonal (every pair visible: no positional
+    mask), the first ``visible`` hold a visible pair at all, the rest are
+    not visited. A scalar in a kernel; an array of tile indices and
+    ``xp=np`` in :func:`causal_tiles`."""
+    q0 = q_blk * block_q + offset          # its first row sees keys <= q0
+    visible = xp.minimum((xp.maximum(q0 + block_q, 0) + chunk - 1) // chunk,
+                         -(-tk // chunk))
+    clear = xp.minimum(xp.maximum(q0 + 1, 0) // chunk, visible)
+    return clear, visible
+
+
+def _chunk_loop(visit, state, q_blk, block_q: int, chunk: int, offset: int,
+                tk: int):
+    """The loop inside a grid step whose length is the diagonal:
+    ``state = visit(state, ci, rows, crossed)`` over the compute chunks of
+    the resident K tile that Q tile ``q_blk`` may see, ``rows`` of the
+    tile known to the mask as block ``ci`` of ``chunk`` keys. The chunks
+    under the diagonal come first and take no causal mask
+    (``crossed=False``), then those it crosses."""
+    clear, visible = _causal_chunks(q_blk, block_q, chunk, offset, tk)
+
+    def run(lo, hi, crossed: bool, state):
+        def body(ci, state):
+            rows = pl.ds(pl.multiple_of(ci * chunk, chunk), chunk)
+            return visit(state, ci, rows, crossed)
+        return jax.lax.fori_loop(lo, hi, body, state)
+
+    return run(clear, visible, True, run(0, clear, False, state))
+
+
+def causal_tiles(t: int, block_q: int, block_k: int, chunk=None,
+                 offset: int = 0):
+    """``(visited, total)`` of one head's causal forward over ``t``
+    positions, from shapes alone (the routing manifest's
+    ``causal_tiles_visited`` / ``causal_tiles_total``): pairs of (Q tile,
+    compute chunk) where the kernels loop over chunks, else the grid's
+    tiles, each counted by the predicate the kernel runs by."""
+    bq, bk, chunk = _tiling(t, t, block_q, block_k, chunk, True, None)
+    q_blk = np.arange(-(-t // bq))
+    if chunk is None:
+        kv_idx = np.arange(-(-t // bk))
+        hit = _causal_skip(True, q_blk[:, None], kv_idx[None, :], bq, bk,
+                           offset, xp=np)
+        return int(np.sum(hit)), hit.size
+    _, visible = _causal_chunks(q_blk, bq, chunk, offset, t, xp=np)
+    return int(np.sum(visible)), q_blk.size * -(-t // chunk)
+
+
 def bd_tiles(seq: int, blk: int, block_q: int, block_k: int):
     """``(visited, total)`` tiles of one head's forward grid over a
     block-diffusion row of ``2 * seq`` positions, from shapes alone (the
@@ -173,10 +260,64 @@ def bd_tiles(seq: int, blk: int, block_q: int, block_k: int):
 # Forward
 # ---------------------------------------------------------------------------
 
+def _scores(q_ref, k_ref, bias_ref, segq_ref, segk_ref, q_blk, kv_blk, rows,
+            *, scale: float, causal: bool, offset: int, block_q: int,
+            block_k: int, tq: int, tk: int, bd=None):
+    """The scaled Q tile, ``rows`` of the resident K tile, and their masked
+    scores: what all three kernels start a (Q tile, K tile or compute
+    chunk) pair with. The mask knows the rows as block ``kv_blk`` of
+    ``block_k`` keys."""
+    q = _zero_oob_rows(q_ref[0].astype(jnp.float32) * scale,
+                       q_blk, block_q, tq)
+    k = _zero_oob_rows(k_ref[0, rows].astype(jnp.float32), kv_blk, block_k,
+                       tk)
+    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
+    bias = None if bias_ref is None else bias_ref[0, rows].reshape(1, -1)
+    seg_q = None if segq_ref is None else segq_ref[0]
+    seg_k = None if segk_ref is None else segk_ref[0, rows]
+    s = _mask_scores(s, q_blk, kv_blk, block_q=block_q, block_k=block_k,
+                     tq=tq, tk=tk, causal=causal, offset=offset,
+                     bias=bias, seg_q=seg_q, seg_k=seg_k, bd=bd)
+    return q, k, s
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref, o_ref,
-                lse_ref, acc_ref, m_ref, l_ref, *, scale: float,
-                causal: bool, offset: int, block_q: int, block_k: int,
-                tq: int, tk: int, bd=None):
+                lse_ref, acc_ref=None, m_ref=None, l_ref=None, *,
+                scale: float, causal: bool, offset: int, block_q: int,
+                block_k: int, tq: int, tk: int, bd=None, chunk=None):
+    scores = functools.partial(
+        _scores, q_ref, k_ref, bias_ref, segq_ref, segk_ref,
+        scale=scale, offset=offset, block_q=block_q, tq=tq, tk=tk, bd=bd)
+    if chunk is not None:
+        q_blk = pl.program_id(1)
+
+        # One grid step holds every key: the online-softmax state is a
+        # value carried round the loop of chunks, never in scratch (whose
+        # read-modify-write a chunk costs more than the chunk's scores).
+        def visit(state, ci, rows, crossed):
+            m_prev, l_prev, acc = state
+            _, _, s = scores(q_blk, ci, rows, causal=crossed,
+                             block_k=chunk)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+            p = jnp.exp(s - m_new[:, None])
+            p = jnp.where(s > _NEG_INF / 2, p, 0.0)
+            correction = jnp.exp(m_prev - m_new)
+            v = _zero_oob_rows(v_ref[0, rows].astype(jnp.float32), ci,
+                               chunk, tk)
+            return (m_new, l_prev * correction + jnp.sum(p, axis=1),
+                    acc * correction[:, None] +
+                    jnp.dot(p, v, preferred_element_type=jnp.float32))
+
+        m, l, acc = _chunk_loop(
+            visit, (jnp.full((block_q,), _NEG_INF, jnp.float32),
+                    jnp.zeros((block_q,), jnp.float32),
+                    jnp.zeros(q_ref.shape[1:], jnp.float32)),
+            q_blk, block_q, chunk, offset, tk)
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
+        lse_ref[0] = (m + jnp.log(l_safe))[:, None]
+        return
+
     kv_idx = pl.program_id(2)
     num_kv = pl.num_programs(2)
 
@@ -191,16 +332,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref, o_ref,
     @pl.when(_tile_visible(causal, bd, q_blk, kv_idx, block_q, block_k,
                            offset))
     def _():
-        q = _zero_oob_rows(q_ref[0].astype(jnp.float32) * scale,
-                           q_blk, block_q, tq)
-        k = _zero_oob_rows(k_ref[0].astype(jnp.float32), kv_idx, block_k, tk)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        bias = None if bias_ref is None else bias_ref[0].reshape(1, -1)
-        seg_q = None if segq_ref is None else segq_ref[0]
-        seg_k = None if segk_ref is None else segk_ref[0]
-        s = _mask_scores(s, q_blk, kv_idx, block_q=block_q, block_k=block_k,
-                         tq=tq, tk=tk, causal=causal, offset=offset,
-                         bias=bias, seg_q=seg_q, seg_k=seg_k, bd=bd)
+        _, _, s = scores(q_blk, kv_idx, slice(None), causal=causal,
+                         block_k=block_k)
 
         m_prev = m_ref[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
@@ -244,15 +377,15 @@ _seg_k_spec = _per_key_spec
 
 
 def _fwd(q, k, v, bias, seg_q, seg_k, h, scale, causal, block_q, block_k,
-         offset=0, bd=None):
+         offset=0, bd=None, chunk=None):
     bh, tq, d = q.shape
     tk = k.shape[1]
-    bq, bk = _block_sizes(tq, tk, block_q, block_k)
+    bq, bk, chunk = _tiling(tq, tk, block_q, block_k, chunk, causal, bd)
     grid = (bh, pl.cdiv(tq, bq), pl.cdiv(tk, bk))
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, offset=offset, block_q=bq,
-        block_k=bk, tq=tq, tk=tk, bd=bd)
+        block_k=bk, tq=tq, tk=tk, bd=bd, chunk=chunk)
     in_specs = [
         pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
@@ -284,7 +417,8 @@ def _fwd(q, k, v, bias, seg_q, seg_k, h, scale, causal, block_q, block_k,
             jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
             jax.ShapeDtypeStruct((bh, tq, 1), jnp.float32),
         ],
-        scratch_shapes=[
+        # the loop over compute chunks carries the softmax state itself
+        scratch_shapes=[] if chunk is not None else [
             pltpu.VMEM((bq, d), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
@@ -323,10 +457,27 @@ def _fill_optionals(kernel, has_bias, has_seg):
 # Backward
 # ---------------------------------------------------------------------------
 
+def _visit_pairs(visit, causal: bool, bd, q_blk, kv_idx, block_q: int,
+                 block_k: int, chunk, offset: int, tk: int):
+    """The backward kernels' ``visit(kv_blk, block_k, rows, causal)`` over
+    what the resident K tile holds that Q tile ``q_blk`` may see: the tile
+    whole, if the grid-level skip lets it through, or with a compute chunk
+    the loop over its chunks as far as the diagonal. Their sums live in
+    scratch either way (there a chunk's read-modify-write is cheaper than
+    carrying them round the loop)."""
+    if chunk is None:
+        pl.when(_tile_visible(causal, bd, q_blk, kv_idx, block_q, block_k,
+                              offset))(
+            lambda: visit(kv_idx, block_k, slice(None), causal))
+        return
+    _chunk_loop(lambda _, ci, rows, crossed: visit(ci, chunk, rows, crossed),
+                None, q_blk, block_q, chunk, offset, tk)
+
+
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
                    do_ref, lse_ref, delta_ref, dq_ref, acc_ref, *,
                    scale: float, causal: bool, offset: int, block_q: int,
-                   block_k: int, tq: int, tk: int, bd=None):
+                   block_k: int, tq: int, tk: int, bd=None, chunk=None):
     kv_idx = pl.program_id(2)
     num_kv = pl.num_programs(2)
 
@@ -336,28 +487,24 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
 
     q_blk = pl.program_id(1)
 
-    @pl.when(_tile_visible(causal, bd, q_blk, kv_idx, block_q, block_k,
-                           offset))
-    def _():
-        q = _zero_oob_rows(q_ref[0].astype(jnp.float32) * scale,
-                           q_blk, block_q, tq)
-        k = _zero_oob_rows(k_ref[0].astype(jnp.float32), kv_idx, block_k, tk)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        bias = None if bias_ref is None else bias_ref[0].reshape(1, -1)
-        seg_q = None if segq_ref is None else segq_ref[0]
-        seg_k = None if segk_ref is None else segk_ref[0]
-        s = _mask_scores(s, q_blk, kv_idx, block_q=block_q, block_k=block_k,
-                         tq=tq, tk=tk, causal=causal, offset=offset,
-                         bias=bias, seg_q=seg_q, seg_k=seg_k, bd=bd)
+    def visit(kv_blk, block_k, rows, causal):
+        _, k, s = _scores(q_ref, k_ref, bias_ref, segq_ref, segk_ref, q_blk,
+                          kv_blk, rows, scale=scale, causal=causal,
+                          offset=offset, block_q=block_q, block_k=block_k,
+                          tq=tq, tk=tk, bd=bd)
         p = jnp.exp(s - lse_ref[0])
         p = jnp.where(s > _NEG_INF / 2, p, 0.0)
         do = _zero_oob_rows(do_ref[0].astype(jnp.float32), q_blk, block_q, tq)
-        v = _zero_oob_rows(v_ref[0].astype(jnp.float32), kv_idx, block_k, tk)
+        v = _zero_oob_rows(v_ref[0, rows].astype(jnp.float32), kv_blk,
+                           block_k, tk)
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
         # p == 0 entries must yield ds == 0 even when dp/delta hold clipped
         # garbage (0 * NaN != 0).
         ds = jnp.where(p > 0.0, p * (dp - delta_ref[0]), 0.0)
         acc_ref[:] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
+
+    _visit_pairs(visit, causal, bd, q_blk, kv_idx, block_q, block_k, chunk,
+                 offset, tk)
 
     @pl.when(kv_idx == num_kv - 1)
     def _():
@@ -368,7 +515,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
                     do_ref, lse_ref, delta_ref, dk_ref, dv_ref, db_ref,
                     dk_acc, dv_acc, db_acc, *, scale: float, causal: bool,
                     offset: int, block_q: int, block_k: int, tq: int,
-                    tk: int, bd=None):
+                    tk: int, bd=None, chunk=None):
     q_idx = pl.program_id(2)
     num_q = pl.num_programs(2)
 
@@ -379,34 +526,30 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
         if db_acc is not None:
             db_acc[:] = jnp.zeros_like(db_acc)
 
-    kv_blk = pl.program_id(1)
+    k_idx = pl.program_id(1)
 
-    @pl.when(_tile_visible(causal, bd, q_idx, kv_blk, block_q, block_k,
-                           offset))
-    def _():
-        q = _zero_oob_rows(q_ref[0].astype(jnp.float32) * scale,
-                           q_idx, block_q, tq)
-        k = _zero_oob_rows(k_ref[0].astype(jnp.float32), kv_blk, block_k, tk)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        bias = None if bias_ref is None else bias_ref[0].reshape(1, -1)
-        seg_q = None if segq_ref is None else segq_ref[0]
-        seg_k = None if segk_ref is None else segk_ref[0]
-        s = _mask_scores(s, q_idx, kv_blk, block_q=block_q, block_k=block_k,
-                         tq=tq, tk=tk, causal=causal, offset=offset,
-                         bias=bias, seg_q=seg_q, seg_k=seg_k, bd=bd)
+    def visit(kv_blk, block_k, rows, causal):
+        q, _, s = _scores(q_ref, k_ref, bias_ref, segq_ref, segk_ref, q_idx,
+                          kv_blk, rows, scale=scale, causal=causal,
+                          offset=offset, block_q=block_q, block_k=block_k,
+                          tq=tq, tk=tk, bd=bd)
         p = jnp.exp(s - lse_ref[0])
         p = jnp.where(s > _NEG_INF / 2, p, 0.0)
         do = _zero_oob_rows(do_ref[0].astype(jnp.float32), q_idx, block_q, tq)
-        dv_acc[:] += jnp.dot(p.T, do, preferred_element_type=jnp.float32)
-        v = _zero_oob_rows(v_ref[0].astype(jnp.float32), kv_blk, block_k, tk)
+        dv_acc[rows] += jnp.dot(p.T, do, preferred_element_type=jnp.float32)
+        v = _zero_oob_rows(v_ref[0, rows].astype(jnp.float32), kv_blk,
+                           block_k, tk)
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
         # p == 0 entries must yield ds == 0 even when dp/delta hold clipped
         # garbage (0 * NaN != 0).
         ds = jnp.where(p > 0.0, p * (dp - delta_ref[0]), 0.0)
-        dk_acc[:] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
+        dk_acc[rows] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
         if db_acc is not None:
             # d(s)/d(bias) = 1 on visible entries → dbias_k = sum_q ds.
             db_acc[:] += jnp.sum(ds, axis=0)
+
+    _visit_pairs(visit, causal, bd, q_idx, k_idx, block_q, block_k, chunk,
+                 offset, tk)
 
     @pl.when(q_idx == num_q - 1)
     def _():
@@ -418,11 +561,11 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
 
 
 def _bwd(h, scale, causal, block_q, block_k, res, do, delta=None,
-         offset=0, want_db=True, bd=None):
+         offset=0, want_db=True, bd=None, chunk=None):
     q, k, v, bias, seg_q, seg_k, o, lse = res
     bh, tq, d = q.shape
     tk = k.shape[1]
-    bq, bk = _block_sizes(tq, tk, block_q, block_k)
+    bq, bk, chunk = _tiling(tq, tk, block_q, block_k, chunk, causal, bd)
 
     if delta is None:
         # delta_i = sum_d dO_i . O_i — the softmax-normalisation term of dS.
@@ -431,10 +574,15 @@ def _bwd(h, scale, causal, block_q, block_k, res, do, delta=None,
                         axis=-1, keepdims=True)
 
     common = dict(scale=scale, causal=causal, offset=offset, block_q=bq,
-                  block_k=bk, tq=tq, tk=tk, bd=bd)
+                  block_k=bk, tq=tq, tk=tk, bd=bd, chunk=chunk)
 
+    track_db = bias is not None and want_db
     dq_kernel = functools.partial(_bwd_dq_kernel, **common)
-    dkv_kernel = functools.partial(_bwd_dkv_kernel, **common)
+    # The bias gradient is accumulated along lanes, where Mosaic takes no
+    # slice at an offset it learns in a loop: with it the dK/dV kernel
+    # keeps the whole K tile as its one compute chunk.
+    dkv_kernel = functools.partial(
+        _bwd_dkv_kernel, **dict(common, chunk=None if track_db else chunk))
 
     def specs(order):
         # order: index_map arg order differs between the two kernels
@@ -469,7 +617,6 @@ def _bwd(h, scale, causal, block_q, block_k, res, do, delta=None,
         ]
         return sp
 
-    track_db = bias is not None and want_db
     extra = () if bias is None else (bias,)
     if seg_q is not None:
         extra = extra + (seg_q, seg_k)
@@ -547,19 +694,18 @@ def _bwd(h, scale, causal, block_q, block_k, res, do, delta=None,
 # Public API
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11,
-                                                    12, 13))
+@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(5, 16)))
 def _flash(q, k, v, bias, seg, h, scale, causal, block_q, block_k,
-           block_q_bwd, block_k_bwd, offset, bd):
+           block_q_bwd, block_k_bwd, offset, bd, chunk, chunk_bwd):
     o, _ = _fwd(q, k, v, bias, seg, seg, h, scale, causal, block_q,
-                block_k, offset=offset, bd=bd)
+                block_k, offset=offset, bd=bd, chunk=chunk)
     return o
 
 
 def _flash_fwd(q, k, v, bias, seg, h, scale, causal, block_q, block_k,
-               block_q_bwd, block_k_bwd, offset, bd):
+               block_q_bwd, block_k_bwd, offset, bd, chunk, chunk_bwd):
     o, lse = _fwd(q, k, v, bias, seg, seg, h, scale, causal, block_q,
-                  block_k, offset=offset, bd=bd)
+                  block_k, offset=offset, bd=bd, chunk=chunk)
     # Named, so that a remat policy can keep them (models/remat.py) and
     # the backward does not run the forward kernel again to get them back.
     # The log-sum-exp is kept without its last dimension of 1, which the
@@ -571,14 +717,14 @@ def _flash_fwd(q, k, v, bias, seg, h, scale, causal, block_q, block_k,
 
 
 def _flash_bwd(h, scale, causal, block_q, block_k, block_q_bwd,
-               block_k_bwd, offset, bd, res, do):
+               block_k_bwd, offset, bd, chunk, chunk_bwd, res, do):
     # The backward kernels' VMEM profile differs from the forward's (two
     # extra fp32 accumulators per tile), so they may want their own tiles
     # — measured entries carry them (tile_table "tuned-*-fwdbwd").
     q, k, v, bias, seg, o, lse = res
     dq, dk, dv, dbias = _bwd(h, scale, causal, block_q_bwd, block_k_bwd,
                              (q, k, v, bias, seg, seg, o, lse[..., None]),
-                             do, offset=offset, bd=bd)
+                             do, offset=offset, bd=bd, chunk=chunk_bwd)
     # Integer segment ids take a symbolic-zero (float0) cotangent.
     dseg = (None if seg is None
             else np.zeros(seg.shape, dtype=jax.dtypes.float0))
@@ -624,17 +770,21 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         skip the tiles that hold no visible pair. Not with ``causal``.
       block_q, block_k: tile sizes (clamped to the sequence lengths).
         ``None`` (default) consults the checked-in tile table
-        (``ops/tile_table.py``, regenerated by ``autotune_flash_blocks``)
-        for the best measured tiling for this (head_dim, seq, dtype);
-        table fallback is (256, 512), measured fastest on v5e for
-        fwd+bwd — 128-tiles drown in per-step grid overhead, and 512x512
-        Q-blocks overflow VMEM in the backward kernels (score temporaries
-        spill). Ragged edges are position-masked.
+        (``ops/tile_table.py``; ``tools/tune_tiles.py`` measures it on
+        the chip) for the nearest measured tiling of this (head_dim, seq,
+        dtype, mask); with no entry the table's default (256, 512).
+        Ragged edges are position-masked. A causal entry may also carry a
+        compute ``chunk``: the kernels then keep the whole key axis
+        resident and loop inside a grid step over chunks of it as far as
+        the diagonal (module docstring). The chunk is the table's to
+        give and goes with the table's tiles only: a call that names its
+        own tiles runs them whole.
       block_q_bwd, block_k_bwd: tile sizes for the backward (dQ and
         dK/dV) kernels, whose VMEM profile differs from the forward's.
         ``None`` consults the tile table (``tuned-*-fwdbwd`` entries from
-        the differentiated-kernel sweep carry measured values); entries
-        without them fall back to the forward tiles.
+        the forward + backward sweep carry measured values, and their
+        own ``chunk_bwd``); entries without them fall back to the
+        forward tiles.
 
     Returns (batch, t_q, heads, head_dim), same dtype as ``q``.
     """
@@ -653,23 +803,44 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                 f"causal={causal}, t_q={tq}, t_kv={tk}")
     scale = d ** -0.5 if scale is None else scale
 
+    chunk = chunk_bwd = None
     if None in (block_q, block_k, block_q_bwd, block_k_bwd):
         from horovod_tpu.ops import tile_table
         kind = ("block_diffusion" if bd is not None
                 else "causal" if causal else "full")
-        tq_, tk_, tqb_, tkb_ = tile_table.lookup_full(
+        tq_, tk_, tqb_, tkb_, chunk_, chunk_bwd_ = tile_table.lookup_full(
             d, max(tq, tk), q.dtype, kind)
         block_q = tq_ if block_q is None else block_q
         block_k = tk_ if block_k is None else block_k
         # Explicit fwd tiles with no explicit bwd tiles: share the fwd
         # tiles (pre-r5 behavior) rather than mixing the caller's fwd
         # choice with a table bwd entry tuned for different fwd tiles.
+        fwd_is_tables = (tq_, tk_) == (block_q, block_k)
         if block_q_bwd is None:
-            block_q_bwd = tqb_ if tq_ == block_q and tk_ == block_k \
-                else block_q
+            block_q_bwd = tqb_ if fwd_is_tables else block_q
         if block_k_bwd is None:
-            block_k_bwd = tkb_ if tq_ == block_q and tk_ == block_k \
-                else block_k
+            block_k_bwd = tkb_ if fwd_is_tables else block_k
+        # A compute chunk was measured inside the table's tile, and goes
+        # with it only.
+        if fwd_is_tables:
+            chunk = chunk_
+        if (tqb_, tkb_) == (block_q_bwd, block_k_bwd):
+            chunk_bwd = chunk_bwd_
+    return _attend(q, k, v, causal, scale, key_bias, segment_ids,
+                   (block_q, block_k, block_q_bwd, block_k_bwd, chunk,
+                    chunk_bwd), causal_offset, bd)
+
+
+def _attend(q, k, v, causal, scale, key_bias, segment_ids, tiles,
+            causal_offset=0, bd=None):
+    """:func:`flash_attention` once the tiles are settled: ``tiles`` is
+    ``(block_q, block_k, block_q_bwd, block_k_bwd, chunk, chunk_bwd)`` as
+    ``tile_table.lookup_full`` gives them. The tile sweep
+    (``autotune_flash_blocks``) enters here, since a compute chunk is the
+    table's to give and no argument of the public call."""
+    b, tq, h, d = q.shape
+    tk = k.shape[1]
+    block_q, block_k, block_q_bwd, block_k_bwd, chunk, chunk_bwd = tiles
 
     # (B, T, H, D) -> (B*H, T, D): each grid row owns one head's sequence.
     def pack(x):
@@ -693,9 +864,15 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
     o = _flash(pack(q), pack(k), pack(v), key_bias, seg, h, float(scale),
                bool(causal), int(block_q), int(block_k),
-               int(block_q_bwd), int(block_k_bwd), int(causal_offset), bd)
+               int(block_q_bwd), int(block_k_bwd), int(causal_offset), bd,
+               chunk and int(chunk), chunk_bwd and int(chunk_bwd))
     if bd is not None:
         visited, total = bd_tiles(bd[0], bd[1], int(block_q), int(block_k))
         _tracing.note_routing(bd_tiles_visited=visited,
                               bd_tiles_total=total)
+    elif causal:
+        visited, total = causal_tiles(tq, int(block_q), int(block_k), chunk,
+                                      int(causal_offset))
+        _tracing.note_routing(causal_tiles_visited=visited,
+                              causal_tiles_total=total)
     return o.reshape(b, h, tq, d).transpose(0, 2, 1, 3)

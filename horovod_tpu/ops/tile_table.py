@@ -20,6 +20,12 @@ Table file: ``flash_tiles.json`` next to this module (override with
                   "kind": "causal", "block_q": 256, "block_k": 512,
                   "us_per_call": 950.0, "source": "tuned-v5e"}, ...]}
 
+An entry from the forward + backward sweep (``tools/tune_tiles.py
+--fwdbwd``, source ``tuned-*-fwdbwd``) may also carry ``block_q_bwd`` /
+``block_k_bwd`` and, for a causal shape, ``chunk`` / ``chunk_bwd``: the keys
+of the resident K tile that one pass of the kernels' inner loop takes
+(``lookup_full``).
+
 ``kind`` is one of "causal" | "full" | "ring" | "block_diffusion" (the ring
 kernel's VMEM profile differs: its per-hop seq is the local shard and the
 backward is an explicit second ring; a block-diffusion row is ``[noisy ;
@@ -169,8 +175,9 @@ def lookup(head_dim: int, seq: int, dtype, kind: str,
 
 def lookup_full(head_dim: int, seq: int, dtype, kind: str,
                 path: Optional[os.PathLike] = None
-                ) -> Tuple[int, int, int, int]:
-    """``(block_q, block_k, block_q_bwd, block_k_bwd)`` for this shape.
+                ) -> Tuple[int, int, int, int, int, int]:
+    """``(block_q, block_k, block_q_bwd, block_k_bwd, chunk, chunk_bwd)``
+    for this shape.
 
     Backward-specific tiles exist only in ``tuned-*-fwdbwd`` entries (the
     differentiated-kernel sweep); entries without them — or with
@@ -178,6 +185,14 @@ def lookup_full(head_dim: int, seq: int, dtype, kind: str,
     for the backward kernels, which is the pre-r5 behavior. Entry
     selection is shared with ``lookup`` (``_best_entry``), so the two can
     never disagree about the forward tiles.
+
+    ``chunk`` is the compute chunk of the causal kernels: how many keys of
+    the resident K tile one pass of the loop inside a grid step takes
+    (``ops/flash_attention._chunk_loop``). An entry without one — every
+    entry nobody has swept for it — yields ``block_k``, which is no loop:
+    the kernels as they were. ``chunk_bwd`` is the backward kernels'; without
+    one it is the forward's where they share the K tile, else
+    ``block_k_bwd``.
     """
     if kind not in KINDS:
         raise ValueError(f"unknown tile kind {kind!r}; expected one of "
@@ -185,7 +200,7 @@ def lookup_full(head_dim: int, seq: int, dtype, kind: str,
     e = _best_entry(head_dim, seq, str(dtype), kind, path)
     if e is None:
         bq, bk = lookup(head_dim, seq, dtype, kind, path)  # default path
-        return bq, bk, bq, bk
+        return bq, bk, bq, bk, bk, bk
     bq, bk = int(e["block_q"]), int(e["block_k"])
     try:
         bqb, bkb = int(e.get("block_q_bwd") or bq), \
@@ -194,7 +209,20 @@ def lookup_full(head_dim: int, seq: int, dtype, kind: str,
             bqb, bkb = bq, bk
     except (TypeError, ValueError):
         bqb, bkb = bq, bk
-    return bq, bk, bqb, bkb
+    chunk = _chunk(e.get("chunk"), bk, bk)
+    return (bq, bk, bqb, bkb, chunk,
+            _chunk(e.get("chunk_bwd"), bkb, chunk if bkb == bk else bkb))
+
+
+def _chunk(said, block_k: int, otherwise: int) -> int:
+    """An entry's compute chunk if it cuts ``block_k`` into whole parts,
+    else ``otherwise`` (a malformed field is no field)."""
+    try:
+        chunk = int(said or 0)
+    except (TypeError, ValueError):
+        return otherwise
+    return chunk if 0 < chunk <= block_k and block_k % chunk == 0 \
+        else otherwise
 
 
 def record(head_dim: int, seq: int, dtype, kind: str, block_q: int,
@@ -202,7 +230,9 @@ def record(head_dim: int, seq: int, dtype, kind: str, block_q: int,
            source: str = "tuned", device: Optional[str] = None,
            path: Optional[os.PathLike] = None,
            block_q_bwd: Optional[int] = None,
-           block_k_bwd: Optional[int] = None) -> Path:
+           block_k_bwd: Optional[int] = None,
+           chunk: Optional[int] = None,
+           chunk_bwd: Optional[int] = None) -> Path:
     """Insert-or-replace one measured entry and rewrite the table file."""
     if kind not in KINDS:
         raise ValueError(f"unknown tile kind {kind!r}; expected one of "
@@ -227,5 +257,9 @@ def record(head_dim: int, seq: int, dtype, kind: str, block_q: int,
         entry["block_q_bwd"] = int(block_q_bwd)
     if block_k_bwd is not None:
         entry["block_k_bwd"] = int(block_k_bwd)
+    if chunk is not None:
+        entry["chunk"] = int(chunk)
+    if chunk_bwd is not None:
+        entry["chunk_bwd"] = int(chunk_bwd)
     table["entries"].append(entry)
     return save_table(table, p)

@@ -328,6 +328,15 @@ NAMES: Dict[str, Name] = {
     "bd_tiles_total": Name(
         "gauge", _KERNELS, "routing manifest: tiles of one head's forward "
         "grid; label program", "bd_tiles_visited_share.train"),
+    "causal_tiles_visited": Name(
+        "gauge", _KERNELS, "routing manifest: (Q tile, compute chunk) "
+        "pairs of one head's causal forward that hold a visible pair, the "
+        "ones the kernels' inner loop visits; label program",
+        "causal_tiles_visited_share.train"),
+    "causal_tiles_total": Name(
+        "gauge", _KERNELS, "routing manifest: (Q tile, compute chunk) "
+        "pairs of one head's causal forward; label program",
+        "causal_tiles_visited_share.train"),
     "flash_residuals_saved": Name(
         "gauge", _MODELS, "remat count: times a block's dots policy "
         "answered save for the flash forward's named output or row "
@@ -530,13 +539,14 @@ def synced_as(tree: Any) -> Any:
 # the routing manifest
 # ---------------------------------------------------------------------------
 
-_ROUTING = ("moe_rows_bound", "bd_tiles_visited", "bd_tiles_total")
+_ROUTING = ("moe_rows_bound", "bd_tiles_visited", "bd_tiles_total",
+            "causal_tiles_visited", "causal_tiles_total")
 
 
 def note_routing(**shapes) -> None:
-    """A routed expert layer or a block-diffusion attention call is being
-    traced: what it is shaped for, from static values (:data:`_ROUTING`
-    names them). Published as gauges ``{program}`` when :func:`program`
+    """A routed expert layer, a block-diffusion or a causal attention call
+    is being traced: what it is shaped for, from static values
+    (:data:`_ROUTING` names them). Published as gauges ``{program}`` when :func:`program`
     exits; every layer of a program says the same, and the last one
     stands. Outside a program nothing is kept."""
     unknown = set(shapes) - set(_ROUTING)

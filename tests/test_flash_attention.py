@@ -483,3 +483,126 @@ class TestFlashSegments:
             segment_ids=jnp.asarray(seg), out_dtype=jnp.float32)
         np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                    rtol=1e-4, atol=1e-4)
+
+
+# --- the causal compute chunk (tile-table entries with a ``chunk``) -------
+
+def _fa():
+    import importlib
+    return importlib.import_module("horovod_tpu.ops.flash_attention")
+
+
+def _masked_dense_loss(q, k, v, tgt, offset=0, seg=None, key_bias=None):
+    """softmax attention under the causal mask (shifted by ``offset``),
+    segment ids and a key bias, in plain jnp; rows with no visible key
+    give zeros, as the kernel does."""
+    t, d = q.shape[1], q.shape[-1]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * d ** -0.5
+    if key_bias is not None:
+        s = s + key_bias[:, None, None, :]
+    ok = (jnp.arange(t)[:, None] + offset >= jnp.arange(t)[None, :])[None,
+                                                                     None]
+    if seg is not None:
+        ok = ok & (seg[:, None, :, None] == seg[:, None, None, :])
+    p = jax.nn.softmax(jnp.where(ok, s, -1e30), axis=-1)
+    p = jnp.where(jnp.any(ok, axis=-1, keepdims=True), p, 0.0)
+    o = jnp.einsum("bhqk,bkhd->bqhd", p, v)
+    return jnp.mean((o - tgt) ** 2)
+
+
+# (T, block_q, block_k, chunk, causal_offset, segment ids, key bias)
+_CHUNKED = {
+    "causal": (64, 16, 64, 16, 0, False, False),
+    "offset-1": (64, 16, 64, 16, -1, False, False),
+    "segment_ids": (64, 16, 64, 16, 0, True, False),
+    "key_bias": (64, 16, 64, 16, 0, False, True),
+    "ragged": (72, 16, 128, 16, 0, False, False),       # 72 = 4.5 chunks
+    "ragged_q_too": (100, 32, 256, 16, 0, True, True),
+    "chunk_over_q": (64, 16, 64, 32, 0, False, False),  # chunk > block_q
+    # the K tile does not hold every key: the grid steps over K as before
+    "k_tile_short": (128, 32, 64, 16, 0, False, False),
+}
+
+
+@pytest.mark.parametrize("case", list(_CHUNKED), ids=list(_CHUNKED))
+def test_chunked_causal_matches_dense(rng, case):
+    """chunk < block_k: the loop inside the tile, whose length is the
+    diagonal, against dense attention: forward and all three gradients
+    (and the bias's), with every mask that shares ``_mask_scores``."""
+    fa = _fa()
+    T, bq, bk, chunk, offset, packed, biased = _CHUNKED[case]
+    B, H, D = 2, 2, 8
+    q, k, v, tgt = (jnp.asarray(rng.standard_normal((B, T, H, D)),
+                                jnp.float32) for _ in range(4))
+    seg = (jnp.asarray(np.sort(rng.integers(0, 3, (B, T)), axis=1),
+                       jnp.int32) if packed else None)
+    bias = (jnp.asarray(rng.standard_normal((B, T)), jnp.float32)
+            if biased else None)
+    tiles = (bq, bk, bq, bk, chunk, chunk)
+
+    def loss_flash(q, k, v, bias):
+        o = fa._attend(q, k, v, True, D ** -0.5, bias, seg, tiles, offset)
+        return jnp.mean((o - tgt) ** 2)
+
+    def loss_dense(q, k, v, bias):
+        return _masked_dense_loss(q, k, v, tgt, offset, seg, bias)
+
+    argnums = (0, 1, 2, 3) if biased else (0, 1, 2)
+    # the loop is there: two in each kernel (the dK/dV kernel keeps its
+    # tile whole where it also sums the bias's gradient along lanes)
+    loops = str(jax.make_jaxpr(jax.grad(loss_flash, argnums))(
+        q, k, v, bias)).count("while[")
+    assert loops == {"k_tile_short": 0}.get(case, 4 if biased else 6)
+    lf, gf = jax.value_and_grad(loss_flash, argnums)(q, k, v, bias)
+    ld, gd = jax.value_and_grad(loss_dense, argnums)(q, k, v, bias)
+    np.testing.assert_allclose(float(lf), float(ld), rtol=1e-5)
+    for a, b in zip(gf, gd):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-3, atol=1e-5)
+
+
+def test_chunked_and_unchunked_kernels_agree(rng):
+    """The same inputs through the loop of chunks and through the one
+    whole tile: the sums are taken in another order, nothing else."""
+    fa = _fa()
+    B, T, H, D = 1, 128, 2, 16
+    q, k, v = (jnp.asarray(rng.standard_normal((B, T, H, D)), jnp.float32)
+               for _ in range(3))
+
+    def grads(chunk):
+        tiles = (32, 128, 32, 128, chunk, chunk)
+        return jax.value_and_grad(
+            lambda q, k, v: jnp.sum(fa._attend(
+                q, k, v, True, D ** -0.5, None, None, tiles) ** 2),
+            argnums=(0, 1, 2))(q, k, v)
+
+    (whole, g_whole), (looped, g_looped) = grads(None), grads(32)
+    np.testing.assert_allclose(float(looped), float(whole), rtol=1e-6)
+    for a, b in zip(g_looped, g_whole):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("t,block_q,block_k,chunk,offset", [
+    (1024, 256, 1024, 256, 0),      # 10 of 16
+    (1024, 256, 1024, 512, 0),      # 6 of 8
+    (1024, 512, 1024, 512, 0),      # 3 of 4
+    (1024, 128, 1024, 128, 0),
+    (200, 32, 256, 16, 0),          # ragged: T is no multiple of anything
+    (1024, 256, 512, None, 0),      # no chunk: the grid's tiles
+    (1024, 128, 512, 128, 0),       # a K tile short of the keys: the same
+    (256, 64, 256, 32, -1),         # strict causal (striped ring layouts)
+])
+def test_causal_tiles_counts_what_the_dense_mask_shows(t, block_q, block_k,
+                                                       chunk, offset):
+    fa = _fa()
+    c = chunk if chunk and block_k >= t else min(block_k, t)
+    mask = np.tril(np.ones((t, t), bool), k=offset)
+    nq, nc = -(-t // block_q), -(-t // c)
+    padded = np.zeros((nq * block_q, nc * c), bool)
+    padded[:t, :t] = mask
+    seen = padded.reshape(nq, block_q, nc, c).any(axis=(1, 3))
+    assert fa.causal_tiles(t, block_q, block_k, chunk, offset) == (
+        int(seen.sum()), nq * nc)
+    if (t, block_q, chunk) == (1024, 256, 256):
+        assert int(seen.sum()) == 10 and nq * nc == 16

@@ -227,16 +227,18 @@ def test_autotune_records_to_table(tmp_path):
 
 def test_lookup_full_defaults_bwd_to_fwd(tmp_table):
     # Entries without bwd dims (the whole pre-r5 table): bwd == fwd.
-    assert tile_table.lookup_full(64, 1024, "bfloat16", "causal",
-                                  path=tmp_table) == (256, 512, 256, 512)
+    assert tile_table.lookup_full(
+        64, 1024, "bfloat16", "causal",
+        path=tmp_table) == (256, 512, 256, 512, 512, 512)
 
 
 def test_record_and_lookup_bwd_tiles(tmp_table):
     tile_table.record(64, 1024, "bfloat16", "causal", 256, 512,
                       us_per_call=9.0, source="tuned-tpu-fwdbwd",
                       path=tmp_table, block_q_bwd=128, block_k_bwd=1024)
-    assert tile_table.lookup_full(64, 1024, "bfloat16", "causal",
-                                  path=tmp_table) == (256, 512, 128, 1024)
+    assert tile_table.lookup_full(
+        64, 1024, "bfloat16", "causal",
+        path=tmp_table) == (256, 512, 128, 1024, 512, 1024)
     # The fwd-only lookup is unchanged by the bwd dims.
     assert tile_table.lookup(64, 1024, "bfloat16", "causal",
                              path=tmp_table) == (256, 512)
@@ -283,4 +285,116 @@ def test_autotune_tune_backward_records_fwdbwd_entry(tmp_path):
     assert (entry["block_q"], entry["block_k"],
             entry["block_q_bwd"], entry["block_k_bwd"]) == best
     assert tile_table.lookup_full(16, 64, "float32", "causal",
+                                  path=p)[:4] == best
+
+
+# --- the causal compute chunk ---------------------------------------------
+
+def test_an_entry_without_a_chunk_yields_the_whole_tile(tmp_table):
+    """Every entry nobody swept for a chunk: chunk = block_k, a loop of
+    one, for the forward and for the backward's own K tile."""
+    assert tile_table.lookup_full(64, 8192, "bfloat16", "causal",
+                                  path=tmp_table)[4:] == (1024, 1024)
+    # no entry at all: the default tiles, whole
+    assert tile_table.lookup_full(
+        64, 1024, "bfloat16", "causal",
+        path=tmp_table.with_name("none.json"))[4:] == (512, 512)
+
+
+def test_a_chunk_round_trips_through_record_and_lookup_full(tmp_table):
+    tile_table.record(64, 1024, "bfloat16", "causal", 256, 1024,
+                      us_per_call=9.0, source="tuned-tpu-fwdbwd",
+                      path=tmp_table, block_q_bwd=256, block_k_bwd=1024,
+                      chunk=256, chunk_bwd=512)
+    assert tile_table.lookup_full(
+        64, 1024, "bfloat16", "causal",
+        path=tmp_table) == (256, 1024, 256, 1024, 256, 512)
+    assert tile_table.lookup(64, 1024, "bfloat16", "causal",
+                             path=tmp_table) == (256, 1024)
+    entry = [e for e in tile_table.load_table(tmp_table)["entries"]
+             if e.get("chunk")]
+    assert len(entry) == 1 and entry[0]["chunk_bwd"] == 512
+
+
+@pytest.mark.parametrize("fields,want", [
+    ({"chunk": 256}, (256, 256)),             # the backward shares the K tile
+    ({"chunk": 256, "block_k_bwd": 512}, (256, 512)),   # its own K tile
+    ({"chunk": 256, "block_k_bwd": 512, "chunk_bwd": 128}, (256, 128)),
+    ({"chunk": 300}, (1024, 1024)),           # cuts no whole parts
+    ({"chunk": "wide"}, (1024, 1024)),        # malformed: no field
+    ({"chunk": 2048}, (1024, 1024)),
+])
+def test_chunk_defaults_and_malformed_chunks(tmp_path, fields, want):
+    p = tmp_path / "t.json"
+    entry = {"head_dim": 64, "seq": 1024, "dtype": "bfloat16",
+             "kind": "causal", "block_q": 256, "block_k": 1024,
+             "us_per_call": 1.0, "source": "test"}
+    tile_table.save_table({"version": 1, "device": "test",
+                           "entries": [dict(entry, **fields)]}, p)
+    assert tile_table.lookup_full(64, 1024, "bfloat16", "causal",
+                                  path=p)[4:] == want
+
+
+def test_the_shipped_gpt2_entry_comes_from_a_fwdbwd_sweep():
+    """(head 64, T 1024, bf16, causal) is what both GPT-2 cells of the
+    benchmark run 24 times a step: its entry was measured forward and
+    backward, and its chunk cuts its K tiles into whole parts."""
+    entry = [e for e in tile_table.load_table()["entries"]
+             if (e["head_dim"], e["seq"], e["dtype"], e["kind"]) ==
+             (64, 1024, "bfloat16", "causal")]
+    assert len(entry) == 1
+    assert "fwdbwd" in entry[0]["source"]
+    bq, bk, bqb, bkb, chunk, chunk_bwd = tile_table.lookup_full(
+        64, 1024, "bfloat16", "causal")
+    assert chunk == entry[0]["chunk"] < bk and bk % chunk == 0
+    assert chunk_bwd < bkb and bkb % chunk_bwd == 0
+
+
+def test_flash_attention_takes_the_tables_chunk(monkeypatch, tmp_path):
+    """The chunk reaches the kernels from the table alone: with the table's
+    tiles the call loops inside the tile, with the caller's own tiles it
+    runs them whole, and the two agree."""
+    import importlib
+
+    import jax
+    fa = importlib.import_module("horovod_tpu.ops.flash_attention")
+    p = tmp_path / "t.json"
+    tile_table.record(16, 64, "float32", "causal", 16, 64, source="test",
+                      path=p, chunk=16)
+    monkeypatch.setenv("HOROVOD_FLASH_TILE_TABLE", str(p))
+    rng = np.random.default_rng(3)
+    q = jnp.asarray(rng.standard_normal((1, 64, 2, 16)), jnp.float32)
+
+    def loops(**tiles):
+        return str(jax.make_jaxpr(jax.grad(lambda q: jnp.sum(
+            fa.flash_attention(q, q, q, causal=True, **tiles))))(q)
+        ).count("while[")
+
+    assert loops() == 6                 # two loops in each of three kernels
+    assert loops(block_q=16, block_k=128) == 0
+    assert loops(block_q=16, block_k=64) == 6    # the table's own tiles
+    np.testing.assert_allclose(
+        np.asarray(fa.flash_attention(q, q, q, causal=True)),
+        np.asarray(fa.flash_attention(q, q, q, causal=True, block_q=16,
+                                      block_k=128)), rtol=1e-5, atol=1e-6)
+    # not causal: nothing to skip, the whole tile
+    assert str(jax.make_jaxpr(lambda q: fa.flash_attention(
+        q, q, q, causal=False))(q)).count("while[") == 0
+
+
+def test_autotune_sweeps_and_records_chunked_candidates(tmp_path):
+    from horovod_tpu.autotune import autotune_flash_blocks
+    p = tmp_path / "tuned.json"
+    best, trials = autotune_flash_blocks(
+        (1, 64, 2, 16), dtype="float32", causal=True,
+        candidates=[(16, 64, 16), (32, 64, 32)], steps_per_trial=1,
+        chain=1, include_backward=False, tune_backward=True, record=True,
+        record_path=p)
+    assert set(trials) == {(16, 64, 16), (32, 64, 32),
+                           ("bwd", 16, 64, 16), ("bwd", 32, 64, 32)}
+    assert len(best) == 6
+    assert tile_table.lookup_full(16, 64, "float32", "causal",
                                   path=p) == best
+    entry = tile_table.load_table(p)["entries"][0]
+    assert entry["source"].endswith("-fwdbwd")
+    assert (entry["chunk"], entry["chunk_bwd"]) == best[4:]

@@ -9,7 +9,10 @@ plane in the table's order."""
 import ast
 import dataclasses
 import glob
+import importlib
+import json
 import os
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +32,7 @@ TRAINER_SCOPES = ["hvd/value_and_grad/sync", "hvd/optimizer/sync",
 KERNELS = ["flash_fwd", "flash_dq", "flash_dkv"]
 SDAR_SCOPES = ["sdar/attn", "moe/route", "moe/experts", "sdar/loss_head"]
 ROUTING = ["moe_rows_bound", "bd_tiles_visited", "bd_tiles_total",
+           "causal_tiles_visited", "causal_tiles_total",
            "moe_local_assignments", "moe_load_max_over_mean"]
 ENGINE_PHASES = ["sweep", "admit", "build", "dispatch", "readback", "commit"]
 
@@ -186,6 +190,8 @@ def _step(devices, readme=True, touch=False, **changes):
             "skipped": _gauges("grad_sync_skipped", program="train_step"),
             "all_reduces": lowered.as_text().count("stablehlo.all_reduce"),
             "saved": _program_gauge("flash_residuals_saved", "train_step"),
+            "causal_tiles": [_program_gauge(name, "train_step") for name in
+                             ("causal_tiles_visited", "causal_tiles_total")],
         }
     finally:
         hvd.init()          # back onto the session's 8 CPU devices
@@ -276,6 +282,28 @@ def test_remat_count_is_the_last_trace_not_a_sum(readme_step):
     nothing (same program name) takes the count back to 0."""
     assert _step(jax.devices()[:2])["saved"] == readme_step["saved"]
     assert _step(jax.devices()[:2], remat=False)["saved"] == [0]
+
+
+def test_manifest_says_how_much_of_the_causal_square_is_visited(
+        readme_step):
+    """A causal flash call notes, from shapes alone, the (Q tile, compute
+    chunk) pairs of one head's forward that hold a visible pair and how
+    many there are: what ``causal_tiles()`` counts for the tiles and the
+    chunk the tile table gives this shape (T 128 is one tile here, the
+    benchmark's T 1024 cuts its own into chunks)."""
+    import importlib
+    fa = importlib.import_module("horovod_tpu.ops.flash_attention")
+    from horovod_tpu.ops import tile_table
+    cfg = GPT2Config.tiny()
+    bq, bk, _, _, chunk, _ = tile_table.lookup_full(
+        cfg.d_model // cfg.num_heads, 128, cfg.dtype, "causal")
+    visited, total = fa.causal_tiles(128, bq, bk, chunk)
+    assert readme_step["causal_tiles"] == [[visited], [total]]
+    assert 0 < visited <= total
+    # dense attention runs no kernel and notes nothing new: the gauge keeps
+    # what the program's last flash trace said
+    dense = _step(jax.devices()[:2], attention="dense")
+    assert dense["causal_tiles"] == readme_step["causal_tiles"]
 
 
 def test_manifest_counts_nothing_on_one_device():
@@ -461,3 +489,51 @@ def test_lowered_block_diffusion_step_carries_its_scopes_and_manifest():
             for name in tracing._ROUTING}
     assert read["moe_rows_bound"] == [2 * 64 * 2]
     assert 0 < read["bd_tiles_visited"][0] < read["bd_tiles_total"][0]
+
+
+# ---------------------------------------------------------------------------
+# the benchmark's reading of the causal manifest (PR 30)
+# ---------------------------------------------------------------------------
+
+def _benchmark_metric(name):
+    root = os.path.dirname(PKG)
+    with open(os.path.join(root, "benchmark", "layer_metrics",
+                           name + ".json")) as f:
+        spec = json.load(f)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        entry = [m for m in json.load(f)["per_layer"] if m["name"] == name]
+    sys.path.insert(0, os.path.join(root, "benchmark"))
+    try:
+        named = importlib.import_module("readers.named")
+    finally:
+        sys.path.pop(0)
+    return spec, entry, named
+
+
+@pytest.mark.parametrize("gauges,want", [
+    ({"causal_tiles_visited": 10, "causal_tiles_total": 16}, 62.5),
+    ({"causal_tiles_visited": 16, "causal_tiles_total": 16}, 100.0),
+    ({}, None),                 # the parent of PR 30 has no such series
+], ids=["10-of-16", "whole-square", "parent"])
+def test_causal_tiles_visited_share_reads_the_manifest(monkeypatch, gauges,
+                                                       want):
+    """``causal_tiles_visited_share.train`` is data for the reader the
+    benchmark has (``named:series_total``): the two gauges of
+    ``train_step`` as a share, and nothing (no raise) where the program
+    does not have them."""
+    spec, entry, named = _benchmark_metric(
+        "causal_tiles_visited_share.train")
+    snapshot = {"counters": {}, "histograms": {}, "gauges": {
+        name: [{"labels": {"program": "train_step"}, "value": value},
+               {"labels": {"program": "eval_step"}, "value": 1}]
+        for name, value in gauges.items()}}
+    monkeypatch.setattr(hvd.metrics, "snapshot", lambda: snapshot)
+    module, function = spec["reader"].split(":")
+    assert module == "named"
+    got = getattr(named, function)(None, **spec["args"])
+    assert got == (want if want is None else pytest.approx(want))
+    assert len(entry) == 1
+    for key in ("unit", "layer", "moves", "source", "better", "workloads"):
+        assert spec[key] == entry[0][key], key
+    for sel in spec["args"]["series"] + spec["args"]["per"]:
+        assert tracing.NAMES[sel["name"]].feeds == spec["name"]

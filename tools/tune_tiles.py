@@ -5,7 +5,8 @@ Sweeps a grid of attention shapes through ``autotune_flash_blocks`` and
 records each winner into ``horovod_tpu/ops/flash_tiles.json`` (the table
 ``flash_attention`` consults by default — see ``ops/tile_table.py``).
 
-Run on a real TPU:  python tools/tune_tiles.py [--quick] [--out PATH]
+Run on a real TPU:  python tools/tune_tiles.py [--quick | --fwdbwd]
+                        [--shape HEAD_DIMxSEQ] [--out PATH]
 
 ``--quick`` uses fwd-only chain=2 probes: differentiated pallas chains
 compile per candidate, so the full sweep is the slow one. Shapes cover the
@@ -48,10 +49,18 @@ SHAPES = [
 # Shapes worth the much costlier differentiated-kernel (phase-2 backward)
 # sweep: the three configs the zoo's headline numbers actually run.
 FWDBWD_SHAPES = [
-    (64, 1024, 8, 12, True, "causal", "bfloat16"),   # GPT-2 @1k
+    (64, 1024, 8, 16, True, "causal", "bfloat16"),   # GPT-2 medium @1k
     (64, 512, 8, 12, False, "full", "bfloat16"),     # BERT @512
     (64, 4096, 2, 12, True, "causal", "bfloat16"),   # GPT-2 @4k
 ]
+
+# What the --fwdbwd sweep tries on a causal shape besides the plain grid:
+# (block_q, chunk) with the K tile the whole key axis, resident, and the
+# kernels looping inside a grid step over chunks of it as far as the
+# diagonal, so that the scores above it are not computed and the chunks
+# under it take no mask.
+CAUSAL_CHUNKED = [(128, 128), (128, 256), (256, 128), (256, 256), (256, 512),
+                  (512, 256), (512, 512)]
 
 
 def main(argv=None) -> int:
@@ -64,12 +73,16 @@ def main(argv=None) -> int:
                          "each candidate re-timed as the backward tiling "
                          "(writes block_q_bwd/block_k_bwd, source "
                          "tuned-*-fwdbwd)")
+    ap.add_argument("--shape", default=None, metavar="HEAD_DIMxSEQ",
+                    help="only the shapes of this head size and length "
+                         "(64x1024), not the whole list")
     ap.add_argument("--out", default=None,
                     help="alternate table path (default: shipped table)")
     args = ap.parse_args(argv)
 
     import jax
-    from horovod_tpu.autotune import autotune_flash_blocks
+    from horovod_tpu.autotune import (FLASH_TILE_CANDIDATES,
+                                      autotune_flash_blocks)
     from horovod_tpu.utils import compile_cache
 
     compile_cache.enable()
@@ -86,21 +99,28 @@ def main(argv=None) -> int:
         # pays the differentiated-kernel compile per candidate — only for
         # the shapes the headline numbers run.
         shapes = FWDBWD_SHAPES
-        kw = dict(include_backward=False, chain=2, steps_per_trial=3,
+        kw = dict(include_backward=False, chain=8, steps_per_trial=5,
                   tune_backward=True)
     else:
         shapes = SHAPES
         kw = dict(include_backward=not args.quick,
                   chain=2 if args.quick else 8,
                   steps_per_trial=3 if args.quick else 5)
+    if args.shape:
+        shapes = [s for s in shapes if f"{s[0]}x{s[1]}" == args.shape]
     failed = 0
     for head_dim, seq, batch, heads, causal, kind, dtype in shapes:
         shape = (batch, seq, heads, head_dim)
         t0 = time.time()
+        candidates = None
+        if args.fwdbwd and kind == "causal":
+            candidates = [c for c in FLASH_TILE_CANDIDATES if c[1] <= seq]
+            candidates += [(bq, seq, chunk) for bq, chunk in CAUSAL_CHUNKED]
         try:
             best, trials = autotune_flash_blocks(
                 shape, dtype=dtype, causal=causal, record=True,
-                record_kind=kind, record_path=args.out, **kw)
+                candidates=candidates, record_kind=kind,
+                record_path=args.out, **kw)
         except Exception as e:   # one bad shape must not kill the sweep
             print(f"  {kind} d{head_dim} T{seq} {dtype}: FAILED ({e})")
             failed += 1
@@ -108,6 +128,10 @@ def main(argv=None) -> int:
         n_timed = len([k for k in trials if k[0] != "bwd"])
         print(f"  {kind} d{head_dim} T{seq} {dtype}: best={best} "
               f"({n_timed} fwd candidates, {time.time() - t0:.0f}s)")
+        # every candidate, the losers too: phase 1 is the forward alone,
+        # "bwd" rows are forward + backward with the forward at its winner
+        for cand, secs in trials.items():
+            print(f"    {cand}: {secs * 1e6:.1f} us/call")
     return 1 if failed else 0
 
 
