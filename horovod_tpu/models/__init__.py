@@ -6,13 +6,24 @@ RMSNorm + SwiGLU + GQA, optional Mixtral-style MoE) and the T5
 encoder-decoder family for modern-LLM migrations — all three
 architecture classes (decoder-only, encoder-only, encoder-decoder) — and a
 block-diffusion decoder with dropless routed experts (``models/sdar.py``,
-training only).
+training only), and a hybrid of gated short convolutions and grouped-query
+attention with a dense SwiGLU first and bias-selected routed experts after
+(``models/lfm2.py``, training only).
 """
 
 from horovod_tpu.models.mnist import MnistCNN  # noqa: F401
 from horovod_tpu.models.resnet import ResNet50, ResNet18  # noqa: F401
 
-__all__ = ["MnistCNN", "ResNet50", "ResNet18", "get_model"]
+__all__ = ["MnistCNN", "ResNet50", "ResNet18", "LFM2", "LFM2Config",
+           "get_model"]
+
+
+def __getattr__(name):
+    # flax-heavy families load when they are asked for, as get_model does
+    if name in ("LFM2", "LFM2Config"):
+        from horovod_tpu.models import lfm2
+        return getattr(lfm2, name)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
 def get_model(name: str, **kw):

@@ -445,12 +445,27 @@ _FAMILIES = {
 }
 
 
+# Families that are trained here and not served, and what serving them
+# lacks (ROADMAP.md, Reach).
+_TRAINING_ONLY = {
+    "SDARConfig": "block-diffusion sampling yields a block a step and the "
+                  "expert layer has no decode path",
+    "LFM2Config": "a conv layer's decode state (its last K - 1 gated "
+                  "inputs) has no place in the cache beside keys and "
+                  "values, and the expert layer has no decode path",
+}
+
+
 def decode_family(cfg) -> DecodeFamily:
     """The :class:`DecodeFamily` for a model config (by config type)."""
-    fam = _FAMILIES.get(type(cfg).__name__)
+    name = type(cfg).__name__
+    fam = _FAMILIES.get(name)
     if fam is None:
+        if name in _TRAINING_ONLY:
+            raise TypeError(f"{name} is trained here and not served: "
+                            f"{_TRAINING_ONLY[name]}")
         raise TypeError(
-            f"no decode family registered for {type(cfg).__name__}; "
+            f"no decode family registered for {name}; "
             f"known: {sorted(_FAMILIES)}")
     return fam
 
@@ -541,8 +556,11 @@ def _step_fn(model):
     elif isinstance(model, GPT2):
         fam = _FAMILIES["GPT2Config"]
     else:
+        why = _TRAINING_ONLY.get(type(getattr(model, "cfg", None)).__name__)
         raise TypeError(f"generate() supports GPT2 and Llama models, got "
-                        f"{type(model).__name__}")
+                        f"{type(model).__name__}"
+                        + (f" (trained here and not served: {why})"
+                           if why else ""))
     fam.validate(model.cfg)
     return fam, fam.kv_heads(model.cfg)
 

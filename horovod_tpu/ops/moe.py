@@ -29,7 +29,11 @@ a TPU) shaped for the worst case, ``positions x min(top_k, held)`` rows, of
 which the kernel visits those the groups cover. Under an ``ep`` mesh axis
 the positions of all peers are gathered, every peer computes its experts'
 share of all of them, and a reduce-scatter sums the shares; on one device it
-runs without that exchange.
+runs without that exchange. Its routing rule is the softmax one (scores over
+all experts, the top-k of them, gates the chosen scores renormalised) unless
+told otherwise: ``score="sigmoid"``, a ``select_bias`` that enters the choice
+and not the gates, the normaliser's epsilon and a scaling factor give the
+rule of the families that balance their experts by such a bias.
 """
 
 from __future__ import annotations
@@ -300,7 +304,9 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def routed_share(tokens, router, w_gate, w_up, w_down, *, first, top_k: int,
-                 norm_topk: bool = True, dtype=jnp.bfloat16):
+                 norm_topk: bool = True, dtype=jnp.bfloat16,
+                 score: str = "softmax", select_bias=None,
+                 norm_eps: float = 0.0, scale: float = 1.0):
     """The part of a dropless top-k expert layer that the experts held here
     give, for ``tokens`` (n, d).
 
@@ -308,7 +314,13 @@ def routed_share(tokens, router, w_gate, w_up, w_down, *, first, top_k: int,
     all of them, the top ``top_k``, renormalised over the chosen ones when
     ``norm_topk``); ``w_gate``/``w_up`` (held, d, f) and ``w_down`` (held, f,
     d) are experts ``first .. first + held - 1`` (``first`` may be traced:
-    an ``ep`` peer's index times ``held``). Returns ``(out, aux)``:
+    an ``ep`` peer's index times ``held``). The routing rule has four more
+    arguments, whose defaults are the rule above and add nothing to its
+    trace: ``score="sigmoid"`` scores each expert on its own;
+    ``select_bias`` (experts_total,) fp32 is added to the scores for the
+    choice alone (the gates stay the unbiased scores of the chosen; it
+    takes no gradient); ``norm_eps`` is added to the normaliser;
+    ``scale`` multiplies the gates. Returns ``(out, aux)``:
     ``out[p] = sum_{e chosen by p and held} gate[p, e] * down_e(silu(gate_e
     x) * up_e x)`` in ``dtype``; the normalisation stays over all chosen
     experts, held or not, so that the shares of all holders add up to the
@@ -323,10 +335,24 @@ def routed_share(tokens, router, w_gate, w_up, w_down, *, first, top_k: int,
     with _tracing.scope("moe/route"):
         logits = jnp.dot(tokens.astype(jnp.float32), router,
                          precision=jax.lax.Precision.HIGHEST)
-        probs = jax.nn.softmax(logits, axis=-1)
-        gate, choice = jax.lax.top_k(probs, top_k)              # (n, k)
+        if score == "softmax":
+            probs = jax.nn.softmax(logits, axis=-1)
+        elif score == "sigmoid":
+            probs = jax.nn.sigmoid(logits)
+        else:
+            raise ValueError(f"unknown score {score!r}; expected 'softmax' "
+                             "or 'sigmoid'")
+        if select_bias is None:
+            gate, choice = jax.lax.top_k(probs, top_k)          # (n, k)
+        else:
+            _, choice = jax.lax.top_k(
+                probs + jax.lax.stop_gradient(select_bias), top_k)
+            gate = jnp.take_along_axis(probs, choice, axis=-1)
         if norm_topk:
-            gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+            total = jnp.sum(gate, axis=-1, keepdims=True)
+            gate = gate / (total + norm_eps if norm_eps else total)
+        if scale != 1.0:
+            gate = gate * scale
         local = choice - first
         mine = (local >= 0) & (local < held)
         # the assignments sorted by held expert, those of other holders
@@ -361,6 +387,11 @@ class RoutedExperts(nn.Module):
     positions. Without it nothing is exchanged and the result is this
     holder's share alone: what the absent experts would add is left out.
 
+    ``score``, ``norm_eps`` and ``scale`` are the routing rule's
+    (:func:`routed_share`); ``select_bias`` (experts_total,) is handed to
+    the call, because it is a buffer that its holder keeps and moves, not a
+    parameter: it has no gradient and no optimizer state.
+
     Returns ``out`` (B, T, D); ``group_sizes`` and ``choice`` are sown into
     the ``"intermediates"`` collection.
     """
@@ -371,9 +402,12 @@ class RoutedExperts(nn.Module):
     norm_topk: bool = True
     dtype: jnp.dtype = jnp.bfloat16
     ep_axis: Optional[str] = None
+    score: str = "softmax"
+    norm_eps: float = 0.0
+    scale: float = 1.0
 
     @nn.compact
-    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+    def __call__(self, x: jnp.ndarray, select_bias=None) -> jnp.ndarray:
         b, t, d = x.shape
         first, held = self.experts_held
         if not 0 <= first <= first + held <= self.experts_total or held < 1:
@@ -391,7 +425,8 @@ class RoutedExperts(nn.Module):
         share = functools.partial(
             routed_share, router=router, w_gate=w_gate, w_up=w_up,
             w_down=w_down, top_k=self.top_k, norm_topk=self.norm_topk,
-            dtype=self.dtype)
+            dtype=self.dtype, score=self.score, select_bias=select_bias,
+            norm_eps=self.norm_eps, scale=self.scale)
         tokens = x.reshape(b * t, d)
         if self.ep_axis is None:
             out, aux = share(tokens, first=first)

@@ -72,7 +72,7 @@ __all__ = ["Span", "mint_span", "current_span", "active_span",
            "NAMES", "Name", "span", "scope", "timed", "current_scope",
            "program", "note_program", "sync_pass", "note_bucket",
            "mark_synced", "synced_as", "note_routing", "routing_load",
-           "note_residual_saved"]
+           "routing_bias_moved", "note_residual_saved"]
 
 _LOCK = threading.Lock()
 _SEQ = 0
@@ -202,6 +202,7 @@ class Name(NamedTuple):
 _TRAINER = "trainer API (spmd, optimizer, collective, fusion, overlap)"
 _MODELS = "models (models/gpt2, remat)"
 _SDAR = "models (models/sdar, remat)"
+_LFM2 = "models (models/lfm2, remat)"
 _EXPERTS = "expert layer (ops/moe)"
 _KERNELS = "kernels (ops/flash_attention)"
 _ENGINE = "engine (serving/engine, scheduler, cache)"
@@ -266,10 +267,11 @@ NAMES: Dict[str, Name] = {
         "attention call and the output projection of one layer",
         "xprof only"),
     "moe/route": Name(
-        "scope", _EXPERTS, "ops.moe.routed_share: router logits and "
-        "softmax in fp32, top-k, the sort of the local assignments by "
-        "expert; flax puts the model's own module path before it "
-        "(SDAR/h<i>/moe/moe/route)", "xprof only"),
+        "scope", _EXPERTS, "ops.moe.routed_share: router logits and their "
+        "scores in fp32 (softmax, or sigmoid with a selection bias), top-k, "
+        "the sort of the local assignments by expert; flax puts the "
+        "model's own module path before it (SDAR/h<i>/moe/moe/route)",
+        "xprof only"),
     "moe/experts": Name(
         "scope", _EXPERTS, "ops.moe.routed_share: gather, the grouped "
         "products over the experts held (ragged-dot custom calls), the "
@@ -280,6 +282,23 @@ NAMES: Dict[str, Name] = {
         "scope", _SDAR, "models.sdar.loss_fn: the head over the noisy "
         "half, log-softmax over the vocabulary slice, the masked 1/t "
         "weighting", "xprof only"),
+    "lfm2/shortconv": Name(
+        "scope", _LFM2, "models.lfm2: one conv layer's operator: the "
+        "projection to the gates and the value, the gated short "
+        "convolution (ops.short_conv: XLA fusions without a name of their "
+        "own) and the projection back", "xprof only (PERF.md section 5 "
+        "gives its device time by hand, from a kept trace)"),
+    "lfm2/attn": Name(
+        "scope", _LFM2, "models.lfm2: the projections, QK-norm, RoPE, the "
+        "causal attention call and the output projection of an attention "
+        "layer", "xprof only (its kernels: lfm2_flash_time_share.train)"),
+    "lfm2/dense_mlp": Name(
+        "scope", _LFM2, "models.lfm2: the dense SwiGLU of a leading block",
+        "xprof only"),
+    "lfm2/loss_head": Name(
+        "scope", _LFM2, "models.lfm2.loss_fn: the tied head over the "
+        "vocabulary slice, log-softmax, the gather of the next tokens",
+        "xprof only"),
     "flash_attention": Name(
         "scope", _KERNELS, "round each flash kernel call, so that jax's "
         "jvp()/transpose() wrap this name and not the kernel's",
@@ -354,6 +373,12 @@ NAMES: Dict[str, Name] = {
         "expert's rows over the mean, over layers; label program",
         "registry only: the imbalance an operator looks at before blaming "
         "the grouped products, whose time follows the rows they are given"),
+    "moe_bias_moved_share": Name(
+        "gauge", _EXPERTS, "routing of one batch (routing_bias_moved): the "
+        "share of a layer's choices that the top-k of the unbiased scores "
+        "would not have made, mean over layers: 0 where the selection "
+        "bias is absent or moves nothing; label program",
+        "moe_bias_moved_share.train"),
     "jax_compile_seconds_total": Name(
         "counter", _COMPILER, "set-up ledger: seconds jax reports per "
         "phase (trace, lower, backend, cache_load) and function; an outer "
@@ -571,6 +596,18 @@ def routing_load(program_name: str, group_sizes) -> None:
     mean = sizes.mean()
     _metrics.gauge("moe_load_max_over_mean", program=program_name).set(
         float(sizes.max() / mean) if mean else 0.0)
+
+
+def routing_bias_moved(program_name: str, moved, choices: int) -> None:
+    """What a selection bias did to the routing of one batch: ``moved``
+    (layers,) are the choices of each routed layer that the top-k of the
+    unbiased scores would not have made, of ``choices`` a layer; like
+    :func:`routing_load` from a forward pass's auxiliary output, never from
+    a timed step. Sets ``moe_bias_moved_share`` (a share of 1, mean over
+    layers)."""
+    import numpy as np
+    _metrics.gauge("moe_bias_moved_share", program=program_name).set(
+        float(np.mean(np.asarray(moved, dtype=np.float64)) / choices))
 
 
 # ---------------------------------------------------------------------------

@@ -194,6 +194,68 @@ def test_block_diffusion_step_compiles_for_v5e_at_published_widths(
         assert scope in hlo, scope
 
 
+def test_causal_flash_compiles_for_v5e_at_the_hybrid_cells_shape(v5e, mosaic):
+    """Head size 64, 8,192 positions, 32 query heads a row, the tiles of
+    the table's row for that shape: the causal kernels' second shape in
+    the benchmark, where the grid's K axis has steps to skip."""
+    from horovod_tpu.ops import tile_table
+    from horovod_tpu.ops.flash_attention import flash_attention
+    entry = tile_table._best_entry(64, 8192, "bfloat16", "causal", None)
+    assert (entry["head_dim"], entry["seq"]) == (64, 8192)
+    on = SingleDeviceSharding(v5e[0])
+    x = jax.ShapeDtypeStruct((1, 8192, 32, 64), jnp.bfloat16, sharding=on)
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True).astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    _assert_kernels_named(jax.jit(fwd_bwd).lower(x, x, x).compile()
+                          .as_text())
+
+
+def test_hybrid_step_compiles_for_v5e_at_published_widths(
+        v5e, mosaic, restore_world):
+    """The third family's step at its published widths and the cell's
+    8,192-token rows, one row and one layer of each kind (conv + dense,
+    attention + routed, conv + routed): the causal flash kernels, XLA's
+    grouped kernel for the experts held, the six scopes."""
+    import optax
+    from horovod_tpu.models import lfm2
+    hvd.init(devices=v5e[:1])
+    cfg = lfm2.LFM2Config(vocab_size=8192, num_layers=3,
+                          layer_types=("conv", "full_attention", "conv"),
+                          num_dense_layers=1, experts_held=(0, 8),
+                          attention="flash", remat=True)
+    model = lfm2.LFM2(cfg)
+    bias = np.zeros((cfg.num_layers, cfg.experts_total), np.float32)
+    opt = hvd.DistributedOptimizer(optax.adamw(1e-5))
+
+    def train_step(params, opt_state, tokens):
+        loss, grads = hvd.value_and_grad(
+            lambda p: lfm2.loss_fn(model, p, tokens, bias))(params)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, loss
+
+    step = hvd.spmd(train_step, in_specs=(P(), P(), P("hvd")),
+                    out_specs=(P(), P(), P()), donate_argnums=(0, 1))
+    replicated = NamedSharding(hvd.mesh(), P())
+    twin = lfm2.LFM2(dataclasses.replace(cfg, attention="dense",
+                                         remat=False))
+    params = jax.eval_shape(lambda: twin.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32,
+                                  sharding=hvd.spmd_data_sharding())
+    hlo = step.lower(_shapes(params, replicated),
+                     _shapes(jax.eval_shape(opt.init, params), replicated),
+                     tokens).compile().as_text()
+    _assert_kernels_named(hlo)
+    assert re.search(r"%ragged-dot[^\n]* = [^\n]*custom-call\(", hlo)
+    for scope in ("lfm2/shortconv", "lfm2/attn", "lfm2/dense_mlp",
+                  "moe/route", "moe/experts", "lfm2/loss_head"):
+        assert scope in hlo, scope
+
+
 def test_engine_programs_compile_for_v5e_with_cache_donation(
         v5e, restore_world, monkeypatch):
     from horovod_tpu.serving import InferenceEngine

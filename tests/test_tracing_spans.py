@@ -31,9 +31,12 @@ TRAINER_SCOPES = ["hvd/value_and_grad/sync", "hvd/optimizer/sync",
                   "hvd/fusion/unpack", "gpt2/loss_head"]
 KERNELS = ["flash_fwd", "flash_dq", "flash_dkv"]
 SDAR_SCOPES = ["sdar/attn", "moe/route", "moe/experts", "sdar/loss_head"]
+LFM2_SCOPES = ["lfm2/shortconv", "lfm2/attn", "lfm2/dense_mlp", "moe/route",
+               "moe/experts", "lfm2/loss_head"]
 ROUTING = ["moe_rows_bound", "bd_tiles_visited", "bd_tiles_total",
            "causal_tiles_visited", "causal_tiles_total",
-           "moe_local_assignments", "moe_load_max_over_mean"]
+           "moe_local_assignments", "moe_load_max_over_mean",
+           "moe_bias_moved_share"]
 ENGINE_PHASES = ["sweep", "admit", "build", "dispatch", "readback", "commit"]
 
 
@@ -123,7 +126,7 @@ def test_every_name_emitted_is_in_the_table():
     assert not unlisted, f"emitted but not in tracing.NAMES: {unlisted}"
     names = {u[2] for u in used}
     assert set(TRAINER_SCOPES) | set(KERNELS) <= names
-    assert set(SDAR_SCOPES) | set(ROUTING) <= names
+    assert set(SDAR_SCOPES) | set(LFM2_SCOPES) | set(ROUTING) <= names
     assert {"engine." + p for p in ENGINE_PHASES} <= names
     # and the table lists nothing that is not emitted
     assert set(tracing.NAMES) - names == set(), set(tracing.NAMES) - names
@@ -492,6 +495,76 @@ def test_lowered_block_diffusion_step_carries_its_scopes_and_manifest():
 
 
 # ---------------------------------------------------------------------------
+# the hybrid conv/attention decoder's names
+# ---------------------------------------------------------------------------
+
+def test_lowered_hybrid_step_carries_its_scopes_and_manifest():
+    """The step of the third model family, lowered: its six scopes (two
+    of them the expert layer's own) and the three kernel names in the text,
+    and the routing manifest published under the program's name: the rows
+    the routed layers are shaped for and the causal tiles of its one
+    attention layer."""
+    from horovod_tpu.models import lfm2
+    hvd.init(devices=jax.devices()[:1])
+    try:
+        cfg = lfm2.LFM2Config.tiny(experts_held=(2, 2), top_k=4,
+                                   attention="flash", remat=True,
+                                   flash_blocks=(16, 16))
+        model = lfm2.LFM2(cfg)
+        tokens = jnp.zeros((2, 32), jnp.int32)
+        params = model.init(jax.random.PRNGKey(0), tokens)["params"]
+        bias = np.full((cfg.num_layers, cfg.experts_total), 0.1, np.float32)
+        opt = hvd.DistributedOptimizer(optax.sgd(0.1))
+
+        def hybrid_step(params, opt_state, tokens):
+            loss, grads = hvd.value_and_grad(
+                lambda p: lfm2.loss_fn(model, p, tokens, bias))(params)
+            updates, opt_state = opt.update(grads, opt_state, params)
+            return optax.apply_updates(params, updates), opt_state, loss
+
+        step = hvd.spmd(hybrid_step, in_specs=(P(), P(), P("hvd")),
+                        out_specs=(P(), P(), P()))
+        text = step.lower(params, opt.init(params), tokens).as_text(
+            debug_info=True)
+    finally:
+        hvd.shutdown()
+        hvd.init()          # back onto the session's 8 CPU devices
+    for name in LFM2_SCOPES + KERNELS:
+        assert name in text, name
+    read = {name: _program_gauge(name, "hybrid_step")
+            for name in tracing._ROUTING}
+    assert read["moe_rows_bound"] == [2 * 32 * 2]
+    assert 0 < read["causal_tiles_visited"][0] < read["causal_tiles_total"][0]
+    assert read["bd_tiles_total"] == []
+
+
+@pytest.mark.parametrize("gauge,want", [(0.125, 12.5), (0.0, 0.0),
+                                        (None, None)],
+                         ids=["an-eighth", "a-bias-that-moves-nothing",
+                              "parent"])
+def test_moe_bias_moved_share_reads_its_gauge(monkeypatch, gauge, want):
+    """``moe_bias_moved_share.train`` is data for the reader the benchmark
+    has (``named:series_total``): the gauge of ``train_step`` in per cent,
+    and nothing (no raise) where the program does not have it."""
+    spec, entry, named = _benchmark_metric("moe_bias_moved_share.train")
+    series = [] if gauge is None else [
+        {"labels": {"program": "train_step"}, "value": gauge},
+        {"labels": {"program": "eval_step"}, "value": 1}]
+    monkeypatch.setattr(hvd.metrics, "snapshot", lambda: {
+        "counters": {}, "histograms": {},
+        "gauges": {"moe_bias_moved_share": series} if series else {}})
+    module, function = spec["reader"].split(":")
+    assert module == "named"
+    got = getattr(named, function)(None, **spec["args"])
+    assert got == (want if want is None else pytest.approx(want))
+    assert len(entry) == 1
+    for key in ("unit", "layer", "moves", "source", "better", "workloads"):
+        assert spec[key] == entry[0][key], key
+    for sel in spec["args"]["series"]:
+        assert tracing.NAMES[sel["name"]].feeds == spec["name"]
+
+
+# ---------------------------------------------------------------------------
 # the benchmark's reading of the causal manifest (PR 30)
 # ---------------------------------------------------------------------------
 
@@ -533,7 +606,13 @@ def test_causal_tiles_visited_share_reads_the_manifest(monkeypatch, gauges,
     got = getattr(named, function)(None, **spec["args"])
     assert got == (want if want is None else pytest.approx(want))
     assert len(entry) == 1
-    for key in ("unit", "layer", "moves", "source", "better", "workloads"):
+    for key in ("unit", "layer", "moves", "source", "better"):
         assert spec[key] == entry[0][key], key
+    # run.py goes by the entry's list; a later cell is appended there alone
+    # (a PR may edit no file the benchmark has), so the file's list is its
+    # head: the cells of PR 30, then (PR 31) the hybrid decoder's
+    cells = entry[0]["workloads"]
+    assert cells[:len(spec["workloads"])] == spec["workloads"]
+    assert cells[len(spec["workloads"]):] == ["lfm2-24b-train-dp1"]
     for sel in spec["args"]["series"] + spec["args"]["per"]:
         assert tracing.NAMES[sel["name"]].feeds == spec["name"]

@@ -47,6 +47,7 @@ MODULES = [
     "horovod_tpu.models.gpt2_pipeline",
     "horovod_tpu.models.llama",
     "horovod_tpu.models.sdar",
+    "horovod_tpu.models.lfm2",
     "horovod_tpu.models.t5",
     "horovod_tpu.models.convert",
     "horovod_tpu.models.generate",
@@ -70,6 +71,7 @@ MODULES = [
     "horovod_tpu.ops.ring_flash",
     "horovod_tpu.ops.sequence",
     "horovod_tpu.ops.moe",
+    "horovod_tpu.ops.short_conv",
     "horovod_tpu.ops.sync_batch_norm",
     "horovod_tpu.ops.quantized",
     "horovod_tpu.ops.tile_table",

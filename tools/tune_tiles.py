@@ -52,6 +52,7 @@ FWDBWD_SHAPES = [
     (64, 1024, 8, 16, True, "causal", "bfloat16"),   # GPT-2 medium @1k
     (64, 512, 8, 12, False, "full", "bfloat16"),     # BERT @512
     (64, 4096, 2, 12, True, "causal", "bfloat16"),   # GPT-2 @4k
+    (64, 8192, 4, 32, True, "causal", "bfloat16"),   # LFM2 hybrid @8k
 ]
 
 # What the --fwdbwd sweep tries on a causal shape besides the plain grid:
@@ -61,6 +62,10 @@ FWDBWD_SHAPES = [
 # under it take no mask.
 CAUSAL_CHUNKED = [(128, 128), (128, 256), (256, 128), (256, 256), (256, 512),
                   (512, 256), (512, 512)]
+# At 4k and beyond the grid's K axis has many steps to skip and a tile may
+# be larger than FLASH_TILE_CANDIDATES goes: these join both lists there.
+LONG_TILES = [(1024, 512), (1024, 1024), (512, 2048)]
+LONG_CHUNKED = [(512, 1024), (1024, 512), (1024, 1024)]
 
 
 def main(argv=None) -> int:
@@ -114,8 +119,11 @@ def main(argv=None) -> int:
         t0 = time.time()
         candidates = None
         if args.fwdbwd and kind == "causal":
+            long = seq >= 4096
             candidates = [c for c in FLASH_TILE_CANDIDATES if c[1] <= seq]
-            candidates += [(bq, seq, chunk) for bq, chunk in CAUSAL_CHUNKED]
+            candidates += LONG_TILES if long else []
+            candidates += [(bq, seq, chunk) for bq, chunk in
+                           CAUSAL_CHUNKED + (LONG_CHUNKED if long else [])]
         try:
             best, trials = autotune_flash_blocks(
                 shape, dtype=dtype, causal=causal, record=True,
