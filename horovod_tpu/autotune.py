@@ -335,7 +335,7 @@ class BayesianAutotuner:
     #: categorical compression levels, in one-hot embedding order
     COMPRESSION_CHOICES = ("none", "fp16")
     #: allreduce algorithm axis (overlap.py), in embedding order — "auto"
-    #: is excluded: the tuner's whole job is to beat the heuristic.
+    #: is excluded: the tuner's whole job is to beat the default.
     ALGORITHM_CHOICES = ("psum", "rs_ag", "chunked_rs_ag")
     #: chunk-count rungs for chunked_rs_ag (log2-embedded)
     CHUNK_CHOICES = (1, 2, 4, 8)
@@ -400,8 +400,9 @@ class BayesianAutotuner:
         return self.COMPRESSION_CHOICES[self._cur[1]]
 
     def current_algorithm(self) -> str:
-        """Current allreduce-algorithm pick ("auto" — i.e. the size
-        heuristic — unless ``tune_algorithm``). With ``tune_topology``
+        """Current allreduce-algorithm pick ("auto" — i.e.
+        ``overlap.resolve_algorithm``'s rule — unless ``tune_algorithm``).
+        With ``tune_topology``
         the topology schedule is folded into the name (``rs_ag`` +
         ``"2d"`` -> ``"rs_ag_2d"``, any pick + ``"swing"`` ->
         ``"swing"``), so consumers keep passing a single algorithm

@@ -1131,10 +1131,12 @@ def allreduce(tensor, op: int = Average, process_set: Optional[ProcessSet] = Non
     * ``"swing"`` — distance-halving pairwise schedule: log2(n) exchange
       steps per direction for latency-bound buckets (exact wire only;
       power-of-two worlds, else falls back to psum);
-    * ``"auto"`` (default via ``HOROVOD_ALLREDUCE_ALGORITHM``) — per
-      bucket by size x world x torus dims: small buckets psum, large
-      rs_ag (the ``_2d`` form when the detected torus has >= 2 dims),
-      largest chunked.
+    * ``"auto"`` (default via ``HOROVOD_ALLREDUCE_ALGORITHM``) — psum on
+      the exact wire, whatever the size (the one fabric timed, a v5e
+      2x2, had every decomposition behind it); under a quantized wire
+      per bucket by size x torus dims: small buckets psum, large rs_ag
+      (the ``_2d`` form when the detected torus has >= 2 dims), largest
+      chunked.
 
     ``wire`` (default ``HOROVOD_ALLREDUCE_WIRE``) sets the default wire
     precision: ``"bf16"`` casts each bucket for the collective and back;

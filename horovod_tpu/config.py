@@ -48,9 +48,10 @@ class Config:
     # HOROVOD_ALLREDUCE_ALGORITHM in {auto, psum, rs_ag, chunked_rs_ag,
     # rs_ag_int8, chunked_rs_ag_int8, rs_ag_fp8, chunked_rs_ag_fp8}
     # picks the per-bucket allreduce lowering; HOROVOD_ALLREDUCE_WIRE in
-    # {fp32, bf16, int8, fp8} sets the default wire precision (auto
-    # resolution upgrades its rs_ag picks to the quantized variants,
-    # bf16 casts the payload around the collective);
+    # {fp32, bf16, int8, fp8} sets the default wire precision (auto is
+    # psum on the exact wire and picks a quantized rs_ag variant by
+    # bucket size under int8/fp8; bf16 casts the payload around the
+    # collective);
     # HOROVOD_OVERLAP_CHUNKS is the pipeline depth of chunked_rs_ag.
     allreduce_algorithm: str = "auto"
     allreduce_wire: str = "fp32"

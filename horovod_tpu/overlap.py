@@ -8,13 +8,16 @@ us leverage (the interleaving itself is the TPU compiler's scheduler,
 which takes no flag from here):
 
 * **Algorithm selection** (:func:`resolve_algorithm`): every allreduce
-  bucket can lower to the latency-optimal single ``psum`` or to a
-  bandwidth-optimal reduce-scatter + all-gather decomposition
-  (``lax.psum_scatter`` + ``lax.all_gather`` — the classic
-  2(n-1)/n-traffic ring split; PAPERS.md "Swing", and the RS+AG shape
-  ``optimizer_sharded.py`` already proves out for the weight update).
-  ``auto`` picks per bucket by size: small buckets keep the one-op psum,
-  large buckets take RS+AG, the largest take the **chunked** pipeline.
+  bucket can lower to the single ``psum`` (XLA's own all-reduce) or to a
+  reduce-scatter + all-gather decomposition (``lax.psum_scatter`` +
+  ``lax.all_gather`` — the classic 2(n-1)/n-traffic ring split;
+  PAPERS.md "Swing", and the RS+AG shape ``optimizer_sharded.py``
+  already proves out for the weight update). ``auto`` resolves to
+  ``psum`` on the exact wire at every size, because on the one fabric
+  timed (a v5e 2x2) every decomposition lost to it; under a quantized
+  wire, which needs the decomposition to quantize inside, it picks by
+  size: RS+AG for large buckets, the **chunked** pipeline for the
+  largest.
 * **Chunked pipelining** (:func:`chunked_rs_ag_psum`): a big bucket is
   split into K chunks whose reduce-scatters are issue-ordered with
   ``lax.optimization_barrier`` so XLA can run chunk i's all-gather
@@ -110,13 +113,19 @@ def compose_algorithm(base: str, wire) -> str:
         return base
     return f"{base}_{wire}"
 
-# auto-selection size cutoffs, per fusion bucket. Below RS_AG_MIN the
-# single psum's one-collective latency wins; above it the ring
-# decomposition's 2(n-1)/n bandwidth optimality takes over; above
-# CHUNKED_MIN the bucket is big enough that splitting it into pipelined
-# chunks buys overlap worth the extra per-chunk latency. Both are
-# deliberately far above anything the CPU test meshes reduce, so `auto`
-# keeps bit-identical psum lowerings there.
+# auto-selection size cutoffs, per fusion bucket, under a quantized wire
+# (HOROVOD_ALLREDUCE_WIRE=int8|fp8), whose payload is quantized inside
+# the decomposition. Below RS_AG_MIN the exact one-op psum stays; above
+# it the bucket takes RS+AG; above CHUNKED_MIN it is split into
+# pipelined chunks. On the exact wire `auto` is psum at every size and
+# these do not apply. The sweep that set that rule (PERF.md section 6,
+# PR 32: GPT-2 medium on a v5e 2x2, 1,419 MB of fp32 gradients a step in
+# 28 buckets of up to 64 MB, ms a step / ms in collectives): psum 243.0 /
+# 24.6, rs_ag 282.1 / 36.3, chunked_rs_ag 289.3 / 47.7, rs_ag_2d 322.2 /
+# 73.7, chunked_rs_ag_2d (what auto resolved to until then) 341.4 / 81.5.
+# The TPU compiler keeps no reduce-scatter: each psum_scatter becomes a
+# whole all-reduce and a dynamic-slice, so a decomposition is psum plus
+# its all-gathers and the padding, slicing and copying round them.
 RS_AG_MIN_BYTES = 4 * 1024 * 1024
 CHUNKED_MIN_BYTES = 32 * 1024 * 1024
 
@@ -165,15 +174,21 @@ def resolve_algorithm(requested: str, nbytes: int, op: int, world: int,
     ``rs_ag`` for an Adasum allreduce is a no-op by design, so one
     training script can set a global algorithm without branching on op).
 
+    ``auto`` on the exact wire (``wire`` ``None``/``"fp32"``/``"bf16"``)
+    is ``psum`` whatever the size, the world or the torus: XLA's own
+    all-reduce over the whole axis. No decomposition has been seen to
+    win on links (see the note at :data:`RS_AG_MIN_BYTES`), and a rule
+    for a fabric nobody timed would be a guess.
+
     ``wire`` is the default wire precision (``HOROVOD_ALLREDUCE_WIRE``):
-    when ``"int8"``/``"fp8"``, ``auto`` resolution upgrades its rs_ag
-    picks to the quantized variants — the size cutoffs are unchanged, so
-    small buckets keep the exact one-op psum and only bandwidth-bound
-    buckets pay the quantize/dequantize math. An explicit ``requested``
-    algorithm always wins over the wire default.
+    when ``"int8"``/``"fp8"``, the payload is quantized inside an RS+AG
+    decomposition, so ``auto`` picks one by size — small buckets keep
+    the exact one-op psum and only bandwidth-bound buckets pay the
+    quantize/dequantize math. An explicit ``requested`` algorithm always
+    wins over the wire default.
 
     ``topology`` is the detected torus dims (``core.topology()``): with
-    >= 2 non-degenerate dims, ``auto``'s bandwidth-bound picks take the
+    >= 2 non-degenerate dims, ``auto``'s quantized picks take the
     multi-phase ``_2d`` lowerings, whose phases ride shorter sub-rings.
     Explicit requests degrade rather than fail when the fabric cannot
     carry them — ``*_2d`` on a 1-D ring runs the 1-D base (same wire),
@@ -196,6 +211,8 @@ def resolve_algorithm(requested: str, nbytes: int, op: int, world: int,
         if base.endswith("_2d") and ndims < 2:
             return compose_algorithm(base[:-3], qw)
         return requested
+    if wire not in QUANT_WIRES:
+        return "psum"
     if nbytes >= CHUNKED_MIN_BYTES:
         return compose_algorithm(
             "chunked_rs_ag_2d" if ndims >= 2 else "chunked_rs_ag", wire)

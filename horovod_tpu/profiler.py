@@ -1243,12 +1243,23 @@ def _check_wire(snap) -> List[Dict]:
     return []
 
 
+#: detected torus shapes on which a timed sweep saw XLA's own all-reduce
+#: (``psum``) beat every exact-wire decomposition. One entry, from the
+#: four-chip cell of the benchmark (PERF.md section 6, PR 32): on a v5e
+#: 2x2 a GPT-2 medium step took 243.0 ms under psum, 282.1 under rs_ag,
+#: 289.3 under chunked_rs_ag, 322.2 under rs_ag_2d and 341.4 under
+#: chunked_rs_ag_2d.
+PSUM_WON_ON = frozenset({(2, 2)})
+
+
 def _check_topology(snap) -> List[Dict]:
-    """Topology/algorithm mismatch: heavy allreduce traffic riding a
-    1-D ring schedule on a slice whose detected torus has >=2 usable
-    dims leaves a whole mesh dimension's bandwidth on the table. Works
-    offline from the exported ``config_topology`` gauges, same as
-    :func:`_check_wire` works from the wire counters."""
+    """A pinned exact-wire decomposition on a fabric where it was timed
+    and lost: heavy allreduce traffic riding an ``rs_ag``-family schedule
+    (1-D or ``_2d``, chunked or not) with no quantized wire, on a detected
+    torus listed in :data:`PSUM_WON_ON`. Says nothing of a fabric nobody
+    timed, nor of a quantized wire, which needs the decomposition to
+    quantize inside. Works offline from the exported ``config_topology``
+    gauges, same as :func:`_check_wire` works from the wire counters."""
     dims = []
     for s in _series(snap, "gauges", "config_topology"):
         try:
@@ -1258,42 +1269,36 @@ def _check_topology(snap) -> List[Dict]:
             continue
         if v > 0:
             dims.append((d, v))
-    torus = tuple(v for _, v in sorted(dims))
-    usable = sum(1 for v in torus if v > 1)
-    if usable < 2:
+    torus = tuple(v for _, v in sorted(dims) if v > 1)
+    if torus not in PSUM_WON_ON:
         return []
-    from horovod_tpu import overlap as _overlap
-    ring = 0.0
-    multi = 0.0
+    decomposed: Dict[str, float] = {}
     for s in _series(snap, "counters", "allreduce_wire_bytes_total"):
         alg = s.get("labels", {}).get("algorithm", "")
-        try:
-            base, _ = _overlap.parse_algorithm(alg)
-        except Exception:
-            continue
-        v = float(s.get("value", 0))
-        if base in ("rs_ag", "chunked_rs_ag"):
-            ring += v
-        elif base.endswith("_2d") or base == "swing":
-            multi += v
-    if multi or ring < WIRE_SUGGEST_MIN_BYTES:
+        # a quantized wire is in the name: "rs_ag_2d_int8" is not listed
+        if alg in ("rs_ag", "chunked_rs_ag", "rs_ag_2d", "chunked_rs_ag_2d"):
+            decomposed[alg] = decomposed.get(alg, 0.0) \
+                + float(s.get("value", 0))
+    total = sum(decomposed.values())
+    if total < WIRE_SUGGEST_MIN_BYTES:
         return []
     topo = "x".join(str(v) for v in torus)
+    names = ", ".join(sorted(decomposed))
     return [_finding(
         "topology_ring", 0.3,
-        f"1-D ring allreduce on a {topo} torus "
-        f"({ring / 1e6:.0f}MB per compiled pass)",
-        "the slice's detected torus has >=2 dims but every reduce-"
-        "scatter/all-gather bucket is scheduled along a single ring; a "
-        "two-phase torus-native lowering shrinks the second leg by the "
-        "first dim's extent and roughly halves per-hop wire time on "
-        "bandwidth-bound buckets",
-        "set HOROVOD_ALLREDUCE_ALGORITHM=rs_ag_2d (or chunked_rs_ag_2d "
-        "for >=32MB buckets; composes with wire=int8/fp8), or leave "
-        "algorithm='auto' which picks the 2D lowering once the torus "
-        "is detected. See docs/PERFORMANCE.md 'Topology-aware "
-        "algorithms'.",
-        topology=topo, ring_wire_bytes=int(ring))]
+        f"decomposed allreduce ({names}) on a {topo} torus "
+        f"({total / 1e6:.0f}MB per compiled pass)",
+        f"on a {topo} a torus dimension is a pair, not a ring: the TPU "
+        "compiler lowers each reduce-scatter as a whole all-reduce plus a "
+        "slice, so an RS+AG schedule sends more bytes than one all-reduce "
+        "over the whole axis and pads, slices and concatenates around "
+        "it; timed on that fabric every decomposition lost to psum "
+        "(PERF.md section 6, PR 32)",
+        "unset HOROVOD_ALLREDUCE_ALGORITHM / algorithm= (the default "
+        "'auto' resolves to psum on the exact wire) or name 'psum'. To "
+        "time a schedule on your own fabric: docs/PERFORMANCE.md "
+        "'Allreduce algorithms'.",
+        topology=topo, decomposed_wire_bytes=int(total))]
 
 
 def _check_recovery(snap) -> List[Dict]:

@@ -97,6 +97,10 @@ def test_flash_variant_compiles_for_v5e(v5e, mosaic, variant):
     _assert_kernels_named(lowered.compile().as_text())
 
 
+_COLLECTIVE = re.compile(r" (all-reduce|all-gather|reduce-scatter|all-to-all|"
+                         r"collective-permute)(-start)?\(")
+
+
 @pytest.mark.parametrize("n_dev", [1, 4])
 def test_spmd_train_step_compiles_for_v5e(v5e, mosaic, restore_world, n_dev):
     hvd.init(devices=v5e[:n_dev])
@@ -116,6 +120,23 @@ def test_spmd_train_step_compiles_for_v5e(v5e, mosaic, restore_world, n_dev):
     hlo = lowered.compile().as_text()
     # the gradient sync is in the program exactly when there is a peer
     assert (" all-reduce(" in hlo) == (n_dev > 1)
+    if n_dev == 1:
+        assert not _COLLECTIVE.search(hlo)
+    else:
+        # `auto` hands every bucket to the fabric's own all-reduce over
+        # the four: no pairwise phases of a `_2d` schedule, and none of
+        # the gathers and slices a decomposition brings with it
+        reduces = [line for line in hlo.splitlines()
+                   if re.search(r" all-reduce(-start)?\(", line)]
+        assert reduces
+        for line in reduces:
+            assert "replica_groups={{0,1,2,3}}" in line, line[:300]
+        sync = [line for line in hlo.splitlines()
+                if "hvd/value_and_grad/sync" in line]
+        assert sync
+        for op in (" all-gather(", " all-gather-start(", " reduce-scatter(",
+                   " dynamic-slice("):
+            assert not [line[:200] for line in sync if op in line], op
     # the program keeps its name, the kernels theirs, and the trainer's
     # scopes reach the chip's program as operation metadata
     assert hlo.startswith("HloModule jit_train_step")

@@ -337,11 +337,17 @@ class TestWireBytesMetrics:
 class TestAutoSelection:
     def test_size_cutoffs(self):
         r = overlap.resolve_algorithm
-        assert r("auto", 1024, hvd.Sum, 8, True) == "psum"
-        assert r("auto", overlap.RS_AG_MIN_BYTES, hvd.Sum, 8,
-                 True) == "rs_ag"
-        assert r("auto", overlap.CHUNKED_MIN_BYTES, hvd.Sum, 8,
-                 True) == "chunked_rs_ag"
+        # the exact wire: XLA's own all-reduce at every size (PR 32)
+        for nbytes in (1024, overlap.RS_AG_MIN_BYTES,
+                       overlap.CHUNKED_MIN_BYTES):
+            assert r("auto", nbytes, hvd.Sum, 8, True) == "psum"
+        # the cutoffs choose where the wire is quantized inside RS+AG
+        assert r("auto", overlap.RS_AG_MIN_BYTES - 1, hvd.Sum, 8, True,
+                 wire="int8") == "psum"
+        assert r("auto", overlap.RS_AG_MIN_BYTES, hvd.Sum, 8, True,
+                 wire="int8") == "rs_ag_int8"
+        assert r("auto", overlap.CHUNKED_MIN_BYTES, hvd.Sum, 8, True,
+                 wire="int8") == "chunked_rs_ag_int8"
 
     def test_non_reducible_and_tiny_world(self):
         r = overlap.resolve_algorithm
@@ -361,8 +367,11 @@ class TestAutoSelection:
                  wire="int8") == "rs_ag_int8"
         assert r("auto", overlap.CHUNKED_MIN_BYTES, hvd.Sum, 8, True,
                  wire="fp8") == "chunked_rs_ag_fp8"
-        # bf16 wire is a cast, not a restructured reduction: names stay
+        # bf16 wire is a cast around the collective, not a restructured
+        # reduction: exact-wire rule, and an explicit name stays as it is
         assert r("auto", overlap.RS_AG_MIN_BYTES, hvd.Sum, 8, True,
+                 wire="bf16") == "psum"
+        assert r("rs_ag", overlap.RS_AG_MIN_BYTES, hvd.Sum, 8, True,
                  wire="bf16") == "rs_ag"
         # explicit algorithm wins over the wire default
         assert r("psum", overlap.CHUNKED_MIN_BYTES, hvd.Sum, 8, True,

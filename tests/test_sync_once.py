@@ -7,6 +7,8 @@ that either way the step gives what two real passes gave.
 (``tracing.mark_synced`` patched to do nothing): then every pass lowers, as
 every pass did before a pass could be skipped."""
 
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,7 +17,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvd
-from horovod_tpu import tracing
+from horovod_tpu import overlap, tracing
 
 N = 4
 VG, OPT, GRAD = ("hvd/value_and_grad/sync", "hvd/optimizer/sync",
@@ -37,13 +39,20 @@ def _loss(p, x, y):
     return jnp.mean((h @ p["w2"] - y) ** 2)
 
 
-def _state():
+def _state(d_in=16, d_hidden=32):
     k = jax.random.split(jax.random.PRNGKey(7), 5)
-    params = {"w1": jax.random.normal(k[0], (16, 32)) * 0.3,
-              "b1": jax.random.normal(k[1], (32,)) * 0.1,
-              "w2": jax.random.normal(k[2], (32, 4)) * 0.3}
-    return (params, jax.random.normal(k[3], (4 * N, 16)),
+    params = {"w1": jax.random.normal(k[0], (d_in, d_hidden)) * 0.3,
+              "b1": jax.random.normal(k[1], (d_hidden,)) * 0.1,
+              "w2": jax.random.normal(k[2], (d_hidden, 4)) * 0.3}
+    return (params, jax.random.normal(k[3], (4 * N, d_in)),
             jax.random.normal(k[4], (4 * N, 4)))
+
+
+def _wide_state():
+    """Gradients of 4.7 MB: one bucket over ``overlap.RS_AG_MIN_BYTES``,
+    the size from which ``auto`` used to decompose on more than one
+    device."""
+    return _state(512, 2304)
 
 
 _RUNS = iter(range(10 ** 6))
@@ -55,14 +64,15 @@ def _gauges(name, program):
             if s["labels"].get("program") == program}
 
 
-def _run(first=None, between=None, opt_kw=None, update_kw=None, own=False):
+def _run(first=None, between=None, opt_kw=None, update_kw=None, own=False,
+         state=_state):
     """One step on N devices: ``first(loss)(params, x, y)`` gives the
     gradients (default ``hvd.value_and_grad``), ``between`` touches them,
     ``hvd.DistributedOptimizer(adamw, **opt_kw).update`` takes them. Returns
     every device's new parameters and optimizer state, the lowered text's
     count of collectives and the manifest (none with ``own``: the step is
     under the caller's own ``shard_map`` and not ``hvd.spmd``)."""
-    params, x, y = _state()
+    params, x, y = state()
     opt = hvd.DistributedOptimizer(optax.adamw(1e-2), **(opt_kw or {}))
     first = first or (lambda f: hvd.value_and_grad(f))
 
@@ -299,3 +309,107 @@ def test_marks_are_all_or_nothing_and_say_the_same_of_every_leaf():
         with tracing.program("inner"):                  # its own marks
             assert tracing.synced_as({"a": a}) is None
         assert tracing.synced_as({"a": a}) == ("avg", 0)
+
+
+# --- how the one pass travels by default (PR 32) -------------------------
+
+def _lowered_as():
+    return {s["labels"]["algorithm"]: int(s["value"])
+            for s in hvd.metrics.snapshot()["counters"].get(
+                "allreduce_algorithm_total", ())}
+
+
+@pytest.fixture
+def torus_2x2():
+    """The four devices as the 2x2 a four-chip v5e host is."""
+    os.environ["HOROVOD_TOPOLOGY"] = "2x2"
+    try:
+        hvd.init(devices=jax.devices()[:N])
+        assert hvd.topology() == (2, 2)
+        yield
+    finally:
+        del os.environ["HOROVOD_TOPOLOGY"]
+        hvd.init(devices=jax.devices()[:N])
+
+
+def test_readme_step_on_a_2x2_lowers_every_bucket_as_psum(torus_2x2):
+    """The README AdamW step with nothing named, buckets over the old
+    cutoff, on a detected 2x2: every bucket of the manifest is counted
+    under ``psum`` and none under a ``_2d`` name, and the parameters are
+    those of the step whose pass names ``algorithm="psum"``."""
+    assert 4 * sum(a.size for a in jax.tree_util.tree_leaves(
+        _wide_state()[0])) >= overlap.RS_AG_MIN_BYTES
+    before = _lowered_as()
+    got = _run(state=_wide_state)
+    after = _lowered_as()
+    assert got["passes"] == {VG: 1, OPT: 0} and got["buckets"][VG] >= 1
+    # (one more for the loss: hvd.value_and_grad averages that scalar by
+    # an allreduce of its own, which the manifest does not hold)
+    assert after.get("psum", 0) - before.get("psum", 0) \
+        == got["buckets"][VG] + 1
+    moved = {name for name in after if after[name] != before.get(name, 0)}
+    assert moved == {"psum"}, (before, after)
+    named = _run(first=lambda f: jax.value_and_grad(f),
+                 opt_kw=dict(algorithm="psum"), state=_wide_state)
+    assert named["passes"] == {OPT: 1}
+    _assert_same(got, named)
+    # and an explicit name still lowers what it names
+    before = _lowered_as()
+    pinned = _run(first=lambda f: jax.value_and_grad(f),
+                  opt_kw=dict(algorithm="rs_ag_2d"), state=_wide_state)
+    assert _lowered_as().get("rs_ag_2d", 0) - before.get("rs_ag_2d", 0) \
+        == pinned["buckets"][OPT]
+
+
+def _parent_rule(requested, nbytes, op, world, reducible, wire=None,
+                 topology=None, knob=None):
+    """What ``auto`` resolved to before PR 32, beyond one device: by size
+    and torus on every wire."""
+    assert requested == "auto" and reducible
+    if world <= 1:
+        return "psum"
+    two_d = "_2d" if overlap._torus_ndims(topology) >= 2 else ""
+    if nbytes >= overlap.CHUNKED_MIN_BYTES:
+        return overlap.compose_algorithm("chunked_rs_ag" + two_d, wire)
+    if nbytes >= overlap.RS_AG_MIN_BYTES:
+        return overlap.compose_algorithm("rs_ag" + two_d, wire)
+    return "psum"
+
+
+def test_one_device_step_does_not_depend_on_the_rule(monkeypatch):
+    """On one device ``resolve_algorithm`` answers before any rule is
+    looked at, so the step's jaxpr is the same text under this tree's rule
+    and under the one it replaced: what the one-chip cells run cannot have
+    changed. (On four devices the two rules part at this size.)"""
+    params, x, y = _wide_state()
+    opt = hvd.DistributedOptimizer(optax.adamw(1e-2))
+
+    def one_device_step(params, opt_state, x, y):
+        loss, grads = hvd.value_and_grad(_loss)(params, x, y)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, loss
+
+    def jaxpr(rule):
+        step = hvd.spmd(one_device_step,
+                        in_specs=(P(), P(), P("hvd"), P("hvd")),
+                        out_specs=(P(), P(), P()))
+        before = _lowered_as()
+        with monkeypatch.context() as m:
+            if rule is not None:
+                m.setattr(overlap, "resolve_algorithm", rule)
+            text = str(jax.make_jaxpr(step)(params, opt.init(params), x, y))
+        after = _lowered_as()
+        return text, {k: after[k] - before.get(k, 0) for k in after
+                      if after[k] != before.get(k, 0)}
+
+    try:
+        hvd.init(devices=jax.devices()[:1])
+        ours, counted = jaxpr(None)
+        theirs, counted_parent = jaxpr(_parent_rule)
+        assert ours == theirs
+        assert set(counted) == set(counted_parent) == {"psum"}
+        hvd.init(devices=jax.devices()[:N])
+        assert set(jaxpr(None)[1]) == {"psum"}
+        assert set(jaxpr(_parent_rule)[1]) == {"psum", "rs_ag"}
+    finally:
+        hvd.init(devices=jax.devices()[:N])

@@ -94,24 +94,29 @@ class TestResolveTopologyAware:
     def r(self, *a, **kw):
         return overlap.resolve_algorithm(*a, **kw)
 
-    def test_auto_picks_2d_on_torus(self):
-        topo = (2, 4)
-        assert self.r("auto", overlap.CHUNKED_MIN_BYTES, hvd.Sum, 8,
-                      True, topology=topo) == "chunked_rs_ag_2d"
-        assert self.r("auto", overlap.RS_AG_MIN_BYTES, hvd.Sum, 8,
-                      True, topology=topo) == "rs_ag_2d"
-        # wire default composes onto the 2D picks like the 1-D ones
-        assert self.r("auto", overlap.CHUNKED_MIN_BYTES, hvd.Sum, 8,
-                      True, wire="int8", topology=topo) \
-            == "chunked_rs_ag_2d_int8"
-        # latency-bound buckets keep the exact fused psum
-        assert self.r("auto", 1024, hvd.Sum, 8, True,
-                      topology=topo) == "psum"
-
-    def test_auto_keeps_1d_on_ring(self):
-        for topo in (None, (8,), (8, 1)):
-            assert self.r("auto", overlap.CHUNKED_MIN_BYTES, hvd.Sum, 8,
-                          True, topology=topo) == "chunked_rs_ag"
+    @pytest.mark.parametrize("world", [1, 4, 8])
+    @pytest.mark.parametrize("wire", ["fp32", "int8"])
+    @pytest.mark.parametrize("nbytes", [1 << 10, 4 << 20, 32 << 20, 64 << 20],
+                             ids=["1KB", "4MB", "32MB", "64MB"])
+    @pytest.mark.parametrize("dims", [(2, 2), (2, 4), (4, 4), (8,), None],
+                             ids=["2x2", "2x4", "4x4", "ring8", "none"])
+    def test_auto_resolve_matrix(self, dims, nbytes, wire, world):
+        """What ``auto`` resolves a Sum bucket to. On the exact wire it is
+        ``psum`` at every size on every fabric (PR 32: on the one fabric
+        timed, a v5e 2x2, every decomposition lost to XLA's own
+        all-reduce, and nothing is shipped for a fabric nobody timed). A
+        quantized wire needs the decomposition to quantize inside, so
+        there the size cutoffs and the torus still choose."""
+        got = self.r("auto", nbytes, hvd.Sum, world, True, wire=wire,
+                     topology=dims)
+        if world == 1 or wire == "fp32" or nbytes < overlap.RS_AG_MIN_BYTES:
+            assert got == "psum"
+            return
+        base = ("chunked_rs_ag" if nbytes >= overlap.CHUNKED_MIN_BYTES
+                else "rs_ag")
+        if dims is not None and sum(d > 1 for d in dims) >= 2:
+            base += "_2d"
+        assert got == f"{base}_{wire}"
 
     def test_explicit_2d_degrades_to_1d_base(self):
         # a pinned *_2d on a 1-D ring runs the 1-D base, same wire
@@ -270,17 +275,19 @@ class TestTopologyParityMatrix:
         base = np.asarray(ref(jnp.asarray(x)))
         np.testing.assert_allclose(got, base, rtol=2e-6, atol=1e-5)
 
-    def test_auto_selects_2d_on_detected_torus(self):
-        # Acceptance: auto resolves >=32MB buckets to the 2D lowering
-        # once the torus is detected (feeding core.topology() through).
+    def test_auto_on_the_detected_torus(self):
+        # what core.topology() detected, fed through: the exact wire
+        # stays on the whole-axis all-reduce at every size, a quantized
+        # wire still takes the torus's phases
         topo = hvd.topology()
         assert topo == (2, 4)
+        for nbytes in (4 * 1024 * 1024, 32 * 1024 * 1024):
+            assert overlap.resolve_algorithm(
+                "auto", nbytes, hvd.Sum, hvd.size(), True,
+                topology=topo) == "psum"
         assert overlap.resolve_algorithm(
             "auto", 32 * 1024 * 1024, hvd.Sum, hvd.size(), True,
-            topology=topo) == "chunked_rs_ag_2d"
-        assert overlap.resolve_algorithm(
-            "auto", 4 * 1024 * 1024, hvd.Sum, hvd.size(), True,
-            topology=topo) == "rs_ag_2d"
+            wire="int8", topology=topo) == "chunked_rs_ag_2d_int8"
 
     def test_metrics_observability(self, rng):
         """allreduce_algorithm_total{algorithm="rs_ag_2d"} plus all four
@@ -362,57 +369,51 @@ class TestDoctorTopology:
         return {"counters": counters, "gauges": gauges,
                 "histograms": {}, "pending_collectives": []}
 
-    def test_ring_on_torus_suggests_2d(self):
+    def _findings(self, torus, counters):
         from horovod_tpu.profiler import doctor
         snap = self._snap(
-            {"config_topology": _topo_gauges(2, 4)},
-            {"allreduce_wire_bytes_total": [
-                _ctr(24 * 1024 * 1024, algorithm="chunked_rs_ag",
-                     wire="fp32", phase="rs"),
-                _ctr(24 * 1024 * 1024, algorithm="chunked_rs_ag",
-                     wire="fp32", phase="ag"),
-            ]})
+            {"config_topology": _topo_gauges(*torus)},
+            {"allreduce_wire_bytes_total": counters})
         rep = doctor(snapshot=snap, trace=None, programs={})
-        f = [x for x in rep["findings"]
-             if x["category"] == "topology_ring"]
+        return [x for x in rep["findings"]
+                if x["category"] == "topology_ring"]
+
+    @pytest.mark.parametrize("alg,phases", [
+        ("chunked_rs_ag_2d", ("rs_d0", "ag_d0")),
+        ("rs_ag_2d", ("rs_d0", "ag_d0")),
+        ("chunked_rs_ag", ("rs", "ag")),
+        ("rs_ag", ("rs", "ag"))])
+    def test_decomposition_on_the_timed_2x2_suggests_psum(self, alg, phases):
+        # what the chip refuted (PR 32): the advice is now the opposite
+        f = self._findings((2, 2), [
+            _ctr(24 * 1024 * 1024, algorithm=alg, wire="fp32", phase=ph)
+            for ph in phases])
         assert len(f) == 1
-        assert "rs_ag_2d" in f[0]["suggestion"]
-        assert "2x4" in f[0]["title"]
+        assert "psum" in f[0]["suggestion"]
+        assert "rs_ag_2d" not in f[0]["suggestion"]
+        assert "2x2" in f[0]["title"] and alg in f[0]["title"]
 
-    def test_quiet_when_2d_already_active(self):
-        from horovod_tpu.profiler import doctor
-        snap = self._snap(
-            {"config_topology": _topo_gauges(2, 4)},
-            {"allreduce_wire_bytes_total": [
-                _ctr(48 * 1024 * 1024, algorithm="rs_ag_2d",
-                     wire="fp32", phase="rs_d0"),
-            ]})
-        rep = doctor(snapshot=snap, trace=None, programs={})
-        assert not [x for x in rep["findings"]
-                    if x["category"] == "topology_ring"]
+    def test_quiet_when_psum_rides(self):
+        assert not self._findings((2, 2), [
+            _ctr(480 * 1024 * 1024, algorithm="psum", wire="fp32")])
 
-    def test_quiet_on_1d_torus(self):
-        from horovod_tpu.profiler import doctor
-        snap = self._snap(
-            {"config_topology": _topo_gauges(8)},
-            {"allreduce_wire_bytes_total": [
-                _ctr(48 * 1024 * 1024, algorithm="chunked_rs_ag",
-                     wire="fp32", phase="rs"),
-            ]})
-        rep = doctor(snapshot=snap, trace=None, programs={})
-        assert not [x for x in rep["findings"]
-                    if x["category"] == "topology_ring"]
+    def test_quiet_on_a_fabric_nobody_timed(self):
+        # nothing is advised, either way, where nothing was measured
+        for torus in ((2, 4), (8,), (4, 4)):
+            for alg in ("chunked_rs_ag", "chunked_rs_ag_2d"):
+                assert not self._findings(torus, [
+                    _ctr(48 * 1024 * 1024, algorithm=alg, wire="fp32",
+                         phase="rs")])
+
+    def test_quiet_for_a_quantized_wire(self):
+        # the quantized wire needs the decomposition to quantize inside
+        assert not self._findings((2, 2), [
+            _ctr(48 * 1024 * 1024, algorithm="chunked_rs_ag_2d_int8",
+                 wire="int8", phase="rs_d0")])
 
     def test_quiet_below_threshold(self):
-        from horovod_tpu.profiler import doctor
-        snap = self._snap(
-            {"config_topology": _topo_gauges(2, 4)},
-            {"allreduce_wire_bytes_total": [
-                _ctr(1024, algorithm="rs_ag", wire="fp32", phase="rs"),
-            ]})
-        rep = doctor(snapshot=snap, trace=None, programs={})
-        assert not [x for x in rep["findings"]
-                    if x["category"] == "topology_ring"]
+        assert not self._findings((2, 2), [
+            _ctr(1024, algorithm="rs_ag_2d", wire="fp32", phase="rs_d0")])
 
 
 class TestTraceMergeAlgorithms:
