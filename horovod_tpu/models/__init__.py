@@ -8,14 +8,16 @@ architecture classes (decoder-only, encoder-only, encoder-decoder) — and a
 block-diffusion decoder with dropless routed experts (``models/sdar.py``,
 training only), and a hybrid of gated short convolutions and grouped-query
 attention with a dense SwiGLU first and bias-selected routed experts after
-(``models/lfm2.py``, training only).
+(``models/lfm2.py``, training only), and a decoder with latent attention, a
+shared expert beside the routed ones and a multi-token-prediction module in
+its loss (``models/glm4_moe_lite.py``, training only).
 """
 
 from horovod_tpu.models.mnist import MnistCNN  # noqa: F401
 from horovod_tpu.models.resnet import ResNet50, ResNet18  # noqa: F401
 
 __all__ = ["MnistCNN", "ResNet50", "ResNet18", "LFM2", "LFM2Config",
-           "get_model"]
+           "Glm4MoeLite", "Glm4MoeLiteConfig", "get_model"]
 
 
 def __getattr__(name):
@@ -23,6 +25,9 @@ def __getattr__(name):
     if name in ("LFM2", "LFM2Config"):
         from horovod_tpu.models import lfm2
         return getattr(lfm2, name)
+    if name in ("Glm4MoeLite", "Glm4MoeLiteConfig"):
+        from horovod_tpu.models import glm4_moe_lite
+        return getattr(glm4_moe_lite, name)
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 

@@ -453,6 +453,11 @@ _TRAINING_ONLY = {
     "LFM2Config": "a conv layer's decode state (its last K - 1 gated "
                   "inputs) has no place in the cache beside keys and "
                   "values, and the expert layer has no decode path",
+    "Glm4MoeLiteConfig": "latent attention's cache of one latent and one "
+                         "rotated key a token, and the absorbed decode "
+                         "path that reads it, are not built, and the "
+                         "expert layer and its shared expert have no "
+                         "decode path",
 }
 
 

@@ -33,7 +33,10 @@ runs without that exchange. Its routing rule is the softmax one (scores over
 all experts, the top-k of them, gates the chosen scores renormalised) unless
 told otherwise: ``score="sigmoid"``, a ``select_bias`` that enters the choice
 and not the gates, the normaliser's epsilon and a scaling factor give the
-rule of the families that balance their experts by such a bias.
+rule of the families that balance their experts by such a bias. A family's
+shared expert is a sibling, ``SharedExpert``: every position goes through
+it, so every holder computes it alike on its own positions and it is no part
+of a share: nothing of it is exchanged, and shares summed count it once.
 """
 
 from __future__ import annotations
@@ -49,7 +52,8 @@ import numpy as np
 from horovod_tpu import tracing as _tracing
 
 __all__ = ["Top1Router", "Top2Router", "MoEMLP",
-           "switch_load_balance_loss", "RoutedExperts", "routed_share"]
+           "switch_load_balance_loss", "RoutedExperts", "routed_share",
+           "SharedExpert"]
 
 
 def switch_load_balance_loss(router_probs: jnp.ndarray,
@@ -377,8 +381,9 @@ def routed_share(tokens, router, w_gate, w_up, w_down, *, first, top_k: int,
 
 
 class RoutedExperts(nn.Module):
-    """Dropless top-k routed SwiGLU experts (no bias, no shared expert, no
-    auxiliary loss), told which experts it holds: ``experts_held = (first,
+    """Dropless top-k routed SwiGLU experts (no bias, no auxiliary loss; a
+    shared expert is :class:`SharedExpert`, beside it), told which experts
+    it holds: ``experts_held = (first,
     count)`` of ``experts_total``. The router keeps its full width. With
     ``ep_axis`` (inside ``shard_map`` over that mesh axis, positions and
     experts sharded over it) peer ``i`` holds experts ``i * count ..``, the
@@ -442,3 +447,24 @@ class RoutedExperts(nn.Module):
         self.sow("intermediates", "group_sizes", aux["group_sizes"])
         self.sow("intermediates", "choice", aux["choice"])
         return out.reshape(b, t, d)
+
+
+class SharedExpert(nn.Module):
+    """The expert that no router chooses: one bias-free SwiGLU of width
+    ``d_ff`` that every position goes through, unweighted, beside the
+    routed ones (``x + RoutedExperts(...)(u) + SharedExpert(...)(u)``). It
+    is whole on every holder of a share and works on that holder's own
+    positions, outside :class:`RoutedExperts` and so outside what its
+    ``ep_axis`` gathers and reduce-scatters: the shares of all holders plus
+    this **once** are the whole layer. At the dense width it is also a
+    family's dense feed-forward."""
+    d_ff: int
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+        dense = lambda width, name: nn.Dense(width, use_bias=False,
+                                             dtype=self.dtype, name=name)
+        return dense(x.shape[-1], "w_down")(
+            nn.silu(dense(self.d_ff, "w_gate")(x))
+            * dense(self.d_ff, "w_up")(x))

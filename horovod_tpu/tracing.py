@@ -20,10 +20,11 @@ scope or a span counter:
   leaves a finished pass of the same trace returned
   (:func:`mark_synced`, :func:`synced_as`), so that a second pass over
   them lowers nothing.
-* **The routing manifest** — what the routed expert layers and the
-  block-diffusion attention of a program are shaped for, noted while the
-  program is traced (:func:`note_routing`), and how the routing of one
-  batch loaded the experts held (:func:`routing_load`).
+* **The routing manifest** — what the routed expert layers, the
+  block-diffusion and causal attention calls and the latent attention of a
+  program are shaped for, noted while the program is traced
+  (:func:`note_routing`), and how the routing of one batch loaded the
+  experts held (:func:`routing_load`).
 * **The remat count** — how often a block's remat policy kept a residual
   that the flash forward named, while the program is traced
   (:func:`note_residual_saved`).
@@ -203,6 +204,7 @@ _TRAINER = "trainer API (spmd, optimizer, collective, fusion, overlap)"
 _MODELS = "models (models/gpt2, remat)"
 _SDAR = "models (models/sdar, remat)"
 _LFM2 = "models (models/lfm2, remat)"
+_GLM4 = "models (models/glm4_moe_lite, remat)"
 _EXPERTS = "expert layer (ops/moe)"
 _KERNELS = "kernels (ops/flash_attention)"
 _ENGINE = "engine (serving/engine, scheduler, cache)"
@@ -299,6 +301,36 @@ NAMES: Dict[str, Name] = {
         "scope", _LFM2, "models.lfm2.loss_fn: the tied head over the "
         "vocabulary slice, log-softmax, the gather of the next tokens",
         "xprof only"),
+    "glm4/mla_down": Name(
+        "scope", _GLM4, "models.glm4_moe_lite: latent attention's two "
+        "down-projections (to the query bottleneck, and to the key/value "
+        "latent with the shared RoPE key) and the norms inside them",
+        "xprof only (PERF.md section 5 gives its device time by hand)"),
+    "glm4/mla_up": Name(
+        "scope", _GLM4, "models.glm4_moe_lite: the up-projections to every "
+        "head's query, key and value, RoPE, the broadcast of the one rotated "
+        "key to all heads and the concatenations",
+        "xprof only (what it writes: mla_kv_expanded_mb.train)"),
+    "glm4/attn": Name(
+        "scope", _GLM4, "models.glm4_moe_lite: the causal attention call at "
+        "head 256 and the output projection",
+        "xprof only (its kernels: mla_flash_time_share.train)"),
+    "glm4/dense_mlp": Name(
+        "scope", _GLM4, "models.glm4_moe_lite: the dense SwiGLU of a leading "
+        "block", "xprof only"),
+    "glm4/shared_expert": Name(
+        "scope", _GLM4, "models.glm4_moe_lite: the shared expert of a routed "
+        "block (ops.moe.SharedExpert), beside moe/route and moe/experts",
+        "xprof only (PERF.md section 5 gives its device time by hand)"),
+    "glm4/mtp": Name(
+        "scope", _GLM4, "models.glm4_moe_lite: everything of the "
+        "multi-token-prediction module: its two norms, eh_proj, its block "
+        "(whose own scopes nest inside this one) and its last norm",
+        "xprof only (PERF.md section 5 gives its device time by hand)"),
+    "glm4/loss_head": Name(
+        "scope", _GLM4, "models.glm4_moe_lite.loss_terms: both passes of "
+        "the untied head over the vocabulary slice, log-sum-exp minus the "
+        "target's logit", "xprof only"),
     "flash_attention": Name(
         "scope", _KERNELS, "round each flash kernel call, so that jax's "
         "jvp()/transpose() wrap this name and not the kernel's",
@@ -356,6 +388,22 @@ NAMES: Dict[str, Name] = {
         "gauge", _KERNELS, "routing manifest: (Q tile, compute chunk) "
         "pairs of one head's causal forward; label program",
         "causal_tiles_visited_share.train"),
+    "mla_kv_expanded_bytes": Name(
+        "gauge", _GLM4, "routing manifest: bytes a step writes as per-head "
+        "keys and values before the attention kernels (positions x "
+        "attention layers x heads x (key + value head size) x itemsize; "
+        "the one rotated key counted once a head it is copied to); label "
+        "program", "mla_kv_expanded_mb.train"),
+    "mla_latent_bytes": Name(
+        "gauge", _GLM4, "routing manifest: bytes of what those keys and "
+        "values are expanded from (positions x attention layers x (latent "
+        "+ rotated key) x itemsize); label program",
+        "registry only: what mla_kv_expanded_bytes is a multiple of (17.8 "
+        "on the benchmark's cell)"),
+    "mtp_modules": Name(
+        "gauge", _GLM4, "routing manifest: multi-token-prediction modules "
+        "in the program's loss (1, or 0 without); label program",
+        "registry only: that the module is in what is timed"),
     "flash_residuals_saved": Name(
         "gauge", _MODELS, "remat count: times a block's dots policy "
         "answered save for the flash forward's named output or row "
@@ -565,15 +613,17 @@ def synced_as(tree: Any) -> Any:
 # ---------------------------------------------------------------------------
 
 _ROUTING = ("moe_rows_bound", "bd_tiles_visited", "bd_tiles_total",
-            "causal_tiles_visited", "causal_tiles_total")
+            "causal_tiles_visited", "causal_tiles_total",
+            "mla_kv_expanded_bytes", "mla_latent_bytes", "mtp_modules")
 
 
 def note_routing(**shapes) -> None:
-    """A routed expert layer, a block-diffusion or a causal attention call
-    is being traced: what it is shaped for, from static values
-    (:data:`_ROUTING` names them). Published as gauges ``{program}`` when :func:`program`
-    exits; every layer of a program says the same, and the last one
-    stands. Outside a program nothing is kept."""
+    """A routed expert layer, a block-diffusion or a causal attention call,
+    or a model with latent attention is being traced: what it is shaped
+    for, from static values (:data:`_ROUTING` names them). Published as
+    gauges ``{program}`` when :func:`program` exits; every layer of a
+    program says the same, and the last one stands. Outside a program
+    nothing is kept."""
     unknown = set(shapes) - set(_ROUTING)
     if unknown:
         raise ValueError(f"not in the routing manifest: {sorted(unknown)}")

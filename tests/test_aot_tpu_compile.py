@@ -277,6 +277,69 @@ def test_hybrid_step_compiles_for_v5e_at_published_widths(
         assert scope in hlo, scope
 
 
+def test_latent_attention_kernels_compile_for_v5e_at_the_table_entry(
+        v5e, mosaic):
+    """The causal kernels at the table's own entry for head 256 and T
+    8,192 (the latent-attention cell's shape: 20 heads, each with its own
+    expanded key and value), forward and backward."""
+    from horovod_tpu.ops import tile_table
+    from horovod_tpu.ops.flash_attention import flash_attention
+    entry = tile_table._best_entry(256, 8192, "bfloat16", "causal", None)
+    assert (entry["head_dim"], entry["seq"]) == (256, 8192)
+    on = SingleDeviceSharding(v5e[0])
+    x = jax.ShapeDtypeStruct((1, 8192, 20, 256), jnp.bfloat16, sharding=on)
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, scale=256 ** -0.5).astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    _assert_kernels_named(jax.jit(fwd_bwd).lower(x, x, x).compile()
+                          .as_text())
+
+
+def test_latent_attention_step_compiles_for_v5e_at_published_widths(
+        v5e, mosaic, restore_world):
+    """The fourth family's step at its published widths and the cell's
+    8,192-token rows, one row, a block of each kind (dense, routed + shared)
+    and the multi-token-prediction module: the causal flash kernels at head
+    256, XLA's grouped kernel for the experts held, the nine scopes."""
+    import optax
+    from horovod_tpu.models import glm4_moe_lite as glm
+    hvd.init(devices=v5e[:1])
+    cfg = glm.Glm4MoeLiteConfig(vocab_size=19360, num_layers=2,
+                                experts_held=(0, 8), attention="flash",
+                                remat=True)
+    model = glm.Glm4MoeLite(cfg)
+    bias = np.zeros((cfg.num_layers + 1, cfg.experts_total), np.float32)
+    opt = hvd.DistributedOptimizer(optax.adamw(1e-5))
+
+    def train_step(params, opt_state, tokens):
+        loss, grads = hvd.value_and_grad(
+            lambda p: glm.loss_fn(model, p, tokens, bias))(params)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, loss
+
+    step = hvd.spmd(train_step, in_specs=(P(), P(), P("hvd")),
+                    out_specs=(P(), P(), P()), donate_argnums=(0, 1))
+    replicated = NamedSharding(hvd.mesh(), P())
+    twin = glm.Glm4MoeLite(dataclasses.replace(cfg, attention="dense",
+                                               remat=False))
+    params = jax.eval_shape(lambda: twin.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32,
+                                  sharding=hvd.spmd_data_sharding())
+    hlo = step.lower(_shapes(params, replicated),
+                     _shapes(jax.eval_shape(opt.init, params), replicated),
+                     tokens).compile().as_text()
+    _assert_kernels_named(hlo)
+    assert re.search(r"%ragged-dot[^\n]* = [^\n]*custom-call\(", hlo)
+    for scope in ("glm4/mla_down", "glm4/mla_up", "glm4/attn",
+                  "glm4/dense_mlp", "glm4/shared_expert", "glm4/mtp",
+                  "glm4/loss_head", "moe/route", "moe/experts"):
+        assert scope in hlo, scope
+
+
 def test_engine_programs_compile_for_v5e_with_cache_donation(
         v5e, restore_world, monkeypatch):
     from horovod_tpu.serving import InferenceEngine

@@ -33,8 +33,12 @@ KERNELS = ["flash_fwd", "flash_dq", "flash_dkv"]
 SDAR_SCOPES = ["sdar/attn", "moe/route", "moe/experts", "sdar/loss_head"]
 LFM2_SCOPES = ["lfm2/shortconv", "lfm2/attn", "lfm2/dense_mlp", "moe/route",
                "moe/experts", "lfm2/loss_head"]
+GLM4_SCOPES = ["glm4/mla_down", "glm4/mla_up", "glm4/attn", "glm4/dense_mlp",
+               "glm4/shared_expert", "glm4/mtp", "glm4/loss_head",
+               "moe/route", "moe/experts"]
 ROUTING = ["moe_rows_bound", "bd_tiles_visited", "bd_tiles_total",
            "causal_tiles_visited", "causal_tiles_total",
+           "mla_kv_expanded_bytes", "mla_latent_bytes", "mtp_modules",
            "moe_local_assignments", "moe_load_max_over_mean",
            "moe_bias_moved_share"]
 ENGINE_PHASES = ["sweep", "admit", "build", "dispatch", "readback", "commit"]
@@ -126,7 +130,8 @@ def test_every_name_emitted_is_in_the_table():
     assert not unlisted, f"emitted but not in tracing.NAMES: {unlisted}"
     names = {u[2] for u in used}
     assert set(TRAINER_SCOPES) | set(KERNELS) <= names
-    assert set(SDAR_SCOPES) | set(LFM2_SCOPES) | set(ROUTING) <= names
+    assert set(SDAR_SCOPES) | set(LFM2_SCOPES) | set(GLM4_SCOPES) <= names
+    assert set(ROUTING) <= names
     assert {"engine." + p for p in ENGINE_PHASES} <= names
     # and the table lists nothing that is not emitted
     assert set(tracing.NAMES) - names == set(), set(tracing.NAMES) - names
@@ -538,6 +543,85 @@ def test_lowered_hybrid_step_carries_its_scopes_and_manifest():
     assert read["bd_tiles_total"] == []
 
 
+def test_lowered_latent_attention_step_carries_its_scopes_and_manifest():
+    """The step of the fourth model family, lowered: its nine scopes (two
+    of them the expert layer's own) and the three kernel names in the text,
+    and the manifest published under the program's name: the rows the
+    routed layers are shaped for, the causal tiles, what latent attention
+    writes as expanded keys and values and the latent it expands, and that
+    the multi-token-prediction module is in the step."""
+    from horovod_tpu.models import glm4_moe_lite as glm
+    hvd.init(devices=jax.devices()[:1])
+    try:
+        cfg = glm.Glm4MoeLiteConfig.tiny(experts_held=(2, 2), top_k=4,
+                                         attention="flash", remat=True,
+                                         flash_blocks=(16, 16))
+        model = glm.Glm4MoeLite(cfg)
+        tokens = jnp.zeros((2, 32), jnp.int32)
+        params = model.init(jax.random.PRNGKey(0), tokens)["params"]
+        bias = np.full((cfg.num_layers + 1, cfg.experts_total), 0.1,
+                       np.float32)
+        opt = hvd.DistributedOptimizer(optax.sgd(0.1))
+
+        def latent_step(params, opt_state, tokens):
+            loss, grads = hvd.value_and_grad(
+                lambda p: glm.loss_fn(model, p, tokens, bias))(params)
+            updates, opt_state = opt.update(grads, opt_state, params)
+            return optax.apply_updates(params, updates), opt_state, loss
+
+        step = hvd.spmd(latent_step, in_specs=(P(), P(), P("hvd")),
+                        out_specs=(P(), P(), P()))
+        text = step.lower(params, opt.init(params), tokens).as_text(
+            debug_info=True)
+    finally:
+        hvd.shutdown()
+        hvd.init()          # back onto the session's 8 CPU devices
+    for name in GLM4_SCOPES + KERNELS:
+        assert name in text, name
+    read = {name: _program_gauge(name, "latent_step")
+            for name in tracing._ROUTING}
+    assert read["moe_rows_bound"] == [2 * 32 * 2]
+    assert 0 < read["causal_tiles_visited"][0] < read["causal_tiles_total"][0]
+    assert read["bd_tiles_total"] == []
+    # 64 positions x 4 attention layers (3 blocks and the module's) in bf16
+    assert read["mla_kv_expanded_bytes"] == [64 * 4 * 4 * (16 + 16) * 2]
+    assert read["mla_latent_bytes"] == [64 * 4 * (16 + 4) * 2]
+    assert read["mtp_modules"] == [1]
+
+
+@pytest.mark.parametrize("gauge,want", [(2013265920, 2013.26592),
+                                        (None, None)],
+                         ids=["the-cell", "parent"])
+def test_mla_kv_expanded_mb_reads_its_gauge(monkeypatch, gauge, want):
+    """``mla_kv_expanded_mb.train`` is data for the reader the benchmark
+    has (``named:series_total``): the gauge of ``train_step`` in MB, and
+    nothing (no raise) where the program does not have it."""
+    spec, entry, named = _benchmark_metric("mla_kv_expanded_mb.train")
+    series = [] if gauge is None else [
+        {"labels": {"program": "train_step"}, "value": gauge},
+        {"labels": {"program": "eval_step"}, "value": 1}]
+    monkeypatch.setattr(hvd.metrics, "snapshot", lambda: {
+        "counters": {}, "histograms": {},
+        "gauges": {"mla_kv_expanded_bytes": series} if series else {}})
+    module, function = spec["reader"].split(":")
+    assert module == "named"
+    got = getattr(named, function)(None, **spec["args"])
+    assert got == (want if want is None else pytest.approx(want))
+    assert len(entry) == 1
+    for key in ("unit", "layer", "moves", "source", "better", "workloads"):
+        assert spec[key] == entry[0][key], key
+    for sel in spec["args"]["series"]:
+        assert tracing.NAMES[sel["name"]].feeds == spec["name"]
+    # the step's whole share of the peak and the kernels' pair are entered
+    # beside it, each with its file
+    for name in ("mfu.train_glm4", "mla_flash_time_share.train",
+                 "mla_flash_roofline.train"):
+        other, its_entry, _ = _benchmark_metric(name)
+        assert len(its_entry) == 1
+        assert other["workloads"] == its_entry[0]["workloads"] == [
+            "glm47f-train-dp1"]
+
+
 @pytest.mark.parametrize("gauge,want", [(0.125, 12.5), (0.0, 0.0),
                                         (None, None)],
                          ids=["an-eighth", "a-bias-that-moves-nothing",
@@ -558,8 +642,12 @@ def test_moe_bias_moved_share_reads_its_gauge(monkeypatch, gauge, want):
     got = getattr(named, function)(None, **spec["args"])
     assert got == (want if want is None else pytest.approx(want))
     assert len(entry) == 1
-    for key in ("unit", "layer", "moves", "source", "better", "workloads"):
+    for key in ("unit", "layer", "moves", "source", "better"):
         assert spec[key] == entry[0][key], key
+    # the file's list is the entry's head (a later cell is appended to the
+    # entry alone): PR 31's cell, then (PR 33) the latent-attention one
+    assert spec["workloads"] == ["lfm2-24b-train-dp1"]
+    assert entry[0]["workloads"] == spec["workloads"] + ["glm47f-train-dp1"]
     for sel in spec["args"]["series"]:
         assert tracing.NAMES[sel["name"]].feeds == spec["name"]
 
@@ -610,9 +698,11 @@ def test_causal_tiles_visited_share_reads_the_manifest(monkeypatch, gauges,
         assert spec[key] == entry[0][key], key
     # run.py goes by the entry's list; a later cell is appended there alone
     # (a PR may edit no file the benchmark has), so the file's list is its
-    # head: the cells of PR 30, then (PR 31) the hybrid decoder's
+    # head: the cells of PR 30, then (PR 31) the hybrid decoder's and
+    # (PR 33) the latent-attention one's
     cells = entry[0]["workloads"]
     assert cells[:len(spec["workloads"])] == spec["workloads"]
-    assert cells[len(spec["workloads"]):] == ["lfm2-24b-train-dp1"]
+    assert cells[len(spec["workloads"]):] == ["lfm2-24b-train-dp1",
+                                              "glm47f-train-dp1"]
     for sel in spec["args"]["series"] + spec["args"]["per"]:
         assert tracing.NAMES[sel["name"]].feeds == spec["name"]

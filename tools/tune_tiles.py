@@ -53,6 +53,7 @@ FWDBWD_SHAPES = [
     (64, 512, 8, 12, False, "full", "bfloat16"),     # BERT @512
     (64, 4096, 2, 12, True, "causal", "bfloat16"),   # GPT-2 @4k
     (64, 8192, 4, 32, True, "causal", "bfloat16"),   # LFM2 hybrid @8k
+    (256, 8192, 2, 20, True, "causal", "bfloat16"),  # latent attention @8k
 ]
 
 # What the --fwdbwd sweep tries on a causal shape besides the plain grid:
