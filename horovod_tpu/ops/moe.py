@@ -25,8 +25,11 @@ and whose references therefore drop nothing. It is told which experts it
 holds, routes over all of them and computes the part of the result that its
 own experts give: the local assignments are sorted by expert and go through
 three grouped products (``jax.lax.ragged_dot``: XLA's own grouped kernel on
-a TPU) shaped for the worst case, ``positions x min(top_k, held)`` rows, of
-which the kernel visits those the groups cover. Under an ``ep`` mesh axis
+a TPU) a window of rows at a time: twice the rows its share of the experts
+can expect, of which the kernel visits those the groups cover. A routing
+that gives more goes over as many windows as it needs, up to the worst
+case of ``positions x min(top_k, held)`` rows, by a loop whose length the
+device counts. Under an ``ep`` mesh axis
 the positions of all peers are gathered, every peer computes its experts'
 share of all of them, and a reduce-scatter sums the shares; on one device it
 runs without that exchange. Its routing rule is the softmax one (scores over
@@ -53,7 +56,7 @@ from horovod_tpu import tracing as _tracing
 
 __all__ = ["Top1Router", "Top2Router", "MoEMLP",
            "switch_load_balance_loss", "RoutedExperts", "routed_share",
-           "SharedExpert"]
+           "row_bounds", "SharedExpert"]
 
 
 def switch_load_balance_loss(router_probs: jnp.ndarray,
@@ -258,53 +261,126 @@ def _int_zero(x):
     return np.zeros(x.shape, dtype=jax.dtypes.float0)
 
 
-@jax.custom_vjp
-def _dispatch(x, src_token, dst, mine):
-    """Rows of ``x`` (n, d) in sorted order: row ``r`` is position
-    ``src_token[r]``. ``dst`` (n, k) is the row of each assignment and
-    ``mine`` (n, k) whether it has one: the transpose is a gather too."""
-    return x[src_token]
+# A window is this many times the local assignments that a holder expects
+# of a uniform router, in whole tiles of rows. A constant: the benchmark's
+# cells fill half a window.
+_WINDOW_OVER_EXPECTED = 2
+_ROW_TILE = 512
 
 
-def _dispatch_fwd(x, src_token, dst, mine):
-    return x[src_token], (src_token, dst, mine)
+def row_bounds(n: int, top_k: int, held: int, experts_total: int
+               ) -> Tuple[int, int]:
+    """``(tight, rows)`` of a share of ``n`` positions. ``rows = n *
+    min(top_k, held)`` is the worst case, every position choosing only
+    experts held here; ``tight``, the rows every ``d``-wide operation of
+    the share is shaped for, is twice what a holder of ``held`` of
+    ``experts_total`` experts expects, in whole tiles of 512 rows, and
+    never more than ``rows`` (a holder of every expert has the one
+    bound)."""
+    rows = n * min(top_k, held)
+    expected = _WINDOW_OVER_EXPECTED * n * top_k * held // experts_total
+    return min(rows, -(-expected // _ROW_TILE) * _ROW_TILE), rows
 
 
-def _dispatch_bwd(res, g):
-    src_token, dst, mine = res
-    dx = jnp.sum(jnp.where(mine[..., None], g[dst], 0), axis=1,
-                 dtype=jnp.float32).astype(g.dtype)
-    return dx, _int_zero(src_token), _int_zero(dst), _int_zero(mine)
+def _ffn(xs, w_gate, w_up, w_down, sizes):
+    """The SwiGLU of each row's expert: three grouped products over rows
+    sorted by expert, ``sizes`` of them for each. What the kernel leaves in
+    the rows past the groups is nobody's promise."""
+    g = jax.lax.ragged_dot(xs, w_gate, sizes)
+    u = jax.lax.ragged_dot(xs, w_up, sizes)
+    return jax.lax.ragged_dot(nn.silu(g) * u, w_down, sizes)
 
 
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+def _window(r, top_k, start, order, group_sizes):
+    """Rows ``start .. start + r - 1`` of the sorted assignments: the flat
+    assignment and the position of each, which of them hold a local
+    assignment, and the rows of each held expert among them."""
+    src = jax.lax.dynamic_slice(order, (start,), (r,))
+    ends = jnp.cumsum(group_sizes)
+    sizes = (jnp.clip(ends - start, 0, r)
+             - jnp.clip(ends - group_sizes - start, 0, r))
+    live = jnp.arange(r, dtype=jnp.int32) < jnp.sum(sizes)
+    return src, src // top_k, live[:, None], sizes
 
 
-@jax.custom_vjp
-def _combine(ys, w, src, dst):
-    """``out[p] = sum_j w[p, j] * ys[dst[p, j]]`` over the sorted rows
-    ``ys`` (rows, d); ``w`` (n, k) fp32 is zero where an assignment has no
-    row. ``src`` (rows,) is the flat assignment of each row, so that the
-    transpose is a gather and not a scatter."""
-    return jnp.einsum("nk,nkd->nd", w, ys[dst].astype(jnp.float32)
-                      ).astype(ys.dtype)
+def _over_windows(r, args, body, sums):
+    """``body(start, carry)`` for every window of ``r`` rows that holds a
+    local assignment, as many as this routing gave, counted on the device;
+    the carry starts as zeros of the ``(shape, dtype)`` pairs ``sums``.
+    Where one window covers the worst case, it is called once. ``args`` are
+    all that ``body`` reads: inside ``shard_map`` a loop's carry must vary
+    over the mesh axes from the start that it varies over at the end."""
+    order, group_sizes = args[:2]
+    varies = tuple(frozenset().union(*(jax.typeof(a).vma for a in args)))
+    carry = tuple(jnp.zeros(shape, dtype) for shape, dtype in sums)
+    if varies:
+        carry = jax.lax.pcast(carry, varies, to="varying")
+    if r >= order.shape[0]:
+        return body(0, carry)
+    total = jnp.sum(group_sizes)
+    return jax.lax.while_loop(
+        lambda c: c[0] < total, lambda c: (c[0] + r, body(*c)),
+        (jnp.int32(0), carry))[1]
 
 
-def _combine_fwd(ys, w, src, dst):
-    return _combine(ys, w, src, dst), (ys, w, src, dst)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _share(r, top_k, order, group_sizes, x, w, w_gate, w_up, w_down):
+    """The share of ``x`` (n, d), ``r`` sorted assignments at a time:
+    gather the rows, the experts' SwiGLU, and the gated rows summed into
+    their positions in fp32. ``order`` (whole windows) is the flat
+    assignments sorted by held expert, ``w`` (n, top_k) fp32 their gates,
+    zero on an assignment of another holder; ``x`` and the weights are in
+    the compute dtype.
+
+    Differentiated as a whole, with its arguments as the residuals: the
+    backward pass goes over the same windows again, and nothing the size
+    of the worst case is ever written."""
+    def body(start, carry):
+        src, pos, live, sizes = _window(r, top_k, start, order, group_sizes)
+        with _tracing.scope("moe/experts"):
+            ys = _ffn(x[pos], w_gate, w_up, w_down, sizes)
+            gated = w.reshape(-1)[src][:, None] * ys.astype(jnp.float32)
+            return (carry[0].at[pos].add(jnp.where(live, gated, 0)),)
+
+    args = (order, group_sizes, x, w, w_gate, w_up, w_down)
+    return _over_windows(r, args, body, [(x.shape, jnp.float32)]
+                         )[0].astype(x.dtype)
 
 
-def _combine_bwd(res, g):
-    ys, w, src, dst = res
-    k = w.shape[1]
-    dys = (g[src // k].astype(jnp.float32)
-           * w.reshape(-1)[src][:, None]).astype(ys.dtype)
-    dw = jnp.einsum("nd,nkd->nk", g.astype(jnp.float32),
-                    ys[dst].astype(jnp.float32))
-    return dys, dw, _int_zero(src), _int_zero(dst)
+def _share_fwd(r, top_k, *args):
+    return _share(r, top_k, *args), args
 
 
-_combine.defvjp(_combine_fwd, _combine_bwd)
+def _share_bwd(r, top_k, args, g):
+    order, group_sizes, x, w, w_gate, w_up, w_down = args
+
+    def body(start, grads):
+        dx, dw, *dweights = grads
+        src, pos, live, sizes = _window(r, top_k, start, order, group_sizes)
+        with _tracing.scope("moe/experts"):
+            ys, back = jax.vjp(functools.partial(_ffn, sizes=sizes), x[pos],
+                               w_gate, w_up, w_down)
+            g_rows = g[pos].astype(jnp.float32)
+            dxs, *more = back(jnp.where(
+                live, g_rows * w.reshape(-1)[src][:, None], 0
+            ).astype(ys.dtype))
+            gate_rows = jnp.where(live, g_rows * ys.astype(jnp.float32), 0)
+            return (dx.at[pos].add(jnp.where(live, dxs.astype(jnp.float32),
+                                             0)),
+                    dw.at[src].add(jnp.sum(gate_rows, axis=-1)),
+                    *(a + b for a, b in zip(dweights, more)))
+
+    # the rows' cotangents are summed into positions in fp32; a window's
+    # weight gradients come out of the grouped products in the compute
+    # dtype and are added in it
+    grads = _over_windows(r, args + (g,), body, [
+        (x.shape, jnp.float32), ((w.size,), jnp.float32),
+        *((a.shape, a.dtype) for a in (w_gate, w_up, w_down))])
+    return (_int_zero(order), _int_zero(group_sizes)) + tuple(
+        d.reshape(a.shape).astype(a.dtype) for d, a in zip(grads, args[2:]))
+
+
+_share.defvjp(_share_fwd, _share_bwd)
 
 
 def routed_share(tokens, router, w_gate, w_up, w_down, *, first, top_k: int,
@@ -330,12 +406,18 @@ def routed_share(tokens, router, w_gate, w_up, w_down, *, first, top_k: int,
     experts, held or not, so that the shares of all holders add up to the
     whole layer. ``aux`` holds ``group_sizes`` (held,), the rows each held
     expert was given, and ``choice`` (n, top_k), the experts chosen.
-    No assignment is dropped: the grouped products are shaped for
-    ``n * min(top_k, held)`` rows.
+
+    No assignment is dropped, and the rows are shaped for what the share
+    can expect, not for the worst case: the gather, the grouped products
+    and the sum back into positions work on a window of ``tight`` sorted
+    assignments (:func:`row_bounds`), and the rows this routing gave
+    (``group_sizes``, on the device) say how many windows there are: one
+    where they fit it, as many as it takes up to the worst case ``n *
+    min(top_k, held)`` where they do not. No ``d``-wide operation is
+    shaped by ``n * top_k``.
     """
     n, d = tokens.shape
     held = w_gate.shape[0]
-    rows = n * min(top_k, held)
     with _tracing.scope("moe/route"):
         logits = jnp.dot(tokens.astype(jnp.float32), router,
                          precision=jax.lax.Precision.HIGHEST)
@@ -360,23 +442,21 @@ def routed_share(tokens, router, w_gate, w_up, w_down, *, first, top_k: int,
         local = choice - first
         mine = (local >= 0) & (local < held)
         # the assignments sorted by held expert, those of other holders
-        # last: every local one lies inside the first ``rows``
+        # last: the local ones are the first ``sum(group_sizes)``
         key = jnp.where(mine, local, held).reshape(-1).astype(jnp.int32)
-        order = jnp.argsort(key, stable=True)
-        dst = jnp.argsort(order).reshape(n, top_k).astype(jnp.int32)
-        src = order[:rows].astype(jnp.int32)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
         group_sizes = jnp.sum(
             key[:, None] == jnp.arange(held, dtype=jnp.int32)[None, :],
             axis=0, dtype=jnp.int32)
         w = jnp.where(mine, gate, 0.0)
-        dst = jnp.where(mine, dst, 0)
-    with _tracing.scope("moe/experts"):
-        xs = _dispatch(tokens.astype(dtype), src // top_k, dst, mine)
-        g = jax.lax.ragged_dot(xs, w_gate.astype(dtype), group_sizes)
-        u = jax.lax.ragged_dot(xs, w_up.astype(dtype), group_sizes)
-        ys = jax.lax.ragged_dot(nn.silu(g) * u, w_down.astype(dtype),
-                                group_sizes)
-        out = _combine(ys, w, src, dst)
+    tight, rows = row_bounds(n, top_k, held, router.shape[1])
+    # whole windows, as many as cover the worst case: the local
+    # assignments lie inside the first ``rows``
+    cover = -(-rows // tight) * tight
+    order = jnp.pad(order, (0, max(0, cover - order.size)))[:cover]
+    out = _share(tight, top_k, order, group_sizes, tokens.astype(dtype), w,
+                 w_gate.astype(dtype), w_up.astype(dtype),
+                 w_down.astype(dtype))
     return out, {"group_sizes": group_sizes, "choice": choice}
 
 
@@ -391,6 +471,14 @@ class RoutedExperts(nn.Module):
     all of them and a reduce-scatter sums the shares into each peer's own
     positions. Without it nothing is exchanged and the result is this
     holder's share alone: what the absent experts would add is left out.
+
+    No assignment is dropped. A share is shaped for twice the rows a
+    holder of ``count`` of ``experts_total`` experts expects
+    (:func:`row_bounds`), which a step runs at; where a routing gives more,
+    up to the worst case of every position choosing only experts held
+    here, it goes over a second window of rows, and a third, by a loop on
+    the device (under ``ep_axis`` each peer by its own rows: the loop holds
+    no collective). A holder of every expert has the one bound and no loop.
 
     ``score``, ``norm_eps`` and ``scale`` are the routing rule's
     (:func:`routed_share`); ``select_bias`` (experts_total,) is handed to
@@ -442,8 +530,9 @@ class RoutedExperts(nn.Module):
                 everyone, first=jax.lax.axis_index(self.ep_axis) * held)
             out = jax.lax.psum_scatter(out, self.ep_axis,
                                        scatter_dimension=0, tiled=True)
-        _tracing.note_routing(
-            moe_rows_bound=aux["choice"].shape[0] * min(self.top_k, held))
+        tight, rows = row_bounds(aux["choice"].shape[0], self.top_k, held,
+                                 self.experts_total)
+        _tracing.note_routing(moe_rows_tight=tight, moe_rows_bound=rows)
         self.sow("intermediates", "group_sizes", aux["group_sizes"])
         self.sow("intermediates", "choice", aux["choice"])
         return out.reshape(b, t, d)

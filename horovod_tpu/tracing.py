@@ -368,10 +368,18 @@ NAMES: Dict[str, Name] = {
         "that found every leaf averaged by an earlier pass of the trace "
         "and lowered nothing; labels program, scope", "xprof only"),
     "moe_rows_bound": Name(
-        "gauge", _EXPERTS, "routing manifest: rows the grouped products "
-        "are shaped for (positions x min(top_k, held): no assignment is "
-        "ever dropped); label program", "registry only: what "
-        "moe_local_assignments is a part of (12 % on the benchmark's cell)"),
+        "gauge", _EXPERTS, "routing manifest: the fallback's shape, the "
+        "worst case that the loop over windows of moe_rows_tight rows may "
+        "have to cover (positions x min(top_k, held): no assignment is "
+        "ever dropped); label program",
+        "registry only: what moe_rows_tight is a quarter of on the "
+        "benchmark's cells"),
+    "moe_rows_tight": Name(
+        "gauge", _EXPERTS, "routing manifest: rows every d-wide operation "
+        "of a step's share is shaped for (ops.moe.row_bounds: twice what a "
+        "holder of held of experts_total experts expects, in tiles of 512, "
+        "at most moe_rows_bound); label program",
+        "moe_rows_filled_share.train"),
     "bd_tiles_visited": Name(
         "gauge", _KERNELS, "routing manifest: tiles of one head's forward "
         "grid that hold a visible pair; label program",
@@ -416,6 +424,12 @@ NAMES: Dict[str, Name] = {
         "gauge", _EXPERTS, "routing of one batch (routing_load): "
         "(position, expert) choices that fell on the experts held, a "
         "layer; label program", "moe_local_assignments.train"),
+    "moe_rows_overflow_layers": Name(
+        "gauge", _EXPERTS, "routing of one batch (routing_load): routed "
+        "layers whose rows exceed the moe_rows_tight the program "
+        "published, the layers that go over a second window of rows; label "
+        "program", "registry only: 0 on the benchmark's cells; how often "
+        "more than the tight shape is paid for"),
     "moe_load_max_over_mean": Name(
         "gauge", _EXPERTS, "routing of one batch: the busiest held "
         "expert's rows over the mean, over layers; label program",
@@ -612,8 +626,8 @@ def synced_as(tree: Any) -> Any:
 # the routing manifest
 # ---------------------------------------------------------------------------
 
-_ROUTING = ("moe_rows_bound", "bd_tiles_visited", "bd_tiles_total",
-            "causal_tiles_visited", "causal_tiles_total",
+_ROUTING = ("moe_rows_bound", "moe_rows_tight", "bd_tiles_visited",
+            "bd_tiles_total", "causal_tiles_visited", "causal_tiles_total",
             "mla_kv_expanded_bytes", "mla_latent_bytes", "mtp_modules")
 
 
@@ -637,12 +651,20 @@ def routing_load(program_name: str, group_sizes) -> None:
     ``group_sizes`` ``(layers, held)`` are the rows each held expert was
     given, an auxiliary output of a forward pass (a check step, never a
     timed one). Sets ``moe_local_assignments`` (rows a layer, mean over
-    layers) and ``moe_load_max_over_mean``."""
+    layers), ``moe_load_max_over_mean`` and, where the program published
+    the ``moe_rows_tight`` its share is shaped for,
+    ``moe_rows_overflow_layers``: the layers whose rows exceed it."""
     import numpy as np
     sizes = np.asarray(group_sizes, dtype=np.float64)
     sizes = sizes.reshape(-1, sizes.shape[-1])
+    rows = sizes.sum(axis=1)
     _metrics.gauge("moe_local_assignments", program=program_name).set(
-        float(sizes.sum(axis=1).mean()))
+        float(rows.mean()))
+    tight = [s["value"] for s in _metrics.snapshot()["gauges"].get(
+        "moe_rows_tight", ()) if s["labels"].get("program") == program_name]
+    if tight:
+        _metrics.gauge("moe_rows_overflow_layers", program=program_name).set(
+            int((rows > tight[0]).sum()))
     mean = sizes.mean()
     _metrics.gauge("moe_load_max_over_mean", program=program_name).set(
         float(sizes.max() / mean) if mean else 0.0)

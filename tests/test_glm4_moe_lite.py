@@ -664,7 +664,8 @@ def test_the_manifest_of_a_traced_step(mtp):
                                              bias[:3 + mtp]))(params)
     visited, total = causal_tiles(T, 16, 16)
     layers = cfg.num_layers + mtp
-    want = {"moe_rows_bound": 2 * T * 2, "causal_tiles_visited": visited,
+    want = {"moe_rows_bound": 2 * T * 2, "moe_rows_tight": 2 * T * 2,
+            "causal_tiles_visited": visited,
             "causal_tiles_total": total, "mtp_modules": mtp,
             # positions x attention layers x 4 heads x (16 + 16) x 2 B
             "mla_kv_expanded_bytes": 2 * T * layers * 4 * 32 * 2,

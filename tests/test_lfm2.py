@@ -492,8 +492,8 @@ def test_the_routing_manifest_of_a_traced_step():
         jax.make_jaxpr(lambda p: lfm2.loss_fn(model, p, tokens, bias))(
             params)
     visited, total = causal_tiles(T, 16, 16)
-    want = {"moe_rows_bound": 2 * T * 2, "causal_tiles_visited": visited,
-            "causal_tiles_total": total}
+    want = {"moe_rows_bound": 2 * T * 2, "moe_rows_tight": 2 * T * 2,
+            "causal_tiles_visited": visited, "causal_tiles_total": total}
     for name, value in want.items():
         assert _gauge(name, "lfm2_step") == [value], name
     assert visited < total

@@ -203,6 +203,173 @@ def test_gradients_of_the_share_are_the_references():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
 
 
+# A holder of 2 of 16 experts under top-2 of 1,024 positions: 256 rows
+# expected, windows of 512, 2,048 at worst.
+CUT = dict(n=1024, experts=16)
+HELD, TOP_K = slice(4, 6), 2
+TIGHT, ROWS = 512, 2048
+# rows a routing gives the two held experts, by what it is
+WINDOWS = {"one window": 212, "two windows": 780, "the worst case": ROWS}
+
+
+def _routing(kind):
+    """The cut layer's inputs under a router that spreads the positions
+    over all 16 experts, one that favours the first held expert (its rows
+    lie across two windows, the second's in the second), and one that
+    sends every position to the two held."""
+    x, router, w_gate, w_up, w_down = _layer(**CUT)
+    if kind == "two windows":
+        x, router = x + 0.5, router.at[:, HELD.start].set(0.3)
+    if kind == "the worst case":
+        x, router = jnp.abs(x), router.at[:, HELD].set(5.0)
+    return x, router, w_gate[HELD], w_up[HELD], w_down[HELD]
+
+
+def _cut_share(*args):
+    return moe.routed_share(*args, first=HELD.start, top_k=TOP_K,
+                            dtype=jnp.float32)
+
+
+def _cut_loss(*args):
+    out, aux = _cut_share(*args)
+    return jnp.sum(out * jnp.cos(out)), (out, aux["group_sizes"])
+
+
+def _cut_grad(*args):
+    (_, (out, sizes)), grads = jax.value_and_grad(
+        _cut_loss, argnums=range(5), has_aux=True)(*args)
+    return out, sizes, grads
+
+
+def _cut_reference(x, router, w_gate, w_up, w_down):
+    p = {"router": router, "w_gate": w_gate, "w_up": w_up, "w_down": w_down}
+    with jax.default_matmul_precision("highest"):
+        out = ref._experts(x, p, top_k=TOP_K, norm_topk=True,
+                           experts_first=HELD.start)[0]
+    return jnp.sum(out * jnp.cos(out)), out
+
+
+def test_the_row_bounds_follow_what_a_share_can_expect():
+    assert moe.row_bounds(CUT["n"], TOP_K, 2, 16) == (TIGHT, ROWS)
+    # the benchmark's cells: a quarter of the worst case
+    assert moe.row_bounds(16384, 8, 16, 128) == (32768, 131072)
+    assert moe.row_bounds(32768, 4, 8, 64) == (32768, 131072)
+    assert moe.row_bounds(16384, 4, 8, 64) == (16384, 65536)
+    # a holder of every expert, and shares under one tile of rows
+    assert moe.row_bounds(64, 2, 8, 8) == (128, 128)
+    assert moe.row_bounds(128, 4, 2, 8) == (256, 256)
+    assert moe.row_bounds(4096, 4, 2, 8) == (8192, 8192)    # held < top_k
+
+
+def _is_the_references(args, given):
+    """Outputs and every gradient of the cut share against the dense
+    reference, on a routing that gives the held experts ``given`` rows."""
+    out, sizes, got = _cut_grad(*args)
+    (_, want_out), want = jax.value_and_grad(
+        _cut_reference, argnums=range(5), has_aux=True)(*args)
+    assert int(sizes.sum()) == given
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want_out),
+                               atol=2e-5)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-4)
+
+
+@pytest.mark.parametrize("kind", list(WINDOWS))
+def test_the_share_over_its_windows_is_the_references(kind):
+    """Whether the routing fits one window of 512 rows or needs all four:
+    none of 2,048 assignments is dropped."""
+    _is_the_references(_routing(kind), WINDOWS[kind])
+
+
+def test_a_worst_case_that_is_no_whole_number_of_windows():
+    """1,000 positions: 2,000 rows at worst, the last of four windows
+    partly past the end of the assignments."""
+    x, router, w_gate, w_up, w_down = _layer(n=1000, experts=16)
+    assert moe.row_bounds(1000, TOP_K, 2, 16) == (TIGHT, 2000)
+    _is_the_references((jnp.abs(x), router.at[:, HELD].set(5.0),
+                        w_gate[HELD], w_up[HELD], w_down[HELD]), 2000)
+
+
+@pytest.mark.parametrize("kind", list(WINDOWS))
+def test_the_windows_agree_with_one_shape_for_the_worst_case(kind,
+                                                              monkeypatch):
+    """The same routing through windows of 512 rows and through one shape
+    of 2,048, which holds any routing and has no loop."""
+    args = _routing(kind)
+    out, _, grads = _cut_grad(*args)
+    monkeypatch.setattr(moe, "row_bounds", lambda *a: (ROWS, ROWS))
+    whole_out, _, whole = _cut_grad(*args)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(whole_out),
+                               rtol=1e-5, atol=2e-6)
+    for a, b in zip(grads, whole):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=2e-5)
+
+
+@pytest.mark.parametrize("kind", ["one window", "two windows"])
+def test_what_the_grouped_products_leave_past_their_groups_is_not_read(
+        kind, monkeypatch):
+    """Nothing promises what a grouped kernel writes in the rows past its
+    groups: with NaN there, outputs and gradients are what they were."""
+    args = _routing(kind)
+    out, _, grads = _cut_grad(*args)
+    ffn, traced = moe._ffn, []
+
+    def poisoned(xs, w_gate, w_up, w_down, sizes):
+        traced.append(xs.shape)
+        past = jnp.arange(xs.shape[0])[:, None] >= jnp.sum(sizes)
+        return jnp.where(past, jnp.nan, ffn(xs, w_gate, w_up, w_down, sizes))
+
+    monkeypatch.setattr(moe, "_ffn", poisoned)
+    again_out, _, again = _cut_grad(*args)
+    assert traced == [(TIGHT, 32)] * 2          # forward, and to go back
+    for a, b in zip((out, *grads), (again_out, *again)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _shapes(jaxpr):
+    """Shapes of every array in ``jaxpr`` and in the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        for var in eqn.invars + eqn.outvars:
+            yield tuple(getattr(var.aval, "shape", ()))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _shapes(sub)
+
+
+def _wide(jaxpr, d=32, f=16):
+    return sorted({s for s in _shapes(jaxpr) if len(s) == 2
+                   and s[0] == CUT["n"] * TOP_K and s[1] in (d, f)})
+
+
+def _traced(what, share, *args):
+    """The jaxpr of ``share``, or of its gradient in every argument."""
+    fn = (lambda *a: share(*a)[0]) if what == "forward" else jax.grad(
+        lambda *a: jnp.sum(share(*a)[0]), argnums=range(5))
+    return jax.make_jaxpr(fn)(*args).jaxpr
+
+
+@pytest.mark.parametrize("what", ["forward", "gradient"])
+def test_nothing_is_shaped_by_positions_x_top_k(what, monkeypatch):
+    args = _routing("one window")
+    jaxpr = _traced(what, _cut_share, *args)
+    # a loop over the windows, and one to go back over them
+    assert str(jaxpr).count("while[") == (1 if what == "forward" else 2)
+    assert "cond[" not in str(jaxpr)
+    assert _wide(jaxpr) == []
+    # the detector sees one shape for the worst case, which is so shaped
+    monkeypatch.setattr(moe, "row_bounds", lambda *a: (ROWS, ROWS))
+    assert _wide(_traced(what, _cut_share, *args)) == [(ROWS, 16),
+                                                       (ROWS, 32)]
+
+
+@pytest.mark.parametrize("what", ["forward", "gradient"])
+def test_a_holder_of_every_expert_has_one_window_and_no_loop(what):
+    share = lambda *a: moe.routed_share(*a, first=0, top_k=2,
+                                        dtype=jnp.float32)
+    text = str(_traced(what, share, *_layer()))
+    assert "while[" not in text and "cond[" not in text
+
+
 def test_under_an_ep_axis_the_shares_are_exchanged():
     """Four peers, positions and experts sharded over ``ep``: every peer
     ends with the whole layer's result for its own positions."""
@@ -222,6 +389,35 @@ def test_under_an_ep_axis_the_shares_are_exchanged():
     np.testing.assert_allclose(
         np.asarray(out),
         np.asarray(_uncut(x, router, w_gate, w_up, w_down, 2)), atol=1e-5)
+
+
+def test_under_an_ep_axis_each_peer_loops_by_its_own_rows():
+    """Four peers of 4 experts each, every position sent to two experts of
+    peer 1: it goes over two windows of 1,024 rows while the other three,
+    given nothing, go over none; the exchanged result is the uncut
+    layer."""
+    x, router, w_gate, w_up, w_down = _layer(**CUT)
+    x, router = jnp.abs(x), router.at[:, 4:6].set(5.0)
+    assert moe.row_bounds(CUT["n"], TOP_K, 4, 16) == (1024, 2048)
+    layer = moe.RoutedExperts(16, (0, 4), TOP_K, 16, dtype=jnp.float32,
+                              ep_axis="ep")
+    mesh = Mesh(np.array(jax.devices()[:4]), ("ep",))
+
+    def run(x, router, w_gate, w_up, w_down):
+        params = {"router": router, "w_gate": w_gate, "w_up": w_up,
+                  "w_down": w_down}
+        out, kept = layer.apply({"params": params}, x[None],
+                                mutable=["intermediates"])
+        return out[0], kept["intermediates"]["group_sizes"][0]
+
+    out, sizes = jax.jit(jax.shard_map(
+        run, mesh=mesh, in_specs=(P("ep"), P(), P("ep"), P("ep"), P("ep")),
+        out_specs=(P("ep"), P("ep"))))(x, router, w_gate, w_up, w_down)
+    assert np.asarray(sizes).reshape(4, 4).sum(1).tolist() == [0, 2048, 0, 0]
+    np.testing.assert_allclose(
+        np.asarray(out),
+        np.asarray(_uncut(x, router, w_gate, w_up, w_down, TOP_K)),
+        atol=2e-5)
 
 
 def test_the_layer_refuses_experts_it_cannot_hold():
@@ -339,7 +535,9 @@ def test_the_routing_manifest_of_a_traced_step():
         jax.make_jaxpr(lambda p: sdar.loss_fn(model, p, tokens, noise))(
             params)
     visited, total = fa.bd_tiles(T, L, 16, 32)
-    want = {"moe_rows_bound": 2 * 2 * T * 2,
+    # 128 positions, 2 of 8 experts held under top-4: 256 rows at worst,
+    # and twice the 128 expected is no less
+    want = {"moe_rows_bound": 2 * 2 * T * 2, "moe_rows_tight": 2 * 2 * T * 2,
             "bd_tiles_visited": visited, "bd_tiles_total": total}
     for name, value in want.items():
         assert _gauge(name, "sdar_step") == [value], name
@@ -364,3 +562,18 @@ def test_routing_load_from_the_auxiliary_output():
         pytest.approx(sizes.sum(1).mean())]
     assert _gauge("moe_load_max_over_mean", "sdar_look") == [
         pytest.approx(sizes.max() / sizes.mean())]
+    # no program of that name said what its share is shaped for
+    assert _gauge("moe_rows_overflow_layers", "sdar_look") == []
+    with tracing.program("sdar_look"):
+        jax.make_jaxpr(lambda p: model.apply({"params": p}, tokens, tokens))(
+            params)
+    tight = _gauge("moe_rows_tight", "sdar_look")[0]
+    assert tight == 2 * 2 * T * 2 and sizes.sum(1).max() <= tight
+    tracing.routing_load("sdar_look", sizes)
+    assert _gauge("moe_rows_overflow_layers", "sdar_look") == [0]
+    # one more layer, a row over the bound: it would go over two windows
+    over = np.concatenate([sizes, [[tight, 1]]])
+    tracing.routing_load("sdar_look", over)
+    assert _gauge("moe_rows_overflow_layers", "sdar_look") == [1]
+    assert _gauge("moe_local_assignments", "sdar_look") == [
+        pytest.approx(over.sum(1).mean())]

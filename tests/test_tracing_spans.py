@@ -36,7 +36,8 @@ LFM2_SCOPES = ["lfm2/shortconv", "lfm2/attn", "lfm2/dense_mlp", "moe/route",
 GLM4_SCOPES = ["glm4/mla_down", "glm4/mla_up", "glm4/attn", "glm4/dense_mlp",
                "glm4/shared_expert", "glm4/mtp", "glm4/loss_head",
                "moe/route", "moe/experts"]
-ROUTING = ["moe_rows_bound", "bd_tiles_visited", "bd_tiles_total",
+ROUTING = ["moe_rows_bound", "moe_rows_tight", "moe_rows_overflow_layers",
+           "bd_tiles_visited", "bd_tiles_total",
            "causal_tiles_visited", "causal_tiles_total",
            "mla_kv_expanded_bytes", "mla_latent_bytes", "mtp_modules",
            "moe_local_assignments", "moe_load_max_over_mean",
@@ -649,6 +650,35 @@ def test_moe_bias_moved_share_reads_its_gauge(monkeypatch, gauge, want):
     assert spec["workloads"] == ["lfm2-24b-train-dp1"]
     assert entry[0]["workloads"] == spec["workloads"] + ["glm47f-train-dp1"]
     for sel in spec["args"]["series"]:
+        assert tracing.NAMES[sel["name"]].feeds == spec["name"]
+
+
+@pytest.mark.parametrize("gauges,want", [
+    ({"moe_local_assignments": 16384, "moe_rows_tight": 32768}, 50.0),
+    ({"moe_local_assignments": 40960, "moe_rows_tight": 32768}, 125.0),
+    ({"moe_local_assignments": 16384}, None),   # the parent of PR 34
+], ids=["half-a-window", "a-second-window", "parent"])
+def test_moe_rows_filled_share_reads_the_manifest(monkeypatch, gauges, want):
+    """``moe_rows_filled_share.train`` is data for the reader the benchmark
+    has (``named:series_total``): the rows the check batch gave the experts
+    held over the rows ``train_step``'s share is shaped for, in per cent,
+    and nothing (no raise) where the program publishes no such shape."""
+    spec, entry, named = _benchmark_metric("moe_rows_filled_share.train")
+    monkeypatch.setattr(hvd.metrics, "snapshot", lambda: {
+        "counters": {}, "histograms": {}, "gauges": {
+            name: [{"labels": {"program": "train_step"}, "value": value},
+                   {"labels": {"program": "eval_step"}, "value": 1}]
+            for name, value in gauges.items()}})
+    module, function = spec["reader"].split(":")
+    assert module == "named"
+    got = getattr(named, function)(None, **spec["args"])
+    assert got == (want if want is None else pytest.approx(want))
+    assert len(entry) == 1
+    for key in ("unit", "layer", "moves", "source", "better", "workloads"):
+        assert spec[key] == entry[0][key], key
+    assert spec["workloads"] == ["sdar30b-bd-train-dp1", "lfm2-24b-train-dp1",
+                                 "glm47f-train-dp1"]
+    for sel in spec["args"]["per"]:
         assert tracing.NAMES[sel["name"]].feeds == spec["name"]
 
 
