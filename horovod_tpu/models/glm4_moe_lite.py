@@ -167,22 +167,24 @@ class Block(nn.Module):
     def __call__(self, x, select_bias=None):
         from horovod_tpu.ops.moe import RoutedExperts, SharedExpert
         cfg = self.cfg
-        x = x + LatentAttention(cfg, name="attn")(
-            RMSNorm(cfg.rms_eps, name="norm_in")(x))
-        u = RMSNorm(cfg.rms_eps, name="norm_post")(x)
-        if self.layer < cfg.num_dense_layers:
-            with _tracing.scope("glm4/dense_mlp"):
-                return x + SharedExpert(cfg.d_ff, cfg.dtype, name="mlp")(u)
-        y = RoutedExperts(
-            cfg.experts_total, cfg.experts_held, cfg.top_k, cfg.d_expert,
-            cfg.norm_topk, cfg.dtype, cfg.ep_axis, score="sigmoid",
-            norm_eps=_NORM_EPS, scale=cfg.routed_scale, name="moe")(
-                u, select_bias)
-        if cfg.shared_experts:
-            with _tracing.scope("glm4/shared_expert"):
-                y = y + SharedExpert(cfg.d_expert * cfg.shared_experts,
-                                     cfg.dtype, name="shared")(u)
-        return x + y
+        with _tracing.scope("glm4/block"):
+            x = x + LatentAttention(cfg, name="attn")(
+                RMSNorm(cfg.rms_eps, name="norm_in")(x))
+            u = RMSNorm(cfg.rms_eps, name="norm_post")(x)
+            if self.layer < cfg.num_dense_layers:
+                with _tracing.scope("glm4/dense_mlp"):
+                    return x + SharedExpert(cfg.d_ff, cfg.dtype,
+                                            name="mlp")(u)
+            y = RoutedExperts(
+                cfg.experts_total, cfg.experts_held, cfg.top_k, cfg.d_expert,
+                cfg.norm_topk, cfg.dtype, cfg.ep_axis, score="sigmoid",
+                norm_eps=_NORM_EPS, scale=cfg.routed_scale, name="moe")(
+                    u, select_bias)
+            if cfg.shared_experts:
+                with _tracing.scope("glm4/shared_expert"):
+                    y = y + SharedExpert(cfg.d_expert * cfg.shared_experts,
+                                         cfg.dtype, name="shared")(u)
+            return x + y
 
 
 class MTP(nn.Module):

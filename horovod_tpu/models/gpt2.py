@@ -128,13 +128,15 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, segment_ids=None, deterministic=True):
         cfg = self.cfg
-        ln1 = nn.LayerNorm(epsilon=cfg.ln_eps, dtype=jnp.float32,
-                           name="ln1")(x)
-        x = x + Attention(cfg, name="attn")(ln1, segment_ids,
-                                            deterministic)
-        ln2 = nn.LayerNorm(epsilon=cfg.ln_eps, dtype=jnp.float32,
-                           name="ln2")(x)
-        x = x + MLP(cfg, name="mlp")(ln2, deterministic)
+        with _tracing.scope("gpt2/attn"):
+            ln1 = nn.LayerNorm(epsilon=cfg.ln_eps, dtype=jnp.float32,
+                               name="ln1")(x)
+            x = x + Attention(cfg, name="attn")(ln1, segment_ids,
+                                                deterministic)
+        with _tracing.scope("gpt2/mlp"):
+            ln2 = nn.LayerNorm(epsilon=cfg.ln_eps, dtype=jnp.float32,
+                               name="ln2")(x)
+            x = x + MLP(cfg, name="mlp")(ln2, deterministic)
         return x
 
 
@@ -176,10 +178,11 @@ class GPT2(nn.Module):
         block = remat_block(Block, cfg, static_argnums=(3,))
         for i in range(cfg.num_layers):
             x = block(cfg, name=f"h{i}")(x, segment_ids, deterministic)
-        x = nn.LayerNorm(epsilon=cfg.ln_eps, dtype=jnp.float32,
-                         name="ln_f")(x)
-        # Tied lm head in fp32 (logits precision matters for loss).
-        return jnp.einsum("btd,vd->btv", x.astype(jnp.float32), wte)
+        with _tracing.scope("gpt2/lm_head"):
+            x = nn.LayerNorm(epsilon=cfg.ln_eps, dtype=jnp.float32,
+                             name="ln_f")(x)
+            # Tied lm head in fp32 (logits precision matters for loss).
+            return jnp.einsum("btd,vd->btv", x.astype(jnp.float32), wte)
 
 
 def partition_rules() -> PartitionRules:

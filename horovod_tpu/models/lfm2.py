@@ -159,26 +159,27 @@ class Block(nn.Module):
     def __call__(self, x, select_bias=None):
         cfg = self.cfg
         kind = cfg.layer_types[self.layer]
-        u = RMSNorm(cfg.rms_eps, name="norm_op")(x)
-        if kind == "conv":
-            with _tracing.scope("lfm2/shortconv"):
-                x = x + ShortConv(cfg, name="conv")(u)
-        elif kind == "full_attention":
-            with _tracing.scope("lfm2/attn"):
-                x = x + Attention(cfg, name="attn")(u)
-        else:
-            raise ValueError(f"layer_types[{self.layer}] = {kind!r}: "
-                             "expected 'conv' or 'full_attention'")
-        u = RMSNorm(cfg.rms_eps, name="norm_ff")(x)
-        if self.layer < cfg.num_dense_layers:
-            with _tracing.scope("lfm2/dense_mlp"):
-                return x + DenseMLP(cfg, name="mlp")(u)
-        from horovod_tpu.ops.moe import RoutedExperts
-        return x + RoutedExperts(
-            cfg.experts_total, cfg.experts_held, cfg.top_k, cfg.d_expert,
-            cfg.norm_topk, cfg.dtype, cfg.ep_axis, score="sigmoid",
-            norm_eps=1e-6, scale=cfg.routed_scale, name="moe")(
-                u, select_bias)
+        with _tracing.scope("lfm2/block"):
+            u = RMSNorm(cfg.rms_eps, name="norm_op")(x)
+            if kind == "conv":
+                with _tracing.scope("lfm2/shortconv"):
+                    x = x + ShortConv(cfg, name="conv")(u)
+            elif kind == "full_attention":
+                with _tracing.scope("lfm2/attn"):
+                    x = x + Attention(cfg, name="attn")(u)
+            else:
+                raise ValueError(f"layer_types[{self.layer}] = {kind!r}: "
+                                 "expected 'conv' or 'full_attention'")
+            u = RMSNorm(cfg.rms_eps, name="norm_ff")(x)
+            if self.layer < cfg.num_dense_layers:
+                with _tracing.scope("lfm2/dense_mlp"):
+                    return x + DenseMLP(cfg, name="mlp")(u)
+            from horovod_tpu.ops.moe import RoutedExperts
+            return x + RoutedExperts(
+                cfg.experts_total, cfg.experts_held, cfg.top_k, cfg.d_expert,
+                cfg.norm_topk, cfg.dtype, cfg.ep_axis, score="sigmoid",
+                norm_eps=1e-6, scale=cfg.routed_scale, name="moe")(
+                    u, select_bias)
 
 
 class LFM2(nn.Module):

@@ -116,10 +116,11 @@ class Block(nn.Module):
         with _tracing.scope("sdar/attn"):
             x = x + Attention(cfg, name="attn")(
                 RMSNorm(cfg.rms_eps, name="norm_attn")(x), positions)
-        return x + RoutedExperts(
-            cfg.experts_total, cfg.experts_held, cfg.top_k, cfg.d_expert,
-            cfg.norm_topk, cfg.dtype, cfg.ep_axis, name="moe")(
-                RMSNorm(cfg.rms_eps, name="norm_mlp")(x))
+        with _tracing.scope("sdar/block"):
+            return x + RoutedExperts(
+                cfg.experts_total, cfg.experts_held, cfg.top_k, cfg.d_expert,
+                cfg.norm_topk, cfg.dtype, cfg.ep_axis, name="moe")(
+                    RMSNorm(cfg.rms_eps, name="norm_mlp")(x))
 
 
 class SDAR(nn.Module):

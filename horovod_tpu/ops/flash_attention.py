@@ -862,7 +862,9 @@ def _attend(q, k, v, causal, scale, key_bias, segment_ids, tiles,
                              f"({b}, {tq}), got {segment_ids.shape}")
         seg = segment_ids.astype(jnp.int32).reshape(b, tq, 1)
 
-    o = _flash(pack(q), pack(k), pack(v), key_bias, seg, h, float(scale),
+    with _tracing.scope("flash/layout"):
+        q, k, v = pack(q), pack(k), pack(v)
+    o = _flash(q, k, v, key_bias, seg, h, float(scale),
                bool(causal), int(block_q), int(block_k),
                int(block_q_bwd), int(block_k_bwd), int(causal_offset), bd,
                chunk and int(chunk), chunk_bwd and int(chunk_bwd))
@@ -875,4 +877,5 @@ def _attend(q, k, v, causal, scale, key_bias, segment_ids, tiles,
                                       int(causal_offset))
         _tracing.note_routing(causal_tiles_visited=visited,
                               causal_tiles_total=total)
-    return o.reshape(b, h, tq, d).transpose(0, 2, 1, 3)
+    with _tracing.scope("flash/layout"):
+        return o.reshape(b, h, tq, d).transpose(0, 2, 1, 3)

@@ -337,10 +337,9 @@ def _share(r, top_k, order, group_sizes, x, w, w_gate, w_up, w_down):
     of the worst case is ever written."""
     def body(start, carry):
         src, pos, live, sizes = _window(r, top_k, start, order, group_sizes)
-        with _tracing.scope("moe/experts"):
-            ys = _ffn(x[pos], w_gate, w_up, w_down, sizes)
-            gated = w.reshape(-1)[src][:, None] * ys.astype(jnp.float32)
-            return (carry[0].at[pos].add(jnp.where(live, gated, 0)),)
+        ys = _ffn(x[pos], w_gate, w_up, w_down, sizes)
+        gated = w.reshape(-1)[src][:, None] * ys.astype(jnp.float32)
+        return (carry[0].at[pos].add(jnp.where(live, gated, 0)),)
 
     args = (order, group_sizes, x, w, w_gate, w_up, w_down)
     return _over_windows(r, args, body, [(x.shape, jnp.float32)]
@@ -357,18 +356,16 @@ def _share_bwd(r, top_k, args, g):
     def body(start, grads):
         dx, dw, *dweights = grads
         src, pos, live, sizes = _window(r, top_k, start, order, group_sizes)
-        with _tracing.scope("moe/experts"):
-            ys, back = jax.vjp(functools.partial(_ffn, sizes=sizes), x[pos],
-                               w_gate, w_up, w_down)
-            g_rows = g[pos].astype(jnp.float32)
-            dxs, *more = back(jnp.where(
-                live, g_rows * w.reshape(-1)[src][:, None], 0
-            ).astype(ys.dtype))
-            gate_rows = jnp.where(live, g_rows * ys.astype(jnp.float32), 0)
-            return (dx.at[pos].add(jnp.where(live, dxs.astype(jnp.float32),
-                                             0)),
-                    dw.at[src].add(jnp.sum(gate_rows, axis=-1)),
-                    *(a + b for a, b in zip(dweights, more)))
+        ys, back = jax.vjp(functools.partial(_ffn, sizes=sizes), x[pos],
+                           w_gate, w_up, w_down)
+        g_rows = g[pos].astype(jnp.float32)
+        dxs, *more = back(jnp.where(
+            live, g_rows * w.reshape(-1)[src][:, None], 0
+        ).astype(ys.dtype))
+        gate_rows = jnp.where(live, g_rows * ys.astype(jnp.float32), 0)
+        return (dx.at[pos].add(jnp.where(live, dxs.astype(jnp.float32), 0)),
+                dw.at[src].add(jnp.sum(gate_rows, axis=-1)),
+                *(a + b for a, b in zip(dweights, more)))
 
     # the rows' cotangents are summed into positions in fp32; a window's
     # weight gradients come out of the grouped products in the compute
@@ -453,10 +450,14 @@ def routed_share(tokens, router, w_gate, w_up, w_down, *, first, top_k: int,
     # whole windows, as many as cover the worst case: the local
     # assignments lie inside the first ``rows``
     cover = -(-rows // tight) * tight
-    order = jnp.pad(order, (0, max(0, cover - order.size)))[:cover]
-    out = _share(tight, top_k, order, group_sizes, tokens.astype(dtype), w,
-                 w_gate.astype(dtype), w_up.astype(dtype),
-                 w_down.astype(dtype))
+    # the scope holds all of the share, forward and backward (a custom_vjp's
+    # backward is named by where it was called): the casts of the weights,
+    # the loop over windows with its carries, and what a window does
+    with _tracing.scope("moe/experts"):
+        order = jnp.pad(order, (0, max(0, cover - order.size)))[:cover]
+        out = _share(tight, top_k, order, group_sizes, tokens.astype(dtype),
+                     w, w_gate.astype(dtype), w_up.astype(dtype),
+                     w_down.astype(dtype))
     return out, {"group_sizes": group_sizes, "choice": choice}
 
 

@@ -10,6 +10,7 @@ lowering to XLA ops inside.
 from __future__ import annotations
 
 import functools
+import weakref
 from typing import Any, Callable, Optional
 
 import jax
@@ -37,22 +38,50 @@ def spmd(fn: Callable, *, in_specs: Any = None, out_specs: Any = None,
         in_specs = P()
     if out_specs is None:
         out_specs = P()
-    # The function's name labels its sync manifest and its set-up ledger
-    # series (tracing.py). ``traced`` runs while jit traces and never per
-    # step: the returned object is still the bare ``jax.jit``, and the
-    # program keeps the function's name (``jit_<name>``).
+    # The function's name labels its sync manifest, its set-up ledger
+    # series and its scope table (tracing.py). ``traced`` runs while jit
+    # traces and never per step: the returned object is still the bare
+    # ``jax.jit``, and the program keeps the function's name
+    # (``jit_<name>``).
     name = getattr(fn, "__name__", "spmd_fn")
     _tracing.note_program(name)
+    jitted = []                 # a weak reference: no cycle through fn
 
     @functools.wraps(fn)
     def traced(*args, **kwargs):
+        if not kwargs and not static_argnums:
+            _tracing.note_lowering(name, jitted[0],
+                                   _global_shapes(args, in_specs, m))
         with _tracing.program(name):
             return fn(*args, **kwargs)
 
     mapped = jax.shard_map(traced, mesh=m, in_specs=in_specs,
                            out_specs=out_specs, check_vma=False)
-    return jax.jit(mapped, donate_argnums=donate_argnums,
+    step = jax.jit(mapped, donate_argnums=donate_argnums,
                    static_argnums=static_argnums)
+    jitted.append(weakref.ref(step))
+    return step
+
+
+def _global_shapes(args, in_specs, mesh):
+    """The arguments a mapped function is traced with, seen from outside
+    the map: each leaf's per-device shape times the mesh axes its spec
+    splits it over, with the ``NamedSharding`` that spec and the mesh fix,
+    as ``jax.ShapeDtypeStruct``s (what ``.lower`` takes in place of
+    arrays). ``in_specs`` is a prefix of ``args``, as shard_map reads it."""
+    def leaf(spec, x):
+        shape = list(x.shape)
+        for dim, axes in enumerate(spec):
+            for axis in ((axes,) if isinstance(axes, str) else axes or ()):
+                shape[dim] *= mesh.shape[axis]
+        return jax.ShapeDtypeStruct(tuple(shape), x.dtype,
+                                    sharding=NamedSharding(mesh, spec),
+                                    weak_type=getattr(x, "weak_type", False))
+
+    return jax.tree_util.tree_map(
+        lambda spec, sub: jax.tree_util.tree_map(
+            lambda x: leaf(spec, x), sub),
+        in_specs, args, is_leaf=lambda s: isinstance(s, P))
 
 
 def spmd_data_sharding() -> NamedSharding:

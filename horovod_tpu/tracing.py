@@ -31,6 +31,13 @@ scope or a span counter:
 * **The set-up ledger** — one ``jax.monitoring`` listener, registered when
   this module is imported, that adds jax's own trace / lower / backend /
   cache-load seconds to ``jax_compile_seconds_total{phase,fun}``.
+* **The scope table** — for a program built by ``hvd.spmd``, every
+  instruction of its compiled text with the scopes of :data:`NAMES` it
+  lies in, its direction and whether it is a container
+  (:func:`scope_table`): the map from a device event's instruction name to
+  a scope, which a reader of a device trace joins with its events. Made
+  when it is asked for and not before, from what ``hvd.spmd`` noted while
+  the program was traced (:func:`note_lowering`).
 
 It also holds the older correlation layer for eager collectives:
 
@@ -59,10 +66,12 @@ re-mesh) — both count the same submission sequence.
 
 from __future__ import annotations
 
+import re
 import threading
 import time
+import weakref
 from contextlib import contextmanager
-from typing import Any, Dict, NamedTuple, Optional
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 
@@ -73,7 +82,8 @@ __all__ = ["Span", "mint_span", "current_span", "active_span",
            "NAMES", "Name", "span", "scope", "timed", "current_scope",
            "program", "note_program", "sync_pass", "note_bucket",
            "mark_synced", "synced_as", "note_routing", "routing_load",
-           "routing_bias_moved", "note_residual_saved"]
+           "routing_bias_moved", "note_residual_saved",
+           "ScopeRow", "scope_table", "note_lowering"]
 
 _LOCK = threading.Lock()
 _SEQ = 0
@@ -197,7 +207,7 @@ class Name(NamedTuple):
     kind: str       # span | scope | kernel | counter | gauge
     layer: str      # the layer as PERF.md section 3 names it
     covers: str     # what the name stands for, in one line
-    feeds: str      # the per-layer metric that reads it, or "xprof only"
+    feeds: str      # the per-layer metric that reads it, or who else does
 
 
 _TRAINER = "trainer API (spmd, optimizer, collective, fusion, overlap)"
@@ -209,6 +219,11 @@ _EXPERTS = "expert layer (ops/moe)"
 _KERNELS = "kernels (ops/flash_attention)"
 _ENGINE = "engine (serving/engine, scheduler, cache)"
 _COMPILER = "compiler (XLA, Mosaic, persistent cache)"
+# A scope no metric has a file for still has its device time read: every
+# traced run of the benchmark prints it (benchmark/readers/scopes.py).
+_PRINTED = "the scope table's printed rows (a --trace 1 run)"
+# A host span: the benchmark's loader keeps none yet (ROADMAP Design 6 a).
+_XPROF = "xprof only (a host span, which no reader is handed yet)"
 
 #: Every span, scope, kernel name and span counter the package emits. The
 #: engine's phases are listed in the order one ``step_once`` runs them.
@@ -216,10 +231,10 @@ NAMES: Dict[str, Name] = {
     # host spans (jax.profiler.TraceAnnotation "hvd:<name>")
     "collective": Name(
         "span", _TRAINER, "the dispatch of one eager collective; args "
-        "kind= and op_id= (the id the host timeline logs)", "xprof only"),
+        "kind= and op_id= (the id the host timeline logs)", _XPROF),
     "engine.step": Name(
         "span", _ENGINE, "one step_once (an idle pass holds only its "
-        "sweep and admit); args step=", "xprof only"),
+        "sweep and admit); args step=", _XPROF),
     "engine.sweep": Name(
         "span", _ENGINE, "finish and evict terminal lanes (before the "
         "dispatch and again after it, with the gauges)",
@@ -232,7 +247,7 @@ NAMES: Dict[str, Name] = {
         "bookkeeping and their transfer", "engine_host_ms.serve"),
     "engine.dispatch": Name(
         "span", _ENGINE, "the jitted decode or prefill call, blocked "
-        "until the device is done", "xprof only"),
+        "until the device is done", _XPROF),
     "engine.readback": Name(
         "span", _ENGINE, "device to host: the greedy picks, and the "
         "logits when a lane samples", "engine_readback_ms.serve"),
@@ -242,99 +257,137 @@ NAMES: Dict[str, Name] = {
     # scopes under jit (jax.named_scope; operation metadata)
     "hvd/value_and_grad/sync": Name(
         "scope", _TRAINER, "hvd.value_and_grad's gradient sync",
-        "grad_sync_mb.train (as the manifest's scope label)"),
+        "grad_sync_mb.train (as the manifest's scope label); "
+        "grad_sync_local_ms.train (its instructions that are no collective)"),
     "hvd/grad/sync": Name(
         "scope", _TRAINER, "hvd.grad's gradient sync (no overlap taps)",
-        "grad_sync_mb.train (as the manifest's scope label)"),
+        "grad_sync_mb.train (as the manifest's scope label); "
+        "grad_sync_local_ms.train"),
     "hvd/tape/sync": Name(
         "scope", _TRAINER, "DistributedGradientTape.gradient's sync",
-        "grad_sync_mb.train (as the manifest's scope label)"),
+        "grad_sync_mb.train (as the manifest's scope label); "
+        "grad_sync_local_ms.train"),
     "hvd/optimizer/sync": Name(
         "scope", _TRAINER, "DistributedOptimizer.update's gradient sync",
-        "grad_sync_mb.train (as the manifest's scope label)"),
+        "grad_sync_mb.train (as the manifest's scope label); "
+        "grad_sync_local_ms.train"),
     "hvd/optimizer/update": Name(
         "scope", _TRAINER, "the inner optax update of "
-        "DistributedOptimizer", "xprof only"),
+        "DistributedOptimizer (where XLA fuses it into the weight-gradient "
+        "products it reads as those: a fusion's scope is its root's)",
+        _PRINTED),
     "hvd/fusion/pack": Name(
         "scope", _TRAINER, "ravel, slice and concatenate leaves into "
-        "fusion buckets", "xprof only"),
+        "fusion buckets", "grad_sync_local_ms.train"),
     "hvd/fusion/unpack": Name(
         "scope", _TRAINER, "slice the reduced buckets back into leaves",
-        "xprof only"),
+        "grad_sync_local_ms.train"),
     "gpt2/loss_head": Name(
         "scope", _MODELS, "models.gpt2.loss_fn: log-softmax over the "
-        "vocabulary and the gather of the targets", "xprof only"),
+        "vocabulary and the gather of the targets", "loss_head_ms.train"),
+    "gpt2/attn": Name(
+        "scope", _MODELS, "models.gpt2: a block's first half: ln1, the qkv "
+        "projection, the attention call, the output projection and the "
+        "residual add", _PRINTED + "; its kernels: flash_time_share.train"),
+    "gpt2/mlp": Name(
+        "scope", _MODELS, "models.gpt2: a block's second half: ln2, the two "
+        "projections round the gelu (or the expert layer of an MoE "
+        "configuration) and the residual add", _PRINTED),
+    "gpt2/lm_head": Name(
+        "scope", _MODELS, "models.gpt2: the final norm and the tied head's "
+        "product in fp32 (the logits that gpt2/loss_head reads)", _PRINTED),
     "sdar/attn": Name(
         "scope", _SDAR, "models.sdar: the projections, QK-norm, RoPE, the "
         "attention call and the output projection of one layer",
-        "xprof only"),
+        _PRINTED + "; its kernels: bd_flash_time_share.train"),
+    "sdar/block": Name(
+        "scope", _SDAR, "models.sdar: a block's second half round the expert "
+        "layer: the norm before it and the residual add (moe/route and "
+        "moe/experts nest inside)", _PRINTED + ": its own part"),
     "moe/route": Name(
         "scope", _EXPERTS, "ops.moe.routed_share: router logits and their "
         "scores in fp32 (softmax, or sigmoid with a selection bias), top-k, "
         "the sort of the local assignments by expert; flax puts the "
         "model's own module path before it (SDAR/h<i>/moe/moe/route)",
-        "xprof only"),
+        _PRINTED),
     "moe/experts": Name(
-        "scope", _EXPERTS, "ops.moe.routed_share: gather, the grouped "
-        "products over the experts held (ragged-dot custom calls), the "
-        "weighted sum back into positions",
-        "moe_expert_time_share.train (the grouped products, by "
+        "scope", _EXPERTS, "ops.moe.routed_share: all of the share, forward "
+        "and backward: the casts of the weights, the loop over windows with "
+        "its carries, and a window's row gather, grouped products over the "
+        "experts held (ragged-dot custom calls) and weighted sum back into "
+        "positions",
+        "moe_experts_outside_products_ms.train (all but the grouped "
+        "products); moe_expert_time_share.train (the products, by "
         "instruction name)"),
     "sdar/loss_head": Name(
         "scope", _SDAR, "models.sdar.loss_fn: the head over the noisy "
         "half, log-softmax over the vocabulary slice, the masked 1/t "
-        "weighting", "xprof only"),
+        "weighting", _PRINTED),
+    "lfm2/block": Name(
+        "scope", _LFM2, "models.lfm2: all of a block: its own part is the "
+        "two norms and, round the expert layer, the residual add (the "
+        "operator's and the feed-forward's scopes nest inside)",
+        _PRINTED + ": its own part, and all it holds"),
     "lfm2/shortconv": Name(
         "scope", _LFM2, "models.lfm2: one conv layer's operator: the "
         "projection to the gates and the value, the gated short "
         "convolution (ops.short_conv: XLA fusions without a name of their "
-        "own) and the projection back", "xprof only (PERF.md section 5 "
-        "gives its device time by hand, from a kept trace)"),
+        "own) and the projection back", "shortconv_ms.train"),
     "lfm2/attn": Name(
         "scope", _LFM2, "models.lfm2: the projections, QK-norm, RoPE, the "
         "causal attention call and the output projection of an attention "
-        "layer", "xprof only (its kernels: lfm2_flash_time_share.train)"),
+        "layer", _PRINTED + "; its kernels: lfm2_flash_time_share.train"),
     "lfm2/dense_mlp": Name(
         "scope", _LFM2, "models.lfm2: the dense SwiGLU of a leading block",
-        "xprof only"),
+        _PRINTED),
     "lfm2/loss_head": Name(
         "scope", _LFM2, "models.lfm2.loss_fn: the tied head over the "
         "vocabulary slice, log-softmax, the gather of the next tokens",
-        "xprof only"),
+        _PRINTED),
+    "glm4/block": Name(
+        "scope", _GLM4, "models.glm4_moe_lite: all of a block: its own part "
+        "is the two norms and the residual adds (latent attention's, the "
+        "feed-forward's and the expert layer's scopes nest inside)",
+        _PRINTED + ": its own part, and all it holds"),
     "glm4/mla_down": Name(
         "scope", _GLM4, "models.glm4_moe_lite: latent attention's two "
         "down-projections (to the query bottleneck, and to the key/value "
         "latent with the shared RoPE key) and the norms inside them",
-        "xprof only (PERF.md section 5 gives its device time by hand)"),
+        _PRINTED),
     "glm4/mla_up": Name(
         "scope", _GLM4, "models.glm4_moe_lite: the up-projections to every "
         "head's query, key and value, RoPE, the broadcast of the one rotated "
         "key to all heads and the concatenations",
-        "xprof only (what it writes: mla_kv_expanded_mb.train)"),
+        "mla_expand_ms.train (what it writes: mla_kv_expanded_mb.train)"),
     "glm4/attn": Name(
         "scope", _GLM4, "models.glm4_moe_lite: the causal attention call at "
         "head 256 and the output projection",
-        "xprof only (its kernels: mla_flash_time_share.train)"),
+        _PRINTED + "; its kernels: mla_flash_time_share.train"),
     "glm4/dense_mlp": Name(
         "scope", _GLM4, "models.glm4_moe_lite: the dense SwiGLU of a leading "
-        "block", "xprof only"),
+        "block", _PRINTED),
     "glm4/shared_expert": Name(
         "scope", _GLM4, "models.glm4_moe_lite: the shared expert of a routed "
         "block (ops.moe.SharedExpert), beside moe/route and moe/experts",
-        "xprof only (PERF.md section 5 gives its device time by hand)"),
+        _PRINTED),
     "glm4/mtp": Name(
         "scope", _GLM4, "models.glm4_moe_lite: everything of the "
         "multi-token-prediction module: its two norms, eh_proj, its block "
         "(whose own scopes nest inside this one) and its last norm",
-        "xprof only (PERF.md section 5 gives its device time by hand)"),
+        _PRINTED + ": its own part, and all it holds"),
     "glm4/loss_head": Name(
         "scope", _GLM4, "models.glm4_moe_lite.loss_terms: both passes of "
         "the untied head over the vocabulary slice, log-sum-exp minus the "
-        "target's logit", "xprof only"),
+        "target's logit", "loss_head_ms.train_glm4"),
     "flash_attention": Name(
         "scope", _KERNELS, "round each flash kernel call, so that jax's "
         "jvp()/transpose() wrap this name and not the kernel's",
-        "xprof only"),
+        _PRINTED + ": the three kernels together"),
+    "flash/layout": Name(
+        "scope", _KERNELS, "ops.flash_attention: the copies from [B,T,H,D] "
+        "into the kernels' [B*H,T,D] (q, k, v) and back (the output), and "
+        "their transposes in the backward: the price of that interface",
+        "flash_layout_ms.train"),
     # kernel names (pallas_call(name=...): the custom call's instruction)
     "flash_fwd": Name(
         "kernel", _KERNELS, "flash attention forward (run again in the "
@@ -346,6 +399,14 @@ NAMES: Dict[str, Name] = {
     "flash_dkv": Name(
         "kernel", _KERNELS, "flash attention backward, dK and dV",
         "flash_dkv_ms.train"),
+    # XLA's own name, of no call in this package: jax.lax.ragged_dot
+    "ragged-dot": Name(
+        "kernel", _EXPERTS, "the grouped products of ops.moe over the experts "
+        "held (XLA's kernel for jax.lax.ragged_dot: custom calls "
+        "%ragged-dot-none.N, which carry no op_name: the scope table gives "
+        "them this row's layer)", "moe_expert_time_share.train (by "
+        "instruction name); left out of "
+        "moe_experts_outside_products_ms.train"),
     # counters and gauges of metrics.registry that this module writes
     "serve_step_phase_seconds_total": Name(
         "counter", _ENGINE, "host seconds per engine phase; labels "
@@ -359,14 +420,14 @@ NAMES: Dict[str, Name] = {
         "grad_sync_mb.train"),
     "grad_sync_buckets": Name(
         "gauge", _TRAINER, "sync manifest: fusion buckets reduced a step; "
-        "labels program, scope", "xprof only"),
+        "labels program, scope", "registry only"),
     "grad_sync_passes": Name(
         "gauge", _TRAINER, "sync manifest: calls of allreduce_gradients "
-        "that reached the wire; labels program, scope", "xprof only"),
+        "that reached the wire; labels program, scope", "registry only"),
     "grad_sync_skipped": Name(
         "gauge", _TRAINER, "sync manifest: calls of allreduce_gradients "
         "that found every leaf averaged by an earlier pass of the trace "
-        "and lowered nothing; labels program, scope", "xprof only"),
+        "and lowered nothing; labels program, scope", "registry only"),
     "moe_rows_bound": Name(
         "gauge", _EXPERTS, "routing manifest: the fallback's shape, the "
         "worst case that the loop over windows of moe_rows_tight rows may "
@@ -530,7 +591,8 @@ def program(name: str):
     as gauges ``{program}``, and so is what :func:`note_residual_saved`
     counted (every program says it, 0 included). The leaves marked by
     :func:`mark_synced` are kept until the trace ends and no longer, so
-    that no tracer outlives its trace."""
+    that no tracer outlives its trace. A trace that :func:`scope_table`
+    itself causes publishes nothing."""
     prev = (getattr(_TLS, "manifest", None), getattr(_TLS, "synced", None),
             getattr(_TLS, "routing", None), getattr(_TLS, "saved", None))
     manifest: Dict[str, list] = {}
@@ -542,6 +604,8 @@ def program(name: str):
         yield
     finally:
         _TLS.manifest, _TLS.synced, _TLS.routing, _TLS.saved = prev
+        if getattr(_TLS, "asking", False):
+            return      # scope_table's own lowering: the gauges stand
         for key, v in routing.items():
             _metrics.gauge(key, program=name).set(v)
         _metrics.gauge("flash_residuals_saved", program=name).set(saved[0])
@@ -703,6 +767,221 @@ def note_residual_saved() -> None:
 
 
 # ---------------------------------------------------------------------------
+# the scope table
+# ---------------------------------------------------------------------------
+
+class ScopeRow(NamedTuple):
+    """One instruction of a compiled program, as :func:`scope_table` reads
+    it."""
+    scopes: Tuple[str, ...]     # NAMES rows of kind scope, outermost first
+    layer: Optional[str]        # the innermost one's layer; else the
+    #                             kernel row's; None: no name of ours
+    direction: str              # fwd | remat | bwd
+    container: bool             # its device event covers its body's events
+    kernel: Optional[str]       # the NAMES kernel row its name holds
+    op_name: str                # the metadata read, "" where it has none
+
+
+#: Opcodes whose device event spans the events of the computations they
+#: call: summing them beside their bodies would count the bodies twice.
+CONTAINERS = ("while", "conditional", "call")
+
+_LOWERINGS: Dict[str, tuple] = {}   # program -> (weak jit, its arguments)
+_TABLES: Dict[str, tuple] = {}      # program -> (the lowering read, table)
+
+
+def note_lowering(name: str, jitted: "weakref.ref", args: Any) -> None:
+    """What lowers the program ``name`` again: a weak reference to the
+    jitted function (the table never keeps a program alive) and its
+    arguments as ``jax.ShapeDtypeStruct``s with global shapes and
+    shardings. Called by ``hvd.spmd`` while jit traces the function, never
+    per step; the last trace stands, as with the manifests."""
+    if getattr(_TLS, "asking", False):
+        _TLS.retraced = True    # scope_table's lowering missed jit's cache
+        return
+    with _LOCK:
+        _LOWERINGS[name] = (jitted, args)
+
+
+def scope_table(program: str) -> Optional[Dict[str, ScopeRow]]:
+    """``{instruction name: ScopeRow}`` over the compiled text of the
+    ``hvd.spmd`` program ``program`` as it was last traced, or None (no
+    such program, never traced, static arguments, or the function is gone).
+    The instruction names are those a device trace's events carry
+    (``%fusion.349 = ...`` is ``fusion.349``), so a reader sums device time
+    by scope by looking each event up here. Rows are the instructions of
+    the entry computation and of every computation a ``while``,
+    ``conditional`` or ``call`` runs, each once; what a fusion holds inside
+    is not listed, as it never runs as an event of its own.
+
+    The rules, which the compiled text fixes and nothing here chooses:
+
+    * ``scopes`` are the :data:`NAMES` rows of kind ``scope`` found as whole
+      path segments of the instruction's ``op_name``, outermost first
+      (``glm4/mtp`` round ``glm4/attn`` round ``flash_attention`` keeps all
+      three; the module path flax puts before a scope is not a scope).
+    * **A fusion's scope is its root's**: XLA gives a fusion instruction the
+      metadata of its root, so a layer's elementwise tail fused into the
+      next layer's product counts for the product's scope.
+    * **A kernel is never unscoped**: an instruction whose name holds a
+      :data:`NAMES` row of kind ``kernel`` (``flash_fwd.7``,
+      ``ragged-dot-none.12``, whose custom calls carry no metadata) has
+      that row as ``kernel`` and, where it lies in no scope, its layer.
+    * ``direction``: ``remat`` where the ``op_name`` holds
+      ``rematted_computation`` (the forward that ``jax.checkpoint`` runs
+      again inside the backward; it lies inside ``transpose(`` too), else
+      ``bwd`` where it holds ``transpose(``, else ``fwd`` (the optimizer
+      update and the gradient sync read ``fwd``: they are not
+      differentiated). A ``custom_vjp``'s backward that runs its forward
+      again by itself (the expert layer's) is ``bwd``.
+    * ``container``: a ``while``, ``conditional`` or ``call``, whose device
+      event covers its body's; the body's instructions are rows of their
+      own.
+
+    What asking costs: nothing until it is asked, nothing per step. The
+    first call lowers the program again from the noted shapes and compiles
+    it. In a process that has run the program both are answered by jax's
+    own in-memory caches (the function's Python does not run, nothing is
+    compiled: a few seconds for the text of a large step), and the table is
+    of the very executable the process runs, so every event's name is in
+    it. That executable's metadata are its writer's: where the persistent
+    cache answered the set-up's compile with an entry another checkout
+    wrote (jax keys an entry without its metadata), the scopes are that
+    checkout's. A process that never compiled the program compiles it here,
+    with the metadata in the persistent cache's key, so that no other
+    checkout's entry answers. A step called with some placed arrays and
+    some not is traced again, in silence. The result is kept until the
+    program is traced anew; the set-up ledger books none of this and no
+    manifest gauge moves."""
+    with _LOCK:
+        noted = _LOWERINGS.get(program)
+        kept = _TABLES.get(program)
+    if noted is None:
+        return None
+    if kept is not None and kept[0] is noted:
+        return kept[1]
+    jitted = noted[0]()
+    if jitted is None:
+        return None
+    _TLS.asking = True
+    try:
+        # Committed arrays give jit their shardings, and so do these
+        # shapes; arrays that were never placed give it none. Whichever
+        # jit's trace cache knows is the program that ran.
+        for args in (noted[1], jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                               weak_type=x.weak_type),
+                noted[1])):
+            _TLS.retraced = False
+            lowered = jitted.lower(*args)
+            if not _TLS.retraced:
+                break
+        # Where the process has compiled this program, jax's in-memory
+        # cache answers with that executable. Where it has not, the
+        # persistent cache would: it keys a program without its metadata,
+        # so an entry another checkout wrote (the parent commit's, whose
+        # program differs in scopes alone) would answer with that
+        # checkout's op_names. For this one compile the metadata is part
+        # of the key.
+        flag = "jax_compilation_cache_include_metadata_in_key"
+        keyed = getattr(jax.config, flag)
+        jax.config.update(flag, True)
+        try:
+            text = lowered.compile().as_text()
+        finally:
+            jax.config.update(flag, keyed)
+    finally:
+        _TLS.asking = False
+    table = _read_scopes(text)
+    with _LOCK:
+        _TABLES[program] = (noted, table)
+    return table
+
+
+_COMPUTATION = re.compile(r"^(ENTRY )?%?([\w.\-]+) \(.*\{$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = (.*)$")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLED = re.compile(r"(?:body|condition|to_apply|calls|true_computation|"
+                     r"false_computation)=%?([\w.\-]+)")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+
+
+def _opcode(rest: str) -> str:
+    """The opcode of an instruction's text after `` = ``: past its shape (a
+    tuple's may hold spaces and comments), up to the operands."""
+    if rest.startswith("("):
+        depth = 0
+        for i, ch in enumerate(rest):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                rest = rest[i + 1:]
+                break
+    else:
+        rest = rest.partition(" ")[2]
+    return rest.lstrip().partition("(")[0].strip()
+
+
+def _read_scopes(text: str) -> Dict[str, ScopeRow]:
+    """:func:`scope_table`'s pass over a compiled program's text."""
+    scope_names = sorted((n for n, row in NAMES.items()
+                          if row.kind == "scope"), key=len, reverse=True)
+    kernels = [n for n, row in NAMES.items() if row.kind == "kernel"]
+    segment = re.compile(r"(?<![\w.\-])(?:" + "|".join(
+        re.escape(n) for n in scope_names) + r")(?![\w.\-])")
+    read: Dict[str, tuple] = {}     # op_name -> (scopes, direction)
+
+    def of(op_name):
+        hit = read.get(op_name)
+        if hit is None:
+            scopes = tuple(dict.fromkeys(segment.findall(op_name)))
+            direction = ("remat" if "rematted_computation" in op_name
+                         else "bwd" if "transpose(" in op_name else "fwd")
+            hit = read[op_name] = (scopes, direction)
+        return hit
+
+    # every computation's instruction lines; only those that run as events
+    # (the entry's, and on from there what a container calls) are read
+    computations: Dict[str, list] = {}
+    entry = current = None
+    for line in text.splitlines():
+        if current is None:
+            head = _COMPUTATION.match(line)
+            if head:
+                current = computations[head.group(2)] = []
+                if head.group(1):
+                    entry = head.group(2)
+        elif line.startswith("}"):
+            current = None
+        else:
+            m = _INSTRUCTION.match(line)
+            if m is not None:
+                current.append(m.groups())
+
+    table: Dict[str, ScopeRow] = {}
+    seen, todo = set(), [entry]
+    while todo:
+        comp = todo.pop()
+        if comp in seen or comp not in computations:
+            continue
+        seen.add(comp)
+        for name, rest in computations[comp]:
+            container = _opcode(rest) in CONTAINERS
+            if container:
+                todo += _CALLED.findall(rest)
+                for group in _BRANCHES.findall(rest):
+                    todo += [c.strip().lstrip("%") for c in group.split(",")]
+            op_name = _OP_NAME.search(rest)
+            op_name = op_name.group(1) if op_name else ""
+            scopes, direction = of(op_name)
+            kernel = next((k for k in kernels if k in name), None)
+            layer = (NAMES[scopes[-1]].layer if scopes
+                     else NAMES[kernel].layer if kernel else None)
+            table[name] = ScopeRow(scopes, layer, direction, container,
+                                   kernel, op_name)
+    return table
+
+
+# ---------------------------------------------------------------------------
 # the set-up ledger
 # ---------------------------------------------------------------------------
 
@@ -726,10 +1005,12 @@ def _on_jax_duration(event: str, secs: float, **kw) -> None:
     and ``other`` for the rest, so the series stay bounded. jax reports a
     cache load without a name, inside the backend event that follows it
     on the same thread: the load is booked under that event's function
-    and taken out of its ``backend`` seconds, so the phases add up."""
+    and taken out of its ``backend`` seconds, so the phases add up. What
+    :func:`scope_table` lowers and loads to read a program's text is not
+    set-up and is not booked."""
     phase_name = _JAX_PHASE.get(event)
-    if phase_name is None:
-        return
+    if phase_name is None or getattr(_TLS, "asking", False):
+        return          # not a phase, or scope_table's own lowering
     if phase_name == "cache_load":
         _TLS.cache_load = getattr(_TLS, "cache_load", 0.0) + secs
         return

@@ -235,12 +235,22 @@ def test_causal_flash_compiles_for_v5e_at_the_hybrid_cells_shape(v5e, mosaic):
                           .as_text())
 
 
-def test_hybrid_step_compiles_for_v5e_at_published_widths(
-        v5e, mosaic, restore_world):
-    """The third family's step at its published widths and the cell's
-    8,192-token rows, one row and one layer of each kind (conv + dense,
-    attention + routed, conv + routed): the causal flash kernels, XLA's
-    grouped kernel for the experts held, the six scopes."""
+@pytest.fixture(scope="module")
+def hybrid_hlo(v5e):
+    """The compiled text of the third family's step at its published widths
+    and the cell's 8,192-token rows, one row and one layer of each kind
+    (conv + dense, attention + routed, conv + routed)."""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(
+            importlib.import_module("horovod_tpu.ops.flash_attention"),
+            "_use_interpret", lambda: False)
+        try:
+            return _hybrid_hlo(v5e)
+        finally:
+            hvd.init()      # back onto the session's 8 CPU devices
+
+
+def _hybrid_hlo(v5e):
     import optax
     from horovod_tpu.models import lfm2
     hvd.init(devices=v5e[:1])
@@ -267,14 +277,59 @@ def test_hybrid_step_compiles_for_v5e_at_published_widths(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
     tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32,
                                   sharding=hvd.spmd_data_sharding())
-    hlo = step.lower(_shapes(params, replicated),
-                     _shapes(jax.eval_shape(opt.init, params), replicated),
-                     tokens).compile().as_text()
+    return step.lower(_shapes(params, replicated),
+                      _shapes(jax.eval_shape(opt.init, params), replicated),
+                      tokens).compile().as_text()
+
+
+def test_hybrid_step_compiles_for_v5e_at_published_widths(hybrid_hlo):
+    """The causal flash kernels, XLA's grouped kernel for the experts held,
+    the six scopes."""
+    hlo = hybrid_hlo
     _assert_kernels_named(hlo)
     assert re.search(r"%ragged-dot[^\n]* = [^\n]*custom-call\(", hlo)
     for scope in ("lfm2/shortconv", "lfm2/attn", "lfm2/dense_mlp",
                   "moe/route", "moe/experts", "lfm2/loss_head"):
         assert scope in hlo, scope
+
+
+def test_scope_table_of_the_chips_hybrid_step(hybrid_hlo):
+    """``tracing.scope_table``'s pass over what the chip's compiler wrote:
+    no kernel is unscoped (the flash kernels lie in their wrapper's scope,
+    XLA's grouped kernel carries no metadata and has the expert layer
+    through its ``ragged-dot`` row); the loops over windows are containers
+    whose bodies' instructions, the grouped kernel among them, are rows of
+    their own; the copies between [B,T,H,D] and the kernels' layout are
+    rows of ``flash/layout``, in the forward and in its re-run (at one row a
+    step the backward's are bitcasts); and every instruction the entry
+    computation runs is in the table."""
+    from horovod_tpu import tracing
+    table = tracing._read_scopes(hybrid_hlo)
+    kernels = {n: r for n, r in table.items() if r.kernel}
+    assert {r.kernel for r in kernels.values()} == {
+        "flash_fwd", "flash_dq", "flash_dkv", "ragged-dot"}
+    for name, row in kernels.items():
+        assert row.layer is not None and not row.container, name
+        if row.kernel == "ragged-dot":
+            assert row.layer == tracing.NAMES["moe/experts"].layer
+        else:
+            assert row.scopes[-2:] == ("lfm2/attn", "flash_attention"), name
+    loops = {n: r for n, r in table.items() if r.container}
+    assert any(n.startswith("while") and "moe/experts" in r.scopes
+               for n, r in loops.items())
+    assert any("/while/body/" in r.op_name and "moe/experts" in r.scopes
+               and not r.container for r in table.values())
+    layout = [r for r in table.values() if "flash/layout" in r.scopes]
+    assert {"fwd", "remat"} <= {r.direction for r in layout}
+    assert all(r.scopes[-2:] == ("lfm2/attn", "flash/layout") for r in layout)
+    for scope in ("lfm2/shortconv", "lfm2/dense_mlp", "moe/route",
+                  "lfm2/loss_head", "hvd/optimizer/update"):
+        assert any(scope in r.scopes for r in table.values()), scope
+    assert {"fwd", "remat", "bwd"} == {r.direction for r in table.values()}
+    entry = hybrid_hlo[hybrid_hlo.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    assert set(re.findall(r"^\s+(?:ROOT )?%?([\w.\-]+) = ", entry, re.M)) \
+        <= set(table)
 
 
 def test_latent_attention_kernels_compile_for_v5e_at_the_table_entry(

@@ -12,6 +12,7 @@ import glob
 import importlib
 import json
 import os
+import re
 import sys
 
 import jax
@@ -28,14 +29,17 @@ from horovod_tpu.models.gpt2 import GPT2, GPT2Config, loss_fn
 PKG = os.path.dirname(os.path.abspath(hvd.__file__))
 TRAINER_SCOPES = ["hvd/value_and_grad/sync", "hvd/optimizer/sync",
                   "hvd/optimizer/update", "hvd/fusion/pack",
-                  "hvd/fusion/unpack", "gpt2/loss_head"]
+                  "hvd/fusion/unpack", "gpt2/loss_head", "gpt2/attn",
+                  "gpt2/mlp", "gpt2/lm_head", "flash/layout"]
 KERNELS = ["flash_fwd", "flash_dq", "flash_dkv"]
-SDAR_SCOPES = ["sdar/attn", "moe/route", "moe/experts", "sdar/loss_head"]
-LFM2_SCOPES = ["lfm2/shortconv", "lfm2/attn", "lfm2/dense_mlp", "moe/route",
-               "moe/experts", "lfm2/loss_head"]
-GLM4_SCOPES = ["glm4/mla_down", "glm4/mla_up", "glm4/attn", "glm4/dense_mlp",
-               "glm4/shared_expert", "glm4/mtp", "glm4/loss_head",
-               "moe/route", "moe/experts"]
+XLAS_OWN = {"ragged-dot"}   # a kernel XLA names: no call here emits it
+SDAR_SCOPES = ["sdar/attn", "sdar/block", "moe/route", "moe/experts",
+               "sdar/loss_head"]
+LFM2_SCOPES = ["lfm2/block", "lfm2/shortconv", "lfm2/attn", "lfm2/dense_mlp",
+               "moe/route", "moe/experts", "lfm2/loss_head"]
+GLM4_SCOPES = ["glm4/block", "glm4/mla_down", "glm4/mla_up", "glm4/attn",
+               "glm4/dense_mlp", "glm4/shared_expert", "glm4/mtp",
+               "glm4/loss_head", "moe/route", "moe/experts"]
 ROUTING = ["moe_rows_bound", "moe_rows_tight", "moe_rows_overflow_layers",
            "bd_tiles_visited", "bd_tiles_total",
            "causal_tiles_visited", "causal_tiles_total",
@@ -135,7 +139,7 @@ def test_every_name_emitted_is_in_the_table():
     assert set(ROUTING) <= names
     assert {"engine." + p for p in ENGINE_PHASES} <= names
     # and the table lists nothing that is not emitted
-    assert set(tracing.NAMES) - names == set(), set(tracing.NAMES) - names
+    assert set(tracing.NAMES) - names == XLAS_OWN, set(tracing.NAMES) - names
 
 
 def test_the_table_names_a_layer_and_a_reader_for_every_row():
@@ -145,6 +149,12 @@ def test_the_table_names_a_layer_and_a_reader_for_every_row():
         assert "\n" not in row.covers
     phases = [n for n in tracing.NAMES if n.startswith("engine.")]
     assert phases == ["engine.step"] + ["engine." + p for p in ENGINE_PHASES]
+    # a scope's device time is read by a metric or printed by every traced
+    # run; nothing is summed by hand any more
+    for name, row in tracing.NAMES.items():
+        assert "by hand" not in row.feeds, name
+        if row.kind == "scope":
+            assert "xprof only" not in row.feeds, name
 
 
 def test_the_profiler_knob_is_gone():
@@ -157,13 +167,25 @@ def test_the_profiler_knob_is_gone():
 # the README step, lowered
 # ---------------------------------------------------------------------------
 
-def _step(devices, readme=True, touch=False, **changes):
+def _asked(program):
+    """``scope_table(program)`` and the compiled text it read."""
+    read, texts = tracing._read_scopes, []
+    tracing._read_scopes = lambda text: texts.append(text) or read(text)
+    try:
+        table = tracing.scope_table(program)
+    finally:
+        tracing._read_scopes = read
+    return table, texts[0]
+
+
+def _step(devices, readme=True, touch=False, table=False, **changes):
     """The README train step (or the same with plain jax.value_and_grad)
     lowered on ``devices``: its text, its program name, the manifest it
     left, and the bytes of its parameter tree. ``touch`` multiplies the
     gradients by one between the two syncs: new objects, so that both
-    passes lower, as they did before a pass could be skipped. ``changes``
-    are to the model's configuration (flash attention under
+    passes lower, as they did before a pass could be skipped. ``table``:
+    its scope table too, and the compiled text that was read from.
+    ``changes`` are to the model's configuration (flash attention under
     ``remat=dots``, two layers)."""
     hvd.init(devices=devices)
     try:
@@ -188,7 +210,9 @@ def _step(devices, readme=True, touch=False, **changes):
                         out_specs=(P(), P(), P()))
         assert type(step) is type(jax.jit(lambda: 0))   # the bare jit
         lowered = step.lower(params, opt.init(params), tokens)
+        asked = _asked("train_step") if table else (None, None)
         return {
+            "table": asked[0], "compiled": asked[1],
             "text": lowered.as_text(debug_info=True),
             "module": lowered.as_text().split("{", 1)[0],
             "tree_bytes": sum(x.size * x.dtype.itemsize for x in
@@ -208,7 +232,7 @@ def _step(devices, readme=True, touch=False, **changes):
 
 @pytest.fixture(scope="module")
 def readme_step():
-    return _step(jax.devices()[:2])
+    return _step(jax.devices()[:2], table=True)
 
 
 @pytest.fixture(scope="module")
@@ -461,10 +485,16 @@ def test_each_engine_phase_has_its_counter_pair(traced_step, phase):
 # the block-diffusion decoder's names
 # ---------------------------------------------------------------------------
 
-def test_lowered_block_diffusion_step_carries_its_scopes_and_manifest():
-    """The step of the second model family, lowered: its four scopes and
-    the three kernel names in the text, and the routing manifest published
-    under the program's name when the trace ends."""
+def _lowered(step, program, *args):
+    """A family's step lowered: its text, and its scope table with the
+    compiled text that was read from."""
+    text = step.lower(*args).as_text(debug_info=True)
+    table, compiled = _asked(program)
+    return {"text": text, "table": table, "compiled": compiled}
+
+
+@pytest.fixture(scope="module")
+def bd_step():
     from horovod_tpu.models import sdar
     hvd.init(devices=jax.devices()[:1])
     try:
@@ -487,13 +517,19 @@ def test_lowered_block_diffusion_step_carries_its_scopes_and_manifest():
 
         step = hvd.spmd(bd_step, in_specs=(P(), P(), P("hvd")),
                         out_specs=(P(), P(), P()))
-        text = step.lower(params, opt.init(params), tokens).as_text(
-            debug_info=True)
+        return _lowered(step, "bd_step", params, opt.init(params), tokens)
     finally:
         hvd.shutdown()
         hvd.init()          # back onto the session's 8 CPU devices
+
+
+def test_lowered_block_diffusion_step_carries_its_scopes_and_manifest(
+        bd_step):
+    """The step of the second model family, lowered: its four scopes and
+    the three kernel names in the text, and the routing manifest published
+    under the program's name when the trace ends."""
     for name in SDAR_SCOPES + KERNELS:
-        assert name in text, name
+        assert name in bd_step["text"], name
     read = {name: _program_gauge(name, "bd_step")
             for name in tracing._ROUTING}
     assert read["moe_rows_bound"] == [2 * 64 * 2]
@@ -504,12 +540,8 @@ def test_lowered_block_diffusion_step_carries_its_scopes_and_manifest():
 # the hybrid conv/attention decoder's names
 # ---------------------------------------------------------------------------
 
-def test_lowered_hybrid_step_carries_its_scopes_and_manifest():
-    """The step of the third model family, lowered: its six scopes (two
-    of them the expert layer's own) and the three kernel names in the text,
-    and the routing manifest published under the program's name: the rows
-    the routed layers are shaped for and the causal tiles of its one
-    attention layer."""
+@pytest.fixture(scope="module")
+def hybrid_step():
     from horovod_tpu.models import lfm2
     hvd.init(devices=jax.devices()[:1])
     try:
@@ -530,13 +562,21 @@ def test_lowered_hybrid_step_carries_its_scopes_and_manifest():
 
         step = hvd.spmd(hybrid_step, in_specs=(P(), P(), P("hvd")),
                         out_specs=(P(), P(), P()))
-        text = step.lower(params, opt.init(params), tokens).as_text(
-            debug_info=True)
+        return _lowered(step, "hybrid_step", params, opt.init(params),
+                        tokens)
     finally:
         hvd.shutdown()
         hvd.init()          # back onto the session's 8 CPU devices
+
+
+def test_lowered_hybrid_step_carries_its_scopes_and_manifest(hybrid_step):
+    """The step of the third model family, lowered: its six scopes (two
+    of them the expert layer's own) and the three kernel names in the text,
+    and the routing manifest published under the program's name: the rows
+    the routed layers are shaped for and the causal tiles of its one
+    attention layer."""
     for name in LFM2_SCOPES + KERNELS:
-        assert name in text, name
+        assert name in hybrid_step["text"], name
     read = {name: _program_gauge(name, "hybrid_step")
             for name in tracing._ROUTING}
     assert read["moe_rows_bound"] == [2 * 32 * 2]
@@ -544,13 +584,8 @@ def test_lowered_hybrid_step_carries_its_scopes_and_manifest():
     assert read["bd_tiles_total"] == []
 
 
-def test_lowered_latent_attention_step_carries_its_scopes_and_manifest():
-    """The step of the fourth model family, lowered: its nine scopes (two
-    of them the expert layer's own) and the three kernel names in the text,
-    and the manifest published under the program's name: the rows the
-    routed layers are shaped for, the causal tiles, what latent attention
-    writes as expanded keys and values and the latent it expands, and that
-    the multi-token-prediction module is in the step."""
+@pytest.fixture(scope="module")
+def latent_step():
     from horovod_tpu.models import glm4_moe_lite as glm
     hvd.init(devices=jax.devices()[:1])
     try:
@@ -572,13 +607,23 @@ def test_lowered_latent_attention_step_carries_its_scopes_and_manifest():
 
         step = hvd.spmd(latent_step, in_specs=(P(), P(), P("hvd")),
                         out_specs=(P(), P(), P()))
-        text = step.lower(params, opt.init(params), tokens).as_text(
-            debug_info=True)
+        return _lowered(step, "latent_step", params, opt.init(params),
+                        tokens)
     finally:
         hvd.shutdown()
         hvd.init()          # back onto the session's 8 CPU devices
+
+
+def test_lowered_latent_attention_step_carries_its_scopes_and_manifest(
+        latent_step):
+    """The step of the fourth model family, lowered: its nine scopes (two
+    of them the expert layer's own) and the three kernel names in the text,
+    and the manifest published under the program's name: the rows the
+    routed layers are shaped for, the causal tiles, what latent attention
+    writes as expanded keys and values and the latent it expands, and that
+    the multi-token-prediction module is in the step."""
     for name in GLM4_SCOPES + KERNELS:
-        assert name in text, name
+        assert name in latent_step["text"], name
     read = {name: _program_gauge(name, "latent_step")
             for name in tracing._ROUTING}
     assert read["moe_rows_bound"] == [2 * 32 * 2]
@@ -736,3 +781,425 @@ def test_causal_tiles_visited_share_reads_the_manifest(monkeypatch, gauges,
                                               "glm47f-train-dp1"]
     for sel in spec["args"]["series"] + spec["args"]["per"]:
         assert tracing.NAMES[sel["name"]].feeds == spec["name"]
+
+
+# ---------------------------------------------------------------------------
+# the scope table
+# ---------------------------------------------------------------------------
+
+def _rows_in(table, scope):
+    return {name: row for name, row in table.items() if scope in row.scopes}
+
+
+@pytest.fixture(scope="module")
+def steps(readme_step, bd_step, hybrid_step, latent_step):
+    # hvd/optimizer/sync lowers nothing on the README path; the slices of
+    # hvd/fusion/unpack and the transposes of flash/layout are fused into
+    # their consumers by the CPU's compiler and read as those (a fusion's
+    # scope is its root's); the chip's compiler keeps them as copies, which
+    # tests/test_aot_tpu_compile.py reads
+    return {"readme": (readme_step, [n for n in TRAINER_SCOPES if n not in (
+        "hvd/optimizer/sync", "hvd/fusion/unpack", "flash/layout")]),
+            "block-diffusion": (bd_step, SDAR_SCOPES),
+            "hybrid": (hybrid_step, LFM2_SCOPES),
+            "latent": (latent_step, GLM4_SCOPES)}
+
+
+@pytest.mark.parametrize("family", ["readme", "block-diffusion", "hybrid",
+                                    "latent"])
+def test_scope_table_holds_every_scope_of_the_step(steps, family):
+    """The table of a family's compiled step holds, in some row, every
+    scope the lowered text carries, the flash kernels' wrapper among them;
+    every row's scopes are rows of ``NAMES`` of kind scope, its layer the
+    innermost one's; and each instruction is there once: the names are
+    those of the instruction lines of the compiled text, none twice."""
+    step, scopes = steps[family]
+    table, compiled = step["table"], step["compiled"]
+    for scope in scopes + ["flash_attention"]:
+        assert _rows_in(table, scope), scope
+    for name, row in table.items():
+        for scope in row.scopes:
+            assert tracing.NAMES[scope].kind == "scope", (name, scope)
+        assert len(set(row.scopes)) == len(row.scopes)
+        if row.scopes:
+            assert row.layer == tracing.NAMES[row.scopes[-1]].layer
+        assert row.direction in ("fwd", "remat", "bwd")
+    defined = re.findall(r"^\s+(?:ROOT )?%?([\w.\-]+) = ", compiled, re.M)
+    assert len(defined) == len(set(defined))
+    assert set(table) <= set(defined)
+    entry = compiled[compiled.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    assert set(re.findall(r"^\s+(?:ROOT )?%?([\w.\-]+) = ", entry, re.M)) \
+        <= set(table)
+
+
+def test_scope_table_keeps_nested_scopes_outermost_first(steps):
+    """The module's block lies in ``glm4/mtp``, its attention in
+    ``glm4/attn`` inside that, the kernel's wrapper inside that; the
+    buckets are packed inside the sync that packs them. The module path
+    flax puts before a scope (``h0/attn``, ``moe/moe/route``) is no scope."""
+    latent = steps["latent"][0]["table"]
+    assert any(row.scopes == ("glm4/mtp", "glm4/block", "glm4/attn",
+                              "flash_attention") for row in latent.values())
+    assert any(row.scopes == ("glm4/block", "moe/route")
+               for row in latent.values())
+    assert any(row.scopes[:1] == ("glm4/mtp",) and "moe/experts" in row.scopes
+               for row in latent.values())
+    readme = steps["readme"][0]["table"]
+    assert any(row.scopes == ("hvd/value_and_grad/sync", "hvd/fusion/pack")
+               for row in readme.values())
+    assert any(row.scopes == ("gpt2/attn", "flash_attention")
+               for row in readme.values())
+
+
+@pytest.mark.parametrize("policy,reruns", [("dots", False), ("full", True)])
+def test_scope_table_tells_backward_from_rerun(readme_step, policy, reruns):
+    """The rule the compiled text supports: ``rematted_computation`` marks
+    the forward that ``jax.checkpoint`` runs again (it lies inside
+    ``transpose(`` too, so that cannot tell them apart), ``transpose(``
+    without it the backward, and the rest is forward: the update and the
+    sync are not differentiated. Under ``full`` the kernel's wrapper is
+    run again; under ``dots`` its output is kept and it is not."""
+    step = readme_step if policy == "dots" else _step(
+        jax.devices()[:2], table=True, remat_policy="full")
+    table = step["table"]
+    for row in table.values():
+        again = "rematted_computation" in row.op_name
+        assert (row.direction == "remat") == again
+        assert (row.direction == "bwd") == (
+            "transpose(" in row.op_name and not again)
+        if again:
+            assert "transpose(" in row.op_name
+    for scope in ("gpt2/loss_head", "gpt2/attn", "gpt2/mlp", "gpt2/lm_head"):
+        assert {"fwd", "bwd"} <= {r.direction for r in
+                                  _rows_in(table, scope).values()}, scope
+    for scope in ("hvd/optimizer/update", "hvd/value_and_grad/sync"):
+        assert {r.direction for r in
+                _rows_in(table, scope).values()} == {"fwd"}, scope
+    rerun = {r.direction for r in _rows_in(table, "flash_attention").values()}
+    assert ("remat" in rerun) == reruns
+    assert "remat" in {r.direction for r in
+                       _rows_in(table, "gpt2/mlp").values()}
+
+
+def test_scope_table_marks_a_loop_and_lists_its_body(readme_step):
+    """On the CPU the interpreted kernel is a ``while``: a container, whose
+    device event would cover its body's; the body's instructions are rows
+    of their own, in the kernel's scope, and are no containers."""
+    table = readme_step["table"]
+    loops = {n: r for n, r in table.items() if r.container}
+    assert loops and all(n.startswith(("while", "conditional", "call"))
+                         for n in loops)
+    assert any("flash_attention" in r.scopes for r in loops.values())
+    body = [r for r in table.values()
+            if "/while/body/" in r.op_name and not r.container]
+    assert body and all("flash_attention" in r.scopes for r in body)
+
+
+# what the chip's compiler writes (lines of an AOT compile for a v5e, cut
+# down): a kernel with its wrapper's metadata, XLA's grouped kernel with
+# none, a fusion in the expert layer's backward, a loop over windows whose
+# body and condition are computations of their own, a fusion's inside,
+# which never runs as an event
+CHIP_TEXT = '''HloModule jit_train_step, is_scheduled=true
+
+%fused_computation.7 (param_0.1: f32[8,4]) -> f32[8,4] {
+  %param_0.1 = f32[8,4]{1,0} parameter(0)
+  ROOT %multiply.3 = f32[8,4]{1,0} multiply(%param_0.1, %param_0.1), metadata={op_name="jit(train_step)/transpose(jvp(LFM2))/h1/moe/moe/experts/mul"}
+}
+
+%wide.region_2.16 (wide.param: (s32[], f32[8,4])) -> (s32[], f32[8,4]) {
+  %wide.param = (s32[]{:T(128)}, f32[8,4]{1,0:T(8,128)}) parameter(0)
+  %ragged-dot-none.3 = f32[8,4]{1,0:T(8,128)} custom-call(%wide.param), custom_call_target="tpu_custom_call", backend_config={"x":{"y":1}}
+  %gather_fusion.1 = f32[8,4]{1,0:T(8,128)} fusion(%ragged-dot-none.3), kind=kLoop, calls=%fused_computation.7, metadata={op_name="jit(train_step)/jvp(LFM2)/h1/moe/moe/experts/while/body/gather" stack_frame_id=3}
+  ROOT %tuple.9 = (s32[]{:T(128)}, f32[8,4]{1,0:T(8,128)}) tuple(%wide.param, %gather_fusion.1)
+}
+
+%wide.region_3.17 (wide.param.1: (s32[], f32[8,4])) -> pred[] {
+  %wide.param.1 = (s32[]{:T(128)}, f32[8,4]{1,0:T(8,128)}) parameter(0)
+  ROOT %lt.5 = pred[]{:T(512)} compare(%wide.param.1, %wide.param.1), direction=LT, metadata={op_name="jit(train_step)/jvp(LFM2)/h1/moe/moe/experts/while/cond/lt"}
+}
+
+ENTRY %main.42 (tokens.1: s32[1,8]) -> (f32[8,4], bf16[8,512,64]) {
+  %tokens.1 = s32[1,8]{1,0:T(1,128)} parameter(0), metadata={op_name="tokens"}
+  %copy-start.2 = (s32[1,8]{1,0:T(1,128)S(1)}, s32[1,8]{1,0:T(1,128)}, u32[]{:S(2)}) copy-start(%tokens.1)
+  %flash_fwd.2 = (bf16[8,512,64]{2,1,0:T(8,128)(2,1)S(1)}, f32[8,512,1]{2,1,0:T(8,128)S(1)}) custom-call(%copy-start.2), custom_call_target="tpu_custom_call", frontend_attributes={kernel_metadata={}}, metadata={op_name="jit(train_step)/jvp(LFM2)/h2/lfm2/attn/attn/flash_attention/flash_fwd/pallas_call" stack_frame_id=1}
+  %flash_dq.7 = bf16[8,512,64]{2,1,0:T(8,128)(2,1)S(1)} custom-call(%flash_fwd.2), custom_call_target="tpu_custom_call"
+  %while.4 = (s32[]{:T(128)}, /*index=1*/f32[8,4]{1,0:T(8,128)}) while(%flash_fwd.2), condition=%wide.region_3.17, body=%wide.region_2.16, metadata={op_name="jit(train_step)/jvp(LFM2)/h1/moe/moe/experts/while" stack_frame_id=3}
+  %fusion.11 = f32[8,4]{1,0:T(8,128)} fusion(%while.4), kind=kLoop, calls=%fused_computation.7, metadata={op_name="jit(train_step)/transpose(jvp(LFM2))/jvp(LFM2)/checkpoint/rematted_computation/h0/lfm2/shortconv/conv/mul" stack_frame_id=9}
+  %all-reduce.1 = f32[8,4]{1,0:T(8,128)} all-reduce(%fusion.11), replica_groups={{0,1,2,3}}, to_apply=%fused_computation.7, metadata={op_name="jit(train_step)/shard_map/hvd/value_and_grad/sync/psum"}
+  ROOT %tuple.1 = (f32[8,4]{1,0:T(8,128)}, bf16[8,512,64]{2,1,0:T(8,128)(2,1)S(1)}) tuple(%all-reduce.1, %flash_fwd.2)
+}
+'''
+
+
+def test_scope_table_of_the_chips_text_leaves_no_kernel_unscoped():
+    """A kernel is never unscoped: the flash kernel lies in its wrapper's
+    scope and XLA's grouped kernel, whose custom calls carry no metadata,
+    has the expert layer through its row ``ragged-dot``; a flash kernel
+    that lost its metadata would still have the kernels' layer. What runs
+    inside a fusion or reduces for a collective is no row; what a loop
+    runs is."""
+    table = tracing._read_scopes(CHIP_TEXT)
+    assert set(table) == {
+        "tokens.1", "copy-start.2", "flash_fwd.2", "flash_dq.7", "while.4",
+        "fusion.11", "all-reduce.1", "tuple.1", "wide.param",
+        "ragged-dot-none.3", "gather_fusion.1", "tuple.9", "wide.param.1",
+        "lt.5"}
+    products = table["ragged-dot-none.3"]
+    assert products.kernel == "ragged-dot" and products.scopes == ()
+    assert products.layer == tracing.NAMES["moe/experts"].layer
+    assert products.op_name == "" and not products.container
+    fwd = table["flash_fwd.2"]
+    assert fwd.kernel == "flash_fwd" and fwd.direction == "fwd"
+    assert fwd.scopes == ("lfm2/attn", "flash_attention")
+    assert fwd.layer == tracing.NAMES["flash_attention"].layer
+    bare = table["flash_dq.7"]
+    assert bare.scopes == () and bare.kernel == "flash_dq"
+    assert bare.layer == tracing.NAMES["flash_dq"].layer
+    assert table["while.4"].container
+    assert table["while.4"].scopes == ("moe/experts",)
+    inside = table["gather_fusion.1"]
+    assert inside.scopes == ("moe/experts",) and not inside.container
+    assert table["fusion.11"].direction == "remat"
+    assert table["fusion.11"].scopes == ("lfm2/shortconv",)
+    # no name of ours: the copy the compiler put in, the parameter
+    for name in ("copy-start.2", "tokens.1", "tuple.1"):
+        assert table[name].layer is None and table[name].kernel is None
+    # the table's own names for kernels are NAMES rows of kind kernel
+    assert {r.kernel for r in table.values()} - {None} <= {
+        n for n, row in tracing.NAMES.items() if row.kind == "kernel"}
+
+
+def _registry_of(program):
+    """Every series of the set-up ledger, and every gauge labelled with
+    ``program``."""
+    snap = hvd.metrics.snapshot()
+    ledger = {name: sorted((sorted(s["labels"].items()), s["value"])
+                           for s in snap["counters"].get(name, ()))
+              for name in ("jax_compile_seconds_total", "jax_compile_total")}
+    gauges = {name: [s["value"] for s in series
+                     if s["labels"].get("program") == program]
+              for name, series in snap["gauges"].items()}
+    return ledger, {k: v for k, v in gauges.items() if v}
+
+
+def test_asking_for_the_table_leaves_the_step_and_the_registry_alone():
+    """Asking lowers and compiles the program once more: the set-up ledger
+    books none of it, no manifest gauge of the program moves, and the step
+    is the bare jit before and after: it donates what it was told to,
+    ``lower`` works, and a second asking costs nothing."""
+    hvd.init(devices=jax.devices()[:2])
+    try:
+        opt = hvd.DistributedOptimizer(optax.sgd(0.1))
+
+        def asked_step(params, opt_state, x):
+            ran.append(1)
+            loss, grads = hvd.value_and_grad(
+                lambda p: jnp.mean((x @ p["w"]) ** 2))(params)
+            updates, opt_state = opt.update(grads, opt_state, params)
+            return optax.apply_updates(params, updates), opt_state, loss
+
+        step = hvd.spmd(asked_step, in_specs=(P(), P(), P("hvd")),
+                        out_specs=(P(), P(), P()), donate_argnums=(0, 1))
+        assert type(step) is type(jax.jit(lambda: 0))
+        assert tracing.scope_table("asked_step") is None    # never traced
+        ran = []
+        place = lambda tree, spec: jax.device_put(
+            tree, jax.sharding.NamedSharding(hvd.mesh(), spec))
+        params = place({"w": jnp.ones((4, 3))}, P())
+        state = place(opt.init(params), P())
+        x = place(jnp.ones((8, 4)), P("hvd"))
+        params1, state1, loss1 = step(params, state, x)
+        assert params["w"].is_deleted()
+        before = _registry_of("asked_step")
+        assert sorted(before[1]["grad_sync_passes"]) == [0, 1]
+        table = tracing.scope_table("asked_step")
+        assert _rows_in(table, "hvd/value_and_grad/sync")
+        assert _registry_of("asked_step") == before
+        assert len(ran) == 1        # jit's trace cache answered
+        assert tracing.scope_table("asked_step") is table
+        params2, state2, loss2 = step(params1, state1, x)
+        assert params1["w"].is_deleted() and not params2["w"].is_deleted()
+        assert float(loss2) < float(loss1)
+        assert _registry_of("asked_step") == before
+        assert "asked_step" in step.lower(params2, state2, x).as_text()
+    finally:
+        hvd.init()          # back onto the session's 8 CPU devices
+
+
+def test_asking_about_a_step_that_ran_on_unplaced_arrays():
+    """Arrays that were never placed give jit no sharding, the noted shapes
+    do: the first lowering misses jit's trace cache (the function's Python
+    runs once more, in silence), the second, without shardings, is the
+    program that ran. The ledger and the gauges stand either way."""
+    ran = []
+
+    def unplaced_step(w, x):
+        ran.append(1)
+        return hvd.allreduce_gradients({"w": w * jnp.mean(x)})["w"]
+
+    step = hvd.spmd(unplaced_step, in_specs=(P(), P("hvd")), out_specs=P())
+    step(np.ones((4, 3), np.float32), np.ones((16, 4), np.float32))
+    before = _registry_of("unplaced_step")
+    table = tracing.scope_table("unplaced_step")
+    assert len(ran) == 2
+    assert any("unplaced_step" in row.op_name for row in table.values())
+    assert _registry_of("unplaced_step") == before
+    assert tracing.scope_table("unplaced_step") is table and len(ran) == 2
+
+
+def test_scope_table_of_an_unknown_or_dead_program_is_none():
+    import gc
+    assert tracing.scope_table("no_such_program") is None
+
+    def short_lived(x):
+        return x * 2.0
+
+    step = hvd.spmd(short_lived)
+    assert tracing.scope_table("short_lived") is None       # never traced
+    step.lower(jnp.ones((8, 4)))
+    del step
+    gc.collect()
+    assert tracing.scope_table("short_lived") is None       # and now gone
+
+    def with_a_static(x, n):
+        return x * n
+
+    hvd.spmd(with_a_static, static_argnums=(1,)).lower(jnp.ones((8, 4)), 2)
+    assert tracing.scope_table("with_a_static") is None
+
+
+def _scope_reader():
+    root = os.path.dirname(PKG)
+    sys.path.insert(0, os.path.join(root, "benchmark"))
+    try:
+        return importlib.import_module("readers.scopes")
+    finally:
+        sys.path.pop(0)
+
+
+def _event(name, start, ns):
+    return (f"%{name} = f32[8,128]{{1,0:T(8,128)}} fusion(f32[8,128] %p.1)",
+            start, ns)
+
+
+@pytest.fixture()
+def joined_window(readme_step, monkeypatch):
+    """Two whole 1,000 ns runs of ``jit_train_step`` whose events are named
+    from the README step's real table: a loop of the kernel's wrapper over
+    two instructions of its body, one instruction of the loss head's
+    backward, one of the update, one in no scope, one name the table does
+    not hold."""
+    table = readme_step["table"]
+    pick = lambda test: next(n for n, r in sorted(table.items()) if test(r))
+    names = {
+        "loop": pick(lambda r: r.container and "flash_attention" in r.scopes),
+        "body": pick(lambda r: not r.container and "/while/body/" in r.op_name
+                     and "flash_attention" in r.scopes),
+        "head": pick(lambda r: r.scopes == ("gpt2/loss_head",)
+                     and r.direction == "bwd" and not r.container),
+        "update": pick(lambda r: r.scopes == ("hvd/optimizer/update",)),
+        "pack": pick(lambda r: "hvd/fusion/pack" in r.scopes),
+        "loose": pick(lambda r: r.layer is None and not r.container),
+    }
+    ops = []
+    for run in (1000, 2000):
+        ops += [_event(names["loop"], run, 400),        # covers its body
+                _event(names["body"], run + 10, 150),
+                _event(names["body"], run + 200, 150),
+                _event(names["head"], run + 400, 100),
+                _event(names["update"], run + 500, 200),
+                _event(names["pack"], run + 700, 50),
+                _event(names["loose"], run + 750, 30),
+                _event("fusion.99999", run + 800, 20)]
+    win = {"modules": [("jit_train_step(1)", 1000, 1000),
+                       ("jit_train_step(1)", 2000, 1000)],
+           "ops": ops, "asyncs": []}
+    monkeypatch.setattr(tracing, "scope_table",
+                        lambda program: table if program == "train_step"
+                        else None)
+    from types import SimpleNamespace
+    return SimpleNamespace(win=win), names
+
+
+@pytest.mark.parametrize("args,want", [
+    (dict(scopes=["flash_attention"]), 300e-6),
+    (dict(scopes=["gpt2/loss_head"]), 100e-6),
+    (dict(scopes=["gpt2/loss_head", "hvd/optimizer/update"]), 300e-6),
+    (dict(scopes=["hvd/fusion/pack", "hvd/value_and_grad/sync"],
+          collectives=False), 50e-6),
+    (dict(unscoped=True), 50e-6),
+    (dict(unscoped=True, share=True), 100 * 50 / 800),
+    (dict(scopes=["moe/experts"]), None),       # not in this program
+], ids=["loop-skipped-body-counted", "one-scope", "any-of-two",
+        "nested-in-the-sync", "unscoped-with-the-unknown", "share-of-busy",
+        "a-scope-the-program-lacks"])
+def test_scope_reader_sums_by_hand(joined_window, capsys, args, want):
+    """``readers/scopes.scope_ms_per_run`` over a synthetic window: the sums
+    by hand in ms a run, the container skipped, the unknown name counted as
+    unscoped and its share printed; busy is 800 ns a run (the loop's 400 and
+    the 400 after it), of which the lines hold 700 (the loop's body is 300)."""
+    r, _ = joined_window
+    got = _scope_reader().scope_ms_per_run(r, "train_step", **args)
+    assert got == (want if want is None else pytest.approx(want))
+    printed = capsys.readouterr().out
+    assert "2 whole runs" in printed
+    assert "did not hold: 2.500 %" in printed       # 20 of 800
+    assert "87.50 % of the busy time" in printed    # 700 of 800
+    # the second reading of a run prints nothing more
+    _scope_reader().scope_ms_per_run(r, "train_step", **args)
+    assert capsys.readouterr().out == ""
+
+
+def test_scope_reader_leaves_out_what_it_is_told_to(joined_window):
+    r, names = joined_window
+    read = _scope_reader().scope_ms_per_run
+    whole = read(r, "train_step", scopes=["flash_attention"])
+    assert read(r, "train_step", scopes=["flash_attention"],
+                exclude_contains=[names["body"]]) == 0.0 != whole
+
+
+@pytest.mark.parametrize("program", ["absent", "older"])
+def test_scope_reader_reads_nothing_without_a_table(joined_window,
+                                                    monkeypatch, program):
+    """A program that was never traced under that name, and a package that
+    has no ``scope_table`` at all (the parent commit): None, no raise."""
+    r, _ = joined_window
+    if program == "older":
+        monkeypatch.delattr(tracing, "scope_table")
+    read = _scope_reader().scope_ms_per_run
+    assert read(r, "eval_step" if program == "absent" else "train_step",
+                unscoped=True, share=True) is None
+    from types import SimpleNamespace
+    assert read(SimpleNamespace(win=None), "train_step", unscoped=True) is None
+
+
+SCOPE_METRICS = ["unscoped_time_share.train", "grad_sync_local_ms.train",
+                 "flash_layout_ms.train",
+                 "moe_experts_outside_products_ms.train",
+                 "loss_head_ms.train", "loss_head_ms.train_glm4",
+                 "mla_expand_ms.train", "shortconv_ms.train"]
+
+
+@pytest.mark.parametrize("name", SCOPE_METRICS)
+def test_scope_metric_file_names_its_entry_and_its_scopes(name):
+    """Each of the eight metrics is data for ``scopes:scope_ms_per_run``:
+    its file and its ``BENCHMARK.json`` entry say the same, the scopes it
+    sums are rows of ``NAMES`` of kind scope, and the row of each scope that
+    has a metric of its own names it."""
+    spec, entry, _ = _benchmark_metric(name)
+    assert spec["reader"] == "scopes:scope_ms_per_run"
+    assert len(entry) == 1
+    for key in ("unit", "layer", "moves", "source", "better", "workloads"):
+        assert spec[key] == entry[0][key], key
+    assert entry[0]["source"] == "device_trace"
+    assert spec["args"]["module"] == "train_step"
+    for scope in spec["args"].get("scopes", ()):
+        assert tracing.NAMES[scope].kind == "scope"
+        assert name in tracing.NAMES[scope].feeds, scope
+    assert bool(spec["args"].get("unscoped")) != bool(
+        spec["args"].get("scopes"))
+    import inspect
+    accepted = inspect.signature(_scope_reader().scope_ms_per_run).parameters
+    assert set(spec["args"]) <= set(accepted)
