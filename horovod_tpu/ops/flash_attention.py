@@ -18,23 +18,35 @@ Design (tpu-first):
   where the grid has K blocks to skip. A shape whose tile-table entry
   carries a compute ``chunk`` (head 64 / T 1024, swept forward and backward
   on the v5e) is tiled at two levels instead: the K tile is the whole key
-  axis, resident for the head, the grid's K axis has one step, and all
-  three kernels loop inside the step over chunks of the keys, as many as
+  axis, resident for the head, the grid's K axis has one step, and the
+  kernels loop inside the step over chunks of the keys, as many as
   the diagonal leaves visible to this Q tile (``_causal_chunks``,
   ``_chunk_loop``). Chunks wholly under the diagonal take no positional
   mask; only those it crosses go through ``_mask_scores``. The forward
   carries its softmax state round the loop as values (a chunk's
   read-modify-write of the 1-D scratch costs more than the chunk's
-  scores), the backward kernels keep their sums in scratch. An entry
-  without a chunk runs the kernels as they always were.
+  scores), the backward keeps its sums in scratch. An entry
+  without a chunk runs the kernels as they always were. A resident K tile
+  takes VMEM (K and V twice, the backward's fp32 dK / dV sums, dK and dV
+  twice on their way out: 43 MB at head 64 and 73 MB at head 256 for 8,192
+  keys): such a call asks the compiler for what ``_vmem_need`` counts
+  where the 16 MiB default does not cover it, and where a core could not
+  give it ``_tiling`` hands back the plain grid.
 - Sequence lengths need not divide the block size: the grid is ``cdiv`` and
   the ragged edge blocks are position-masked (ViT's 197 tokens, odd context
   lengths). Tiling — and the VMEM bound — is preserved.
 - ``key_bias`` adds a per-(batch, key) additive logit bias, the TPU shape of
   the reference's attention masks (BERT key-padding = 0/-inf bias).
-- Backward is the standard flash recomputation split into two kernels —
-  dQ (grid over Q blocks) and dK/dV (grid over K blocks) — wired up with
-  ``jax.custom_vjp``. Residuals are O and the per-row logsumexp only.
+- Backward is the standard flash recomputation, wired up with
+  ``jax.custom_vjp``; residuals are O and the per-row logsumexp only. On a
+  plain grid it is two kernels, dQ (grid over Q blocks) and dK/dV (grid
+  over K blocks), each of which recomputes the scores of every pair it
+  visits. Where the K tile is resident with a loop of chunks it is one:
+  the dK/dV kernel's grid walks the Q tiles, a Q tile meets all of its
+  keys inside one grid step, and its dQ is summed there from the same
+  ``ds`` (``flash_dkv`` alone; five products and one pass of the
+  exponential a pair instead of seven and two). A tracked key-bias
+  gradient, the block-diffusion mask and the ring's calls keep two.
 - Off-TPU (the virtual CPU test mesh) the same kernels run in Pallas
   interpreter mode, so tests exercise the real kernel code path.
 
@@ -66,6 +78,17 @@ from horovod_tpu.ops.attention import block_diffusion_mask
 __all__ = ["flash_attention"]
 
 _NEG_INF = -1e30
+# VMEM, in bytes. What Mosaic gives a kernel that asks for nothing (its
+# default scoped limit) and what a call may ask of a v5e core's 128 MiB (the
+# rest is the compiler's own). The compiled kernels hold about 2.5 fp32
+# temporaries the size of one pair's scores at once (the v5e's compiler,
+# asked at which limit each shape starts to compile: _vmem_need is within
+# a MiB of it at head 256 and above it at head 64), and a call asks for a
+# quarter more than it counts.
+_VMEM_DEFAULT = 16 * 2 ** 20
+_VMEM_CAP = 100 * 2 ** 20
+_VMEM_PAIR_TEMPS = 2.5
+_VMEM_MARGIN = 1.25
 # What the forward rule names of its own outputs (``checkpoint_name``): the
 # attention output and the row log-sum-exp, both O(T) and all the backward
 # kernels need of the forward besides its inputs.
@@ -178,19 +201,117 @@ def _tile_visible(causal: bool, bd, q_blk, kv_idx, block_q: int,
     return _causal_skip(causal, q_blk, kv_idx, block_q, block_k, offset)
 
 
+def _tile_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """Bytes of a ``(rows, cols)`` block as VMEM lays it out: 128 lanes
+    (a head of 64 takes what one of 128 does, a ``(rows, 1)`` column 128
+    times its numbers) by sublanes of 32 bits."""
+    sub = 8 * 4 // itemsize
+    return -(-rows // sub) * sub * -(-cols // 128) * 128 * itemsize
+
+
+def _vmem_need(kernel: str, bq: int, bk: int, chunk, d: int, itemsize: int,
+               per_key: int = 0, per_q: int = 0, extra: str = "") -> int:
+    """Bytes of VMEM a call of ``kernel`` (``fwd``, ``dq``, ``dkv``) is to
+    ask for at these tiles, margin included: every block in and out twice
+    (the pipeline's double buffer: with a resident K tile that is K and V
+    twice, and dK and dV twice on their way out), the fp32 sums in scratch
+    once, the fp32 copies of the operands of one (Q tile, K tile or chunk)
+    pair, and the fp32 temporaries the size of its scores. ``per_key`` /
+    ``per_q`` count the ``(T, 1)`` inputs that follow the K / Q tile (key
+    bias, segment ids); ``extra`` is what the dK/dV kernel yields besides:
+    ``db`` or ``dq``."""
+    ck = chunk or bk
+    q_tile, k_tile = _tile_bytes(bq, d, itemsize), _tile_bytes(bk, d, itemsize)
+    q_col, k_col = _tile_bytes(bq, 1, 4), _tile_bytes(bk, 1, 4)
+    q_sum, k_sum = _tile_bytes(bq, d, 4), _tile_bytes(bk, d, 4)
+    blocks = 2 * k_tile + per_key * k_col + per_q * q_col
+    pair = 2 * _tile_bytes(ck, d, 4)                    # k, v of the pair
+    if kernel == "fwd":
+        blocks += 2 * q_tile + q_col                    # q | o, lse
+        scratch = 0 if chunk else q_sum + 2 * _tile_bytes(1, bq, 4)
+        pair += 2 * q_sum                               # q, the output's sum
+    elif kernel == "dq":
+        blocks += 3 * q_tile + 2 * q_col                # q, do, lse, delta | dq
+        scratch = q_sum
+        pair += 2 * q_sum                               # q, do
+    else:
+        blocks += 2 * q_tile + 2 * q_col + 2 * k_tile   # ... | dk, dv
+        scratch = 2 * k_sum
+        pair += 2 * q_sum
+        if extra == "db":
+            blocks, scratch = blocks + k_col, scratch + _tile_bytes(1, bk, 4)
+        elif extra == "dq":
+            blocks, scratch = blocks + q_tile, scratch + q_sum
+    pair += _VMEM_PAIR_TEMPS * _tile_bytes(bq, ck, 4)
+    return int(_VMEM_MARGIN * (2 * blocks + scratch + pair))
+
+
+def _vmem_shape(d: int, dtype, bias, seg) -> dict:
+    """What :func:`_vmem_need` and :func:`_tiling` weigh a call by besides
+    its tiles: head size, bytes a number, and the ``(T, 1)`` inputs that
+    follow the K tile (key bias, segment ids) and the Q tile (segment
+    ids)."""
+    return dict(d=d, itemsize=jnp.dtype(dtype).itemsize,
+                per_key=(bias is not None) + (seg is not None),
+                per_q=int(seg is not None))
+
+
+def _dkv_yields(chunk, track_db: bool):
+    """``(chunk, extra)`` of the dK/dV kernel: with the bias's gradient
+    (summed along lanes, where Mosaic takes no slice at an offset it learns
+    in a loop) it takes its K tile whole and yields ``db``; without, a loop
+    of chunks also yields ``dq``."""
+    if track_db:
+        return None, "db"
+    return chunk, "dq" if chunk else ""
+
+
+def _vmem_params(chunk, need: int) -> dict:
+    """The ``pallas_call`` arguments of a kernel that needs ``need`` bytes
+    of VMEM. A kernel with a resident K tile and a loop of chunks asks for
+    them where the compiler's default does not cover them; every other call
+    is compiled under the default, as it always was."""
+    if chunk is None or need <= _VMEM_DEFAULT:
+        return {}
+    return dict(compiler_params=pltpu.CompilerParams(vmem_limit_bytes=need))
+
+
 def _tiling(tq: int, tk: int, block_q: int, block_k: int, chunk,
-            causal: bool, bd):
+            causal: bool, bd, d: int = 64, itemsize: int = 2,
+            kernel: str = "fwd", per_key: int = 0, per_q: int = 0,
+            track_db: bool = False):
     """``(block_q, block_k, chunk)`` as the kernels run them. Without a
     compute chunk (``None``) the tiles are the grid's, clamped to the
     lengths. With one (the tile table gave it, the mask is the causal one,
     the table's K tile holds every key, and they make more than one chunk)
     the K tile is the whole key axis, in whole chunks: K and V of a head
     stay resident, the grid's K axis has one step, and a loop inside the
-    step takes its place (:func:`_chunk_loop`)."""
+    step takes its place (:func:`_chunk_loop`). ``kernel`` is who asks
+    (the forward, or ``dkv`` for the backward): where a core cannot give
+    it the VMEM a resident K tile takes (:func:`_vmem_need` over
+    ``_VMEM_CAP``: a tracked bias gradient, ``track_db``, whose kernel
+    takes the K tile whole), or the keys are more than the entry's K tile
+    and that tile as the grid's would not fit the compiler's default (a
+    sequence longer than the entry was measured at), the grid's K tile is
+    the chunk."""
     bq, bk = _block_sizes(tq, tk, block_q, block_k)
-    if causal and bd is None and chunk and block_k >= tk and 0 < chunk < tk:
-        return bq, -(-tk // chunk) * chunk, int(chunk)
-    return bq, bk, None
+    if not (causal and bd is None and chunk and 0 < chunk < bk):
+        return bq, bk, None
+
+    def need(k_tile, chunk):
+        extra = ""
+        if kernel == "dkv":
+            chunk, extra = _dkv_yields(chunk, track_db)
+        return _vmem_need(kernel, bq, k_tile, chunk, d, itemsize, per_key,
+                          per_q, extra)
+
+    if block_k >= tk:
+        whole = -(-tk // chunk) * chunk
+        if need(whole, int(chunk)) <= _VMEM_CAP:
+            return bq, whole, int(chunk)
+    elif need(bk, None) <= _VMEM_DEFAULT:       # a plain grid asks nothing
+        return bq, bk, None
+    return bq, int(chunk), None
 
 
 def _causal_chunks(q_blk, block_q: int, chunk: int, offset: int, tk: int,
@@ -228,13 +349,16 @@ def _chunk_loop(visit, state, q_blk, block_q: int, chunk: int, offset: int,
 
 
 def causal_tiles(t: int, block_q: int, block_k: int, chunk=None,
-                 offset: int = 0):
+                 offset: int = 0, **shape):
     """``(visited, total)`` of one head's causal forward over ``t``
     positions, from shapes alone (the routing manifest's
     ``causal_tiles_visited`` / ``causal_tiles_total``): pairs of (Q tile,
     compute chunk) where the kernels loop over chunks, else the grid's
-    tiles, each counted by the predicate the kernel runs by."""
-    bq, bk, chunk = _tiling(t, t, block_q, block_k, chunk, True, None)
+    tiles, each counted by the predicate the kernel runs by. ``shape`` is
+    what :func:`_tiling` weighs the forward's VMEM by (``d``, ``itemsize``,
+    ``per_key``, ``per_q``)."""
+    bq, bk, chunk = _tiling(t, t, block_q, block_k, chunk, True, None,
+                            **shape)
     q_blk = np.arange(-(-t // bq))
     if chunk is None:
         kv_idx = np.arange(-(-t // bk))
@@ -380,7 +504,9 @@ def _fwd(q, k, v, bias, seg_q, seg_k, h, scale, causal, block_q, block_k,
          offset=0, bd=None, chunk=None):
     bh, tq, d = q.shape
     tk = k.shape[1]
-    bq, bk, chunk = _tiling(tq, tk, block_q, block_k, chunk, causal, bd)
+    shape = _vmem_shape(d, q.dtype, bias, seg_q)
+    bq, bk, chunk = _tiling(tq, tk, block_q, block_k, chunk, causal, bd,
+                            kernel="fwd", **shape)
     grid = (bh, pl.cdiv(tq, bq), pl.cdiv(tk, bk))
 
     kernel = functools.partial(
@@ -425,6 +551,7 @@ def _fwd(q, k, v, bias, seg_q, seg_k, h, scale, causal, block_q, block_k,
         ],
         interpret=_use_interpret(),
         name="flash_fwd",
+        **_vmem_params(chunk, _vmem_need("fwd", bq, bk, chunk, **shape)),
     )
     with _tracing.scope("flash_attention"):
         o, lse = call(*args)
@@ -513,9 +640,13 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
                     do_ref, lse_ref, delta_ref, dk_ref, dv_ref, db_ref,
-                    dk_acc, dv_acc, db_acc, *, scale: float, causal: bool,
-                    offset: int, block_q: int, block_k: int, tq: int,
-                    tk: int, bd=None, chunk=None):
+                    dk_acc, dv_acc, db_acc, dq_ref=None, dq_acc=None, *,
+                    scale: float, causal: bool, offset: int, block_q: int,
+                    block_k: int, tq: int, tk: int, bd=None, chunk=None):
+    """dK and dV of the resident K tile, summed over the grid's Q tiles.
+    With ``dq_ref`` (the one-kernel backward: the K tile holds every key
+    and the loop over its chunks meets all that this Q tile sees inside
+    the grid step) also the Q tile's dQ, from the same ``ds``."""
     q_idx = pl.program_id(2)
     num_q = pl.num_programs(2)
 
@@ -526,10 +657,13 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
         if db_acc is not None:
             db_acc[:] = jnp.zeros_like(db_acc)
 
+    if dq_acc is not None:
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
     k_idx = pl.program_id(1)
 
     def visit(kv_blk, block_k, rows, causal):
-        q, _, s = _scores(q_ref, k_ref, bias_ref, segq_ref, segk_ref, q_idx,
+        q, k, s = _scores(q_ref, k_ref, bias_ref, segq_ref, segk_ref, q_idx,
                           kv_blk, rows, scale=scale, causal=causal,
                           offset=offset, block_q=block_q, block_k=block_k,
                           tq=tq, tk=tk, bd=bd)
@@ -547,9 +681,14 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
         if db_acc is not None:
             # d(s)/d(bias) = 1 on visible entries → dbias_k = sum_q ds.
             db_acc[:] += jnp.sum(ds, axis=0)
+        if dq_acc is not None:
+            dq_acc[:] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
 
     _visit_pairs(visit, causal, bd, q_idx, k_idx, block_q, block_k, chunk,
                  offset, tk)
+
+    if dq_acc is not None:
+        dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
     @pl.when(q_idx == num_q - 1)
     def _():
@@ -565,7 +704,10 @@ def _bwd(h, scale, causal, block_q, block_k, res, do, delta=None,
     q, k, v, bias, seg_q, seg_k, o, lse = res
     bh, tq, d = q.shape
     tk = k.shape[1]
-    bq, bk, chunk = _tiling(tq, tk, block_q, block_k, chunk, causal, bd)
+    shape = _vmem_shape(d, q.dtype, bias, seg_q)
+    track_db = bias is not None and want_db
+    bq, bk, chunk = _tiling(tq, tk, block_q, block_k, chunk, causal, bd,
+                            kernel="dkv", track_db=track_db, **shape)
 
     if delta is None:
         # delta_i = sum_d dO_i . O_i — the softmax-normalisation term of dS.
@@ -576,13 +718,14 @@ def _bwd(h, scale, causal, block_q, block_k, res, do, delta=None,
     common = dict(scale=scale, causal=causal, offset=offset, block_q=bq,
                   block_k=bk, tq=tq, tk=tk, bd=bd, chunk=chunk)
 
-    track_db = bias is not None and want_db
-    dq_kernel = functools.partial(_bwd_dq_kernel, **common)
-    # The bias gradient is accumulated along lanes, where Mosaic takes no
-    # slice at an offset it learns in a loop: with it the dK/dV kernel
-    # keeps the whole K tile as its one compute chunk.
+    dkv_chunk, dkv_extra = _dkv_yields(chunk, track_db)
+    # One kernel: where the dK/dV kernel loops over the chunks of a K tile
+    # that holds every key, a Q tile meets all its keys inside one grid
+    # step, and its dQ is summed there from the ``ds`` the step already
+    # has. Everywhere else dQ has a kernel of its own.
+    fused = dkv_extra == "dq"
     dkv_kernel = functools.partial(
-        _bwd_dkv_kernel, **dict(common, chunk=None if track_db else chunk))
+        _bwd_dkv_kernel, **dict(common, chunk=dkv_chunk))
 
     def specs(order):
         # order: index_map arg order differs between the two kernels
@@ -620,25 +763,27 @@ def _bwd(h, scale, causal, block_q, block_k, res, do, delta=None,
     extra = () if bias is None else (bias,)
     if seg_q is not None:
         extra = extra + (seg_q, seg_k)
-    dq_kernel = _fill_optionals(dq_kernel, bias is not None,
-                                seg_q is not None)
     if not track_db:
         # No db output/scratch: either there is no bias at all, or the
         # caller discards the mask-derived cotangent — keep the bias
-        # INPUT (scores must mask) but skip the db work entirely.
+        # INPUT (scores must mask) but skip the db work entirely. The
+        # one-kernel backward has dQ's output and sum in their place.
         _dkv_canon = dkv_kernel
 
         def dkv_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
-                       do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-                       dk_acc, dv_acc):
+                       do_ref, lse_ref, delta_ref, dk_ref, dv_ref, *rest):
+            dq_ref, dk_acc, dv_acc, dq_acc = (
+                rest if fused else (None, *rest, None))
             return _dkv_canon(q_ref, k_ref, v_ref, bias_ref, segq_ref,
                               segk_ref, do_ref, lse_ref, delta_ref,
-                              dk_ref, dv_ref, None, dk_acc, dv_acc, None)
+                              dk_ref, dv_ref, None, dk_acc, dv_acc, None,
+                              dq_ref, dq_acc)
     dkv_kernel = _fill_optionals(dkv_kernel, bias is not None,
                                  seg_q is not None)
 
-    dq_call = pl.pallas_call(
-        dq_kernel,
+    dq_call = None if fused else pl.pallas_call(
+        _fill_optionals(functools.partial(_bwd_dq_kernel, **common),
+                        bias is not None, seg_q is not None),
         grid=(bh, pl.cdiv(tq, bq), pl.cdiv(tk, bk)),
         in_specs=specs("dq"),
         out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
@@ -646,6 +791,7 @@ def _bwd(h, scale, causal, block_q, block_k, res, do, delta=None,
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=_use_interpret(),
         name="flash_dq",
+        **_vmem_params(chunk, _vmem_need("dq", bq, bk, chunk, **shape)),
     )
 
     out_specs = [
@@ -666,7 +812,13 @@ def _bwd(h, scale, causal, block_q, block_k, res, do, delta=None,
                                       lambda b, j, i: (b, j, 0)))
         out_shape.append(jax.ShapeDtypeStruct((bh, tk, 1), jnp.float32))
         scratch.append(pltpu.VMEM((bk,), jnp.float32))
+    elif fused:
+        out_specs.append(pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0)))
+        out_shape.append(jax.ShapeDtypeStruct(q.shape, q.dtype))
+        scratch.append(pltpu.VMEM((bq, d), jnp.float32))
 
+    dkv_need = _vmem_need("dkv", bq, bk, dkv_chunk, extra=dkv_extra, **shape)
+    dkv_vmem = _vmem_params(dkv_chunk, dkv_need)
     dkv_call = pl.pallas_call(
         dkv_kernel,
         grid=(bh, pl.cdiv(tk, bk), pl.cdiv(tq, bq)),
@@ -676,17 +828,24 @@ def _bwd(h, scale, causal, block_q, block_k, res, do, delta=None,
         scratch_shapes=scratch,
         interpret=_use_interpret(),
         name="flash_dkv",
+        **dkv_vmem,
     )
+    _tracing.note_routing(
+        flash_bwd_kernels=1 if fused else 2,
+        flash_bwd_vmem_bytes=dkv_need if dkv_vmem else 0)
     with _tracing.scope("flash_attention"):
-        dq = dq_call(q, k, v, *extra, do, lse, delta)
+        if not fused:
+            dq = dq_call(q, k, v, *extra, do, lse, delta)
         outs = dkv_call(q, k, v, *extra, do, lse, delta)
 
+    dbias = None
     if track_db:
         dk, dv, db = outs
         dbias = db.reshape(bh // h, h, tk, 1).sum(axis=1)
+    elif fused:
+        dk, dv, dq = outs
     else:
         dk, dv = outs
-        dbias = None
     return dq, dk, dv, dbias
 
 
@@ -784,7 +943,9 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         ``None`` consults the tile table (``tuned-*-fwdbwd`` entries from
         the forward + backward sweep carry measured values, and their
         own ``chunk_bwd``); entries without them fall back to the
-        forward tiles.
+        forward tiles. With a ``chunk_bwd`` the backward is one kernel:
+        the dK/dV kernel's loop over the chunks of the resident K tile
+        also sums dQ (module docstring).
 
     Returns (batch, t_q, heads, head_dim), same dtype as ``q``.
     """
@@ -873,8 +1034,9 @@ def _attend(q, k, v, causal, scale, key_bias, segment_ids, tiles,
         _tracing.note_routing(bd_tiles_visited=visited,
                               bd_tiles_total=total)
     elif causal:
-        visited, total = causal_tiles(tq, int(block_q), int(block_k), chunk,
-                                      int(causal_offset))
+        visited, total = causal_tiles(
+            tq, int(block_q), int(block_k), chunk, int(causal_offset),
+            **_vmem_shape(d, q.dtype, key_bias, seg))
         _tracing.note_routing(causal_tiles_visited=visited,
                               causal_tiles_total=total)
     with _tracing.scope("flash/layout"):

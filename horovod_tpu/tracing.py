@@ -457,6 +457,18 @@ NAMES: Dict[str, Name] = {
         "gauge", _KERNELS, "routing manifest: (Q tile, compute chunk) "
         "pairs of one head's causal forward; label program",
         "causal_tiles_visited_share.train"),
+    "flash_bwd_kernels": Name(
+        "gauge", _KERNELS, "routing manifest: kernels the backward of the "
+        "program's flash attention calls is (as last traced): 1 where the "
+        "dK/dV kernel loops over the chunks of a resident K tile and sums "
+        "dQ beside them (no flash_dq call), 2 where flash_dq and flash_dkv "
+        "each visit the pairs; label program", "flash_bwd_kernels.train"),
+    "flash_bwd_vmem_bytes": Name(
+        "gauge", _KERNELS, "routing manifest: bytes of VMEM that backward's "
+        "dK/dV call asked the compiler for (vmem_limit_bytes: "
+        "ops/flash_attention._vmem_need); 0 where the compiler's default "
+        "covers it and the call asks for nothing; label program",
+        "registry only: what a resident K tile costs at this shape"),
     "mla_kv_expanded_bytes": Name(
         "gauge", _GLM4, "routing manifest: bytes a step writes as per-head "
         "keys and values before the attention kernels (positions x "
@@ -692,7 +704,8 @@ def synced_as(tree: Any) -> Any:
 
 _ROUTING = ("moe_rows_bound", "moe_rows_tight", "bd_tiles_visited",
             "bd_tiles_total", "causal_tiles_visited", "causal_tiles_total",
-            "mla_kv_expanded_bytes", "mla_latent_bytes", "mtp_modules")
+            "mla_kv_expanded_bytes", "mla_latent_bytes", "mtp_modules",
+            "flash_bwd_kernels", "flash_bwd_vmem_bytes")
 
 
 def note_routing(**shapes) -> None:

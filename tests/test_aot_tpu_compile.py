@@ -65,13 +65,27 @@ def _shapes(tree, sharding):
         tree)
 
 
-def _assert_kernels_named(hlo):
+def _kernel_calls(hlo, kernel):
+    return re.findall(rf"%{kernel}[.\d]* = [^\n]*custom-call\(", hlo)
+
+
+def _assert_kernels_named(hlo, bwd_kernels=2):
     """The Mosaic custom calls carry the names the per-kernel metrics sum
     by (``pallas_call(name=)``, ``tracing.NAMES``): a refactor that loses
-    one would leave ``flash_*_ms.train`` without a reading."""
-    for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
-        assert re.search(rf"%{kernel}[.\d]* = [^\n]*custom-call\(", hlo), \
-            kernel
+    one would leave ``flash_*_ms.train`` without a reading. ``flash_dq`` is
+    there exactly where the backward is two kernels: with a resident K
+    tile and a loop of chunks ``flash_dkv`` yields dQ too."""
+    for kernel in ("flash_fwd", "flash_dkv"):
+        assert _kernel_calls(hlo, kernel), kernel
+    assert bool(_kernel_calls(hlo, "flash_dq")) == (bwd_kernels == 2)
+
+
+# The chip smoke's variants whose table entry keeps K resident with a loop
+# of chunks (head 64 at T 1024, whatever the dtype: the nearest entry): no
+# key bias among them, so their backward is the one kernel.
+_ONE_KERNEL = {"causal d64 T1024 bf16", "packed d64 T1024 bf16",
+               "strict causal (offset -1) d64 T1024 bf16",
+               "causal d64 T1024 fp32"}
 
 
 @pytest.mark.parametrize("variant", _FLASH, ids=[v[0] for v in _FLASH])
@@ -94,7 +108,8 @@ def test_flash_variant_compiles_for_v5e(v5e, mosaic, variant):
 
     lowered = jax.jit(fwd_bwd).lower(x, x, x, seg, mask)
     assert "tpu_custom_call" in lowered.as_text()
-    _assert_kernels_named(lowered.compile().as_text())
+    _assert_kernels_named(lowered.compile().as_text(),
+                          1 if variant[0] in _ONE_KERNEL else 2)
 
 
 _COLLECTIVE = re.compile(r" (all-reduce|all-gather|reduce-scatter|all-to-all|"
@@ -140,12 +155,14 @@ def test_spmd_train_step_compiles_for_v5e(v5e, mosaic, restore_world, n_dev):
     # the program keeps its name, the kernels theirs, and the trainer's
     # scopes reach the chip's program as operation metadata
     assert hlo.startswith("HloModule jit_train_step")
-    _assert_kernels_named(hlo)
+    _assert_kernels_named(hlo, bwd_kernels=1)
     # remat=dots keeps what the forward kernel wrote: one forward call a
-    # layer in the chip's program, not a second one in the backward
-    for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
-        calls = re.findall(rf"%{kernel}[.\d]* = [^\n]*custom-call\(", hlo)
-        assert len(calls) == cfg.num_layers, (kernel, calls)
+    # layer in the chip's program, not a second one in the backward; and
+    # the backward is one kernel a layer (head 64 at T 1024: K resident)
+    for kernel, a_layer in (("flash_fwd", 1), ("flash_dq", 0),
+                            ("flash_dkv", 1)):
+        calls = _kernel_calls(hlo, kernel)
+        assert len(calls) == a_layer * cfg.num_layers, (kernel, calls)
     for scope in ("hvd/value_and_grad/sync", "hvd/optimizer/update",
                   "hvd/fusion/pack", "hvd/fusion/unpack", "gpt2/loss_head"):
         assert scope in hlo, scope
@@ -215,24 +232,53 @@ def test_block_diffusion_step_compiles_for_v5e_at_published_widths(
         assert scope in hlo, scope
 
 
+def _causal_fwd_bwd(head, scale=None):
+    from horovod_tpu.ops.flash_attention import flash_attention
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, scale=scale).astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)
+    return jax.jit(fwd_bwd)
+
+
+def _vmem_asked(lowered):
+    """Bytes of scoped VMEM the Mosaic calls of a lowered text ask for
+    (``vmem_limit_bytes``), one a call that asks: a call under the
+    compiler's default carries no ``scoped_memory_configs``."""
+    return [int(n) for n in re.findall(
+        r"scoped_memory_configs.{0,80}?size[^:]*: *(\d+)", lowered.as_text())]
+
+
 def test_causal_flash_compiles_for_v5e_at_the_hybrid_cells_shape(v5e, mosaic):
     """Head size 64, 8,192 positions, 32 query heads a row, the tiles of
     the table's row for that shape: the causal kernels' second shape in
-    the benchmark, where the grid's K axis has steps to skip."""
+    the benchmark. K is resident forward and backward, the backward is one
+    kernel, and it compiles with the VMEM the code itself asks for."""
     from horovod_tpu.ops import tile_table
-    from horovod_tpu.ops.flash_attention import flash_attention
     entry = tile_table._best_entry(64, 8192, "bfloat16", "causal", None)
     assert (entry["head_dim"], entry["seq"]) == (64, 8192)
     on = SingleDeviceSharding(v5e[0])
     x = jax.ShapeDtypeStruct((1, 8192, 32, 64), jnp.bfloat16, sharding=on)
+    lowered = _causal_fwd_bwd(64).lower(x, x, x)
+    # the forward fits the default; the backward's resident tile does not
+    assert _vmem_asked(lowered) == [42598400]
+    _assert_kernels_named(lowered.compile().as_text(), bwd_kernels=1)
 
-    def fwd_bwd(q, k, v):
-        return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
-            q, k, v, causal=True).astype(jnp.float32)),
-            argnums=(0, 1, 2))(q, k, v)
 
-    _assert_kernels_named(jax.jit(fwd_bwd).lower(x, x, x).compile()
-                          .as_text())
+def test_the_compilers_default_refuses_a_resident_backward_at_8k(
+        v5e, mosaic, monkeypatch):
+    """What the ask is for: the same kernels handed to the compiler with
+    no ``vmem_limit_bytes`` run out of its default 16 MiB of scoped VMEM
+    (this was the wall PRs 31 and 33 met, not the chip's 128 MiB)."""
+    fa = importlib.import_module("horovod_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "_vmem_params", lambda chunk, need: {})
+    on = SingleDeviceSharding(v5e[0])
+    x = jax.ShapeDtypeStruct((1, 8192, 20, 256), jnp.bfloat16, sharding=on)
+    lowered = _causal_fwd_bwd(256, 256 ** -0.5).lower(x, x, x)
+    assert _vmem_asked(lowered) == []
+    with pytest.raises(Exception, match="(?i)vmem"):
+        lowered.compile()
 
 
 @pytest.fixture(scope="module")
@@ -286,7 +332,7 @@ def test_hybrid_step_compiles_for_v5e_at_published_widths(hybrid_hlo):
     """The causal flash kernels, XLA's grouped kernel for the experts held,
     the six scopes."""
     hlo = hybrid_hlo
-    _assert_kernels_named(hlo)
+    _assert_kernels_named(hlo, bwd_kernels=1)
     assert re.search(r"%ragged-dot[^\n]* = [^\n]*custom-call\(", hlo)
     for scope in ("lfm2/shortconv", "lfm2/attn", "lfm2/dense_mlp",
                   "moe/route", "moe/experts", "lfm2/loss_head"):
@@ -306,8 +352,9 @@ def test_scope_table_of_the_chips_hybrid_step(hybrid_hlo):
     from horovod_tpu import tracing
     table = tracing._read_scopes(hybrid_hlo)
     kernels = {n: r for n, r in table.items() if r.kernel}
+    # the backward of the causal kernels is the one that also yields dQ
     assert {r.kernel for r in kernels.values()} == {
-        "flash_fwd", "flash_dq", "flash_dkv", "ragged-dot"}
+        "flash_fwd", "flash_dkv", "ragged-dot"}
     for name, row in kernels.items():
         assert row.layer is not None and not row.container, name
         if row.kernel == "ragged-dot":
@@ -336,21 +383,16 @@ def test_latent_attention_kernels_compile_for_v5e_at_the_table_entry(
         v5e, mosaic):
     """The causal kernels at the table's own entry for head 256 and T
     8,192 (the latent-attention cell's shape: 20 heads, each with its own
-    expanded key and value), forward and backward."""
+    expanded key and value), forward and backward: K resident in both,
+    one backward kernel, each with the VMEM the code asks for."""
     from horovod_tpu.ops import tile_table
-    from horovod_tpu.ops.flash_attention import flash_attention
     entry = tile_table._best_entry(256, 8192, "bfloat16", "causal", None)
     assert (entry["head_dim"], entry["seq"]) == (256, 8192)
     on = SingleDeviceSharding(v5e[0])
     x = jax.ShapeDtypeStruct((1, 8192, 20, 256), jnp.bfloat16, sharding=on)
-
-    def fwd_bwd(q, k, v):
-        return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
-            q, k, v, causal=True, scale=256 ** -0.5).astype(jnp.float32)),
-            argnums=(0, 1, 2))(q, k, v)
-
-    _assert_kernels_named(jax.jit(fwd_bwd).lower(x, x, x).compile()
-                          .as_text())
+    lowered = _causal_fwd_bwd(256, 256 ** -0.5).lower(x, x, x)
+    assert sorted(_vmem_asked(lowered)) == [25559040, 72744960]
+    _assert_kernels_named(lowered.compile().as_text(), bwd_kernels=1)
 
 
 def test_latent_attention_step_compiles_for_v5e_at_published_widths(
@@ -387,7 +429,7 @@ def test_latent_attention_step_compiles_for_v5e_at_published_widths(
     hlo = step.lower(_shapes(params, replicated),
                      _shapes(jax.eval_shape(opt.init, params), replicated),
                      tokens).compile().as_text()
-    _assert_kernels_named(hlo)
+    _assert_kernels_named(hlo, bwd_kernels=1)
     assert re.search(r"%ragged-dot[^\n]* = [^\n]*custom-call\(", hlo)
     for scope in ("glm4/mla_down", "glm4/mla_up", "glm4/attn",
                   "glm4/dense_mlp", "glm4/shared_expert", "glm4/mtp",

@@ -492,6 +492,11 @@ def _fa():
     return importlib.import_module("horovod_tpu.ops.flash_attention")
 
 
+def _kernel_calls(jaxpr_text):
+    return {name: jaxpr_text.count(f"name={name}")
+            for name in ("flash_fwd", "flash_dq", "flash_dkv")}
+
+
 def _masked_dense_loss(q, k, v, tgt, offset=0, seg=None, key_bias=None):
     """softmax attention under the causal mask (shifted by ``offset``),
     segment ids and a key bias, in plain jnp; rows with no visible key
@@ -548,11 +553,13 @@ def test_chunked_causal_matches_dense(rng, case):
         return _masked_dense_loss(q, k, v, tgt, offset, seg, bias)
 
     argnums = (0, 1, 2, 3) if biased else (0, 1, 2)
-    # the loop is there: two in each kernel (the dK/dV kernel keeps its
-    # tile whole where it also sums the bias's gradient along lanes)
-    loops = str(jax.make_jaxpr(jax.grad(loss_flash, argnums))(
-        q, k, v, bias)).count("while[")
-    assert loops == {"k_tile_short": 0}.get(case, 4 if biased else 6)
+    # the loop is there: two in each kernel, and two kernels: the forward
+    # and the dK/dV kernel that also yields dQ or, where it keeps its tile
+    # whole to sum the bias's gradient along lanes, the dQ kernel
+    text = str(jax.make_jaxpr(jax.grad(loss_flash, argnums))(q, k, v, bias))
+    assert text.count("while[") == {"k_tile_short": 0}.get(case, 4)
+    assert _kernel_calls(text)["flash_dq"] == (
+        1 if biased or case == "k_tile_short" else 0)
     lf, gf = jax.value_and_grad(loss_flash, argnums)(q, k, v, bias)
     ld, gd = jax.value_and_grad(loss_dense, argnums)(q, k, v, bias)
     np.testing.assert_allclose(float(lf), float(ld), rtol=1e-5)
@@ -606,3 +613,134 @@ def test_causal_tiles_counts_what_the_dense_mask_shows(t, block_q, block_k,
         int(seen.sum()), nq * nc)
     if (t, block_q, chunk) == (1024, 256, 256):
         assert int(seen.sum()) == 10 and nq * nc == 16
+
+
+# --- the one-kernel backward (a resident K tile with a chunk loop) --------
+
+# (T, block_q, chunk, causal_offset, segment ids, key bias, dtype, head_dim)
+_FUSED = {
+    # the table's own entry for GPT-2 medium's attention (head 64, T 1024)
+    "table_entry_1024": (1024, 512, 512, 0, False, False, "bfloat16", 64),
+    "ragged": (200, 64, 64, 0, False, False, "float32", 16),
+    "offset-1": (256, 64, 64, -1, False, False, "float32", 16),
+    "segment_ids": (256, 64, 64, 0, True, False, "float32", 16),
+    "bias_gradient_discarded": (200, 32, 64, 0, False, True, "float32", 16),
+    "bf16": (256, 64, 64, 0, False, False, "bfloat16", 64),
+}
+
+
+@pytest.mark.parametrize("case", list(_FUSED), ids=list(_FUSED))
+def test_one_kernel_backward_equals_the_two_kernel_one(rng, case):
+    """Where the dK/dV kernel loops over the chunks of a resident K tile it
+    also sums dQ, and no ``flash_dq`` call is made. The two-kernel
+    backward of the same tiles is still there where a bias gradient is
+    tracked (``flash_dq`` with the same loop of chunks, ``flash_dkv`` with
+    its tile whole): dQ is bit for bit that kernel's, dK and dV are its
+    sums taken in another order."""
+    fa = _fa()
+    T, bq, chunk, offset, packed, biased, dtype, D = _FUSED[case]
+    if case == "table_entry_1024":
+        from horovod_tpu.ops import tile_table
+        assert tile_table.lookup_full(D, T, dtype, "causal")[2:] == (
+            bq, T, 512, chunk)
+    B, H = 1, 2
+    bk = -(-T // chunk) * chunk
+    q, k, v, do = (jnp.asarray(rng.standard_normal((B * H, T, D)), dtype)
+                   for _ in range(4))
+    seg = (jnp.asarray(np.sort(rng.integers(0, 3, (B, T)), axis=1),
+                       jnp.int32).reshape(B, T, 1) if packed else None)
+    bias = (jnp.asarray(rng.standard_normal((B, T, 1)), jnp.float32)
+            if biased else None)
+    scale = D ** -0.5
+
+    def backward(bias, want_db):
+        o, lse = fa._fwd(q, k, v, bias, seg, seg, H, scale, True, bq, bk,
+                         offset=offset, chunk=chunk)
+
+        def bwd(q, k, v, o, lse, do):
+            return fa._bwd(H, scale, True, bq, bk,
+                           (q, k, v, bias, seg, seg, o, lse), do,
+                           offset=offset, want_db=want_db, chunk=chunk)[:3]
+        calls = _kernel_calls(str(jax.make_jaxpr(bwd)(q, k, v, o, lse, do)))
+        return calls, bwd(q, k, v, o, lse, do)
+
+    calls, one = backward(bias, want_db=False)
+    assert calls == {"flash_fwd": 0, "flash_dq": 0, "flash_dkv": 1}
+    # a zero bias adds 0.0 to every score: the same numbers, two kernels
+    zero = jnp.zeros((B, T, 1), jnp.float32)
+    calls, two = backward(zero if bias is None else bias, want_db=True)
+    assert calls == {"flash_fwd": 0, "flash_dq": 1, "flash_dkv": 1}
+    as32 = lambda x: np.asarray(x.astype(jnp.float32))
+    assert one[0].dtype == two[0].dtype == q.dtype
+    np.testing.assert_array_equal(as32(one[0]), as32(two[0]))
+    tol = dict(rtol=2e-2, atol=2e-2) if dtype == "bfloat16" \
+        else dict(rtol=1e-5, atol=1e-5)
+    for a, b in zip(one[1:], two[1:]):
+        np.testing.assert_allclose(as32(a), as32(b), **tol)
+
+
+def _two_kernel_paths():
+    """name -> (function of (q, k, v[, bias]), shapes): calls whose
+    backward stays ``flash_dq`` + ``flash_dkv`` (their jaxprs were diffed
+    equal to the parent's when the one-kernel backward came, PR 36)."""
+    fa = _fa()
+    from horovod_tpu.ops import tile_table
+    assert tile_table.lookup_full(64, 1024, "bfloat16", "causal")[4:] == (
+        512, 512)
+    return {
+        "block_diffusion": (lambda q, k, v: fa.flash_attention(
+            q, k, v, block_diffusion=(128, 4)), (1, 256, 2, 64), None),
+        # the table's entry (K resident, chunks of 512), but the bias's
+        # gradient is summed along lanes and keeps the dK/dV tile whole
+        "tracked_bias_gradient": (lambda q, k, v, b: fa.flash_attention(
+            q, k, v, causal=True, key_bias=b), (1, 1024, 2, 64), (1, 1024)),
+        "explicit_tiles": (lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True, block_q=128, block_k=1024,
+            block_q_bwd=128, block_k_bwd=1024), (1, 1024, 2, 64), None),
+        "no_chunk_in_the_entry": (lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True), (1, 4096, 2, 64), None),
+    }
+
+
+@pytest.mark.parametrize("path", ["block_diffusion", "tracked_bias_gradient",
+                                  "explicit_tiles", "no_chunk_in_the_entry"])
+def test_backward_stays_two_kernels_off_the_resident_path(monkeypatch, path):
+    fa = _fa()
+    # lowered as for the chip: the jaxpr holds the calls, not their
+    # interpretation, and any compiler_params they carry
+    monkeypatch.setattr(fa, "_use_interpret", lambda: False)
+    fn, shape, bias_shape = _two_kernel_paths()[path]
+    args = [jnp.zeros(shape, jnp.bfloat16)] * 3
+    if bias_shape:
+        args.append(jnp.zeros(bias_shape, jnp.float32))
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(fn(*a).astype(jnp.float32)),
+        argnums=tuple(range(len(args)))))(*args))
+    assert _kernel_calls(text) == {"flash_fwd": 1, "flash_dq": 1,
+                                   "flash_dkv": 1}
+    # nothing here is over the compiler's default: no call asks for VMEM
+    assert "vmem_limit_bytes" not in text
+
+
+def test_ring_flash_backward_stays_two_kernels(monkeypatch):
+    """``ops/ring_flash.py`` hands ``_bwd`` explicit tiles, a precomputed
+    ``delta`` and an offset, and no chunk: ``flash_dq`` + ``flash_dkv`` a
+    hop, under the causal, the full and the strict mask."""
+    from jax.sharding import Mesh, PartitionSpec as P
+    from horovod_tpu.ops.ring_flash import ring_flash_attention
+    fa = _fa()
+    monkeypatch.setattr(fa, "_use_interpret", lambda: False)
+    mesh = Mesh(np.array(jax.devices()[:4]), ("sp",))
+    x = jnp.zeros((1, 4096, 2, 64), jnp.bfloat16)
+    for layout in ("contiguous", "striped"):
+        ring = jax.shard_map(
+            lambda q, k, v: ring_flash_attention(q, k, v, "sp", causal=True,
+                                                 layout=layout),
+            mesh=mesh, in_specs=(P(None, "sp"),) * 3,
+            out_specs=P(None, "sp"), check_vma=False)
+        text = str(jax.make_jaxpr(jax.grad(
+            lambda q, k, v: jnp.sum(ring(q, k, v).astype(jnp.float32)),
+            argnums=(0, 1, 2)))(x, x, x))
+        calls = _kernel_calls(text)
+        assert calls["flash_dq"] == calls["flash_dkv"] > 0
+        assert "vmem_limit_bytes" not in text

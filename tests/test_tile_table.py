@@ -370,9 +370,11 @@ def test_flash_attention_takes_the_tables_chunk(monkeypatch, tmp_path):
             fa.flash_attention(q, q, q, causal=True, **tiles))))(q)
         ).count("while[")
 
-    assert loops() == 6                 # two loops in each of three kernels
+    # two loops in each of two kernels: the forward, and the dK/dV kernel
+    # that also yields dQ
+    assert loops() == 4
     assert loops(block_q=16, block_k=128) == 0
-    assert loops(block_q=16, block_k=64) == 6    # the table's own tiles
+    assert loops(block_q=16, block_k=64) == 4    # the table's own tiles
     np.testing.assert_allclose(
         np.asarray(fa.flash_attention(q, q, q, causal=True)),
         np.asarray(fa.flash_attention(q, q, q, causal=True, block_q=16,
@@ -398,3 +400,93 @@ def test_autotune_sweeps_and_records_chunked_candidates(tmp_path):
     entry = tile_table.load_table(p)["entries"][0]
     assert entry["source"].endswith("-fwdbwd")
     assert (entry["chunk"], entry["chunk_bwd"]) == best[4:]
+
+
+# --- the VMEM a resident K tile takes (PR 36) ------------------------------
+
+@pytest.mark.parametrize("head,tiles", [
+    (64, (256, 8192, 512, 8192, 512, 1024)),
+    (256, (256, 8192, 512, 8192, 512, 512)),
+])
+def test_the_8k_entries_keep_k_resident_forward_and_backward(head, tiles):
+    """The two causal shapes the benchmark runs at T 8,192 (the hybrid
+    decoder's head 64, latent attention's head 256) come from PR 36's
+    forward + backward sweep on the v5e, and both keep the whole key axis
+    resident: the forward loops over its chunks, the backward is the one
+    kernel that does."""
+    fa = _fa()
+    entry = tile_table._best_entry(head, 8192, "bfloat16", "causal", None)
+    assert (entry["head_dim"], entry["seq"]) == (head, 8192)
+    assert entry["source"] == "tuned-v5e-fwdbwd-pr36"
+    assert tile_table.lookup_full(head, 8192, "bfloat16", "causal") == tiles
+    bq, bk, bqb, bkb, chunk, chunk_bwd = tiles
+    assert fa._tiling(8192, 8192, bq, bk, chunk, True, None, d=head,
+                      itemsize=2, kernel="fwd") == (bq, 8192, chunk)
+    assert fa._tiling(8192, 8192, bqb, bkb, chunk_bwd, True, None, d=head,
+                      itemsize=2, kernel="dkv") == (bqb, 8192, chunk_bwd)
+
+
+def _fa():
+    import importlib
+    return importlib.import_module("horovod_tpu.ops.flash_attention")
+
+
+def test_the_gpt2_entry_runs_under_the_compilers_default_vmem():
+    """T1's entry (head 64, T 1024: K resident, chunks of 512) needs less
+    VMEM than Mosaic gives a kernel that asks for nothing: neither its
+    forward nor its one-kernel backward passes ``compiler_params``, so
+    their compiled bodies carry no limit of ours."""
+    fa = _fa()
+    bq, bk, bqb, bkb, chunk, chunk_bwd = tile_table.lookup_full(
+        64, 1024, "bfloat16", "causal")
+    assert fa._tiling(1024, 1024, bq, bk, chunk, True, None, d=64,
+                      itemsize=2, kernel="fwd") == (bq, 1024, chunk)
+    assert fa._tiling(1024, 1024, bqb, bkb, chunk_bwd, True, None, d=64,
+                      itemsize=2, kernel="dkv") == (bqb, 1024, chunk_bwd)
+    for kernel, tq, c, extra in (("fwd", bq, chunk, ""),
+                                 ("dkv", bqb, chunk_bwd, "dq")):
+        need = fa._vmem_need(kernel, tq, 1024, c, 64, 2, extra=extra)
+        assert need <= fa._VMEM_DEFAULT
+        assert fa._vmem_params(c, need) == {}
+    # a plain grid never asks, whatever it is counted at
+    assert fa._vmem_params(None, 10 * fa._VMEM_DEFAULT) == {}
+    asked = fa._vmem_params(512, 3 * fa._VMEM_DEFAULT)
+    assert asked["compiler_params"].vmem_limit_bytes == 3 * fa._VMEM_DEFAULT
+
+
+def test_a_key_axis_too_long_for_a_cores_vmem_runs_the_plain_grid():
+    """Where a resident K tile (K and V twice, the fp32 dK / dV sums, dK and
+    dV twice on their way out) would take more than a v5e core can give,
+    ``_tiling`` hands back the grid with the chunk as its K tile: at head
+    256 the 8,192 keys of the benchmark's cell fit, 32,768 do not."""
+    fa = _fa()
+    shape = dict(d=256, itemsize=2, kernel="dkv")
+    assert fa._vmem_need("dkv", 512, 8192, 512, 256, 2,
+                         extra="dq") <= fa._VMEM_CAP < 128 * 2 ** 20
+    assert fa._tiling(8192, 8192, 512, 8192, 512, True, None,
+                      **shape) == (512, 8192, 512)
+    assert fa._vmem_need("dkv", 512, 32768, 512, 256, 2,
+                         extra="dq") > fa._VMEM_CAP
+    assert fa._tiling(32768, 32768, 512, 32768, 512, True, None,
+                      **shape) == (512, 512, None)
+    # what the two 8k entries ask for is inside what a core has
+    for head, (_, _, bqb, bkb, _, chunk_bwd) in (
+            (h, tile_table.lookup_full(h, 8192, "bfloat16", "causal"))
+            for h in (64, 256)):
+        asked = fa._vmem_need("dkv", bqb, bkb, chunk_bwd, head, 2,
+                              extra="dq")
+        assert fa._VMEM_DEFAULT < asked <= fa._VMEM_CAP, head
+    # a tracked bias gradient keeps the dK/dV tile whole, and 1024 x 8192
+    # scores are more than a core has: the plain grid for both kernels
+    assert fa._tiling(8192, 8192, 1024, 8192, 1024, True, None, d=64,
+                      itemsize=2, kernel="dkv", per_key=1,
+                      track_db=True) == (1024, 1024, None)
+    # an entry whose K tile is short of the keys runs its tiles as the
+    # grid's, as it always did, if the compiler's default covers them (a
+    # plain grid asks for nothing); else the chunk is the grid's K tile
+    assert fa._tiling(2048, 2048, 256, 1024, 512, True, None, d=64,
+                      itemsize=2) == (256, 1024, None)
+    assert fa._tiling(16384, 16384, 256, 8192, 512, True, None, d=256,
+                      itemsize=2) == (256, 512, None)
+    assert fa._tiling(16384, 16384, 512, 8192, 1024, True, None, d=64,
+                      itemsize=2, kernel="dkv") == (512, 1024, None)

@@ -44,6 +44,7 @@ ROUTING = ["moe_rows_bound", "moe_rows_tight", "moe_rows_overflow_layers",
            "bd_tiles_visited", "bd_tiles_total",
            "causal_tiles_visited", "causal_tiles_total",
            "mla_kv_expanded_bytes", "mla_latent_bytes", "mtp_modules",
+           "flash_bwd_kernels", "flash_bwd_vmem_bytes",
            "moe_local_assignments", "moe_load_max_over_mean",
            "moe_bias_moved_share"]
 ENGINE_PHASES = ["sweep", "admit", "build", "dispatch", "readback", "commit"]
@@ -193,7 +194,7 @@ def _step(devices, readme=True, touch=False, table=False, **changes):
             GPT2Config.tiny(attention="flash", remat=True,
                             remat_policy="dots"), **changes)
         model = GPT2(cfg)
-        tokens = jnp.zeros((2 * len(devices), 128), jnp.int32)
+        tokens = jnp.zeros((2 * len(devices), cfg.max_seq_len), jnp.int32)
         params = model.init(jax.random.PRNGKey(0), tokens[:1])
         opt = hvd.DistributedOptimizer(optax.adamw(1e-3))
         vg = hvd.value_and_grad if readme else jax.value_and_grad
@@ -225,6 +226,8 @@ def _step(devices, readme=True, touch=False, table=False, **changes):
             "saved": _program_gauge("flash_residuals_saved", "train_step"),
             "causal_tiles": [_program_gauge(name, "train_step") for name in
                              ("causal_tiles_visited", "causal_tiles_total")],
+            "flash_bwd": [_program_gauge(name, "train_step") for name in
+                          ("flash_bwd_kernels", "flash_bwd_vmem_bytes")],
         }
     finally:
         hvd.init()          # back onto the session's 8 CPU devices
@@ -337,6 +340,25 @@ def test_manifest_says_how_much_of_the_causal_square_is_visited(
     # what the program's last flash trace said
     dense = _step(jax.devices()[:2], attention="dense")
     assert dense["causal_tiles"] == readme_step["causal_tiles"]
+
+
+def test_manifest_says_how_many_kernels_the_flash_backward_is(readme_step,
+                                                             bd_step):
+    """The backward of a step's flash attention calls says what it lowered
+    to: one kernel where the tile table's entry keeps the K tile resident
+    with a loop of chunks (GPT-2 medium's attention shape, head 64 at T
+    1024: the dK/dV kernel also sums dQ, and the compiler's default VMEM
+    covers it), two where it cannot: a sequence of one tile, the
+    block-diffusion mask."""
+    assert readme_step["flash_bwd"] == [[2], [0]]       # T 128: no loop
+    assert "flash_dq" in readme_step["text"]
+    at_entry = _step(jax.devices()[:1], max_seq_len=1024, num_layers=1,
+                     num_heads=1)
+    assert at_entry["flash_bwd"] == [[1], [0]]
+    assert "flash_dkv" in at_entry["text"]
+    assert "flash_dq" not in at_entry["text"]
+    assert _program_gauge("flash_bwd_kernels", "bd_step") == [2]
+    assert _program_gauge("flash_bwd_vmem_bytes", "bd_step") == [0]
 
 
 def test_manifest_counts_nothing_on_one_device():
@@ -781,6 +803,39 @@ def test_causal_tiles_visited_share_reads_the_manifest(monkeypatch, gauges,
                                               "glm47f-train-dp1"]
     for sel in spec["args"]["series"] + spec["args"]["per"]:
         assert tracing.NAMES[sel["name"]].feeds == spec["name"]
+
+
+@pytest.mark.parametrize("gauges,want", [
+    ({"flash_bwd_kernels": 1, "flash_bwd_vmem_bytes": 52428800}, 1.0),
+    ({"flash_bwd_kernels": 2, "flash_bwd_vmem_bytes": 0}, 2.0),
+    ({}, None),                 # the parent of PR 36 has no such series
+], ids=["one-kernel", "two-kernels", "parent"])
+def test_flash_bwd_kernels_reads_the_manifest(monkeypatch, gauges, want):
+    """``flash_bwd_kernels.train`` is data for the reader the benchmark has
+    (``named:series_total``, no ``per``): ``train_step``'s gauge as it is,
+    in all five training cells, and nothing (no raise) where the program
+    does not publish it."""
+    spec, entry, named = _benchmark_metric("flash_bwd_kernels.train")
+    monkeypatch.setattr(hvd.metrics, "snapshot", lambda: {
+        "counters": {}, "histograms": {}, "gauges": {
+            name: [{"labels": {"program": "train_step"}, "value": value},
+                   {"labels": {"program": "eval_step"}, "value": 7}]
+            for name, value in gauges.items()}})
+    module, function = spec["reader"].split(":")
+    assert (module, function) == ("named", "series_total")
+    assert "per" not in spec["args"]
+    got = getattr(named, function)(None, **spec["args"])
+    assert got == (want if want is None else pytest.approx(want))
+    assert len(entry) == 1
+    for key in ("unit", "layer", "moves", "source", "better", "workloads"):
+        assert spec[key] == entry[0][key], key
+    assert (spec["unit"], spec["better"]) == ("kernels", "lower")
+    assert spec["workloads"] == [
+        "gpt2m-train-dp1", "gpt2m-train-dp4", "sdar30b-bd-train-dp1",
+        "lfm2-24b-train-dp1", "glm47f-train-dp1"]
+    for sel in spec["args"]["series"]:
+        assert tracing.NAMES[sel["name"]].feeds == spec["name"]
+    assert tracing.NAMES["flash_bwd_vmem_bytes"].feeds.startswith("registry")
 
 
 # ---------------------------------------------------------------------------

@@ -60,13 +60,20 @@ FWDBWD_SHAPES = [
 # (block_q, chunk) with the K tile the whole key axis, resident, and the
 # kernels looping inside a grid step over chunks of it as far as the
 # diagonal, so that the scores above it are not computed and the chunks
-# under it take no mask.
+# under it take no mask. As a backward tiling such a candidate is the
+# one-kernel backward (dQ summed in the dK/dV kernel's loop); the kernels
+# ask for the VMEM a resident tile takes (flash_attention._vmem_need), so
+# at 8,192 keys these compile, at head 256 in the forward too.
 CAUSAL_CHUNKED = [(128, 128), (128, 256), (256, 128), (256, 256), (256, 512),
                   (512, 256), (512, 512)]
-# At 4k and beyond the grid's K axis has many steps to skip and a tile may
-# be larger than FLASH_TILE_CANDIDATES goes: these join both lists there.
-LONG_TILES = [(1024, 512), (1024, 1024), (512, 2048)]
-LONG_CHUNKED = [(512, 1024), (1024, 512), (1024, 1024)]
+# At 4k and beyond the grid's K axis has many steps to skip, a tile may be
+# larger than FLASH_TILE_CANDIDATES goes, and nothing 128 wide has ever
+# come near winning (PERF.md section 6, PRs 30, 31, 33): there the sweep
+# is over these.
+LONG_TILES = [(512, 512), (512, 1024), (1024, 512), (1024, 1024),
+              (512, 2048)]
+LONG_CHUNKED = [(256, 512), (512, 512), (256, 1024), (512, 1024),
+                (1024, 512), (1024, 1024)]
 
 
 def main(argv=None) -> int:
@@ -121,10 +128,11 @@ def main(argv=None) -> int:
         candidates = None
         if args.fwdbwd and kind == "causal":
             long = seq >= 4096
-            candidates = [c for c in FLASH_TILE_CANDIDATES if c[1] <= seq]
-            candidates += LONG_TILES if long else []
-            candidates += [(bq, seq, chunk) for bq, chunk in
-                           CAUSAL_CHUNKED + (LONG_CHUNKED if long else [])]
+            candidates = LONG_TILES if long else [
+                c for c in FLASH_TILE_CANDIDATES if c[1] <= seq]
+            candidates = candidates + [(bq, seq, chunk) for bq, chunk in
+                                       (LONG_CHUNKED if long
+                                        else CAUSAL_CHUNKED)]
         try:
             best, trials = autotune_flash_blocks(
                 shape, dtype=dtype, causal=causal, record=True,
