@@ -75,7 +75,8 @@ def autotune_flash_blocks(q_shape, dtype="bfloat16", causal: bool = True,
                           record: bool = False,
                           record_kind: Optional[str] = None,
                           record_path=None,
-                          tune_backward: bool = False):
+                          tune_backward: bool = False,
+                          window: Optional[int] = None):
     """Measure flash-attention (block_q, block_k) tilings on this device.
 
     The best tiles depend on head_dim, sequence length and VMEM pressure
@@ -128,6 +129,9 @@ def autotune_flash_blocks(q_shape, dtype="bfloat16", causal: bool = True,
         "causal"/"full" from ``causal``. Pass "ring" when tuning tiles
         for ``ring_flash_attention``'s per-hop shape.
       record_path: alternate table file (tests); None = the shipped table.
+      window: tune the causal variant under a sliding window of this many
+        keys (``flash_attention(window=)``); record it with
+        ``record_kind="window"``.
     """
     import jax
     import jax.numpy as jnp
@@ -172,7 +176,7 @@ def autotune_flash_blocks(q_shape, dtype="bfloat16", causal: bool = True,
         def chained(q, k, v):
             def body(c, _):
                 o = _attend(c, k, v, causal, q_shape[-1] ** -0.5, None,
-                            None, tiles)
+                            None, tiles, window=window)
                 return o.astype(c.dtype), None
             out, _ = lax.scan(body, q, None, length=chain)
             return out
@@ -249,7 +253,7 @@ def autotune_flash_blocks(q_shape, dtype="bfloat16", causal: bool = True,
             us_per_call=us * 1e6,
             source=f"tuned-{jax.default_backend()}" + suffix,
             device=jax.devices()[0].device_kind,
-            path=record_path, **extra)
+            path=record_path, window=window, **extra)
     return best, trials
 
 

@@ -10,14 +10,18 @@ training only), and a hybrid of gated short convolutions and grouped-query
 attention with a dense SwiGLU first and bias-selected routed experts after
 (``models/lfm2.py``, training only), and a decoder with latent attention, a
 shared expert beside the routed ones and a multi-token-prediction module in
-its loss (``models/glm4_moe_lite.py``, training only).
+its loss (``models/glm4_moe_lite.py``, training only), and a decoder of
+position-free global attention beside sliding-window attention with RoPE,
+whose router reads a block's input before attention and whose experts are
+ReGLU (``models/smallthinker.py``, training only).
 """
 
 from horovod_tpu.models.mnist import MnistCNN  # noqa: F401
 from horovod_tpu.models.resnet import ResNet50, ResNet18  # noqa: F401
 
 __all__ = ["MnistCNN", "ResNet50", "ResNet18", "LFM2", "LFM2Config",
-           "Glm4MoeLite", "Glm4MoeLiteConfig", "get_model"]
+           "Glm4MoeLite", "Glm4MoeLiteConfig", "SmallThinker",
+           "SmallThinkerConfig", "get_model"]
 
 
 def __getattr__(name):
@@ -28,6 +32,9 @@ def __getattr__(name):
     if name in ("Glm4MoeLite", "Glm4MoeLiteConfig"):
         from horovod_tpu.models import glm4_moe_lite
         return getattr(glm4_moe_lite, name)
+    if name in ("SmallThinker", "SmallThinkerConfig"):
+        from horovod_tpu.models import smallthinker
+        return getattr(smallthinker, name)
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 

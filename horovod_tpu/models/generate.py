@@ -458,6 +458,12 @@ _TRAINING_ONLY = {
                          "path that reads it, are not built, and the "
                          "expert layer and its shared expert have no "
                          "decode path",
+    "SmallThinkerConfig": "the cache has one kind of block table and the "
+                          "decode kernels no window (a window layer's "
+                          "blocks would have to be a ring of its last "
+                          "sliding_window keys beside a global layer's "
+                          "whole row), and the expert layer has no decode "
+                          "path",
 }
 
 

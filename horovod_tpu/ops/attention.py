@@ -16,7 +16,7 @@ import jax.numpy as jnp
 
 __all__ = ["multihead_attention", "ATTENTION_IMPLS", "validate_sp_config",
            "sp_global_positions", "sp_attention", "packed_positions",
-           "segment_mask", "block_diffusion_mask"]
+           "segment_mask", "block_diffusion_mask", "window_mask"]
 
 ATTENTION_IMPLS = ("dense", "flash")
 
@@ -31,7 +31,8 @@ def multihead_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         flash_blocks: Optional[tuple] = None,
                         bias: Optional[jnp.ndarray] = None,
                         scale: Optional[float] = None,
-                        block_diffusion: Optional[tuple] = None
+                        block_diffusion: Optional[tuple] = None,
+                        window: Optional[int] = None
                         ) -> jnp.ndarray:
     """softmax(q k^T * scale [+ bias + masks]) v over (B, T, H, D).
 
@@ -61,6 +62,10 @@ def multihead_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         are ``[noisy ; clean]``, ``2 * seq_len`` positions, masked by
         :func:`block_diffusion_mask`. Both impls; the flash kernels skip
         the tiles that hold no visible pair.
+      window: optional static number of keys a query sees, its own
+        included (sliding-window attention, :func:`window_mask`; needs
+        ``causal``). Both impls; the flash kernels skip what lies wholly
+        under the band.
 
     Returns (B, T_q, H, D).
     """
@@ -88,7 +93,7 @@ def multihead_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                                key_bias=key_bias,
                                segment_ids=segment_ids,
                                block_diffusion=block_diffusion,
-                               **blocks).astype(out_dtype)
+                               window=window, **blocks).astype(out_dtype)
 
     scale = d ** -0.5 if scale is None else scale
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
@@ -103,6 +108,13 @@ def multihead_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if causal:
         tq, tk = q.shape[1], k.shape[1]
         mask = jnp.tril(jnp.ones((tq, tk), bool))
+        s = jnp.where(mask[None, None], s, _NEG_INF)
+    if window is not None:
+        if not causal or block_diffusion is not None:
+            raise ValueError(f"window={window} needs causal=True and no "
+                             "block_diffusion")
+        mask = window_mask(jnp.arange(q.shape[1])[:, None],
+                           jnp.arange(k.shape[1])[None, :], window)
         s = jnp.where(mask[None, None], s, _NEG_INF)
     if block_diffusion is not None:
         seq_len, block_len = block_diffusion
@@ -208,6 +220,17 @@ def block_diffusion_mask(q_pos, k_pos, seq_len: int, block_len: int):
     before = (jnp.where(k_noisy, jnp.int32(2 ** 30), k_blk)
               < q_blk + jnp.where(q_noisy, 0, 1))
     return same | before
+
+
+def window_mask(q_pos, k_pos, window: int):
+    """The lower edge of a sliding window over a causal mask: a query sees
+    the ``window`` keys up to and including its own, so a key is hidden once
+    it lies ``window`` or more positions back. THE definition for the dense
+    path; the flash kernels mask by the same inequality on their tiles
+    (``ops/flash_attention._mask_scores``). ``q_pos`` ``(Tq, 1)`` and
+    ``k_pos`` ``(1, Tk)`` (or any shapes that broadcast); returns their
+    broadcast, bool. The causal mask is the other edge and is not in it."""
+    return q_pos - k_pos < window
 
 
 def packed_positions(segment_ids: jnp.ndarray) -> jnp.ndarray:

@@ -110,17 +110,19 @@ def _block_sizes(tq: int, tk: int, block_q: int, block_k: int):
 
 
 def _mask_scores(s, q_blk, kv_blk, *, block_q, block_k, tq, tk, causal,
-                 offset=0, bias=None, seg_q=None, seg_k=None, bd=None):
-    """Apply causal / block-diffusion / ragged-edge / key-bias masking to a
-    score block.
+                 offset=0, bias=None, seg_q=None, seg_k=None, bd=None,
+                 window=None):
+    """Apply causal / window / block-diffusion / ragged-edge / key-bias
+    masking to a score block.
 
     Shared by the forward and both backward kernels so the mask definition
     cannot diverge between passes. ``s`` is (block_q, block_k) fp32.
     ``offset`` shifts the causal diagonal: visible iff
     ``q_pos + offset >= k_pos`` (offset -1 = strict causal — what striped
-    ring layouts need for the src > rank blocks). ``bd`` is the static
-    ``(seq_len, block_len)`` of a block-diffusion row ``[noisy ; clean]``
-    (``ops.attention.block_diffusion_mask``).
+    ring layouts need for the src > rank blocks); with a ``window`` also
+    ``q_pos + offset - k_pos < window``, the causal mask's lower edge.
+    ``bd`` is the static ``(seq_len, block_len)`` of a block-diffusion row
+    ``[noisy ; clean]`` (``ops.attention.block_diffusion_mask``).
     """
     need_pos = causal or tq % block_q or tk % block_k
     if bias is not None:
@@ -136,6 +138,8 @@ def _mask_scores(s, q_blk, kv_blk, *, block_q, block_k, tq, tk, causal,
         ok = jnp.logical_and(q_pos < tq, k_pos < tk)
         if causal:
             ok = jnp.logical_and(ok, q_pos + offset >= k_pos)
+            if window is not None:
+                ok = jnp.logical_and(ok, q_pos + offset - k_pos < window)
         s = jnp.where(ok, s, _NEG_INF)
     if bd is not None:
         # positions past the ragged edge were masked above; here a column of
@@ -192,13 +196,28 @@ def _bd_skip(q_blk, kv_idx, block_q: int, block_k: int, seq: int,
             | (q_clean & k_clean & (kc_lo <= qc_hi)))
 
 
+def _window_skip(q_blk, kv_idx, block_q: int, block_k: int, offset: int,
+                 window: int):
+    """True when the last key of K tile ``kv_idx`` lies inside the window of
+    the first row of Q tile ``q_blk``: the tile is not wholly under the
+    band. Scalars in a kernel; arrays of tile indices in
+    :func:`window_tiles`."""
+    return (kv_idx + 1) * block_k + window > q_blk * block_q + offset + 1
+
+
 def _tile_visible(causal: bool, bd, q_blk, kv_idx, block_q: int,
-                  block_k: int, offset: int = 0):
+                  block_k: int, offset: int = 0, window=None, xp=jnp):
     """The tile skip of the three kernels: the block-diffusion one for a
-    ``bd`` row, else the causal one."""
+    ``bd`` row, else the causal one, with a ``window`` between the
+    diagonal and the band's lower edge."""
     if bd is not None:
         return _bd_skip(q_blk, kv_idx, block_q, block_k, *bd)
-    return _causal_skip(causal, q_blk, kv_idx, block_q, block_k, offset)
+    visible = _causal_skip(causal, q_blk, kv_idx, block_q, block_k, offset,
+                           xp)
+    if window is None:
+        return visible
+    return xp.logical_and(visible, _window_skip(q_blk, kv_idx, block_q,
+                                                block_k, offset, window))
 
 
 def _tile_bytes(rows: int, cols: int, itemsize: int) -> int:
@@ -329,15 +348,39 @@ def _causal_chunks(q_blk, block_q: int, chunk: int, offset: int, tk: int,
     return clear, visible
 
 
+def _window_chunks(q_blk, block_q: int, chunk: int, offset: int, tk: int,
+                   window: int, xp=jnp):
+    """``(first, inside, clear, visible)`` of the compute chunks of the key
+    axis against Q tile ``q_blk`` under the causal mask with a ``window``:
+    chunks ``first .. visible - 1`` hold a visible pair, and of them
+    ``inside .. clear - 1`` lie wholly between the band's lower edge and the
+    diagonal (no positional mask); the band's edge crosses those before
+    ``inside``, the diagonal those from ``clear``. ``inside == clear`` where
+    the window is too short for a chunk to lie clear of both."""
+    clear, visible = _causal_chunks(q_blk, block_q, chunk, offset, tk, xp)
+    q0 = q_blk * block_q + offset          # its first row sees keys > q0 - W
+    first = xp.minimum(xp.maximum(q0 - window + 1, 0) // chunk, visible)
+    inside = (xp.maximum(q0 + block_q - window, 0) + chunk - 1) // chunk
+    inside = xp.minimum(xp.maximum(inside, first), visible)
+    return first, inside, xp.maximum(clear, inside), visible
+
+
 def _chunk_loop(visit, state, q_blk, block_q: int, chunk: int, offset: int,
-                tk: int):
+                tk: int, window=None):
     """The loop inside a grid step whose length is the diagonal:
     ``state = visit(state, ci, rows, crossed)`` over the compute chunks of
     the resident K tile that Q tile ``q_blk`` may see, ``rows`` of the
     tile known to the mask as block ``ci`` of ``chunk`` keys. The chunks
     under the diagonal come first and take no causal mask
-    (``crossed=False``), then those it crosses."""
-    clear, visible = _causal_chunks(q_blk, block_q, chunk, offset, tk)
+    (``crossed=False``), then those it crosses. With a ``window`` the loop
+    starts at the first chunk the tile can see, and the chunks the band's
+    lower edge crosses are a second masked run before the clear ones."""
+    first, inside = 0, 0
+    if window is None:
+        clear, visible = _causal_chunks(q_blk, block_q, chunk, offset, tk)
+    else:
+        first, inside, clear, visible = _window_chunks(
+            q_blk, block_q, chunk, offset, tk, window)
 
     def run(lo, hi, crossed: bool, state):
         def body(ci, state):
@@ -345,7 +388,9 @@ def _chunk_loop(visit, state, q_blk, block_q: int, chunk: int, offset: int,
             return visit(state, ci, rows, crossed)
         return jax.lax.fori_loop(lo, hi, body, state)
 
-    return run(clear, visible, True, run(0, clear, False, state))
+    if window is not None:
+        state = run(first, inside, True, state)
+    return run(clear, visible, True, run(inside, clear, False, state))
 
 
 def causal_tiles(t: int, block_q: int, block_k: int, chunk=None,
@@ -357,16 +402,31 @@ def causal_tiles(t: int, block_q: int, block_k: int, chunk=None,
     tiles, each counted by the predicate the kernel runs by. ``shape`` is
     what :func:`_tiling` weighs the forward's VMEM by (``d``, ``itemsize``,
     ``per_key``, ``per_q``)."""
+    return window_tiles(t, None, block_q, block_k, chunk, offset, **shape)
+
+
+def window_tiles(t: int, window: int, block_q: int, block_k: int, chunk=None,
+                 offset: int = 0, **shape):
+    """:func:`causal_tiles` under a ``window`` (``None``: the causal mask
+    alone): ``(visited, total)`` of one head's forward over ``t`` positions
+    of which a row sees the ``window`` keys up to its own (the routing
+    manifest's ``window_tiles_visited`` / ``window_tiles_total``), by the
+    predicates the kernels run by."""
     bq, bk, chunk = _tiling(t, t, block_q, block_k, chunk, True, None,
                             **shape)
     q_blk = np.arange(-(-t // bq))
     if chunk is None:
         kv_idx = np.arange(-(-t // bk))
-        hit = _causal_skip(True, q_blk[:, None], kv_idx[None, :], bq, bk,
-                           offset, xp=np)
+        hit = _tile_visible(True, None, q_blk[:, None], kv_idx[None, :], bq,
+                            bk, offset, window, xp=np)
         return int(np.sum(hit)), hit.size
-    _, visible = _causal_chunks(q_blk, bq, chunk, offset, t, xp=np)
-    return int(np.sum(visible)), q_blk.size * -(-t // chunk)
+    if window is None:
+        first, visible = 0, _causal_chunks(q_blk, bq, chunk, offset, t,
+                                           xp=np)[1]
+    else:
+        first, _, _, visible = _window_chunks(q_blk, bq, chunk, offset, t,
+                                              window, xp=np)
+    return int(np.sum(visible - first)), q_blk.size * -(-t // chunk)
 
 
 def bd_tiles(seq: int, blk: int, block_q: int, block_k: int):
@@ -386,11 +446,12 @@ def bd_tiles(seq: int, blk: int, block_q: int, block_k: int):
 
 def _scores(q_ref, k_ref, bias_ref, segq_ref, segk_ref, q_blk, kv_blk, rows,
             *, scale: float, causal: bool, offset: int, block_q: int,
-            block_k: int, tq: int, tk: int, bd=None):
+            block_k: int, tq: int, tk: int, bd=None, window=None):
     """The scaled Q tile, ``rows`` of the resident K tile, and their masked
     scores: what all three kernels start a (Q tile, K tile or compute
     chunk) pair with. The mask knows the rows as block ``kv_blk`` of
-    ``block_k`` keys."""
+    ``block_k`` keys; a pair that neither edge of the band crosses
+    (``causal=False`` from a chunk loop) takes no ``window`` either."""
     q = _zero_oob_rows(q_ref[0].astype(jnp.float32) * scale,
                        q_blk, block_q, tq)
     k = _zero_oob_rows(k_ref[0, rows].astype(jnp.float32), kv_blk, block_k,
@@ -401,17 +462,20 @@ def _scores(q_ref, k_ref, bias_ref, segq_ref, segk_ref, q_blk, kv_blk, rows,
     seg_k = None if segk_ref is None else segk_ref[0, rows]
     s = _mask_scores(s, q_blk, kv_blk, block_q=block_q, block_k=block_k,
                      tq=tq, tk=tk, causal=causal, offset=offset,
-                     bias=bias, seg_q=seg_q, seg_k=seg_k, bd=bd)
+                     bias=bias, seg_q=seg_q, seg_k=seg_k, bd=bd,
+                     window=window)
     return q, k, s
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref, o_ref,
                 lse_ref, acc_ref=None, m_ref=None, l_ref=None, *,
                 scale: float, causal: bool, offset: int, block_q: int,
-                block_k: int, tq: int, tk: int, bd=None, chunk=None):
+                block_k: int, tq: int, tk: int, bd=None, chunk=None,
+                window=None):
     scores = functools.partial(
         _scores, q_ref, k_ref, bias_ref, segq_ref, segk_ref,
-        scale=scale, offset=offset, block_q=block_q, tq=tq, tk=tk, bd=bd)
+        scale=scale, offset=offset, block_q=block_q, tq=tq, tk=tk, bd=bd,
+        window=window)
     if chunk is not None:
         q_blk = pl.program_id(1)
 
@@ -436,7 +500,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref, o_ref,
             visit, (jnp.full((block_q,), _NEG_INF, jnp.float32),
                     jnp.zeros((block_q,), jnp.float32),
                     jnp.zeros(q_ref.shape[1:], jnp.float32)),
-            q_blk, block_q, chunk, offset, tk)
+            q_blk, block_q, chunk, offset, tk, window)
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
         lse_ref[0] = (m + jnp.log(l_safe))[:, None]
@@ -454,7 +518,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref, o_ref,
     q_blk = pl.program_id(1)
 
     @pl.when(_tile_visible(causal, bd, q_blk, kv_idx, block_q, block_k,
-                           offset))
+                           offset, window))
     def _():
         _, _, s = scores(q_blk, kv_idx, slice(None), causal=causal,
                          block_k=block_k)
@@ -501,7 +565,7 @@ _seg_k_spec = _per_key_spec
 
 
 def _fwd(q, k, v, bias, seg_q, seg_k, h, scale, causal, block_q, block_k,
-         offset=0, bd=None, chunk=None):
+         offset=0, bd=None, chunk=None, window=None):
     bh, tq, d = q.shape
     tk = k.shape[1]
     shape = _vmem_shape(d, q.dtype, bias, seg_q)
@@ -511,7 +575,7 @@ def _fwd(q, k, v, bias, seg_q, seg_k, h, scale, causal, block_q, block_k,
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, offset=offset, block_q=bq,
-        block_k=bk, tq=tq, tk=tk, bd=bd, chunk=chunk)
+        block_k=bk, tq=tq, tk=tk, bd=bd, chunk=chunk, window=window)
     in_specs = [
         pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
@@ -585,7 +649,7 @@ def _fill_optionals(kernel, has_bias, has_seg):
 # ---------------------------------------------------------------------------
 
 def _visit_pairs(visit, causal: bool, bd, q_blk, kv_idx, block_q: int,
-                 block_k: int, chunk, offset: int, tk: int):
+                 block_k: int, chunk, offset: int, tk: int, window=None):
     """The backward kernels' ``visit(kv_blk, block_k, rows, causal)`` over
     what the resident K tile holds that Q tile ``q_blk`` may see: the tile
     whole, if the grid-level skip lets it through, or with a compute chunk
@@ -594,17 +658,18 @@ def _visit_pairs(visit, causal: bool, bd, q_blk, kv_idx, block_q: int,
     carrying them round the loop)."""
     if chunk is None:
         pl.when(_tile_visible(causal, bd, q_blk, kv_idx, block_q, block_k,
-                              offset))(
+                              offset, window))(
             lambda: visit(kv_idx, block_k, slice(None), causal))
         return
     _chunk_loop(lambda _, ci, rows, crossed: visit(ci, chunk, rows, crossed),
-                None, q_blk, block_q, chunk, offset, tk)
+                None, q_blk, block_q, chunk, offset, tk, window)
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
                    do_ref, lse_ref, delta_ref, dq_ref, acc_ref, *,
                    scale: float, causal: bool, offset: int, block_q: int,
-                   block_k: int, tq: int, tk: int, bd=None, chunk=None):
+                   block_k: int, tq: int, tk: int, bd=None, chunk=None,
+                   window=None):
     kv_idx = pl.program_id(2)
     num_kv = pl.num_programs(2)
 
@@ -618,7 +683,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
         _, k, s = _scores(q_ref, k_ref, bias_ref, segq_ref, segk_ref, q_blk,
                           kv_blk, rows, scale=scale, causal=causal,
                           offset=offset, block_q=block_q, block_k=block_k,
-                          tq=tq, tk=tk, bd=bd)
+                          tq=tq, tk=tk, bd=bd, window=window)
         p = jnp.exp(s - lse_ref[0])
         p = jnp.where(s > _NEG_INF / 2, p, 0.0)
         do = _zero_oob_rows(do_ref[0].astype(jnp.float32), q_blk, block_q, tq)
@@ -631,7 +696,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
         acc_ref[:] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
 
     _visit_pairs(visit, causal, bd, q_blk, kv_idx, block_q, block_k, chunk,
-                 offset, tk)
+                 offset, tk, window)
 
     @pl.when(kv_idx == num_kv - 1)
     def _():
@@ -642,7 +707,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
                     do_ref, lse_ref, delta_ref, dk_ref, dv_ref, db_ref,
                     dk_acc, dv_acc, db_acc, dq_ref=None, dq_acc=None, *,
                     scale: float, causal: bool, offset: int, block_q: int,
-                    block_k: int, tq: int, tk: int, bd=None, chunk=None):
+                    block_k: int, tq: int, tk: int, bd=None, chunk=None,
+                    window=None):
     """dK and dV of the resident K tile, summed over the grid's Q tiles.
     With ``dq_ref`` (the one-kernel backward: the K tile holds every key
     and the loop over its chunks meets all that this Q tile sees inside
@@ -666,7 +732,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
         q, k, s = _scores(q_ref, k_ref, bias_ref, segq_ref, segk_ref, q_idx,
                           kv_blk, rows, scale=scale, causal=causal,
                           offset=offset, block_q=block_q, block_k=block_k,
-                          tq=tq, tk=tk, bd=bd)
+                          tq=tq, tk=tk, bd=bd, window=window)
         p = jnp.exp(s - lse_ref[0])
         p = jnp.where(s > _NEG_INF / 2, p, 0.0)
         do = _zero_oob_rows(do_ref[0].astype(jnp.float32), q_idx, block_q, tq)
@@ -685,7 +751,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
             dq_acc[:] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
 
     _visit_pairs(visit, causal, bd, q_idx, k_idx, block_q, block_k, chunk,
-                 offset, tk)
+                 offset, tk, window)
 
     if dq_acc is not None:
         dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
@@ -700,7 +766,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
 
 
 def _bwd(h, scale, causal, block_q, block_k, res, do, delta=None,
-         offset=0, want_db=True, bd=None, chunk=None):
+         offset=0, want_db=True, bd=None, chunk=None, window=None):
     q, k, v, bias, seg_q, seg_k, o, lse = res
     bh, tq, d = q.shape
     tk = k.shape[1]
@@ -716,7 +782,8 @@ def _bwd(h, scale, causal, block_q, block_k, res, do, delta=None,
                         axis=-1, keepdims=True)
 
     common = dict(scale=scale, causal=causal, offset=offset, block_q=bq,
-                  block_k=bk, tq=tq, tk=tk, bd=bd, chunk=chunk)
+                  block_k=bk, tq=tq, tk=tk, bd=bd, chunk=chunk,
+                  window=window)
 
     dkv_chunk, dkv_extra = _dkv_yields(chunk, track_db)
     # One kernel: where the dK/dV kernel loops over the chunks of a K tile
@@ -853,18 +920,19 @@ def _bwd(h, scale, causal, block_q, block_k, res, do, delta=None,
 # Public API
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(5, 16)))
+@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(5, 17)))
 def _flash(q, k, v, bias, seg, h, scale, causal, block_q, block_k,
-           block_q_bwd, block_k_bwd, offset, bd, chunk, chunk_bwd):
+           block_q_bwd, block_k_bwd, offset, bd, chunk, chunk_bwd, window):
     o, _ = _fwd(q, k, v, bias, seg, seg, h, scale, causal, block_q,
-                block_k, offset=offset, bd=bd, chunk=chunk)
+                block_k, offset=offset, bd=bd, chunk=chunk, window=window)
     return o
 
 
 def _flash_fwd(q, k, v, bias, seg, h, scale, causal, block_q, block_k,
-               block_q_bwd, block_k_bwd, offset, bd, chunk, chunk_bwd):
+               block_q_bwd, block_k_bwd, offset, bd, chunk, chunk_bwd,
+               window):
     o, lse = _fwd(q, k, v, bias, seg, seg, h, scale, causal, block_q,
-                  block_k, offset=offset, bd=bd, chunk=chunk)
+                  block_k, offset=offset, bd=bd, chunk=chunk, window=window)
     # Named, so that a remat policy can keep them (models/remat.py) and
     # the backward does not run the forward kernel again to get them back.
     # The log-sum-exp is kept without its last dimension of 1, which the
@@ -876,14 +944,15 @@ def _flash_fwd(q, k, v, bias, seg, h, scale, causal, block_q, block_k,
 
 
 def _flash_bwd(h, scale, causal, block_q, block_k, block_q_bwd,
-               block_k_bwd, offset, bd, chunk, chunk_bwd, res, do):
+               block_k_bwd, offset, bd, chunk, chunk_bwd, window, res, do):
     # The backward kernels' VMEM profile differs from the forward's (two
     # extra fp32 accumulators per tile), so they may want their own tiles
     # — measured entries carry them (tile_table "tuned-*-fwdbwd").
     q, k, v, bias, seg, o, lse = res
     dq, dk, dv, dbias = _bwd(h, scale, causal, block_q_bwd, block_k_bwd,
                              (q, k, v, bias, seg, seg, o, lse[..., None]),
-                             do, offset=offset, bd=bd, chunk=chunk_bwd)
+                             do, offset=offset, bd=bd, chunk=chunk_bwd,
+                             window=window)
     # Integer segment ids take a symbolic-zero (float0) cotangent.
     dseg = (None if seg is None
             else np.zeros(seg.shape, dtype=jax.dtypes.float0))
@@ -902,7 +971,8 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     block_q_bwd: Optional[int] = None,
                     block_k_bwd: Optional[int] = None,
                     causal_offset: int = 0,
-                    block_diffusion: Optional[tuple] = None) -> jnp.ndarray:
+                    block_diffusion: Optional[tuple] = None,
+                    window: Optional[int] = None) -> jnp.ndarray:
     """Fused attention ``softmax(q k^T * scale + key_bias [+ mask]) v``.
 
     Args:
@@ -927,6 +997,13 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         ``2 * seq_len`` positions (``ops.attention.block_diffusion_mask``
         says who sees whom); the kernels mask score tiles by position and
         skip the tiles that hold no visible pair. Not with ``causal``.
+      window: optional static number of keys a row sees, its own included
+        (sliding-window attention; needs ``causal=True``): visible iff
+        ``0 <= i + causal_offset - j < window``. The kernels mask the
+        band's lower edge as they mask the diagonal and visit no tile or
+        chunk that lies wholly under it. A window that holds every key a
+        row can see is the causal mask and runs as it. Composes with
+        ``key_bias`` and ``segment_ids``; not with ``block_diffusion``.
       block_q, block_k: tile sizes (clamped to the sequence lengths).
         ``None`` (default) consults the checked-in tile table
         (``ops/tile_table.py``; ``tools/tune_tiles.py`` measures it on
@@ -962,12 +1039,22 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                 f"block_diffusion={bd} needs non-causal self-attention "
                 f"over 2 * seq_len positions in whole blocks, got "
                 f"causal={causal}, t_q={tq}, t_kv={tk}")
+    if window is not None:
+        window = int(window)
+        if not causal or bd is not None or window < 1:
+            raise ValueError(
+                f"window={window} needs causal=True, no block_diffusion "
+                f"and at least one key, got causal={causal}, "
+                f"block_diffusion={block_diffusion}")
+        if window >= tq + causal_offset:    # no row has a key under it
+            window = None
     scale = d ** -0.5 if scale is None else scale
 
     chunk = chunk_bwd = None
     if None in (block_q, block_k, block_q_bwd, block_k_bwd):
         from horovod_tpu.ops import tile_table
         kind = ("block_diffusion" if bd is not None
+                else "window" if window is not None
                 else "causal" if causal else "full")
         tq_, tk_, tqb_, tkb_, chunk_, chunk_bwd_ = tile_table.lookup_full(
             d, max(tq, tk), q.dtype, kind)
@@ -989,11 +1076,11 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             chunk_bwd = chunk_bwd_
     return _attend(q, k, v, causal, scale, key_bias, segment_ids,
                    (block_q, block_k, block_q_bwd, block_k_bwd, chunk,
-                    chunk_bwd), causal_offset, bd)
+                    chunk_bwd), causal_offset, bd, window)
 
 
 def _attend(q, k, v, causal, scale, key_bias, segment_ids, tiles,
-            causal_offset=0, bd=None):
+            causal_offset=0, bd=None, window=None):
     """:func:`flash_attention` once the tiles are settled: ``tiles`` is
     ``(block_q, block_k, block_q_bwd, block_k_bwd, chunk, chunk_bwd)`` as
     ``tile_table.lookup_full`` gives them. The tile sweep
@@ -1028,11 +1115,17 @@ def _attend(q, k, v, causal, scale, key_bias, segment_ids, tiles,
     o = _flash(q, k, v, key_bias, seg, h, float(scale),
                bool(causal), int(block_q), int(block_k),
                int(block_q_bwd), int(block_k_bwd), int(causal_offset), bd,
-               chunk and int(chunk), chunk_bwd and int(chunk_bwd))
+               chunk and int(chunk), chunk_bwd and int(chunk_bwd), window)
     if bd is not None:
         visited, total = bd_tiles(bd[0], bd[1], int(block_q), int(block_k))
         _tracing.note_routing(bd_tiles_visited=visited,
                               bd_tiles_total=total)
+    elif window is not None:
+        visited, total = window_tiles(
+            tq, window, int(block_q), int(block_k), chunk,
+            int(causal_offset), **_vmem_shape(d, q.dtype, key_bias, seg))
+        _tracing.note_routing(window_tiles_visited=visited,
+                              window_tiles_total=total)
     elif causal:
         visited, total = causal_tiles(
             tq, int(block_q), int(block_k), chunk, int(causal_offset),

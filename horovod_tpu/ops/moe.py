@@ -282,13 +282,17 @@ def row_bounds(n: int, top_k: int, held: int, experts_total: int
     return min(rows, -(-expected // _ROW_TILE) * _ROW_TILE), rows
 
 
-def _ffn(xs, w_gate, w_up, w_down, sizes):
-    """The SwiGLU of each row's expert: three grouped products over rows
-    sorted by expert, ``sizes`` of them for each. What the kernel leaves in
-    the rows past the groups is nobody's promise."""
+_ACTS = {"silu": nn.silu, "relu": nn.relu}
+
+
+def _ffn(xs, w_gate, w_up, w_down, sizes, act: str = "silu"):
+    """The gated unit of each row's expert (SwiGLU, or ReGLU with
+    ``act="relu"``): three grouped products over rows sorted by expert,
+    ``sizes`` of them for each. What the kernel leaves in the rows past the
+    groups is nobody's promise."""
     g = jax.lax.ragged_dot(xs, w_gate, sizes)
     u = jax.lax.ragged_dot(xs, w_up, sizes)
-    return jax.lax.ragged_dot(nn.silu(g) * u, w_down, sizes)
+    return jax.lax.ragged_dot(_ACTS[act](g) * u, w_down, sizes)
 
 
 def _window(r, top_k, start, order, group_sizes):
@@ -323,10 +327,10 @@ def _over_windows(r, args, body, sums):
         (jnp.int32(0), carry))[1]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _share(r, top_k, order, group_sizes, x, w, w_gate, w_up, w_down):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _share(r, top_k, act, order, group_sizes, x, w, w_gate, w_up, w_down):
     """The share of ``x`` (n, d), ``r`` sorted assignments at a time:
-    gather the rows, the experts' SwiGLU, and the gated rows summed into
+    gather the rows, the experts' gated unit, and the gated rows summed into
     their positions in fp32. ``order`` (whole windows) is the flat
     assignments sorted by held expert, ``w`` (n, top_k) fp32 their gates,
     zero on an assignment of another holder; ``x`` and the weights are in
@@ -337,7 +341,7 @@ def _share(r, top_k, order, group_sizes, x, w, w_gate, w_up, w_down):
     of the worst case is ever written."""
     def body(start, carry):
         src, pos, live, sizes = _window(r, top_k, start, order, group_sizes)
-        ys = _ffn(x[pos], w_gate, w_up, w_down, sizes)
+        ys = _ffn(x[pos], w_gate, w_up, w_down, sizes, act)
         gated = w.reshape(-1)[src][:, None] * ys.astype(jnp.float32)
         return (carry[0].at[pos].add(jnp.where(live, gated, 0)),)
 
@@ -346,18 +350,18 @@ def _share(r, top_k, order, group_sizes, x, w, w_gate, w_up, w_down):
                          )[0].astype(x.dtype)
 
 
-def _share_fwd(r, top_k, *args):
-    return _share(r, top_k, *args), args
+def _share_fwd(r, top_k, act, *args):
+    return _share(r, top_k, act, *args), args
 
 
-def _share_bwd(r, top_k, args, g):
+def _share_bwd(r, top_k, act, args, g):
     order, group_sizes, x, w, w_gate, w_up, w_down = args
 
     def body(start, grads):
         dx, dw, *dweights = grads
         src, pos, live, sizes = _window(r, top_k, start, order, group_sizes)
-        ys, back = jax.vjp(functools.partial(_ffn, sizes=sizes), x[pos],
-                           w_gate, w_up, w_down)
+        ys, back = jax.vjp(functools.partial(_ffn, sizes=sizes, act=act),
+                           x[pos], w_gate, w_up, w_down)
         g_rows = g[pos].astype(jnp.float32)
         dxs, *more = back(jnp.where(
             live, g_rows * w.reshape(-1)[src][:, None], 0
@@ -383,7 +387,8 @@ _share.defvjp(_share_fwd, _share_bwd)
 def routed_share(tokens, router, w_gate, w_up, w_down, *, first, top_k: int,
                  norm_topk: bool = True, dtype=jnp.bfloat16,
                  score: str = "softmax", select_bias=None,
-                 norm_eps: float = 0.0, scale: float = 1.0):
+                 norm_eps: float = 0.0, scale: float = 1.0,
+                 route_from=None, act: str = "silu"):
     """The part of a dropless top-k expert layer that the experts held here
     give, for ``tokens`` (n, d).
 
@@ -397,8 +402,14 @@ def routed_share(tokens, router, w_gate, w_up, w_down, *, first, top_k: int,
     ``select_bias`` (experts_total,) fp32 is added to the scores for the
     choice alone (the gates stay the unbiased scores of the chosen; it
     takes no gradient); ``norm_eps`` is added to the normaliser;
-    ``scale`` multiplies the gates. Returns ``(out, aux)``:
-    ``out[p] = sum_{e chosen by p and held} gate[p, e] * down_e(silu(gate_e
+    ``scale`` multiplies the gates. Two more say what the layer is made
+    of: ``route_from`` (n, d) is what the router reads where that is not
+    what the experts read (a route made from a block's input, before its
+    attention, for rows that come after it): the choice and the gates come
+    from it and the router's gradient goes to it, the rows' to ``tokens``;
+    ``act`` is the experts' gate activation, ``"silu"`` (SwiGLU) or
+    ``"relu"`` (ReGLU). Returns ``(out, aux)``:
+    ``out[p] = sum_{e chosen by p and held} gate[p, e] * down_e(act(gate_e
     x) * up_e x)`` in ``dtype``; the normalisation stays over all chosen
     experts, held or not, so that the shares of all holders add up to the
     whole layer. ``aux`` holds ``group_sizes`` (held,), the rows each held
@@ -415,8 +426,15 @@ def routed_share(tokens, router, w_gate, w_up, w_down, *, first, top_k: int,
     """
     n, d = tokens.shape
     held = w_gate.shape[0]
+    if act not in _ACTS:
+        raise ValueError(f"unknown act {act!r}; expected one of "
+                         f"{sorted(_ACTS)}")
+    read = tokens if route_from is None else route_from
+    if read.shape != tokens.shape:
+        raise ValueError(f"route_from {read.shape} is not the tokens' "
+                         f"{tokens.shape}")
     with _tracing.scope("moe/route"):
-        logits = jnp.dot(tokens.astype(jnp.float32), router,
+        logits = jnp.dot(read.astype(jnp.float32), router,
                          precision=jax.lax.Precision.HIGHEST)
         if score == "softmax":
             probs = jax.nn.softmax(logits, axis=-1)
@@ -455,14 +473,16 @@ def routed_share(tokens, router, w_gate, w_up, w_down, *, first, top_k: int,
     # the loop over windows with its carries, and what a window does
     with _tracing.scope("moe/experts"):
         order = jnp.pad(order, (0, max(0, cover - order.size)))[:cover]
-        out = _share(tight, top_k, order, group_sizes, tokens.astype(dtype),
+        out = _share(tight, top_k, act, order, group_sizes,
+                     tokens.astype(dtype),
                      w, w_gate.astype(dtype), w_up.astype(dtype),
                      w_down.astype(dtype))
     return out, {"group_sizes": group_sizes, "choice": choice}
 
 
 class RoutedExperts(nn.Module):
-    """Dropless top-k routed SwiGLU experts (no bias, no auxiliary loss; a
+    """Dropless top-k routed gated experts (SwiGLU, or ReGLU with
+    ``act="relu"``; no bias, no auxiliary loss; a
     shared expert is :class:`SharedExpert`, beside it), told which experts
     it holds: ``experts_held = (first,
     count)`` of ``experts_total``. The router keeps its full width. With
@@ -484,7 +504,10 @@ class RoutedExperts(nn.Module):
     ``score``, ``norm_eps`` and ``scale`` are the routing rule's
     (:func:`routed_share`); ``select_bias`` (experts_total,) is handed to
     the call, because it is a buffer that its holder keeps and moves, not a
-    parameter: it has no gradient and no optimizer state.
+    parameter: it has no gradient and no optimizer state. ``route_from``
+    (B, T, D), also handed to the call, is what the router reads where that
+    is not ``x`` (:func:`routed_share`); under ``ep_axis`` it is gathered
+    beside ``x``, a second stream of the same size.
 
     Returns ``out`` (B, T, D); ``group_sizes`` and ``choice`` are sown into
     the ``"intermediates"`` collection.
@@ -499,9 +522,11 @@ class RoutedExperts(nn.Module):
     score: str = "softmax"
     norm_eps: float = 0.0
     scale: float = 1.0
+    act: str = "silu"
 
     @nn.compact
-    def __call__(self, x: jnp.ndarray, select_bias=None) -> jnp.ndarray:
+    def __call__(self, x: jnp.ndarray, select_bias=None,
+                 route_from=None) -> jnp.ndarray:
         b, t, d = x.shape
         first, held = self.experts_held
         if not 0 <= first <= first + held <= self.experts_total or held < 1:
@@ -520,15 +545,20 @@ class RoutedExperts(nn.Module):
             routed_share, router=router, w_gate=w_gate, w_up=w_up,
             w_down=w_down, top_k=self.top_k, norm_topk=self.norm_topk,
             dtype=self.dtype, score=self.score, select_bias=select_bias,
-            norm_eps=self.norm_eps, scale=self.scale)
+            norm_eps=self.norm_eps, scale=self.scale, act=self.act)
         tokens = x.reshape(b * t, d)
+        if route_from is not None:
+            route_from = route_from.reshape(b * t, d)
         if self.ep_axis is None:
-            out, aux = share(tokens, first=first)
+            out, aux = share(tokens, first=first, route_from=route_from)
         else:
-            everyone = jax.lax.all_gather(tokens, self.ep_axis, axis=0,
-                                          tiled=True)
+            everyone = lambda rows: jax.lax.all_gather(
+                rows, self.ep_axis, axis=0, tiled=True)
             out, aux = share(
-                everyone, first=jax.lax.axis_index(self.ep_axis) * held)
+                everyone(tokens),
+                first=jax.lax.axis_index(self.ep_axis) * held,
+                route_from=(None if route_from is None
+                            else everyone(route_from)))
             out = jax.lax.psum_scatter(out, self.ep_axis,
                                        scatter_dimension=0, tiled=True)
         tight, rows = row_bounds(aux["choice"].shape[0], self.top_k, held,

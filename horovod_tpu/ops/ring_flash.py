@@ -256,8 +256,8 @@ def ring_flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                          block_k: Optional[int] = None,
                          layout: str = "contiguous",
                          key_mask: Optional[jnp.ndarray] = None,
-                         segment_ids: Optional[jnp.ndarray] = None
-                         ) -> jnp.ndarray:
+                         segment_ids: Optional[jnp.ndarray] = None,
+                         window: Optional[int] = None) -> jnp.ndarray:
     """Exact attention with q/k/v sequence-sharded across ``axis_name``.
 
     Same contract as ``ring_attention`` (including the ``layout`` arg),
@@ -292,9 +292,17 @@ def ring_flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         sequence-packing segment ids. The k-side copy travels around the
         ring with its K/V block; each hop's kernel masks score tiles to
         same-segment (home-q, resident-k) pairs.
+      window: not built. A sliding window over a ring needs each hop's
+        kernel told how far its keys lie behind its queries and the hops
+        wholly under the band left out; a value raises rather than attend
+        over the whole causal triangle.
 
     Returns (batch, t_local, heads, head_dim), dtype of ``q``.
     """
+    if window is not None:
+        raise ValueError(
+            f"window={window}: ring flash attention has no sliding window "
+            "(flash_attention on one device has)")
     b, t, h, d = q.shape
     scale = d ** -0.5 if scale is None else scale
     if block_q is None or block_k is None:

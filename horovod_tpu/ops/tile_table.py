@@ -22,15 +22,19 @@ Table file: ``flash_tiles.json`` next to this module (override with
 
 An entry from the forward + backward sweep (``tools/tune_tiles.py
 --fwdbwd``, source ``tuned-*-fwdbwd``) may also carry ``block_q_bwd`` /
-``block_k_bwd`` and, for a causal shape, ``chunk`` / ``chunk_bwd``: the keys
+``block_k_bwd`` and, for a causal or window shape, ``chunk`` /
+``chunk_bwd``: the keys
 of the resident K tile that one pass of the kernels' inner loop takes
 (``lookup_full``).
 
-``kind`` is one of "causal" | "full" | "ring" | "block_diffusion" (the ring
-kernel's VMEM profile differs: its per-hop seq is the local shard and the
-backward is an explicit second ring; a block-diffusion row is ``[noisy ;
-clean]``, ``seq`` counts both halves, and its tiles are skipped along three
-diagonals). Lookup is nearest-match: exact kind and dtype
+``kind`` is one of "causal" | "full" | "ring" | "block_diffusion" | "window"
+(the ring kernel's VMEM profile differs: its per-hop seq is the local shard
+and the backward is an explicit second ring; a block-diffusion row is
+``[noisy ; clean]``, ``seq`` counts both halves, and its tiles are skipped
+along three diagonals; a window is a causal band, whose tiles are skipped
+above the diagonal and under the band: an entry may say the ``window`` it
+was measured at, which no lookup reads). Lookup is nearest-match: exact kind
+and dtype
 preferred, then closest head_dim and seq in log space — so one measured
 point generalises to neighbouring shapes until the tuner fills them in.
 """
@@ -51,7 +55,7 @@ __all__ = ["lookup", "lookup_full", "record", "load_table", "save_table",
            "table_path", "DEFAULT_TILES", "KINDS"]
 
 DEFAULT_TILES = (256, 512)   # measured fastest on v5e for fwd+bwd (round 1)
-KINDS = ("causal", "full", "ring", "block_diffusion")
+KINDS = ("causal", "full", "ring", "block_diffusion", "window")
 
 _lock = threading.Lock()
 # path -> (mtime_ns, parsed table); one live version per path, so tuner
@@ -232,7 +236,8 @@ def record(head_dim: int, seq: int, dtype, kind: str, block_q: int,
            block_q_bwd: Optional[int] = None,
            block_k_bwd: Optional[int] = None,
            chunk: Optional[int] = None,
-           chunk_bwd: Optional[int] = None) -> Path:
+           chunk_bwd: Optional[int] = None,
+           window: Optional[int] = None) -> Path:
     """Insert-or-replace one measured entry and rewrite the table file."""
     if kind not in KINDS:
         raise ValueError(f"unknown tile kind {kind!r}; expected one of "
@@ -261,5 +266,7 @@ def record(head_dim: int, seq: int, dtype, kind: str, block_q: int,
         entry["chunk"] = int(chunk)
     if chunk_bwd is not None:
         entry["chunk_bwd"] = int(chunk_bwd)
+    if window is not None:
+        entry["window"] = int(window)
     table["entries"].append(entry)
     return save_table(table, p)

@@ -21,8 +21,8 @@ scope or a span counter:
   (:func:`mark_synced`, :func:`synced_as`), so that a second pass over
   them lowers nothing.
 * **The routing manifest** — what the routed expert layers, the
-  block-diffusion and causal attention calls and the latent attention of a
-  program are shaped for, noted while the program is traced
+  block-diffusion, causal and sliding-window attention calls and the latent
+  attention of a program are shaped for, noted while the program is traced
   (:func:`note_routing`), and how the routing of one batch loaded the
   experts held (:func:`routing_load`).
 * **The remat count** — how often a block's remat policy kept a residual
@@ -215,6 +215,7 @@ _MODELS = "models (models/gpt2, remat)"
 _SDAR = "models (models/sdar, remat)"
 _LFM2 = "models (models/lfm2, remat)"
 _GLM4 = "models (models/glm4_moe_lite, remat)"
+_SMALLTHINKER = "models (models/smallthinker, remat)"
 _EXPERTS = "expert layer (ops/moe)"
 _KERNELS = "kernels (ops/flash_attention)"
 _ENGINE = "engine (serving/engine, scheduler, cache)"
@@ -379,6 +380,26 @@ NAMES: Dict[str, Name] = {
         "scope", _GLM4, "models.glm4_moe_lite.loss_terms: both passes of "
         "the untied head over the vocabulary slice, log-sum-exp minus the "
         "target's logit", "loss_head_ms.train_glm4"),
+    "smallthinker/block": Name(
+        "scope", _SMALLTHINKER, "models.smallthinker: all of a block: its "
+        "own part is the two norms and the residual adds (the attention's "
+        "scope nests inside; the route made from the block's input before "
+        "the first norm is moe/route, the share moe/experts)",
+        _PRINTED + ": its own part, and all it holds"),
+    "smallthinker/attn_global": Name(
+        "scope", _SMALLTHINKER, "models.smallthinker: a global layer's "
+        "attention: the projections, no positional encoding, the causal "
+        "attention call over every key and the output projection",
+        _PRINTED + "; its kernels: swa_flash_time_share.train"),
+    "smallthinker/attn_window": Name(
+        "scope", _SMALLTHINKER, "models.smallthinker: a window layer's "
+        "attention: the projections, RoPE, the causal attention call under "
+        "the sliding window and the output projection",
+        "window_attn_ms.train"),
+    "smallthinker/loss_head": Name(
+        "scope", _SMALLTHINKER, "models.smallthinker.loss_fn: the untied "
+        "head over the vocabulary slice, log-sum-exp minus the target's "
+        "logit", _PRINTED),
     "flash_attention": Name(
         "scope", _KERNELS, "round each flash kernel call, so that jax's "
         "jvp()/transpose() wrap this name and not the kernel's",
@@ -457,6 +478,16 @@ NAMES: Dict[str, Name] = {
         "gauge", _KERNELS, "routing manifest: (Q tile, compute chunk) "
         "pairs of one head's causal forward; label program",
         "causal_tiles_visited_share.train"),
+    "window_tiles_visited": Name(
+        "gauge", _KERNELS, "routing manifest: (Q tile, compute chunk) "
+        "pairs of one head's forward under a sliding window that hold a "
+        "visible pair, the ones the kernels visit: between the band's lower "
+        "edge and the diagonal (ops/flash_attention.window_tiles); label "
+        "program", "window_tiles_visited_share.train"),
+    "window_tiles_total": Name(
+        "gauge", _KERNELS, "routing manifest: (Q tile, compute chunk) "
+        "pairs of one head's forward under a sliding window; label program",
+        "window_tiles_visited_share.train"),
     "flash_bwd_kernels": Name(
         "gauge", _KERNELS, "routing manifest: kernels the backward of the "
         "program's flash attention calls is (as last traced): 1 where the "
@@ -705,13 +736,15 @@ def synced_as(tree: Any) -> Any:
 _ROUTING = ("moe_rows_bound", "moe_rows_tight", "bd_tiles_visited",
             "bd_tiles_total", "causal_tiles_visited", "causal_tiles_total",
             "mla_kv_expanded_bytes", "mla_latent_bytes", "mtp_modules",
-            "flash_bwd_kernels", "flash_bwd_vmem_bytes")
+            "flash_bwd_kernels", "flash_bwd_vmem_bytes",
+            "window_tiles_visited", "window_tiles_total")
 
 
 def note_routing(**shapes) -> None:
-    """A routed expert layer, a block-diffusion or a causal attention call,
-    or a model with latent attention is being traced: what it is shaped
-    for, from static values (:data:`_ROUTING` names them). Published as
+    """A routed expert layer, a block-diffusion, causal or sliding-window
+    attention call, or a model with latent attention is being traced: what
+    it is shaped for, from static values (:data:`_ROUTING` names them).
+    Published as
     gauges ``{program}`` when :func:`program` exits; every layer of a
     program says the same, and the last one stands. Outside a program
     nothing is kept."""

@@ -437,6 +437,90 @@ def test_latent_attention_step_compiles_for_v5e_at_published_widths(
         assert scope in hlo, scope
 
 
+@pytest.mark.parametrize("window", [None, 4096], ids=["causal", "window"])
+def test_flash_compiles_for_v5e_at_the_16k_table_entries(v5e, mosaic, window):
+    """Head size 128, 16,384 positions, 28 query heads a row: the causal
+    kernels' fourth shape in the benchmark, without a window (the global
+    layer) and under one of 4,096 keys, at the table's own entries for the
+    two kinds. K is resident forward and backward, the backward is one
+    kernel whether or not the loop starts at the band's lower edge, and each
+    call compiles with the VMEM the code itself asks for."""
+    from horovod_tpu.ops import tile_table
+    from horovod_tpu.ops.flash_attention import flash_attention
+    fa = importlib.import_module("horovod_tpu.ops.flash_attention")
+    kind = "window" if window else "causal"
+    entry = tile_table._best_entry(128, 16384, "bfloat16", kind, None)
+    assert (entry["head_dim"], entry["seq"], entry["kind"]) == (
+        128, 16384, kind)
+    bq, bk, bqb, bkb, chunk, chunk_bwd = tile_table.lookup_full(
+        128, 16384, "bfloat16", kind)
+    on = SingleDeviceSharding(v5e[0])
+    x = jax.ShapeDtypeStruct((1, 16384, 28, 128), jnp.bfloat16, sharding=on)
+    lowered = jax.jit(lambda q, k, v: jax.grad(
+        lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, window=window).astype(jnp.float32)),
+        argnums=(0, 1, 2))(q, k, v)).lower(x, x, x)
+    assert sorted(_vmem_asked(lowered)) == sorted([
+        fa._vmem_need("fwd", bq, bk, chunk, 128, 2),
+        fa._vmem_need("dkv", bqb, bkb, chunk_bwd, 128, 2, extra="dq")])
+    _assert_kernels_named(lowered.compile().as_text(), bwd_kernels=1)
+
+
+def test_window_attention_step_compiles_for_v5e_and_fits_the_chip(
+        v5e, mosaic, restore_world):
+    """The fifth family's step as the cell runs it: the published widths,
+    one whole period (a global layer without positions, three window layers
+    with RoPE), 8 of 64 experts, the vocabulary slice, 2 rows of 16,384
+    tokens, ``remat=dots``, AdamW: the flash kernels with one backward
+    kernel, XLA's grouped kernel, the family's scopes, and the compiler's
+    count of the step's memory under the chip's 15.75 GiB."""
+    import optax
+    from horovod_tpu.models import smallthinker as st
+    hvd.init(devices=v5e[:1])
+    cfg = st.SmallThinkerConfig(
+        vocab_size=18992, num_layers=4, sliding_window_layout=(0, 1, 1, 1),
+        rope_layout=(0, 1, 1, 1), experts_held=(0, 8), attention="flash",
+        remat=True, remat_policy="dots")
+    model = st.SmallThinker(cfg)
+    opt = hvd.DistributedOptimizer(optax.adamw(1e-5))
+
+    def train_step(params, opt_state, tokens):
+        loss, grads = hvd.value_and_grad(
+            lambda p: st.loss_fn(model, p, tokens))(params)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return (optax.apply_updates(params, updates), opt_state, loss,
+                optax.global_norm(grads))
+
+    step = hvd.spmd(train_step, in_specs=(P(), P(), P("hvd")),
+                    out_specs=(P(), P(), P(), P()), donate_argnums=(0, 1))
+    replicated = NamedSharding(hvd.mesh(), P())
+    twin = st.SmallThinker(dataclasses.replace(cfg, attention="dense",
+                                               remat=False))
+    params = jax.eval_shape(lambda: twin.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    assert sum(x.size for x in jax.tree_util.tree_leaves(params)) \
+        == 370_547_200
+    tokens = jax.ShapeDtypeStruct((2, 16384), jnp.int32,
+                                  sharding=hvd.spmd_data_sharding())
+    compiled = step.lower(
+        _shapes(params, replicated),
+        _shapes(jax.eval_shape(opt.init, params), replicated),
+        tokens).compile()
+    mem = compiled.memory_analysis()
+    counted = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+               - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+               + mem.generated_code_size_in_bytes)
+    assert 10e9 < counted < 15.75 * 2 ** 30, counted
+    hlo = compiled.as_text()
+    _assert_kernels_named(hlo, bwd_kernels=1)
+    assert len(_kernel_calls(hlo, "flash_fwd")) == 4     # dots keeps them
+    assert re.search(r"%ragged-dot[^\n]* = [^\n]*custom-call\(", hlo)
+    for scope in ("smallthinker/block", "smallthinker/attn_global",
+                  "smallthinker/attn_window", "smallthinker/loss_head",
+                  "moe/route", "moe/experts", "flash/layout"):
+        assert scope in hlo, scope
+
+
 def test_engine_programs_compile_for_v5e_with_cache_donation(
         v5e, restore_world, monkeypatch):
     from horovod_tpu.serving import InferenceEngine

@@ -744,3 +744,234 @@ def test_ring_flash_backward_stays_two_kernels(monkeypatch):
         calls = _kernel_calls(text)
         assert calls["flash_dq"] == calls["flash_dkv"] > 0
         assert "vmem_limit_bytes" not in text
+
+
+# --- the sliding window (causal with a lower edge) -------------------------
+
+def _window_dense_loss(q, k, v, tgt, window, offset=0, seg=None):
+    """softmax attention over ``0 <= t + offset - j < window`` (and segment
+    ids), in plain jnp; rows with no visible key give zeros."""
+    t, d = q.shape[1], q.shape[-1]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * d ** -0.5
+    back = jnp.arange(t)[:, None] + offset - jnp.arange(t)[None, :]
+    ok = ((back >= 0) & (back < window))[None, None]
+    if seg is not None:
+        ok = ok & (seg[:, None, :, None] == seg[:, None, None, :])
+    p = jax.nn.softmax(jnp.where(ok, s, -1e30), axis=-1)
+    p = jnp.where(jnp.any(ok, axis=-1, keepdims=True), p, 0.0)
+    return jnp.mean((jnp.einsum("bhqk,bkhd->bqhd", p, v) - tgt) ** 2)
+
+
+# (T, window, block_q, block_k, chunk, causal_offset, segment ids)
+_WINDOWED = {
+    # the plain grid: tiles under the band and over the diagonal skipped
+    "grid": (128, 40, 32, 32, None, 0, False),
+    "grid_window_under_a_tile": (128, 8, 32, 32, None, 0, False),
+    "grid_ragged": (100, 24, 32, 16, None, 0, False),
+    "grid_unequal_tiles": (200, 64, 16, 64, None, 0, True),
+    "grid_offset-1": (128, 40, 32, 32, None, -1, False),
+    # the loop over chunks of a resident K tile: from the band to the
+    # diagonal, the chunks neither edge crosses unmasked between
+    "chunked": (256, 100, 32, 256, 32, 0, False),
+    "chunked_window_under_a_chunk": (128, 8, 32, 128, 32, 0, False),
+    "chunked_one_key": (96, 1, 32, 128, 32, 0, False),
+    "chunked_ragged_packed": (200, 64, 64, 256, 16, 0, True),
+    "chunked_chunk_over_q": (256, 100, 32, 256, 64, 0, False),
+    "chunked_offset-1": (256, 100, 64, 256, 32, -1, False),
+}
+
+
+@pytest.mark.parametrize("case", list(_WINDOWED), ids=list(_WINDOWED))
+def test_window_kernels_match_the_dense_mask(rng, case):
+    """``window=W``: forward and all three gradients against dense attention
+    under the band, at T not a multiple of the tiles, W smaller and larger
+    than a tile, with segment ids, on the plain grid and under a chunk (the
+    one-kernel backward: no ``flash_dq``)."""
+    fa = _fa()
+    T, W, bq, bk, chunk, offset, packed = _WINDOWED[case]
+    B, H, D = 2, 2, 8
+    q, k, v, tgt = (jnp.asarray(rng.standard_normal((B, T, H, D)),
+                                jnp.float32) for _ in range(4))
+    seg = (jnp.asarray(np.sort(rng.integers(0, 3, (B, T)), axis=1),
+                       jnp.int32) if packed else None)
+    tiles = (bq, bk, bq, bk, chunk, chunk)
+
+    def loss_flash(q, k, v):
+        o = fa._attend(q, k, v, True, D ** -0.5, None, seg, tiles, offset,
+                       None, W)
+        return jnp.mean((o - tgt) ** 2)
+
+    text = str(jax.make_jaxpr(jax.grad(loss_flash, (0, 1, 2)))(q, k, v))
+    # three runs of chunks in each of the two kernels, or none
+    assert text.count("while[") == (6 if chunk else 0)
+    assert _kernel_calls(text)["flash_dq"] == (0 if chunk else 1)
+    lf, gf = jax.value_and_grad(loss_flash, (0, 1, 2))(q, k, v)
+    ld, gd = jax.value_and_grad(
+        lambda q, k, v: _window_dense_loss(q, k, v, tgt, W, offset, seg),
+        (0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(float(lf), float(ld), rtol=1e-5)
+    for a, b in zip(gf, gd):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-3, atol=1e-5)
+
+
+@pytest.mark.parametrize("impl", ["dense", "flash"])
+def test_multihead_attention_takes_a_window(rng, impl):
+    """Both impls of the zoo's dispatch, and a key-padding mask beside
+    the window (the flash kernels' key bias composes with it)."""
+    from horovod_tpu.ops.attention import multihead_attention
+    B, T, H, D, W = 2, 48, 2, 8, 12
+    q, k, v, tgt = (jnp.asarray(rng.standard_normal((B, T, H, D)),
+                                jnp.float32) for _ in range(4))
+    got = multihead_attention(q, k, v, impl=impl, causal=True, window=W,
+                              flash_blocks=(16, 16))
+    want = jax.grad(lambda v: _window_dense_loss(q, k, v, tgt, W))(v)
+    got_grad = jax.grad(lambda v: jnp.mean((multihead_attention(
+        q, k, v, impl=impl, causal=True, window=W,
+        flash_blocks=(16, 16)) - tgt) ** 2))(v)
+    np.testing.assert_allclose(np.asarray(got_grad), np.asarray(want),
+                               rtol=1e-3, atol=1e-6)
+    mask = jnp.asarray(rng.random((B, T)) > 0.3)
+    masked = multihead_attention(q, k, v, impl=impl, causal=True, window=W,
+                                 key_mask=mask, flash_blocks=(16, 16))
+    other = multihead_attention(q, k, v, impl="dense" if impl == "flash"
+                                else "flash", causal=True, window=W,
+                                key_mask=mask, flash_blocks=(16, 16))
+    np.testing.assert_allclose(np.asarray(masked), np.asarray(other),
+                               rtol=1e-4, atol=1e-5)
+    assert np.abs(np.asarray(masked) - np.asarray(got)).max() > 1e-3
+
+
+@pytest.mark.parametrize("tiles", [(16, 16, 16, 16, None, None),
+                                   (16, 64, 16, 64, 16, 16)],
+                         ids=["grid", "chunked"])
+def test_a_window_that_holds_every_key_is_the_causal_mask(rng, tiles):
+    """W >= T: the public call runs the causal kernels themselves (the
+    same jaxpr, the causal gauges), and the window kernels told to mask an
+    edge no row reaches give the causal result."""
+    import horovod_tpu as hvd
+    from horovod_tpu import tracing
+    fa = _fa()
+    B, T, H, D = 1, 64, 2, 8
+    q, k, v = (jnp.asarray(rng.standard_normal((B, T, H, D)), jnp.float32)
+               for _ in range(3))
+    call = lambda **kw: (lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=True, block_q=16, block_k=16, **kw))
+    causal = str(jax.make_jaxpr(call())(q, k, v))
+    assert str(jax.make_jaxpr(call(window=T))(q, k, v)) == causal
+    assert str(jax.make_jaxpr(call(window=T - 1))(q, k, v)) != causal
+    with tracing.program("window_all"):
+        jax.make_jaxpr(call(window=5 * T))(q, k, v)
+    gauges = hvd.metrics.snapshot()["gauges"]
+    said = lambda name: [s["value"] for s in gauges.get(name, ())
+                         if s["labels"].get("program") == "window_all"]
+    assert said("causal_tiles_visited") and not said("window_tiles_visited")
+    attend = lambda window: jax.value_and_grad(lambda q, k, v: jnp.sum(
+        fa._attend(q, k, v, True, D ** -0.5, None, None, tiles, 0, None,
+                   window) ** 2), (0, 1, 2))(q, k, v)
+    for a, b in zip(jax.tree_util.tree_leaves(attend(T)),
+                    jax.tree_util.tree_leaves(attend(None))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_no_window_traces_as_it_did(rng):
+    """``window=None`` adds nothing: the default call's jaxpr, forward and
+    gradient, on the plain grid and under a chunk, is that of the call that
+    never heard of a window, and holds neither the band's comparison nor
+    its third run of chunks (the parent's jaxprs, compared once against a
+    checkout of it: CHANGES.md)."""
+    fa = _fa()
+    q = jnp.zeros((1, 128, 2, 8), jnp.float32)
+    grad = lambda fn: str(jax.make_jaxpr(jax.grad(
+        lambda q, k, v: jnp.sum(fn(q, k, v)), (0, 1, 2)))(q, q, q))
+    for tiles, loops in (((32, 32, 32, 32, None, None), 0),
+                         ((32, 128, 32, 128, 32, 32), 4)):
+        plain = grad(lambda q, k, v: fa._attend(
+            q, k, v, True, 8 ** -0.5, None, None, tiles))
+        assert plain == grad(lambda q, k, v: fa._attend(
+            q, k, v, True, 8 ** -0.5, None, None, tiles, 0, None, None))
+        assert plain.count("while[") == loops
+        windowed = grad(lambda q, k, v: fa._attend(
+            q, k, v, True, 8 ** -0.5, None, None, tiles, 0, None, 40))
+        assert windowed != plain
+        assert windowed.count("while[") == loops * 3 // 2
+
+
+@pytest.mark.parametrize("t,window,block_q,block_k,chunk,offset", [
+    (16384, 4096, 512, 16384, 512, 0),      # the benchmark's: 252 of 1024
+    (1024, 256, 256, 1024, 256, 0),
+    (1024, 100, 128, 1024, 64, 0),          # W under a chunk and a tile
+    (1024, 300, 256, 1024, 512, 0),         # chunk over the Q tile
+    (200, 64, 32, 256, 16, 0),              # ragged
+    (1024, 256, 256, 256, None, 0),         # no chunk: the grid's tiles
+    (1000, 130, 128, 64, None, 0),
+    (256, 100, 64, 256, 32, -1),            # strict causal
+    (1024, 1, 128, 1024, 128, 0),           # a row sees itself alone
+])
+def test_window_tiles_counts_what_the_dense_mask_shows(t, window, block_q,
+                                                       block_k, chunk,
+                                                       offset):
+    fa = _fa()
+    c = chunk if chunk and block_k >= t else min(block_k, t)
+    back = np.arange(t)[:, None] + offset - np.arange(t)[None, :]
+    mask = (back >= 0) & (back < window)
+    nq, nc = -(-t // block_q), -(-t // c)
+    padded = np.zeros((nq * block_q, nc * c), bool)
+    padded[:t, :t] = mask
+    seen = padded.reshape(nq, block_q, nc, c).any(axis=(1, 3))
+    assert fa.window_tiles(t, window, block_q, block_k, chunk, offset) == (
+        int(seen.sum()), nq * nc)
+    if t == 16384:
+        assert (int(seen.sum()), nq * nc) == (252, 1024)
+        assert fa.causal_tiles(t, block_q, block_k, chunk) == (528, 1024)
+        assert 100 * mask.mean() == pytest.approx(21.9, abs=0.05)
+
+
+def test_the_chunks_between_the_edges_take_no_mask():
+    """``_window_chunks``: against each Q tile, the chunks before
+    ``inside`` are crossed by the band's lower edge, those from ``clear`` by
+    the diagonal, and those between lie whole inside the band."""
+    fa = _fa()
+    for t, w, bq, c in ((1024, 256, 128, 64), (1024, 100, 128, 64),
+                        (512, 300, 64, 128), (200, 64, 32, 16)):
+        back = np.arange(t)[:, None] - np.arange(t)[None, :]
+        mask = (back >= 0) & (back < w)
+        nq, nc = -(-t // bq), -(-t // c)
+        padded = np.zeros((nq * bq, nc * c), bool)
+        padded[:t, :t] = mask
+        tiles = padded.reshape(nq, bq, nc, c)
+        rows = (np.arange(nq * bq) < t).reshape(nq, bq)
+        cols = (np.arange(nc * c) < t).reshape(nc, c)
+        first, inside, clear, visible = fa._window_chunks(
+            np.arange(nq), bq, c, 0, t, w, xp=np)
+        for i in range(nq):
+            real = rows[i][:, None] & cols[:, None, :]      # (nc, bq, c)
+            whole = (tiles[i].transpose(1, 0, 2) | ~real).all(axis=(1, 2))
+            some = tiles[i].any(axis=(0, 2))
+            assert some[first[i]:visible[i]].all()
+            assert not some[:first[i]].any() and not some[visible[i]:].any()
+            assert whole[inside[i]:clear[i]].all(), (t, w, bq, c, i)
+            assert first[i] <= inside[i] <= clear[i] <= visible[i]
+
+
+def test_a_window_is_refused_where_it_is_not_built(rng):
+    from jax.sharding import Mesh, PartitionSpec as P
+    from horovod_tpu.ops.attention import multihead_attention
+    from horovod_tpu.ops.ring_flash import ring_flash_attention
+    x = jnp.zeros((1, 16, 2, 8), jnp.float32)
+    with pytest.raises(ValueError, match="needs causal=True"):
+        flash_attention(x, x, x, causal=False, window=4)
+    with pytest.raises(ValueError, match="at least one key"):
+        flash_attention(x, x, x, causal=True, window=0)
+    with pytest.raises(ValueError, match="block_diffusion"):
+        flash_attention(x, x, x, block_diffusion=(8, 4), window=4)
+    with pytest.raises(ValueError, match="needs causal=True"):
+        multihead_attention(x, x, x, impl="dense", causal=False, window=4)
+    mesh = Mesh(np.array(jax.devices()[:2]), ("sp",))
+    ring = jax.shard_map(
+        lambda q, k, v: ring_flash_attention(q, k, v, "sp", causal=True,
+                                             window=4),
+        mesh=mesh, in_specs=(P(None, "sp"),) * 3, out_specs=P(None, "sp"),
+        check_vma=False)
+    with pytest.raises(ValueError, match="no sliding window"):
+        jax.make_jaxpr(ring)(x, x, x)

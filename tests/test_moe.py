@@ -190,3 +190,139 @@ class TestTop2Router:
         m = MoEMLP(2, 8, router_type="topk")
         with pytest.raises(ValueError, match="router_type"):
             m.init(jax.random.PRNGKey(0), x)
+
+
+# --- the dropless layer: a route made elsewhere, and ReGLU -----------------
+
+def _dropless(rng, n=24, d=16, f=8, experts=8):
+    """Tokens, a second stream, and the weights of ``experts`` experts."""
+    draw = lambda *shape: jnp.asarray(rng.standard_normal(shape),
+                                      jnp.float32)
+    return (draw(n, d), draw(n, d), draw(d, experts),
+            draw(experts, d, f) / 4, draw(experts, d, f) / 4,
+            draw(experts, f, d) / 4)
+
+
+def _dense_layer(tokens, read, router, w_gate, w_up, w_down, top_k, act,
+                 held=None):
+    """The whole layer (or the experts ``held``) in plain jnp: every
+    position through every expert, weighted by its gate or zero."""
+    probs = jax.nn.softmax(read @ router, axis=-1)
+    gate, choice = jax.lax.top_k(probs, top_k)
+    gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    out = jnp.zeros_like(tokens)
+    for e in (held or range(router.shape[1])):
+        w = jnp.sum(jnp.where(choice == e, gate, 0.0), axis=-1)
+        y = (act(tokens @ w_gate[e]) * (tokens @ w_up[e])) @ w_down[e]
+        out = out + w[:, None] * y
+    return out
+
+
+@pytest.mark.parametrize("act", ["relu", "silu"])
+def test_routed_share_routes_from_a_second_stream(rng, act):
+    """``route_from``: the choice and the gates come from the second stream
+    and the rows from the tokens, for either activation; every gradient is
+    the dense layer's, so the router's goes to the second stream and only
+    the rows' to the tokens."""
+    from horovod_tpu.ops import moe
+    tokens, read, router, w_gate, w_up, w_down = _dropless(rng)
+    fn = {"relu": jax.nn.relu, "silu": jax.nn.silu}[act]
+    first, held = 2, 4
+    target = jnp.asarray(rng.standard_normal(tokens.shape), jnp.float32)
+
+    def share(tokens, read, router, w_gate, w_up, w_down):
+        out, aux = moe.routed_share(
+            tokens, router, w_gate[first:first + held],
+            w_up[first:first + held], w_down[first:first + held],
+            first=first, top_k=3, dtype=jnp.float32, route_from=read,
+            act=act)
+        return jnp.sum(out * target), aux
+
+    def dense(tokens, read, router, w_gate, w_up, w_down):
+        return jnp.sum(_dense_layer(
+            tokens, read, router, w_gate, w_up, w_down, 3, fn,
+            held=range(first, first + held)) * target)
+
+    args = (tokens, read, router, w_gate, w_up, w_down)
+    (got, aux), grads = jax.value_and_grad(share, range(6), has_aux=True)(
+        *args)
+    want, wanted = jax.value_and_grad(dense, range(6))(*args)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+    for a, b in zip(grads, wanted):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
+                                   atol=2e-5)
+    assert float(jnp.abs(grads[1]).max()) > 0       # the second stream's
+    # the choices are the second stream's, not the tokens'
+    probs = jax.nn.softmax(read @ router, axis=-1)
+    np.testing.assert_array_equal(np.asarray(aux["choice"]),
+                                  np.asarray(jax.lax.top_k(probs, 3)[1]))
+    mine = jax.nn.softmax(tokens @ router, axis=-1)
+    assert (np.asarray(jax.lax.top_k(mine, 3)[1])
+            != np.asarray(aux["choice"])).any()
+
+
+def test_the_dropless_defaults_trace_as_they_did(rng):
+    """``route_from=None`` and ``act="silu"`` add nothing: the default
+    call's jaxpr, forward and gradient, is that of the call that names
+    them, holds a logistic (the experts' silu) and no relu (the parent's jaxpr, compared once against a checkout of it:
+    CHANGES.md); and a second stream equal to the tokens gives the same
+    numbers."""
+    from horovod_tpu.ops import moe
+    tokens, read, router, w_gate, w_up, w_down = _dropless(rng)
+    args = (tokens, router, w_gate[:4], w_up[:4], w_down[:4])
+    fn = lambda **kw: (lambda *a: jnp.sum(moe.routed_share(
+        *a, first=0, top_k=3, **kw)[0].astype(jnp.float32)))
+    for trace in (lambda f: str(jax.make_jaxpr(f)(*args)),
+                  lambda f: str(jax.make_jaxpr(jax.grad(f, range(5)))(
+                      *args))):
+        default = trace(fn())
+        assert default == trace(fn(route_from=None, act="silu"))
+        assert " logistic " in default and "name=relu" not in default
+        relu = trace(fn(act="relu"))
+        assert " logistic " not in relu and "name=relu" in relu
+    same = jax.grad(fn(route_from=tokens, dtype=jnp.float32), (0, 1))(*args)
+    plain = jax.grad(fn(dtype=jnp.float32), (0, 1))(*args)
+    # the tokens' gradient is then the rows' part alone, the router's whole
+    np.testing.assert_allclose(np.asarray(same[1]), np.asarray(plain[1]),
+                               rtol=1e-5, atol=1e-6)
+    assert np.abs(np.asarray(same[0]) - np.asarray(plain[0])).max() > 1e-4
+    with pytest.raises(ValueError, match="unknown act"):
+        fn(act="gelu")(*args)
+    with pytest.raises(ValueError, match="is not the tokens'"):
+        fn(route_from=read[:5])(*args)
+
+
+def test_routed_experts_over_ep_gathers_the_second_stream(rng):
+    """``RoutedExperts(act="relu")`` fed ``route_from`` under an ``ep`` axis
+    of 4: each peer's positions and their second stream are gathered, the
+    shares reduce-scattered; the result and the gradient of both streams
+    are the uncut dense layer's."""
+    from jax.sharding import Mesh
+    from horovod_tpu.ops import moe
+    tokens, read, router, w_gate, w_up, w_down = _dropless(rng, n=32)
+    mesh = Mesh(np.array(jax.devices()[:4]), ("ep",))
+    layer = moe.RoutedExperts(8, (0, 2), 3, 8, dtype=jnp.float32,
+                              ep_axis="ep", act="relu")
+
+    def run(x, r, router, w_gate, w_up, w_down):
+        params = {"router": router, "w_gate": w_gate, "w_up": w_up,
+                  "w_down": w_down}
+        return layer.apply({"params": params}, x[None],
+                           route_from=r[None])[0]
+
+    sharded = jax.shard_map(
+        run, mesh=mesh,
+        in_specs=(P("ep"), P("ep"), P(), P("ep"), P("ep"), P("ep")),
+        out_specs=P("ep"))
+    weights = (router, w_gate, w_up, w_down)
+    target = jnp.asarray(rng.standard_normal(tokens.shape), jnp.float32)
+    got, grads = jax.value_and_grad(
+        lambda x, r: jnp.sum(sharded(x, r, *weights) * target), (0, 1))(
+            tokens, read)
+    want, wanted = jax.value_and_grad(
+        lambda x, r: jnp.sum(_dense_layer(x, r, *weights, 3, jax.nn.relu)
+                             * target), (0, 1))(tokens, read)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+    for a, b in zip(grads, wanted):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
+                                   atol=2e-5)

@@ -315,10 +315,11 @@ def test_what_the_grouped_products_leave_past_their_groups_is_not_read(
     out, _, grads = _cut_grad(*args)
     ffn, traced = moe._ffn, []
 
-    def poisoned(xs, w_gate, w_up, w_down, sizes):
+    def poisoned(xs, w_gate, w_up, w_down, sizes, act="silu"):
         traced.append(xs.shape)
         past = jnp.arange(xs.shape[0])[:, None] >= jnp.sum(sizes)
-        return jnp.where(past, jnp.nan, ffn(xs, w_gate, w_up, w_down, sizes))
+        return jnp.where(past, jnp.nan,
+                         ffn(xs, w_gate, w_up, w_down, sizes, act))
 
     monkeypatch.setattr(moe, "_ffn", poisoned)
     again_out, _, again = _cut_grad(*args)

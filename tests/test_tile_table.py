@@ -9,6 +9,7 @@ data via ``tools/tune_tiles.py``.
 
 import json
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -490,3 +491,87 @@ def test_a_key_axis_too_long_for_a_cores_vmem_runs_the_plain_grid():
                       itemsize=2) == (256, 512, None)
     assert fa._tiling(16384, 16384, 512, 8192, 1024, True, None, d=64,
                       itemsize=2, kernel="dkv") == (512, 1024, None)
+
+
+# --- the window kind (PR 37) ------------------------------------------------
+
+def test_the_window_kind_round_trips_with_its_window(tmp_table):
+    tile_table.record(128, 16384, "bfloat16", "window", 512, 16384,
+                      us_per_call=1.0, path=tmp_table, block_q_bwd=256,
+                      block_k_bwd=16384, chunk=512, chunk_bwd=1024,
+                      window=4096)
+    assert tile_table.lookup_full(128, 16384, "bfloat16", "window",
+                                  path=tmp_table) == (
+        512, 16384, 256, 16384, 512, 1024)
+    entry = [e for e in tile_table.load_table(tmp_table)["entries"]
+             if e["kind"] == "window"]
+    assert len(entry) == 1 and entry[0]["window"] == 4096
+    # another kind's entry at the same shape is another entry
+    assert tile_table.lookup(128, 16384, "bfloat16", "causal",
+                             path=tmp_table) != (512, 16384)
+
+
+def test_flash_attention_asks_for_the_window_kind(monkeypatch):
+    """A windowed call looks its tiles up under ``window``; a window that
+    holds every key is the causal mask and asks for that kind."""
+    fa = _fa()
+    calls = []
+    real = tile_table.lookup_full
+
+    def spy(head_dim, seq, dtype, kind, path=None):
+        calls.append((head_dim, seq, str(dtype), kind))
+        return real(head_dim, seq, dtype, kind, path)
+
+    monkeypatch.setattr(tile_table, "lookup_full", spy)
+    q = jnp.zeros((1, 64, 2, 16), jnp.float32)
+    jax.make_jaxpr(lambda q: fa.flash_attention(q, q, q, causal=True,
+                                                window=16))(q)
+    jax.make_jaxpr(lambda q: fa.flash_attention(q, q, q, causal=True,
+                                                window=64))(q)
+    assert calls == [(16, 64, "float32", "window"),
+                     (16, 64, "float32", "causal")]
+
+
+def test_autotune_sweeps_and_records_under_a_window(tmp_path):
+    from horovod_tpu.autotune import autotune_flash_blocks
+    p = tmp_path / "tuned.json"
+    best, trials = autotune_flash_blocks(
+        (1, 64, 2, 16), dtype="float32", causal=True, window=24,
+        candidates=[(16, 16), (16, 64, 16)], steps_per_trial=1, chain=1,
+        include_backward=False, tune_backward=True, record=True,
+        record_kind="window", record_path=p)
+    assert set(trials) == {(16, 16), (16, 64, 16), ("bwd", 16, 16),
+                           ("bwd", 16, 64, 16)}
+    entry = tile_table.load_table(p)["entries"][0]
+    assert (entry["kind"], entry["window"]) == ("window", 24)
+    assert tile_table.lookup_full(16, 64, "float32", "window",
+                                  path=p)[:4] == best[:4]
+
+
+@pytest.mark.parametrize("kind", ["causal", "window"])
+def test_the_16k_entries_keep_k_resident_forward_and_backward(kind):
+    """The two shapes the window/global-attention cell runs at head 128 and
+    T 16,384 come from this PR's forward + backward sweep on the v5e; both
+    keep the whole key axis resident within what a core has, so the
+    backward is one kernel, and the window's loop visits under 30 % of the
+    pairs where the causal one visits over half."""
+    fa = _fa()
+    entry = tile_table._best_entry(128, 16384, "bfloat16", kind, None)
+    assert (entry["head_dim"], entry["seq"], entry["kind"]) == (
+        128, 16384, kind)
+    assert entry["source"] == "tuned-v5e-fwdbwd-pr37"
+    assert entry.get("window") == (4096 if kind == "window" else None)
+    bq, bk, bqb, bkb, chunk, chunk_bwd = tile_table.lookup_full(
+        128, 16384, "bfloat16", kind)
+    assert fa._tiling(16384, 16384, bq, bk, chunk, True, None, d=128,
+                      itemsize=2, kernel="fwd") == (bq, 16384, chunk)
+    assert fa._tiling(16384, 16384, bqb, bkb, chunk_bwd, True, None, d=128,
+                      itemsize=2, kernel="dkv") == (bqb, 16384, chunk_bwd)
+    asked = fa._vmem_need("dkv", bqb, bkb, chunk_bwd, 128, 2, extra="dq")
+    assert fa._VMEM_DEFAULT < asked <= fa._VMEM_CAP
+    seen, of = fa.causal_tiles(16384, bq, bk, chunk, d=128, itemsize=2)
+    assert 50 < 100 * seen / of < 60
+    if kind == "window":
+        seen, of = fa.window_tiles(16384, 4096, bq, bk, chunk, d=128,
+                                   itemsize=2)
+        assert 21.9 < 100 * seen / of < 30

@@ -40,7 +40,11 @@ LFM2_SCOPES = ["lfm2/block", "lfm2/shortconv", "lfm2/attn", "lfm2/dense_mlp",
 GLM4_SCOPES = ["glm4/block", "glm4/mla_down", "glm4/mla_up", "glm4/attn",
                "glm4/dense_mlp", "glm4/shared_expert", "glm4/mtp",
                "glm4/loss_head", "moe/route", "moe/experts"]
+SMALLTHINKER_SCOPES = ["smallthinker/block", "smallthinker/attn_global",
+                       "smallthinker/attn_window", "smallthinker/loss_head",
+                       "moe/route", "moe/experts"]
 ROUTING = ["moe_rows_bound", "moe_rows_tight", "moe_rows_overflow_layers",
+           "window_tiles_visited", "window_tiles_total",
            "bd_tiles_visited", "bd_tiles_total",
            "causal_tiles_visited", "causal_tiles_total",
            "mla_kv_expanded_bytes", "mla_latent_bytes", "mtp_modules",
@@ -137,6 +141,7 @@ def test_every_name_emitted_is_in_the_table():
     names = {u[2] for u in used}
     assert set(TRAINER_SCOPES) | set(KERNELS) <= names
     assert set(SDAR_SCOPES) | set(LFM2_SCOPES) | set(GLM4_SCOPES) <= names
+    assert set(SMALLTHINKER_SCOPES) <= names
     assert set(ROUTING) <= names
     assert {"engine." + p for p in ENGINE_PHASES} <= names
     # and the table lists nothing that is not emitted
@@ -657,6 +662,111 @@ def test_lowered_latent_attention_step_carries_its_scopes_and_manifest(
     assert read["mtp_modules"] == [1]
 
 
+@pytest.fixture(scope="module")
+def window_step():
+    from horovod_tpu.models import smallthinker as st
+    hvd.init(devices=jax.devices()[:1])
+    try:
+        cfg = st.SmallThinkerConfig.tiny(experts_held=(2, 2),
+                                         attention="flash", remat=True,
+                                         remat_policy="dots",
+                                         flash_blocks=(8, 8))
+        model = st.SmallThinker(cfg)
+        tokens = jnp.zeros((2, 32), jnp.int32)
+        params = model.init(jax.random.PRNGKey(0), tokens)["params"]
+        opt = hvd.DistributedOptimizer(optax.sgd(0.1))
+
+        def window_step(params, opt_state, tokens):
+            loss, grads = hvd.value_and_grad(
+                lambda p: st.loss_fn(model, p, tokens))(params)
+            updates, opt_state = opt.update(grads, opt_state, params)
+            return optax.apply_updates(params, updates), opt_state, loss
+
+        step = hvd.spmd(window_step, in_specs=(P(), P(), P("hvd")),
+                        out_specs=(P(), P(), P()))
+        return _lowered(step, "window_step", params, opt.init(params),
+                        tokens)
+    finally:
+        hvd.shutdown()
+        hvd.init()          # back onto the session's 8 CPU devices
+
+
+def test_lowered_window_attention_step_carries_its_scopes_and_manifest(
+        window_step):
+    """The step of the fifth model family, lowered: its six scopes (two of
+    them the expert layer's own, the early route among them) and the three
+    kernel names in the text, and the manifest published under the
+    program's name: the rows the routed layers are shaped for, the tiles of
+    the global layer's causal call and of the window layers' calls, fewer
+    of the same total."""
+    for name in SMALLTHINKER_SCOPES + KERNELS:
+        assert name in window_step["text"], name
+    read = {name: _program_gauge(name, "window_step")
+            for name in tracing._ROUTING}
+    assert read["moe_rows_bound"] == [2 * 32 * 2]
+    assert (0 < read["window_tiles_visited"][0]
+            < read["causal_tiles_visited"][0]
+            < read["causal_tiles_total"][0] == read["window_tiles_total"][0])
+    assert read["bd_tiles_total"] == [] and read["mtp_modules"] == []
+    assert read["flash_bwd_kernels"] == [2]
+    # the route made before attention lies in the block and in no attention
+    # scope; the kernels of a window layer lie in its scope
+    table = window_step["table"]
+    assert any(row.scopes == ("smallthinker/block", "moe/route")
+               for row in table.values())
+    for kind in ("attn_global", "attn_window"):
+        assert any(row.scopes[:2] == ("smallthinker/block",
+                                      "smallthinker/" + kind)
+                   and row.scopes[-1] == "flash_attention"
+                   for row in table.values()), kind
+    assert not any("moe/route" in row.scopes
+                   and any("attn" in s for s in row.scopes)
+                   for row in table.values())
+
+
+@pytest.mark.parametrize("gauges,want", [
+    ({"window_tiles_visited": 252, "window_tiles_total": 1024}, 24.609375),
+    ({"window_tiles_visited": 1024, "window_tiles_total": 1024}, 100.0),
+    ({}, None),                 # the parent of PR 37 has no such series
+], ids=["252-of-1024", "whole-square", "parent"])
+def test_window_tiles_visited_share_reads_the_manifest(monkeypatch, gauges,
+                                                       want):
+    """``window_tiles_visited_share.train`` is data for the reader the
+    benchmark has (``named:series_total``): ``train_step``'s two gauges as
+    a percentage, and nothing (no raise) where the program does not publish
+    them. Its four siblings are entered beside it, each with its file, each
+    for the one cell; the scope's row names the metric that sums it."""
+    spec, entry, named = _benchmark_metric("window_tiles_visited_share.train")
+    monkeypatch.setattr(hvd.metrics, "snapshot", lambda: {
+        "counters": {}, "histograms": {}, "gauges": {
+            name: [{"labels": {"program": "train_step"}, "value": value},
+                   {"labels": {"program": "eval_step"}, "value": 7}]
+            for name, value in gauges.items()}})
+    module, function = spec["reader"].split(":")
+    assert (module, function) == ("named", "series_total")
+    got = getattr(named, function)(None, **spec["args"])
+    assert got == (want if want is None else pytest.approx(want))
+    for sel in spec["args"]["series"] + spec["args"]["per"]:
+        assert tracing.NAMES[sel["name"]].feeds == spec["name"]
+    for name in ("window_tiles_visited_share.train", "mfu.train_smallthinker",
+                 "swa_flash_roofline.train", "swa_flash_time_share.train",
+                 "window_attn_ms.train"):
+        other, its_entry, _ = _benchmark_metric(name)
+        assert len(its_entry) == 1
+        for key in ("unit", "layer", "moves", "source", "better"):
+            assert other[key] == its_entry[0][key], (name, key)
+        assert other["workloads"] == its_entry[0]["workloads"] == [
+            "smallthinker21b-train-dp1"]
+    attn, _, _ = _benchmark_metric("window_attn_ms.train")
+    assert attn["reader"] == "scopes:scope_ms_per_run"
+    assert attn["args"]["scopes"] == ["smallthinker/attn_window"]
+    assert tracing.NAMES["smallthinker/attn_window"].feeds == attn["name"]
+    assert attn["layer"] == tracing.NAMES["smallthinker/attn_window"].layer
+    roofline, _, _ = _benchmark_metric("swa_flash_roofline.train")
+    assert roofline["args"]["flops"] == "flops_smallthinker"
+    assert sorted(roofline["args"]["contains"]) == sorted(KERNELS)
+
+
 @pytest.mark.parametrize("gauge,want", [(2013265920, 2013.26592),
                                         (None, None)],
                          ids=["the-cell", "parent"])
@@ -741,10 +851,14 @@ def test_moe_rows_filled_share_reads_the_manifest(monkeypatch, gauges, want):
     got = getattr(named, function)(None, **spec["args"])
     assert got == (want if want is None else pytest.approx(want))
     assert len(entry) == 1
-    for key in ("unit", "layer", "moves", "source", "better", "workloads"):
+    for key in ("unit", "layer", "moves", "source", "better"):
         assert spec[key] == entry[0][key], key
+    # the file's list is the entry's head: a later cell (PR 37's) is
+    # appended to the entry alone
     assert spec["workloads"] == ["sdar30b-bd-train-dp1", "lfm2-24b-train-dp1",
                                  "glm47f-train-dp1"]
+    assert entry[0]["workloads"] == spec["workloads"] + [
+        "smallthinker21b-train-dp1"]
     for sel in spec["args"]["per"]:
         assert tracing.NAMES[sel["name"]].feeds == spec["name"]
 
@@ -800,7 +914,8 @@ def test_causal_tiles_visited_share_reads_the_manifest(monkeypatch, gauges,
     cells = entry[0]["workloads"]
     assert cells[:len(spec["workloads"])] == spec["workloads"]
     assert cells[len(spec["workloads"]):] == ["lfm2-24b-train-dp1",
-                                              "glm47f-train-dp1"]
+                                              "glm47f-train-dp1",
+                                              "smallthinker21b-train-dp1"]
     for sel in spec["args"]["series"] + spec["args"]["per"]:
         assert tracing.NAMES[sel["name"]].feeds == spec["name"]
 
@@ -827,12 +942,14 @@ def test_flash_bwd_kernels_reads_the_manifest(monkeypatch, gauges, want):
     got = getattr(named, function)(None, **spec["args"])
     assert got == (want if want is None else pytest.approx(want))
     assert len(entry) == 1
-    for key in ("unit", "layer", "moves", "source", "better", "workloads"):
+    for key in ("unit", "layer", "moves", "source", "better"):
         assert spec[key] == entry[0][key], key
     assert (spec["unit"], spec["better"]) == ("kernels", "lower")
     assert spec["workloads"] == [
         "gpt2m-train-dp1", "gpt2m-train-dp4", "sdar30b-bd-train-dp1",
         "lfm2-24b-train-dp1", "glm47f-train-dp1"]
+    assert entry[0]["workloads"] == spec["workloads"] + [
+        "smallthinker21b-train-dp1"]
     for sel in spec["args"]["series"]:
         assert tracing.NAMES[sel["name"]].feeds == spec["name"]
     assert tracing.NAMES["flash_bwd_vmem_bytes"].feeds.startswith("registry")
@@ -847,7 +964,7 @@ def _rows_in(table, scope):
 
 
 @pytest.fixture(scope="module")
-def steps(readme_step, bd_step, hybrid_step, latent_step):
+def steps(readme_step, bd_step, hybrid_step, latent_step, window_step):
     # hvd/optimizer/sync lowers nothing on the README path; the slices of
     # hvd/fusion/unpack and the transposes of flash/layout are fused into
     # their consumers by the CPU's compiler and read as those (a fusion's
@@ -857,11 +974,12 @@ def steps(readme_step, bd_step, hybrid_step, latent_step):
         "hvd/optimizer/sync", "hvd/fusion/unpack", "flash/layout")]),
             "block-diffusion": (bd_step, SDAR_SCOPES),
             "hybrid": (hybrid_step, LFM2_SCOPES),
-            "latent": (latent_step, GLM4_SCOPES)}
+            "latent": (latent_step, GLM4_SCOPES),
+            "window": (window_step, SMALLTHINKER_SCOPES)}
 
 
 @pytest.mark.parametrize("family", ["readme", "block-diffusion", "hybrid",
-                                    "latent"])
+                                    "latent", "window"])
 def test_scope_table_holds_every_scope_of_the_step(steps, family):
     """The table of a family's compiled step holds, in some row, every
     scope the lowered text carries, the flash kernels' wrapper among them;
@@ -1246,8 +1364,13 @@ def test_scope_metric_file_names_its_entry_and_its_scopes(name):
     spec, entry, _ = _benchmark_metric(name)
     assert spec["reader"] == "scopes:scope_ms_per_run"
     assert len(entry) == 1
-    for key in ("unit", "layer", "moves", "source", "better", "workloads"):
+    for key in ("unit", "layer", "moves", "source", "better"):
         assert spec[key] == entry[0][key], key
+    # the file's list is the entry's head; the four that every training cell
+    # reports had PR 37's cell appended to the entry alone
+    later = (["smallthinker21b-train-dp1"] if "glm4" not in name
+             and len(spec["workloads"]) > 2 else [])
+    assert entry[0]["workloads"] == spec["workloads"] + later
     assert entry[0]["source"] == "device_trace"
     assert spec["args"]["module"] == "train_step"
     for scope in spec["args"].get("scopes", ()):

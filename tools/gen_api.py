@@ -49,6 +49,7 @@ MODULES = [
     "horovod_tpu.models.sdar",
     "horovod_tpu.models.lfm2",
     "horovod_tpu.models.glm4_moe_lite",
+    "horovod_tpu.models.smallthinker",
     "horovod_tpu.models.t5",
     "horovod_tpu.models.convert",
     "horovod_tpu.models.generate",

@@ -26,7 +26,8 @@ import time
 # Runnable from any cwd.
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# (head_dim, seq, batch, heads, causal, kind, dtype)
+# (head_dim, seq, batch, heads, causal, kind, dtype[, window]); a "window"
+# shape is causal under a sliding window of that many keys.
 # Ring probes run causal=False: all but one of a ring's n hops carry
 # fully-unmasked blocks (the causal mask only bites near the diagonal hop),
 # so the unmasked kernel is the representative per-hop workload — a causal
@@ -54,6 +55,8 @@ FWDBWD_SHAPES = [
     (64, 4096, 2, 12, True, "causal", "bfloat16"),   # GPT-2 @4k
     (64, 8192, 4, 32, True, "causal", "bfloat16"),   # LFM2 hybrid @8k
     (256, 8192, 2, 20, True, "causal", "bfloat16"),  # latent attention @8k
+    (128, 16384, 2, 28, True, "causal", "bfloat16"),  # global layer @16k
+    (128, 16384, 2, 28, True, "window", "bfloat16", 4096),  # SWA 4,096 @16k
 ]
 
 # What the --fwdbwd sweep tries on a causal shape besides the plain grid:
@@ -63,7 +66,8 @@ FWDBWD_SHAPES = [
 # under it take no mask. As a backward tiling such a candidate is the
 # one-kernel backward (dQ summed in the dK/dV kernel's loop); the kernels
 # ask for the VMEM a resident tile takes (flash_attention._vmem_need), so
-# at 8,192 keys these compile, at head 256 in the forward too.
+# at 8,192 keys these compile, at head 256 in the forward too. Under a
+# window the loop also starts at the band's lower edge.
 CAUSAL_CHUNKED = [(128, 128), (128, 256), (256, 128), (256, 256), (256, 512),
                   (512, 256), (512, 512)]
 # At 4k and beyond the grid's K axis has many steps to skip, a tile may be
@@ -122,11 +126,12 @@ def main(argv=None) -> int:
     if args.shape:
         shapes = [s for s in shapes if f"{s[0]}x{s[1]}" == args.shape]
     failed = 0
-    for head_dim, seq, batch, heads, causal, kind, dtype in shapes:
+    for head_dim, seq, batch, heads, causal, kind, dtype, *window in shapes:
         shape = (batch, seq, heads, head_dim)
+        window = window[0] if window else None
         t0 = time.time()
         candidates = None
-        if args.fwdbwd and kind == "causal":
+        if args.fwdbwd and kind in ("causal", "window"):
             long = seq >= 4096
             candidates = LONG_TILES if long else [
                 c for c in FLASH_TILE_CANDIDATES if c[1] <= seq]
@@ -137,13 +142,14 @@ def main(argv=None) -> int:
             best, trials = autotune_flash_blocks(
                 shape, dtype=dtype, causal=causal, record=True,
                 candidates=candidates, record_kind=kind,
-                record_path=args.out, **kw)
+                record_path=args.out, window=window, **kw)
         except Exception as e:   # one bad shape must not kill the sweep
             print(f"  {kind} d{head_dim} T{seq} {dtype}: FAILED ({e})")
             failed += 1
             continue
         n_timed = len([k for k in trials if k[0] != "bwd"])
-        print(f"  {kind} d{head_dim} T{seq} {dtype}: best={best} "
+        print(f"  {kind}{window or ''} d{head_dim} T{seq} {dtype}: "
+              f"best={best} "
               f"({n_timed} fwd candidates, {time.time() - t0:.0f}s)")
         # every candidate, the losers too: phase 1 is the forward alone,
         # "bwd" rows are forward + backward with the forward at its winner
