@@ -76,7 +76,8 @@ def autotune_flash_blocks(q_shape, dtype="bfloat16", causal: bool = True,
                           record_kind: Optional[str] = None,
                           record_path=None,
                           tune_backward: bool = False,
-                          window: Optional[int] = None):
+                          window: Optional[int] = None,
+                          block_diffusion: Optional[tuple] = None):
     """Measure flash-attention (block_q, block_k) tilings on this device.
 
     The best tiles depend on head_dim, sequence length and VMEM pressure
@@ -96,9 +97,9 @@ def autotune_flash_blocks(q_shape, dtype="bfloat16", causal: bool = True,
     compile, so pinned-then-sweep is the practical shape.
 
     A candidate may be ``(block_q, block_k, chunk)`` with ``block_k`` the
-    whole sequence: the causal kernels then loop inside each grid step
-    over compute chunks of ``chunk`` keys of the resident K tile, as far
-    as the diagonal
+    whole sequence: the causal, window and block-diffusion kernels then
+    loop inside each grid step over compute chunks of ``chunk`` keys of the
+    resident K tile, as far as the mask shows
     (``ops/flash_attention._chunk_loop``). Such a winner is returned as
     the candidate it was (with ``tune_backward``: ``(bq, bk, bq_bwd,
     bk_bwd, chunk, chunk_bwd)``, ``tile_table.lookup_full``'s order) and
@@ -132,6 +133,11 @@ def autotune_flash_blocks(q_shape, dtype="bfloat16", causal: bool = True,
       window: tune the causal variant under a sliding window of this many
         keys (``flash_attention(window=)``); record it with
         ``record_kind="window"``.
+      block_diffusion: tune under the block-diffusion mask of rows
+        ``[noisy ; clean]``: ``(seq_len, block_len)`` with ``q_shape``'s
+        sequence ``2 * seq_len`` (``flash_attention(block_diffusion=)``;
+        not with ``causal``); record it with
+        ``record_kind="block_diffusion"``.
     """
     import jax
     import jax.numpy as jnp
@@ -161,6 +167,8 @@ def autotune_flash_blocks(q_shape, dtype="bfloat16", causal: bool = True,
 
     if candidates is None:
         candidates = FLASH_TILE_CANDIDATES
+    if block_diffusion is not None:     # a static argument of the kernels
+        block_diffusion = tuple(int(n) for n in block_diffusion)
     rng = np.random.default_rng(0)
     q, k, v = (jnp.asarray(rng.standard_normal(q_shape), dtype)
                for _ in range(3))
@@ -176,7 +184,7 @@ def autotune_flash_blocks(q_shape, dtype="bfloat16", causal: bool = True,
         def chained(q, k, v):
             def body(c, _):
                 o = _attend(c, k, v, causal, q_shape[-1] ** -0.5, None,
-                            None, tiles, window=window)
+                            None, tiles, bd=block_diffusion, window=window)
                 return o.astype(c.dtype), None
             out, _ = lax.scan(body, q, None, length=chain)
             return out

@@ -20,9 +20,12 @@ Design (tpu-first):
   on the v5e) is tiled at two levels instead: the K tile is the whole key
   axis, resident for the head, the grid's K axis has one step, and the
   kernels loop inside the step over chunks of the keys, as many as
-  the diagonal leaves visible to this Q tile (``_causal_chunks``,
-  ``_chunk_loop``). Chunks wholly under the diagonal take no positional
-  mask; only those it crosses go through ``_mask_scores``. The forward
+  the mask leaves visible to this Q tile (``_chunk_runs``,
+  ``_chunk_loop``): up to the diagonal under the causal mask, from the
+  band's lower edge under a window, a noisy Q tile's own chunk and the
+  clean prefix under the block-diffusion mask (``_bd_chunks``). Chunks no
+  edge of the mask crosses take no positional mask; only those one
+  crosses go through ``_mask_scores``. The forward
   carries its softmax state round the loop as values (a chunk's
   read-modify-write of the 1-D scratch costs more than the chunk's
   scores), the backward keeps its sums in scratch. An entry
@@ -45,8 +48,10 @@ Design (tpu-first):
   the dK/dV kernel's grid walks the Q tiles, a Q tile meets all of its
   keys inside one grid step, and its dQ is summed there from the same
   ``ds`` (``flash_dkv`` alone; five products and one pass of the
-  exponential a pair instead of seven and two). A tracked key-bias
-  gradient, the block-diffusion mask and the ring's calls keep two.
+  exponential a pair instead of seven and two): one shape of the backward
+  under the causal, the window and the block-diffusion mask. A tracked
+  key-bias gradient, the ring's calls and a call that names its own tiles
+  keep two.
 - Off-TPU (the virtual CPU test mesh) the same kernels run in Pallas
   interpreter mode, so tests exercise the real kernel code path.
 
@@ -211,7 +216,7 @@ def _tile_visible(causal: bool, bd, q_blk, kv_idx, block_q: int,
     ``bd`` row, else the causal one, with a ``window`` between the
     diagonal and the band's lower edge."""
     if bd is not None:
-        return _bd_skip(q_blk, kv_idx, block_q, block_k, *bd)
+        return _bd_skip(q_blk, kv_idx, block_q, block_k, *bd, xp=xp)
     visible = _causal_skip(causal, q_blk, kv_idx, block_q, block_k, offset,
                            xp)
     if window is None:
@@ -301,21 +306,26 @@ def _tiling(tq: int, tk: int, block_q: int, block_k: int, chunk,
             track_db: bool = False):
     """``(block_q, block_k, chunk)`` as the kernels run them. Without a
     compute chunk (``None``) the tiles are the grid's, clamped to the
-    lengths. With one (the tile table gave it, the mask is the causal one,
-    the table's K tile holds every key, and they make more than one chunk)
-    the K tile is the whole key axis, in whole chunks: K and V of a head
-    stay resident, the grid's K axis has one step, and a loop inside the
+    lengths. With one (the tile table gave it, the mask is one with a
+    chunk loop: the causal one, with or without a window, or a
+    block-diffusion row ``bd`` whose halves are whole chunks and whole Q
+    tiles; the table's K tile holds every key, and they make more than one
+    chunk) the K tile is the whole key axis, in whole chunks: K and V of a
+    head stay resident, the grid's K axis has one step, and a loop inside the
     step takes its place (:func:`_chunk_loop`). ``kernel`` is who asks
     (the forward, or ``dkv`` for the backward): where a core cannot give
     it the VMEM a resident K tile takes (:func:`_vmem_need` over
     ``_VMEM_CAP``: a tracked bias gradient, ``track_db``, whose kernel
     takes the K tile whole), or the keys are more than the entry's K tile
     and that tile as the grid's would not fit the compiler's default (a
-    sequence longer than the entry was measured at), the grid's K tile is
-    the chunk."""
+    sequence longer than the entry was measured at), or a block-diffusion
+    row is one the loop cannot take, the grid's K tile is the chunk."""
     bq, bk = _block_sizes(tq, tk, block_q, block_k)
-    if not (causal and bd is None and chunk and 0 < chunk < bk):
+    if not ((causal or bd is not None) and chunk and 0 < chunk < bk):
         return bq, bk, None
+    # a block-diffusion row: whole chunks in each half, and no Q tile over
+    # the seam between them (_bd_chunks)
+    loops = bd is None or (bd[0] % chunk == 0 and bd[0] % bq == 0)
 
     def need(k_tile, chunk):
         extra = ""
@@ -326,7 +336,7 @@ def _tiling(tq: int, tk: int, block_q: int, block_k: int, chunk,
 
     if block_k >= tk:
         whole = -(-tk // chunk) * chunk
-        if need(whole, int(chunk)) <= _VMEM_CAP:
+        if loops and need(whole, int(chunk)) <= _VMEM_CAP:
             return bq, whole, int(chunk)
     elif need(bk, None) <= _VMEM_DEFAULT:       # a plain grid asks nothing
         return bq, bk, None
@@ -365,32 +375,85 @@ def _window_chunks(q_blk, block_q: int, chunk: int, offset: int, tk: int,
     return first, inside, xp.maximum(clear, inside), visible
 
 
-def _chunk_loop(visit, state, q_blk, block_q: int, chunk: int, offset: int,
-                tk: int, window=None):
-    """The loop inside a grid step whose length is the diagonal:
-    ``state = visit(state, ci, rows, crossed)`` over the compute chunks of
-    the resident K tile that Q tile ``q_blk`` may see, ``rows`` of the
-    tile known to the mask as block ``ci`` of ``chunk`` keys. The chunks
-    under the diagonal come first and take no causal mask
-    (``crossed=False``), then those it crosses. With a ``window`` the loop
-    starts at the first chunk the tile can see, and the chunks the band's
-    lower edge crosses are a second masked run before the clear ones."""
-    first, inside = 0, 0
-    if window is None:
-        clear, visible = _causal_chunks(q_blk, block_q, chunk, offset, tk)
-    else:
-        first, inside, clear, visible = _window_chunks(
-            q_blk, block_q, chunk, offset, tk, window)
+def _bd_chunks(q_blk, block_q: int, chunk: int, seq: int, blk: int, xp=jnp):
+    """The runs ``(lo, hi, crossed)`` of compute chunks that Q tile ``q_blk``
+    of a block-diffusion row ``[noisy ; clean]`` of ``2 * seq`` positions may
+    see, for a tile that lies in one half (``seq % block_q == 0``) and
+    chunks that do (``seq % chunk == 0``). A noisy tile sees the noisy
+    chunks that hold its own blocks, under the mask (``crossed``: with blocks
+    of 4 in chunks of hundreds nearly all of such a chunk is masked; a
+    narrower diagonal is left for later), and the clean prefix: the clean
+    chunks whose every key lies in a block before its first row's take no
+    mask, those as far as its last row's block are masked. A clean tile
+    sees no noisy chunk, and its own block too: the clean chunks whose
+    every key lies in or before its first row's block take no mask, those
+    as far as its last row's block are masked. Scalars in a kernel; arrays
+    of tile indices and ``xp=np`` in :func:`bd_tiles`."""
+    q0 = q_blk * block_q
+    noisy = q0 < seq
+    r0 = xp.where(noisy, q0, q0 - seq)          # from the start of its half
+    own = xp.where(noisy, 0, 1)                 # a clean row sees its block
+    b_lo, b_hi = r0 // blk, (r0 + block_q - 1) // blk
+    base = seq // chunk                         # the first clean chunk
+    clear = base + (b_lo + own) * blk // chunk
+    visible = base + (xp.minimum((b_hi + own) * blk, seq) + chunk - 1) // chunk
+    diag_lo = b_lo * blk // chunk
+    diag_hi = (xp.minimum((b_hi + 1) * blk, seq) + chunk - 1) // chunk
+    return [(xp.where(noisy, diag_lo, 0), xp.where(noisy, diag_hi, 0), True),
+            (base, clear, False), (clear, visible, True)]
 
-    def run(lo, hi, crossed: bool, state):
-        def body(ci, state):
+
+def _chunk_runs(q_blk, block_q: int, chunk: int, offset: int, tk: int,
+                window=None, bd=None, xp=jnp):
+    """The runs ``(lo, hi, crossed)`` of compute chunks of the resident K
+    tile that Q tile ``q_blk`` may see, in the order the kernels take them,
+    by the kind of mask. Causal: the chunks under the diagonal, which take
+    no mask (``crossed=False``), then those it crosses. With a ``window``
+    the chunks the band's lower edge crosses come first, a second masked
+    run. A block-diffusion row ``bd``: :func:`_bd_chunks`."""
+    if bd is not None:
+        return _bd_chunks(q_blk, block_q, chunk, *bd, xp=xp)
+    if window is None:
+        clear, visible = _causal_chunks(q_blk, block_q, chunk, offset, tk, xp)
+        return [(0, clear, False), (clear, visible, True)]
+    first, inside, clear, visible = _window_chunks(
+        q_blk, block_q, chunk, offset, tk, window, xp)
+    return [(first, inside, True), (inside, clear, False),
+            (clear, visible, True)]
+
+
+def _chunk_loop(visit, state, q_blk, block_q: int, chunk: int, offset: int,
+                tk: int, window=None, bd=None):
+    """The loop inside a grid step whose length is what the mask shows:
+    ``state = visit(state, ci, rows, crossed)`` over the compute chunks of
+    the resident K tile that Q tile ``q_blk`` may see (:func:`_chunk_runs`),
+    ``rows`` of the tile known to the mask as block ``ci`` of ``chunk``
+    keys. A chunk no edge of the mask crosses (``crossed=False``) takes no
+    positional mask."""
+    for lo, hi, crossed in _chunk_runs(q_blk, block_q, chunk, offset, tk,
+                                       window, bd):
+        def body(ci, state):    # traced here, with this run's ``crossed``
             rows = pl.ds(pl.multiple_of(ci * chunk, chunk), chunk)
             return visit(state, ci, rows, crossed)
-        return jax.lax.fori_loop(lo, hi, body, state)
+        state = jax.lax.fori_loop(lo, hi, body, state)
+    return state
 
-    if window is not None:
-        state = run(first, inside, True, state)
-    return run(clear, visible, True, run(inside, clear, False, state))
+
+def _tiles_visited(t: int, bq: int, bk: int, chunk, causal: bool, bd,
+                   offset: int = 0, window=None):
+    """``(visited, total)`` of one head's forward over ``t`` positions at
+    the tiles :func:`_tiling` gave: pairs of (Q tile, compute chunk) where
+    the kernels loop over chunks, else the grid's tiles, each counted by
+    the function the kernel runs by."""
+    q_blk = np.arange(-(-t // bq))
+    if chunk is None:
+        kv_idx = np.arange(-(-t // bk))
+        hit = _tile_visible(causal, bd, q_blk[:, None], kv_idx[None, :], bq,
+                            bk, offset, window, xp=np)
+        return int(np.sum(hit)), hit.size
+    runs = _chunk_runs(q_blk, bq, chunk, offset, t, window, bd, xp=np)
+    return (int(sum(np.sum(hi - lo) for lo, hi, _ in runs)),
+            q_blk.size * -(-t // chunk))
 
 
 def causal_tiles(t: int, block_q: int, block_k: int, chunk=None,
@@ -414,30 +477,18 @@ def window_tiles(t: int, window: int, block_q: int, block_k: int, chunk=None,
     predicates the kernels run by."""
     bq, bk, chunk = _tiling(t, t, block_q, block_k, chunk, True, None,
                             **shape)
-    q_blk = np.arange(-(-t // bq))
-    if chunk is None:
-        kv_idx = np.arange(-(-t // bk))
-        hit = _tile_visible(True, None, q_blk[:, None], kv_idx[None, :], bq,
-                            bk, offset, window, xp=np)
-        return int(np.sum(hit)), hit.size
-    if window is None:
-        first, visible = 0, _causal_chunks(q_blk, bq, chunk, offset, t,
-                                           xp=np)[1]
-    else:
-        first, _, _, visible = _window_chunks(q_blk, bq, chunk, offset, t,
-                                              window, xp=np)
-    return int(np.sum(visible - first)), q_blk.size * -(-t // chunk)
+    return _tiles_visited(t, bq, bk, chunk, True, None, offset, window)
 
 
-def bd_tiles(seq: int, blk: int, block_q: int, block_k: int):
-    """``(visited, total)`` tiles of one head's forward grid over a
-    block-diffusion row of ``2 * seq`` positions, from shapes alone (the
-    routing manifest's ``bd_tiles_visited`` / ``bd_tiles_total``)."""
-    bq, bk = _block_sizes(2 * seq, 2 * seq, block_q, block_k)
-    nq, nk = -(-2 * seq // bq), -(-2 * seq // bk)
-    hit = _bd_skip(np.arange(nq)[:, None], np.arange(nk)[None, :], bq, bk,
-                   seq, blk, xp=np)
-    return int(np.sum(hit)), nq * nk
+def bd_tiles(seq: int, blk: int, block_q: int, block_k: int, chunk=None,
+             **shape):
+    """:func:`causal_tiles` of a block-diffusion row of ``2 * seq``
+    positions in blocks of ``blk`` (the routing manifest's
+    ``bd_tiles_visited`` / ``bd_tiles_total``)."""
+    bd = (seq, blk)
+    bq, bk, chunk = _tiling(2 * seq, 2 * seq, block_q, block_k, chunk, False,
+                            bd, **shape)
+    return _tiles_visited(2 * seq, bq, bk, chunk, False, bd)
 
 
 # ---------------------------------------------------------------------------
@@ -446,12 +497,17 @@ def bd_tiles(seq: int, blk: int, block_q: int, block_k: int):
 
 def _scores(q_ref, k_ref, bias_ref, segq_ref, segk_ref, q_blk, kv_blk, rows,
             *, scale: float, causal: bool, offset: int, block_q: int,
-            block_k: int, tq: int, tk: int, bd=None, window=None):
+            block_k: int, tq: int, tk: int, bd=None, window=None,
+            crossed: bool = True):
     """The scaled Q tile, ``rows`` of the resident K tile, and their masked
     scores: what all three kernels start a (Q tile, K tile or compute
     chunk) pair with. The mask knows the rows as block ``kv_blk`` of
-    ``block_k`` keys; a pair that neither edge of the band crosses
-    (``causal=False`` from a chunk loop) takes no ``window`` either."""
+    ``block_k`` keys; a pair that no edge of the mask crosses
+    (``crossed=False`` from a chunk loop: every pair of it visible) takes
+    neither the causal mask, nor the ``window``, nor the block-diffusion
+    one."""
+    if not crossed:
+        causal, bd = False, None
     q = _zero_oob_rows(q_ref[0].astype(jnp.float32) * scale,
                        q_blk, block_q, tq)
     k = _zero_oob_rows(k_ref[0, rows].astype(jnp.float32), kv_blk, block_k,
@@ -474,8 +530,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref, o_ref,
                 window=None):
     scores = functools.partial(
         _scores, q_ref, k_ref, bias_ref, segq_ref, segk_ref,
-        scale=scale, offset=offset, block_q=block_q, tq=tq, tk=tk, bd=bd,
-        window=window)
+        scale=scale, causal=causal, offset=offset, block_q=block_q, tq=tq,
+        tk=tk, bd=bd, window=window)
     if chunk is not None:
         q_blk = pl.program_id(1)
 
@@ -484,7 +540,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref, o_ref,
         # read-modify-write a chunk costs more than the chunk's scores).
         def visit(state, ci, rows, crossed):
             m_prev, l_prev, acc = state
-            _, _, s = scores(q_blk, ci, rows, causal=crossed,
+            _, _, s = scores(q_blk, ci, rows, crossed=crossed,
                              block_k=chunk)
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
             p = jnp.exp(s - m_new[:, None])
@@ -500,7 +556,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref, o_ref,
             visit, (jnp.full((block_q,), _NEG_INF, jnp.float32),
                     jnp.zeros((block_q,), jnp.float32),
                     jnp.zeros(q_ref.shape[1:], jnp.float32)),
-            q_blk, block_q, chunk, offset, tk, window)
+            q_blk, block_q, chunk, offset, tk, window, bd)
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
         lse_ref[0] = (m + jnp.log(l_safe))[:, None]
@@ -520,8 +576,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref, o_ref,
     @pl.when(_tile_visible(causal, bd, q_blk, kv_idx, block_q, block_k,
                            offset, window))
     def _():
-        _, _, s = scores(q_blk, kv_idx, slice(None), causal=causal,
-                         block_k=block_k)
+        _, _, s = scores(q_blk, kv_idx, slice(None), block_k=block_k)
 
         m_prev = m_ref[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
@@ -650,19 +705,19 @@ def _fill_optionals(kernel, has_bias, has_seg):
 
 def _visit_pairs(visit, causal: bool, bd, q_blk, kv_idx, block_q: int,
                  block_k: int, chunk, offset: int, tk: int, window=None):
-    """The backward kernels' ``visit(kv_blk, block_k, rows, causal)`` over
+    """The backward kernels' ``visit(kv_blk, block_k, rows, crossed)`` over
     what the resident K tile holds that Q tile ``q_blk`` may see: the tile
     whole, if the grid-level skip lets it through, or with a compute chunk
-    the loop over its chunks as far as the diagonal. Their sums live in
+    the loop over its chunks as far as the mask shows. Their sums live in
     scratch either way (there a chunk's read-modify-write is cheaper than
     carrying them round the loop)."""
     if chunk is None:
         pl.when(_tile_visible(causal, bd, q_blk, kv_idx, block_q, block_k,
                               offset, window))(
-            lambda: visit(kv_idx, block_k, slice(None), causal))
+            lambda: visit(kv_idx, block_k, slice(None), True))
         return
     _chunk_loop(lambda _, ci, rows, crossed: visit(ci, chunk, rows, crossed),
-                None, q_blk, block_q, chunk, offset, tk, window)
+                None, q_blk, block_q, chunk, offset, tk, window, bd)
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
@@ -679,11 +734,12 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
 
     q_blk = pl.program_id(1)
 
-    def visit(kv_blk, block_k, rows, causal):
+    def visit(kv_blk, block_k, rows, crossed):
         _, k, s = _scores(q_ref, k_ref, bias_ref, segq_ref, segk_ref, q_blk,
                           kv_blk, rows, scale=scale, causal=causal,
                           offset=offset, block_q=block_q, block_k=block_k,
-                          tq=tq, tk=tk, bd=bd, window=window)
+                          tq=tq, tk=tk, bd=bd, window=window,
+                          crossed=crossed)
         p = jnp.exp(s - lse_ref[0])
         p = jnp.where(s > _NEG_INF / 2, p, 0.0)
         do = _zero_oob_rows(do_ref[0].astype(jnp.float32), q_blk, block_q, tq)
@@ -728,11 +784,12 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, segq_ref, segk_ref,
 
     k_idx = pl.program_id(1)
 
-    def visit(kv_blk, block_k, rows, causal):
+    def visit(kv_blk, block_k, rows, crossed):
         q, k, s = _scores(q_ref, k_ref, bias_ref, segq_ref, segk_ref, q_idx,
                           kv_blk, rows, scale=scale, causal=causal,
                           offset=offset, block_q=block_q, block_k=block_k,
-                          tq=tq, tk=tk, bd=bd, window=window)
+                          tq=tq, tk=tk, bd=bd, window=window,
+                          crossed=crossed)
         p = jnp.exp(s - lse_ref[0])
         p = jnp.where(s > _NEG_INF / 2, p, 0.0)
         do = _zero_oob_rows(do_ref[0].astype(jnp.float32), q_idx, block_q, tq)
@@ -996,7 +1053,8 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         are block-diffusion training rows ``[noisy ; clean]`` of
         ``2 * seq_len`` positions (``ops.attention.block_diffusion_mask``
         says who sees whom); the kernels mask score tiles by position and
-        skip the tiles that hold no visible pair. Not with ``causal``.
+        skip the tiles, or with the table's ``chunk`` the chunks of the
+        resident keys, that hold no visible pair. Not with ``causal``.
       window: optional static number of keys a row sees, its own included
         (sliding-window attention; needs ``causal=True``): visible iff
         ``0 <= i + causal_offset - j < window``. The kernels mask the
@@ -1009,10 +1067,11 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         (``ops/tile_table.py``; ``tools/tune_tiles.py`` measures it on
         the chip) for the nearest measured tiling of this (head_dim, seq,
         dtype, mask); with no entry the table's default (256, 512).
-        Ragged edges are position-masked. A causal entry may also carry a
-        compute ``chunk``: the kernels then keep the whole key axis
-        resident and loop inside a grid step over chunks of it as far as
-        the diagonal (module docstring). The chunk is the table's to
+        Ragged edges are position-masked. A causal, window or
+        block-diffusion entry may also carry a compute ``chunk``: the
+        kernels then keep the whole key axis resident and loop inside a
+        grid step over chunks of it as far as the mask shows (module
+        docstring). The chunk is the table's to
         give and goes with the table's tiles only: a call that names its
         own tiles runs them whole.
       block_q_bwd, block_k_bwd: tile sizes for the backward (dQ and
@@ -1117,7 +1176,9 @@ def _attend(q, k, v, causal, scale, key_bias, segment_ids, tiles,
                int(block_q_bwd), int(block_k_bwd), int(causal_offset), bd,
                chunk and int(chunk), chunk_bwd and int(chunk_bwd), window)
     if bd is not None:
-        visited, total = bd_tiles(bd[0], bd[1], int(block_q), int(block_k))
+        visited, total = bd_tiles(
+            bd[0], bd[1], int(block_q), int(block_k), chunk,
+            **_vmem_shape(d, q.dtype, key_bias, seg))
         _tracing.note_routing(bd_tiles_visited=visited,
                               bd_tiles_total=total)
     elif window is not None:

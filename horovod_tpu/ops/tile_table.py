@@ -22,21 +22,21 @@ Table file: ``flash_tiles.json`` next to this module (override with
 
 An entry from the forward + backward sweep (``tools/tune_tiles.py
 --fwdbwd``, source ``tuned-*-fwdbwd``) may also carry ``block_q_bwd`` /
-``block_k_bwd`` and, for a causal or window shape, ``chunk`` /
-``chunk_bwd``: the keys
+``block_k_bwd`` and, for a causal, window or block-diffusion shape,
+``chunk`` / ``chunk_bwd``: the keys
 of the resident K tile that one pass of the kernels' inner loop takes
 (``lookup_full``).
 
 ``kind`` is one of "causal" | "full" | "ring" | "block_diffusion" | "window"
 (the ring kernel's VMEM profile differs: its per-hop seq is the local shard
 and the backward is an explicit second ring; a block-diffusion row is
-``[noisy ; clean]``, ``seq`` counts both halves, and its tiles are skipped
-along three diagonals; a window is a causal band, whose tiles are skipped
-above the diagonal and under the band: an entry may say the ``window`` it
-was measured at, which no lookup reads). Lookup is nearest-match: exact kind
-and dtype
-preferred, then closest head_dim and seq in log space — so one measured
-point generalises to neighbouring shapes until the tuner fills them in.
+``[noisy ; clean]``, ``seq`` counts both halves, and its tiles or chunks
+are skipped along three diagonals; a window is a causal band, whose tiles
+are skipped above the diagonal and under the band: an entry may say the
+``window`` it was measured at, which no lookup reads). Lookup is
+nearest-match: exact kind and dtype preferred, then closest head_dim and
+seq in log space — so one measured point generalises to neighbouring shapes
+until the tuner fills them in.
 """
 
 from __future__ import annotations

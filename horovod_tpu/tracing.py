@@ -463,12 +463,16 @@ NAMES: Dict[str, Name] = {
         "at most moe_rows_bound); label program",
         "moe_rows_filled_share.train"),
     "bd_tiles_visited": Name(
-        "gauge", _KERNELS, "routing manifest: tiles of one head's forward "
-        "grid that hold a visible pair; label program",
+        "gauge", _KERNELS, "routing manifest: (Q tile, compute chunk) "
+        "pairs of one head's forward under the block-diffusion mask that "
+        "hold a visible pair, the ones the kernels' inner loop visits; "
+        "tiles of the grid where the kernels do not loop "
+        "(ops/flash_attention.bd_tiles); label program",
         "bd_tiles_visited_share.train"),
     "bd_tiles_total": Name(
-        "gauge", _KERNELS, "routing manifest: tiles of one head's forward "
-        "grid; label program", "bd_tiles_visited_share.train"),
+        "gauge", _KERNELS, "routing manifest: (Q tile, compute chunk) "
+        "pairs of one head's forward under the block-diffusion mask, or "
+        "tiles of its grid; label program", "bd_tiles_visited_share.train"),
     "causal_tiles_visited": Name(
         "gauge", _KERNELS, "routing manifest: (Q tile, compute chunk) "
         "pairs of one head's causal forward that hold a visible pair, the "

@@ -173,20 +173,63 @@ def test_spmd_train_step_compiles_for_v5e(v5e, mosaic, restore_world, n_dev):
 
 def test_block_diffusion_flash_compiles_for_v5e_at_the_cells_shape(v5e,
                                                                    mosaic):
-    """Head size 128, 8,192 positions ``[noisy ; clean]``, the tiles of the
-    table's row: the mask's integer arithmetic and the tile skip lower for
-    the chip in all three kernels (a select between booleans did not)."""
-    from horovod_tpu.ops.flash_attention import flash_attention
+    """Head size 128, 8,192 positions ``[noisy ; clean]`` on a plain grid:
+    the mask's integer arithmetic and the tile skip lower for the chip in
+    all three kernels (a select between booleans did not)."""
     on = SingleDeviceSharding(v5e[0])
     x = jax.ShapeDtypeStruct((1, 8192, 8, 128), jnp.bfloat16, sharding=on)
+    # PR 27's row of the table, named: the plain grid, which a shape the
+    # chunk loop cannot take still runs
+    lowered = _bd_fwd_bwd(block_q=1024, block_k=1024).lower(x, x, x)
+    assert _vmem_asked(lowered) == []
+    _assert_kernels_named(lowered.compile().as_text())
+
+
+def _bd_fwd_bwd(**tiles):
+    from horovod_tpu.ops.flash_attention import flash_attention
 
     def fwd_bwd(q, k, v):
         return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
-            q, k, v, block_diffusion=(4096, 4)).astype(jnp.float32)),
-            argnums=(0, 1, 2))(q, k, v)
+            q, k, v, block_diffusion=(4096, 4), **tiles)
+            .astype(jnp.float32)), argnums=(0, 1, 2))(q, k, v)
+    return jax.jit(fwd_bwd)
 
-    _assert_kernels_named(jax.jit(fwd_bwd).lower(x, x, x).compile()
-                          .as_text())
+
+def test_block_diffusion_flash_is_resident_and_one_kernel_at_the_cells_shape(
+        v5e, mosaic):
+    """The same shape by the table's row since PR 38, at the cell's 32
+    heads a row: K resident forward and backward with a loop over what the
+    mask shows (``_bd_chunks``), the backward one kernel (no ``flash_dq``
+    in the text, the routing manifest's ``flash_bwd_kernels`` 1), compiled
+    with the VMEM the code itself asks for, which a core has."""
+    from horovod_tpu import tracing
+    from horovod_tpu.ops import tile_table
+    fa = importlib.import_module("horovod_tpu.ops.flash_attention")
+    entry = tile_table._best_entry(128, 8192, "bfloat16", "block_diffusion",
+                                   None)
+    assert (entry["head_dim"], entry["seq"]) == (128, 8192)
+    assert entry["block_k"] == entry["block_k_bwd"] == 8192
+    assert entry["chunk"] < 8192 and entry["chunk_bwd"] < 8192
+    on = SingleDeviceSharding(v5e[0])
+    x = jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.bfloat16, sharding=on)
+    with tracing.program("bd_aot"):
+        lowered = _bd_fwd_bwd().lower(x, x, x)
+    gauges = hvd.metrics.snapshot()["gauges"]
+    read = {name: [s["value"] for s in gauges.get(name, ())
+                   if s["labels"].get("program") == "bd_aot"]
+            for name in ("flash_bwd_kernels", "flash_bwd_vmem_bytes",
+                         "bd_tiles_visited", "bd_tiles_total")}
+    assert read["flash_bwd_kernels"] == [1]
+    asked = _vmem_asked(lowered)
+    assert asked and read["flash_bwd_vmem_bytes"] == [asked[-1]]
+    assert all(fa._VMEM_DEFAULT < n <= fa._VMEM_CAP for n in asked)
+    assert (read["bd_tiles_visited"][0], read["bd_tiles_total"][0]) == (
+        fa.bd_tiles(4096, 4, entry["block_q"], 8192, entry["chunk"], d=128,
+                    itemsize=2))
+    assert read["bd_tiles_visited"][0] / read["bd_tiles_total"][0] <= 0.375
+    hlo = lowered.compile().as_text()
+    _assert_kernels_named(hlo, bwd_kernels=1)
+    assert "flash_dq" not in hlo
 
 
 def test_block_diffusion_step_compiles_for_v5e_at_published_widths(
@@ -225,7 +268,9 @@ def test_block_diffusion_step_compiles_for_v5e_at_published_widths(
     hlo = step.lower(_shapes(params, replicated),
                      _shapes(jax.eval_shape(opt.init, params), replicated),
                      tokens).compile().as_text()
-    _assert_kernels_named(hlo)
+    # since PR 38 the table's row keeps K resident under the mask too: the
+    # layer's backward is one kernel
+    _assert_kernels_named(hlo, bwd_kernels=1)
     assert re.search(r"%ragged-dot[^\n]* = [^\n]*custom-call\(", hlo)
     for scope in ("sdar/attn", "moe/route", "moe/experts",
                   "sdar/loss_head"):

@@ -975,3 +975,270 @@ def test_a_window_is_refused_where_it_is_not_built(rng):
         check_vma=False)
     with pytest.raises(ValueError, match="no sliding window"):
         jax.make_jaxpr(ring)(x, x, x)
+
+
+# --- the block-diffusion mask under the chunk loop (PR 38) -----------------
+
+def _bd_visible(seq, blk):
+    """The block-diffusion mask of ``2 * seq`` positions ``[noisy ; clean]``
+    as the benchmark's plain reference writes it, from its words
+    (``ops.attention.block_diffusion_mask`` is held to the same table in
+    ``tests/test_sdar.py``)."""
+    import os
+    import sys
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from reference import sdar_moe_ref
+    return np.asarray(sdar_moe_ref.visible(seq, blk))
+
+
+def _bd_dense_loss(q, k, v, tgt, seq, blk, seg=None, key_bias=None):
+    d = q.shape[-1]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * d ** -0.5
+    if key_bias is not None:
+        s = s + key_bias[:, None, None, :]
+    ok = jnp.asarray(_bd_visible(seq, blk))[None, None]
+    if seg is not None:
+        ok = ok & (seg[:, None, :, None] == seg[:, None, None, :])
+    p = jax.nn.softmax(jnp.where(ok, s, -1e30), axis=-1)
+    p = jnp.where(jnp.any(ok, axis=-1, keepdims=True), p, 0.0)
+    o = jnp.einsum("bhqk,bkhd->bqhd", p, v)
+    return jnp.mean((o - tgt) ** 2)
+
+
+# (seq, block_len, block_q, chunk, segment ids, key bias): a row is 2 * seq
+_BD_CHUNKED = {
+    "q_is_chunk": (64, 4, 16, 16, False, False),
+    "q_over_chunk": (64, 4, 32, 16, False, False),
+    "q_under_chunk": (64, 4, 16, 32, False, False),
+    "block_of_3": (48, 3, 16, 16, False, False),     # no power of two
+    "block_of_6_q_over": (96, 6, 32, 16, False, False),
+    "block_is_chunk": (64, 16, 16, 16, False, False),
+    "block_over_chunk": (64, 32, 16, 16, False, False),
+    "one_q_tile_a_half": (64, 8, 64, 32, False, False),
+    "segment_ids": (64, 4, 16, 16, True, False),
+    "key_bias": (64, 4, 16, 32, False, True),
+    # the shapes the loop cannot take: the plain grid, two kernels
+    "seq_no_whole_chunks": (48, 4, 16, 32, False, False),
+    "q_tile_over_the_seam": (48, 4, 32, 16, False, False),
+}
+_BD_PLAIN = ("seq_no_whole_chunks", "q_tile_over_the_seam")
+
+
+@pytest.mark.parametrize("case", list(_BD_CHUNKED), ids=list(_BD_CHUNKED))
+def test_chunked_block_diffusion_matches_dense(rng, case):
+    """The K axis resident and the loop over what the mask shows to a Q
+    tile (``_bd_chunks``), against dense attention under
+    ``block_diffusion_mask``: forward, dQ, dK, dV (and the bias's). Three
+    runs of chunks in each of two kernels; where the loop cannot take the
+    shape, the plain grid and two backward kernels."""
+    fa = _fa()
+    seq, blk, bq, chunk, packed, biased = _BD_CHUNKED[case]
+    B, T, H, D = 2, 2 * seq, 2, 8
+    q, k, v, tgt = (jnp.asarray(rng.standard_normal((B, T, H, D)),
+                                jnp.float32) for _ in range(4))
+    seg = (jnp.asarray(np.sort(rng.integers(0, 3, (B, T)), axis=1),
+                       jnp.int32) if packed else None)
+    bias = (jnp.asarray(rng.standard_normal((B, T)), jnp.float32)
+            if biased else None)
+    tiles = (bq, T, bq, T, chunk, chunk)
+
+    def loss_flash(q, k, v, bias):
+        o = fa._attend(q, k, v, False, D ** -0.5, bias, seg, tiles, 0,
+                       (seq, blk))
+        return jnp.mean((o - tgt) ** 2)
+
+    def loss_dense(q, k, v, bias):
+        return _bd_dense_loss(q, k, v, tgt, seq, blk, seg, bias)
+
+    argnums = (0, 1, 2, 3) if biased else (0, 1, 2)
+    text = str(jax.make_jaxpr(jax.grad(loss_flash, argnums))(q, k, v, bias))
+    plain = case in _BD_PLAIN
+    assert text.count("while[") == (0 if plain else 6)
+    assert _kernel_calls(text)["flash_dq"] == (1 if plain or biased else 0)
+    lf, gf = jax.value_and_grad(loss_flash, argnums)(q, k, v, bias)
+    ld, gd = jax.value_and_grad(loss_dense, argnums)(q, k, v, bias)
+    np.testing.assert_allclose(float(lf), float(ld), rtol=1e-5)
+    for a, b in zip(gf, gd):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-3, atol=1e-5)
+
+
+@pytest.mark.parametrize("seq,blk,bq,chunk", [
+    (64, 4, 16, 16), (64, 4, 32, 16), (64, 4, 16, 32), (48, 3, 16, 16),
+    (96, 6, 32, 16), (64, 16, 16, 16), (64, 32, 16, 16), (64, 8, 64, 32),
+    (120, 5, 40, 24), (512, 4, 64, 128)])
+def test_bd_chunks_are_what_the_dense_mask_shows(seq, blk, bq, chunk):
+    """``_bd_chunks``: against each Q tile the union of its runs is exactly
+    the chunks that hold a visible pair, no chunk is in two runs, and every
+    chunk of a run that takes no mask is visible whole. The same function on
+    traced scalars (as in a kernel) gives the same runs, and ``bd_tiles``
+    counts them."""
+    fa = _fa()
+    mask = _bd_visible(seq, blk)
+    nq, nc = 2 * seq // bq, 2 * seq // chunk
+    tiles = mask.reshape(nq, bq, nc, chunk)
+    some, whole = tiles.any(axis=(1, 3)), tiles.all(axis=(1, 3))
+    # a run's bounds for every Q tile at once (a bound may be one number)
+    runs = [(np.broadcast_to(lo, nq), np.broadcast_to(hi, nq), crossed)
+            for lo, hi, crossed in fa._bd_chunks(np.arange(nq), bq, chunk,
+                                                 seq, blk, xp=np)]
+    traced = jax.jit(lambda i: [(lo, hi) for lo, hi, _ in
+                                fa._bd_chunks(i, bq, chunk, seq, blk)])
+    assert [crossed for _, _, crossed in runs] == [True, False, True]
+    for i in range(nq):
+        visits = np.zeros(nc, int)
+        for lo, hi, crossed in runs:
+            assert 0 <= lo[i] <= hi[i] <= nc
+            visits[lo[i]:hi[i]] += 1
+            if not crossed:
+                assert whole[i, lo[i]:hi[i]].all(), (i, lo[i], hi[i])
+        np.testing.assert_array_equal(visits, some[i].astype(int))
+        assert [(int(lo), int(hi)) for lo, hi in traced(i)] == [
+            (lo[i], hi[i]) for lo, hi, _ in runs]
+    assert fa.bd_tiles(seq, blk, bq, 2 * seq, chunk) == (int(some.sum()),
+                                                          nq * nc)
+
+
+@pytest.mark.parametrize("block_q,block_k,chunk,visited,total", [
+    (1024, 1024, None, 24, 64),         # the plain grid of PR 27: 37.5 %
+    (256, 8192, 512, 160, 512),         # 31.25 %
+    (512, 8192, 512, 80, 256),          # 31.25 %
+    (256, 8192, 256, 288, 1024),        # 28.1 %
+    (512, 8192, 1024, 48, 128),         # 37.5 %: chunks of 1,024 save none
+])
+def test_bd_tiles_at_the_cells_shape(block_q, block_k, chunk, visited, total):
+    """8,192 positions in blocks of 4, head 128 in bfloat16: what
+    ``bd_tiles_visited`` / ``bd_tiles_total`` say at the tilings the sweep
+    tries, against a brute count over the dense mask (of which a quarter
+    is visible)."""
+    fa = _fa()
+    seq, blk = 4096, 4
+    mask = _bd_visible(seq, blk)
+    assert mask.mean() == pytest.approx(0.25, abs=1e-3)
+    c = chunk or block_k
+    seen = mask.reshape(2 * seq // block_q, block_q, 2 * seq // c, c).any(
+        axis=(1, 3))
+    assert (int(seen.sum()), seen.size) == (visited, total)
+    assert fa.bd_tiles(seq, blk, block_q, block_k, chunk, d=128,
+                       itemsize=2) == (visited, total)
+
+
+@pytest.mark.parametrize("seq,blk,bq,chunk,dtype,D", [
+    (64, 4, 16, 16, "float32", 16), (96, 6, 32, 16, "float32", 16),
+    (128, 4, 64, 128, "bfloat16", 64), (64, 4, 16, 32, "float32", 16)])
+def test_one_kernel_bd_backward_equals_the_two_kernel_one(rng, seq, blk, bq,
+                                                          chunk, dtype, D):
+    """Under the block-diffusion mask too the dK/dV kernel's loop sums dQ
+    and no ``flash_dq`` call is made; a tracked bias gradient brings the
+    two-kernel backward of the same tiles back (``flash_dq`` with the same
+    loop): dQ bit for bit, dK and dV summed in another order."""
+    fa = _fa()
+    B, H, T = 1, 2, 2 * seq
+    bd = (seq, blk)
+    q, k, v, do = (jnp.asarray(rng.standard_normal((B * H, T, D)), dtype)
+                   for _ in range(4))
+    scale = D ** -0.5
+
+    def backward(bias, want_db):
+        o, lse = fa._fwd(q, k, v, bias, None, None, H, scale, False, bq, T,
+                         bd=bd, chunk=chunk)
+
+        def bwd(q, k, v, o, lse, do):
+            return fa._bwd(H, scale, False, bq, T,
+                           (q, k, v, bias, None, None, o, lse), do,
+                           want_db=want_db, bd=bd, chunk=chunk)[:3]
+        calls = _kernel_calls(str(jax.make_jaxpr(bwd)(q, k, v, o, lse, do)))
+        return calls, bwd(q, k, v, o, lse, do)
+
+    calls, one = backward(None, want_db=False)
+    assert calls == {"flash_fwd": 0, "flash_dq": 0, "flash_dkv": 1}
+    calls, two = backward(jnp.zeros((B, T, 1), jnp.float32), want_db=True)
+    assert calls == {"flash_fwd": 0, "flash_dq": 1, "flash_dkv": 1}
+    as32 = lambda x: np.asarray(x.astype(jnp.float32))
+    np.testing.assert_array_equal(as32(one[0]), as32(two[0]))
+    tol = dict(rtol=2e-2, atol=2e-2) if dtype == "bfloat16" \
+        else dict(rtol=1e-5, atol=1e-5)
+    for a, b in zip(one[1:], two[1:]):
+        np.testing.assert_allclose(as32(a), as32(b), **tol)
+
+
+def test_an_unmasked_bd_chunk_skips_the_masks_arithmetic(rng):
+    """A chunk of the clean prefix that a Q tile sees whole goes through no
+    ``block_diffusion_mask``: of the forward's three runs of chunks two hold
+    the mask's shifts, one holds none."""
+    fa = _fa()
+    x = jnp.zeros((1, 128, 2, 8), jnp.float32)
+    tiles = (16, 128, 16, 128, 16, 16)
+    text = str(jax.make_jaxpr(lambda q: fa._attend(
+        q, q, q, False, 1.0, None, None, tiles, 0, (64, 4)))(x))
+    loops = text.split("while[")[1:]
+    assert len(loops) == 3
+    assert ["shift_right_logical" in body for body in loops] == [
+        True, False, True]
+
+
+_BD_FALLBACKS = {
+    # name -> (table entry's (block_q, block_k, chunk), (seq, block_len),
+    # head_dim): a block-diffusion call by the public API whose shape the
+    # loop cannot take
+    "no_chunk_in_the_entry": ((32, 32, None), (64, 4), 16),
+    "seq_no_whole_chunks": ((16, 128, 32), (48, 4), 16),
+    "q_tile_over_the_seam": ((32, 128, 16), (48, 4), 16),
+}
+
+
+@pytest.mark.parametrize("case", ["looped"] + list(_BD_FALLBACKS))
+def test_a_bd_call_the_loop_cannot_take_runs_the_plain_grid(
+        monkeypatch, tmp_path, case):
+    """``flash_attention(block_diffusion=)`` by the table alone: with an
+    entry that carries a chunk the call loops and its backward is one
+    kernel; without a chunk, with a half that is no whole number of
+    chunks, or with a Q tile over the seam between the halves, it runs the
+    plain grid and two backward kernels, as before."""
+    from horovod_tpu.ops import tile_table
+    fa = _fa()
+    (bq, bk, chunk), (seq, blk), D = _BD_FALLBACKS.get(
+        case, ((16, 128, 16), (64, 4), 16))
+    p = tmp_path / "t.json"
+    extra = {} if chunk is None else dict(chunk=chunk, chunk_bwd=chunk)
+    tile_table.record(D, 2 * seq, "float32", "block_diffusion", bq, bk,
+                      source="test", path=p, block_q_bwd=bq, block_k_bwd=bk,
+                      **extra)
+    monkeypatch.setenv("HOROVOD_FLASH_TILE_TABLE", str(p))
+    monkeypatch.setattr(fa, "_use_interpret", lambda: False)
+    x = jnp.zeros((1, 2 * seq, 2, D), jnp.float32)
+    text = str(jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
+        fa.flash_attention(q, k, v, block_diffusion=(seq, blk))),
+        argnums=(0, 1, 2)))(x, x, x))
+    looped = case == "looped"
+    assert _kernel_calls(text) == {"flash_fwd": 1, "flash_dkv": 1,
+                                   "flash_dq": 0 if looped else 1}
+    assert text.count("while[") == (6 if looped else 0)
+
+
+def test_a_bd_row_too_long_for_a_cores_vmem_runs_the_plain_grid():
+    """The fourth way out: where the resident K tile of a block-diffusion
+    call would take more VMEM than a core can give, ``_tiling`` hands back
+    the grid with the chunk as its K tile, as for the causal mask; at the
+    cell's shape (head 128, 8,192 positions) both kernels fit."""
+    fa = _fa()
+    for kernel, t in (("fwd", 131072), ("dkv", 65536)):
+        shape = dict(d=128, itemsize=2, kernel=kernel)
+        assert fa._tiling(8192, 8192, 512, 8192, 512, False, (4096, 4),
+                          **shape) == (512, 8192, 512)
+        assert fa._tiling(t, t, 512, t, 512, False, (t // 2, 4),
+                          **shape) == (512, 512, None)
+    assert fa._vmem_need("dkv", 512, 8192, 512, 128, 2,
+                         extra="dq") <= fa._VMEM_CAP
+    assert fa._vmem_need("dkv", 512, 65536, 512, 128, 2,
+                         extra="dq") > fa._VMEM_CAP
+    # and the other three, by the same function
+    assert fa._tiling(8192, 8192, 1024, 1024, None, False, (4096, 4),
+                      d=128, itemsize=2) == (1024, 1024, None)
+    assert fa._tiling(8000, 8000, 256, 8192, 512, False, (4000, 4), d=128,
+                      itemsize=2) == (256, 512, None)
+    assert fa._tiling(8192, 8192, 3072, 8192, 512, False, (4096, 4), d=128,
+                      itemsize=2) == (3072, 512, None)

@@ -427,6 +427,32 @@ def test_the_8k_entries_keep_k_resident_forward_and_backward(head, tiles):
                       itemsize=2, kernel="dkv") == (bqb, 8192, chunk_bwd)
 
 
+def test_the_block_diffusion_entry_keeps_k_resident_too():
+    """The one block-diffusion shape the benchmark runs (head 128, 8,192
+    positions ``[noisy ; clean]`` in blocks of 4) comes from PR 38's forward
+    + backward sweep on the v5e (``tools/tune_tiles.py --fwdbwd --shape
+    128x8192``): K resident in chunks of 512 both ways, so the backward is
+    one kernel, which asks for the 38.7 MB it needs. A row whose halves
+    are no whole chunks takes the entry's chunk as its grid's K tile."""
+    fa = _fa()
+    entry = tile_table._best_entry(128, 8192, "bfloat16", "block_diffusion",
+                                   None)
+    assert entry["source"] == "tuned-v5e-fwdbwd-pr38"
+    tiles = tile_table.lookup_full(128, 8192, "bfloat16", "block_diffusion")
+    assert tiles == (256, 8192, 512, 8192, 512, 512)
+    bq, bk, bqb, bkb, chunk, chunk_bwd = tiles
+    shape = dict(d=128, itemsize=2)
+    assert fa._tiling(8192, 8192, bq, bk, chunk, False, (4096, 4),
+                      kernel="fwd", **shape) == (bq, 8192, chunk)
+    assert fa._tiling(8192, 8192, bqb, bkb, chunk_bwd, False, (4096, 4),
+                      kernel="dkv", **shape) == (bqb, 8192, chunk_bwd)
+    need = fa._vmem_need("dkv", bqb, 8192, chunk_bwd, extra="dq", **shape)
+    assert fa._VMEM_DEFAULT < need == 38666240 <= fa._VMEM_CAP
+    assert fa.bd_tiles(4096, 4, bq, bk, chunk, **shape) == (160, 512)
+    assert fa._tiling(8000, 8000, bq, bk, chunk, False, (4000, 4),
+                      **shape) == (bq, chunk, None)
+
+
 def _fa():
     import importlib
     return importlib.import_module("horovod_tpu.ops.flash_attention")

@@ -14,6 +14,11 @@ model zoo: GPT-2 (d64 causal @1024), BERT (d64 full @512), long-context
 (d64/d128 @4096/8192), and the per-hop ring shard shapes. Off-TPU the
 kernels run in the Pallas interpreter and a timing means nothing, so the
 tool refuses to start.
+
+How the block-diffusion entry (head 128, 8,192 positions ``[noisy ;
+clean]``) was made: ``python tools/tune_tiles.py --fwdbwd --shape 128x8192
+--out chiprun_out/t.json`` on the v5e (~2.5 min, every candidate printed),
+the winner copied into the shipped table with the PR in its ``source``.
 """
 
 from __future__ import annotations
@@ -26,8 +31,10 @@ import time
 # Runnable from any cwd.
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# (head_dim, seq, batch, heads, causal, kind, dtype[, window]); a "window"
-# shape is causal under a sliding window of that many keys.
+# (head_dim, seq, batch, heads, causal, kind, dtype[, window or block]); a
+# "window" shape is causal under a sliding window of that many keys, a
+# "block_diffusion" shape is ``seq`` positions [noisy ; clean] in blocks of
+# that many.
 # Ring probes run causal=False: all but one of a ring's n hops carry
 # fully-unmasked blocks (the causal mask only bites near the diagonal hop),
 # so the unmasked kernel is the representative per-hop workload — a causal
@@ -57,6 +64,7 @@ FWDBWD_SHAPES = [
     (256, 8192, 2, 20, True, "causal", "bfloat16"),  # latent attention @8k
     (128, 16384, 2, 28, True, "causal", "bfloat16"),  # global layer @16k
     (128, 16384, 2, 28, True, "window", "bfloat16", 4096),  # SWA 4,096 @16k
+    (128, 8192, 2, 32, False, "block_diffusion", "bfloat16", 4),  # SDAR 2x4k
 ]
 
 # What the --fwdbwd sweep tries on a causal shape besides the plain grid:
@@ -67,7 +75,10 @@ FWDBWD_SHAPES = [
 # one-kernel backward (dQ summed in the dK/dV kernel's loop); the kernels
 # ask for the VMEM a resident tile takes (flash_attention._vmem_need), so
 # at 8,192 keys these compile, at head 256 in the forward too. Under a
-# window the loop also starts at the band's lower edge.
+# window the loop also starts at the band's lower edge; under the
+# block-diffusion mask it takes a noisy Q tile's own chunk and the clean
+# prefix (flash_attention._bd_chunks), and a chunk of 256 is tried too:
+# the chunk on the noisy diagonal is nearly all masked whatever its size.
 CAUSAL_CHUNKED = [(128, 128), (128, 256), (256, 128), (256, 256), (256, 512),
                   (512, 256), (512, 512)]
 # At 4k and beyond the grid's K axis has many steps to skip, a tile may be
@@ -78,6 +89,7 @@ LONG_TILES = [(512, 512), (512, 1024), (1024, 512), (1024, 1024),
               (512, 2048)]
 LONG_CHUNKED = [(256, 512), (512, 512), (256, 1024), (512, 1024),
                 (1024, 512), (1024, 1024)]
+BD_CHUNKED = LONG_CHUNKED + [(256, 256), (512, 256)]
 
 
 def main(argv=None) -> int:
@@ -126,30 +138,33 @@ def main(argv=None) -> int:
     if args.shape:
         shapes = [s for s in shapes if f"{s[0]}x{s[1]}" == args.shape]
     failed = 0
-    for head_dim, seq, batch, heads, causal, kind, dtype, *window in shapes:
+    for head_dim, seq, batch, heads, causal, kind, dtype, *extra in shapes:
         shape = (batch, seq, heads, head_dim)
-        window = window[0] if window else None
+        window = extra[0] if kind == "window" else None
+        bd = (seq // 2, extra[0]) if kind == "block_diffusion" else None
         t0 = time.time()
         candidates = None
-        if args.fwdbwd and kind in ("causal", "window"):
+        if args.fwdbwd and kind in ("causal", "window", "block_diffusion"):
             long = seq >= 4096
             candidates = LONG_TILES if long else [
                 c for c in FLASH_TILE_CANDIDATES if c[1] <= seq]
-            candidates = candidates + [(bq, seq, chunk) for bq, chunk in
-                                       (LONG_CHUNKED if long
-                                        else CAUSAL_CHUNKED)]
+            chunked = (BD_CHUNKED if bd else LONG_CHUNKED if long
+                       else CAUSAL_CHUNKED)
+            candidates = candidates + [(bq, seq, chunk)
+                                       for bq, chunk in chunked]
         try:
             best, trials = autotune_flash_blocks(
                 shape, dtype=dtype, causal=causal, record=True,
                 candidates=candidates, record_kind=kind,
-                record_path=args.out, window=window, **kw)
+                record_path=args.out, window=window, block_diffusion=bd,
+                **kw)
         except Exception as e:   # one bad shape must not kill the sweep
             print(f"  {kind} d{head_dim} T{seq} {dtype}: FAILED ({e})")
             failed += 1
             continue
         n_timed = len([k for k in trials if k[0] != "bwd"])
-        print(f"  {kind}{window or ''} d{head_dim} T{seq} {dtype}: "
-              f"best={best} "
+        print(f"  {kind}{extra[0] if extra else ''} d{head_dim} T{seq} "
+              f"{dtype}: best={best} "
               f"({n_timed} fwd candidates, {time.time() - t0:.0f}s)")
         # every candidate, the losers too: phase 1 is the forward alone,
         # "bwd" rows are forward + backward with the forward at its winner
