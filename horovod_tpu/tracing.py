@@ -414,8 +414,10 @@ NAMES: Dict[str, Name] = {
         "-exp(A_log)) and the write strength beta", "kda_in_ms.train"),
     "kimi/kda": Name(
         "scope", _KDA, "models.kimi_linear: the chunked gated delta rule "
-        "(ops.kda.chunk_kda), forward and its own backward: the chunks' "
-        "factors, the triangular solve, the scans over chunks and groups",
+        "(ops.kda.chunk_kda), forward and its own backward: the forward one "
+        "kernel (kda_fwd), the backward plain XLA (the "
+        "chunks' factors again, the triangular solve, the scans over chunks "
+        "and groups), and the copies into the grouped layout and back",
         "kda_ms.train; kda_roofline.train"),
     "kimi/kda_out": Name(
         "scope", _KIMI, "models.kimi_linear: a KDA layer's way out: the "
@@ -448,6 +450,12 @@ NAMES: Dict[str, Name] = {
         "their transposes in the backward: the price of that interface",
         "flash_layout_ms.train"),
     # kernel names (pallas_call(name=...): the custom call's instruction)
+    "kda_fwd": Name(
+        "kernel", _KDA, "the chunked gated delta rule's forward "
+        "(ops.kda._forward): a grid step is one chunk of a (row, head), "
+        "whose factors it makes and whose state stays in VMEM across the "
+        "row's chunks; it writes the output and each group's starting state "
+        "(run again in the backward under remat=dots)", "kda_fwd_ms.train"),
     "flash_fwd": Name(
         "kernel", _KERNELS, "flash attention forward (run again in the "
         "backward under remat=full; under dots its named output and "
@@ -563,6 +571,12 @@ NAMES: Dict[str, Name] = {
         "step's forward runs the gated delta rule over (ops.kda: chunks of "
         "64 positions, a row padded to whole groups); label program",
         "registry only: what kda_ms.train is spread over"),
+    "kda_fwd_kernel_chunks": Name(
+        "gauge", _KDA, "routing manifest: (row, head, chunk) steps the grids "
+        "of the KDA forward kernel (ops.kda._forward) walk, summed over the "
+        "calls the program's trace makes (one a KDA layer); equal to "
+        "kda_chunks where every KDA forward is the kernel; label program",
+        "registry only: that kda_fwd_ms.train times every forward"),
     "kda_state_saved_bytes": Name(
         "gauge", _KDA, "routing manifest: bytes the KDA forwards of one "
         "step keep for their backward besides their inputs (the float32 "
@@ -790,7 +804,9 @@ _ROUTING = ("moe_rows_bound", "moe_rows_tight", "bd_tiles_visited",
             "mla_kv_expanded_bytes", "mla_latent_bytes", "mtp_modules",
             "flash_bwd_kernels", "flash_bwd_vmem_bytes",
             "window_tiles_visited", "window_tiles_total", "kda_chunks",
-            "kda_state_saved_bytes")
+            "kda_state_saved_bytes", "kda_fwd_kernel_chunks")
+# what each call adds to, where the others' last call stands
+_ROUTING_SUMMED = ("kda_fwd_kernel_chunks",)
 
 
 def note_routing(**shapes) -> None:
@@ -799,14 +815,17 @@ def note_routing(**shapes) -> None:
     it is shaped for, from static values (:data:`_ROUTING` names them).
     Published as
     gauges ``{program}`` when :func:`program` exits; every layer of a
-    program says the same, and the last one stands. Outside a program
+    program says the same, and the last one stands, but for the counts of
+    :data:`_ROUTING_SUMMED`, which every call adds to. Outside a program
     nothing is kept."""
     unknown = set(shapes) - set(_ROUTING)
     if unknown:
         raise ValueError(f"not in the routing manifest: {sorted(unknown)}")
     routing = getattr(_TLS, "routing", None)
     if routing is not None:
-        routing.update(shapes)
+        for key, value in shapes.items():
+            routing[key] = value + (routing.get(key, 0)
+                                    if key in _ROUTING_SUMMED else 0)
 
 
 def routing_load(program_name: str, group_sizes) -> None:

@@ -546,23 +546,31 @@ def test_latent_attention_without_positions_compiles_at_its_16k_entry(
                           bwd_kernels=1 if dkv[2] else 2)
 
 
-def test_kda_compiles_for_v5e_at_the_cells_shape(v5e):
+def test_kda_compiles_for_v5e_at_the_cells_shape(v5e, mosaic):
     """The chunked gated delta rule and its own backward at the KDA cell's
     shape (one row of 16,384, 32 heads of 128; q, k, v in bfloat16, decays
-    and write strengths in float32) compile for a described v5e: plain XLA,
-    no kernel of its own, and nothing of a size that grows with positions x
-    positions."""
-    from horovod_tpu.ops.kda import chunk_kda
+    and write strengths in float32) compile for a described v5e: the
+    forward one Mosaic kernel, ``kda_fwd``, called once a forward, whose
+    VMEM lies under the flash kernels' cap; the backward plain XLA; and
+    nothing of a size that grows with positions x positions."""
+    kda = importlib.import_module("horovod_tpu.ops.kda")
+    fa = importlib.import_module("horovod_tpu.ops.flash_attention")
     on = SingleDeviceSharding(v5e[0])
     x = jax.ShapeDtypeStruct((1, 16384, 32, 128), jnp.bfloat16, sharding=on)
     g = jax.ShapeDtypeStruct((1, 16384, 32, 128), jnp.float32, sharding=on)
     b = jax.ShapeDtypeStruct((1, 16384, 32), jnp.float32, sharding=on)
-    compiled = jax.jit(jax.grad(
-        lambda q, k, v, g, b: jnp.sum(chunk_kda(q, k, v, g, b).astype(
-            jnp.float32)), argnums=(0, 1, 2, 3, 4))).lower(
-                x, x, x, g, b).compile()
+    lowered = jax.jit(jax.grad(
+        lambda q, k, v, g, b: jnp.sum(kda.chunk_kda(q, k, v, g, b).astype(
+            jnp.float32)), argnums=(0, 1, 2, 3, 4))).lower(x, x, x, g, b)
+    need = kda._vmem_need(kda.HEADS, kda.CHUNK, 128, 128, 2)
+    assert need < fa._VMEM_CAP
+    assert all(n < fa._VMEM_CAP for n in _vmem_asked(lowered))
+    compiled = lowered.compile()
     hlo = compiled.as_text()
-    assert "tpu_custom_call" not in hlo
+    # named kda_fwd, jvp_kda_fwd_ where no scope of a caller is round it
+    assert len(re.findall(r"%\w*kda_fwd\w*[.\d]* = [^\n]*custom-call\(",
+                          hlo)) == 1
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 4e9, mem.temp_size_in_bytes
 

@@ -2,9 +2,13 @@
 recurrence position by position: forward and ``jax.grad`` of every input,
 whole chunks and ragged rows, one group and several, decays at
 the initialisation's extremes, write strengths at 0 and 1, and bfloat16
-inputs against float32."""
+inputs against float32. The forward kernel (run by the Pallas interpreter
+off a TPU) is also held to the forward in plain XLA that it replaced, and
+the backward's private interface to what patches it."""
 
+import importlib
 import re
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -14,6 +18,7 @@ import pytest
 from horovod_tpu.ops.kda import (CHUNK, GROUP, SUB, chunk_kda, chunks_run,
                                  saved_state_bytes)
 
+kda = importlib.import_module("horovod_tpu.ops.kda")
 
 
 def recurrence(q, k, v, g, beta, scale):
@@ -93,6 +98,136 @@ def test_chunked_form_is_the_recurrence(case):
                                    err_msg=name)
 
 
+def xla_forward(q, k, v, g, beta):
+    """The forward as plain XLA over grouped inputs: each group's factors
+    by ``_intra``, then a scan of ``_step`` over its chunks, inside a scan
+    over the groups. ``(O in v's dtype, the state at every group's
+    start)``, what ``_forward`` returns."""
+    B, H, dk, dv = q.shape[1], q.shape[2], q.shape[-1], v.shape[-1]
+
+    def body(S, grp):
+        S1, O = jax.lax.scan(kda._step, S,
+                             kda._chunks_first(kda._intra(*grp)))
+        return S1, (kda._chunks_back(O), S)
+
+    _, (O, starts) = jax.lax.scan(body, jnp.zeros((B, H, dk, dv)),
+                                  (q, k, v, g, beta))
+    return O.astype(v.dtype), starts
+
+
+def grouped(x, T):
+    """(B, T, H, ...) -> (groups, B, H, group, chunk, ...), padded as
+    ``chunk_kda`` pads."""
+    B, _, H = x.shape[:3]
+    chunk, group, groups, padded = kda._layout(T)
+    x = jnp.pad(x, [(0, 0), (0, padded - T)] + [(0, 0)] * (x.ndim - 2))
+    x = x.reshape(B, groups, group, chunk, H, *x.shape[3:])
+    return jnp.moveaxis(x, (1, 4), (0, 2))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(_CASES), ids=list(_CASES))
+def test_the_forward_kernel_is_the_xla_forward(case, dtype):
+    """The kernel against the forward it replaced, on the same grouped
+    inputs (q, k, v in ``dtype``): the output in v's dtype, and the state
+    at every group's start, which the backward reads, to float32's
+    rounding; and the output against the recurrence position by
+    position."""
+    T, dk, dv, decay, beta = _CASES[case]
+    q, k, v, g, b = inputs(7, 2, T, 2, dk, dv, decay, beta)
+    q, k, v = (x.astype(dtype) for x in (q, k, v))
+    xs = [grouped(x, T) for x in (q, k, v, g, b)]
+    with jax.default_matmul_precision("highest"):
+        got, got_starts = jax.jit(kda._forward)(*xs)
+        want, want_starts = jax.jit(xla_forward)(*xs)
+        exact = recurrence(q, k, v, g, b, dk ** -0.5)
+    assert got.dtype == want.dtype == v.dtype and got.shape == want.shape
+    assert got_starts.shape == want_starts.shape == (
+        xs[0].shape[0], 2, 2, dk, dv)
+    top = max(float(jnp.max(jnp.abs(want.astype(jnp.float32)))), 1e-6)
+    ulp = 1e-5 if dtype == "float32" else 1e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), rtol=ulp,
+                               atol=ulp * top)
+    top = max(float(jnp.max(jnp.abs(want_starts))), 1e-6)
+    np.testing.assert_allclose(np.asarray(got_starts),
+                               np.asarray(want_starts), rtol=1e-5,
+                               atol=1e-5 * top)
+    o = jnp.moveaxis(got, (0, 2), (1, 4)).reshape(2, -1, 2, dv)[:, :T]
+    rel = float(jnp.linalg.norm(o.astype(jnp.float32) - exact)
+                / max(float(jnp.linalg.norm(exact)), 1e-30))
+    assert rel < (1e-5 if dtype == "float32" else 1e-2), rel
+
+
+@pytest.mark.parametrize("beta", [0.5, 1.0])
+def test_the_forward_kernel_holds_the_hardest_chunk(beta):
+    """One unit key repeated with no decay: ``A`` is all ones under the
+    diagonal, where the powers in the inverse of the chunk's triangular
+    system cancel (at ``beta`` 1 the chunked form itself parts from the
+    recurrence by ~4e-5 in float32). The kernel's output and kept states,
+    two groups of whole chunks, lie as near the recurrence as the forward
+    it replaced."""
+    B, T, H, dk, dv = 1, 1024, 2, 32, 16
+    q, _, v, _, _ = inputs(10, B, T, H, dk, dv, (1e-3, 1.6))
+    key = np.random.default_rng(11).standard_normal(dk)
+    k = jnp.broadcast_to(jnp.asarray(key / np.linalg.norm(key), jnp.float32),
+                         q.shape)
+    g = jnp.zeros_like(q)
+    b = jnp.full((B, T, H), beta, jnp.float32)
+    xs = [grouped(x, T) for x in (q, k, v, g, b)]
+    with jax.default_matmul_precision("highest"):
+        got, got_starts = jax.jit(kda._forward)(*xs)
+        want, want_starts = jax.jit(xla_forward)(*xs)
+        exact = recurrence(q, k, v, g, b, dk ** -0.5)
+    rel = lambda a, c: float(jnp.linalg.norm(a - c) / jnp.linalg.norm(c))
+    rowed = lambda o: jnp.moveaxis(o, (0, 2), (1, 4)).reshape(B, T, H, dv)
+    mine, theirs = rel(rowed(got), exact), rel(rowed(want), exact)
+    assert mine < max(2 * theirs, 1e-5) and mine < 1e-4, (mine, theirs)
+    # the kept states carry the same conditioning: within a few times the
+    # outputs' distance of each other
+    assert rel(got_starts, want_starts) < 5e-4
+
+
+def test_a_backward_patched_in_as_the_controls_do_is_the_one_run():
+    """``benchmark/controls_kimi_linear.py`` puts a faulty backward into
+    ``chunk_kda`` by patching ``_kda`` with a ``custom_vjp`` of ``_kda_fwd``
+    and ``_kda_bwd`` run on each group alone (each group's reverse scan
+    from ``dS = 0``). Through that interface the output is the sound one
+    and the gradient of every input that reads dS moves: a ``chunk_kda``
+    that stopped calling the module's ``_kda`` would read as the sound
+    system."""
+    T, dk, dv, decay, _ = _CASES["several_groups"]
+    q, k, v, g, b = inputs(8, 1, T, 2, dk, dv, decay)
+    w = jnp.asarray(np.random.default_rng(9).standard_normal((1, T, 2, dv)),
+                    jnp.float32)
+
+    @jax.custom_vjp
+    def faulty(*xs):
+        return kda._kda(*xs)
+
+    def bwd(res, dO):
+        one = lambda xs: kda._kda_bwd(tuple(x[None] for x in xs[:-1]),
+                                      xs[-1][None])
+        return tuple(x[:, 0] for x in jax.lax.map(one, (*res, dO)))
+
+    faulty.defvjp(kda._kda_fwd, bwd)
+    with jax.default_matmul_precision("highest"):
+        # as the check pulls a cotangent back: through jax.vjp, which runs
+        # the patched rule's forward and never its primal
+        want, pull = jax.vjp(chunk_kda, q, k, v, g, b)
+        sound = pull(w)
+        with mock.patch.object(kda, "_kda", faulty):
+            out, pull = jax.vjp(chunk_kda, q, k, v, g, b)
+            dropped = pull(w)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=1e-6, atol=1e-7)
+    apart = {name: float(jnp.linalg.norm(a - c) / jnp.linalg.norm(c))
+             for name, a, c in zip("q k v g beta".split(), dropped, sound)}
+    assert apart["q"] < 1e-5, apart          # dq reads no dS
+    for name in ("k", "v", "g", "beta"):
+        assert apart[name] > 1e-2, apart
+
+
 def test_bf16_inputs_stay_near_float32():
     """q, k, v in bfloat16 (the compute dtype), the state and decays in
     float32: the output is v's dtype and within bfloat16's rounding of the
@@ -119,15 +254,19 @@ def test_bf16_inputs_stay_near_float32():
 
 
 def test_no_position_is_visited_one_at_a_time():
-    """The forward and its backward scan over chunks and groups, never over
-    positions: no scan in the jaxpr of the gradient is as long as the
-    row."""
+    """The forward is one kernel whose grid steps are chunks of a (row,
+    head), its backward scans over chunks and groups, never over
+    positions: no scan in the jaxpr of the gradient is as long as the row,
+    and the kernel is called once."""
     B, T, H, dk, dv = 1, 2048, 2, 16, 16
     q, k, v, g, b = inputs(4, B, T, H, dk, dv, (1e-3, 1.6))
     text = str(jax.make_jaxpr(jax.grad(lambda q: jnp.sum(chunk_kda(
         q, k, v, g, b))))(q))
     lengths = [int(n) for n in re.findall(r"length=(\d+)", text)]
     assert lengths and max(lengths) <= GROUP, lengths
+    assert text.count("name=kda_fwd") == 1
+    heads, grid = kda._grid(grouped(q, T))
+    assert heads * np.prod(grid) == B * H * chunks_run(T)
 
 
 def test_what_the_forward_keeps_is_counted_from_shapes():

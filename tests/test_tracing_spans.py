@@ -54,7 +54,8 @@ ROUTING = ["moe_rows_bound", "moe_rows_tight", "moe_rows_overflow_layers",
            "mla_kv_expanded_bytes", "mla_latent_bytes", "mtp_modules",
            "flash_bwd_kernels", "flash_bwd_vmem_bytes",
            "moe_local_assignments", "moe_load_max_over_mean",
-           "moe_bias_moved_share", "kda_chunks", "kda_state_saved_bytes"]
+           "moe_bias_moved_share", "kda_chunks", "kda_state_saved_bytes",
+           "kda_fwd_kernel_chunks"]
 ENGINE_PHASES = ["sweep", "admit", "build", "dispatch", "readback", "commit"]
 
 
@@ -759,14 +760,16 @@ def kda_step():
 def test_lowered_kda_step_carries_its_scopes_and_manifest(kda_step):
     """The step of the sixth model family, lowered: its scopes (latent
     attention's own nested in ``kimi/mla``) and the flash kernels in the
-    text, and the manifest: the chunks the four KDA layers run (a row of
-    64 is one chunk, two heads) and the state their forwards keep (one
-    group's start a head and layer)."""
-    for name in KIMI_SCOPES + ["flash_fwd", "flash_dkv"]:
+    text, the KDA forward kernel, and the manifest: the
+    chunks the four KDA layers run (a row of 64 is one chunk, two heads),
+    as many as the forward kernel's grids walk, and the state their
+    forwards keep (one group's start a head and layer)."""
+    for name in KIMI_SCOPES + ["flash_fwd", "flash_dkv", "kda_fwd"]:
         assert name in kda_step["text"], name
     read = {name: _program_gauge(name, "kda_step")
             for name in tracing._ROUTING}
     assert read["kda_chunks"] == [1 * 1 * 2 * 4]
+    assert read["kda_fwd_kernel_chunks"] == read["kda_chunks"]
     assert read["kda_state_saved_bytes"] == [4 * 1 * 1 * 2 * 16 * 16 * 4]
     assert read["mla_kv_expanded_bytes"] == []
     table = kda_step["table"]
