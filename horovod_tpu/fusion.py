@@ -48,6 +48,8 @@ def _nbytes(leaf) -> int:
 # (128 lanes x 4 B), matching how the reference pads entries in its fusion
 # buffer; bucket *contents* are still tightly concatenated.
 FUSION_ALIGN_BYTES = 512
+# A TPU vector register's lanes: the minor dimension is padded to it.
+_LANES = 128
 
 
 def _plan_buckets(sizes: Sequence[int], threshold_bytes: int) -> List[int]:
@@ -220,6 +222,13 @@ def fuse(leaves: Sequence[Any],
             parts.sort(key=lambda p: p[0])
             flat = parts[0][1] if len(parts) == 1 else \
                 jnp.concatenate([p for _, p in parts])
+            if len(shapes[i]) > 1 and shapes[i][-1] < _LANES:
+                # A narrow leaf: without the barrier XLA rewrites
+                # reshape(slice(bucket)) as slice(reshape(bucket)), the
+                # WHOLE bucket reshaped to the leaf's trailing dims with
+                # each row padded to 128 lanes (a (4096, 4) conv kernel:
+                # a 1.2 GB copy of a 38 MB bucket to unpack 64 KB).
+                flat = lax.optimization_barrier(flat)
             out[i] = flat.reshape(shapes[i])
         return out
 

@@ -414,10 +414,9 @@ NAMES: Dict[str, Name] = {
         "-exp(A_log)) and the write strength beta", "kda_in_ms.train"),
     "kimi/kda": Name(
         "scope", _KDA, "models.kimi_linear: the chunked gated delta rule "
-        "(ops.kda.chunk_kda), forward and its own backward: the forward one "
-        "kernel (kda_fwd), the backward plain XLA (the "
-        "chunks' factors again, the triangular solve, the scans over chunks "
-        "and groups), and the copies into the grouped layout and back",
+        "(ops.kda.chunk_kda), forward and its own backward, one kernel "
+        "each (kda_fwd, kda_bwd), and the copies into the grouped layout "
+        "and back",
         "kda_ms.train; kda_roofline.train"),
     "kimi/kda_out": Name(
         "scope", _KIMI, "models.kimi_linear: a KDA layer's way out: the "
@@ -456,6 +455,12 @@ NAMES: Dict[str, Name] = {
         "whose factors it makes and whose state stays in VMEM across the "
         "row's chunks; it writes the output and each group's starting state "
         "(run again in the backward under remat=dots)", "kda_fwd_ms.train"),
+    "kda_bwd": Name(
+        "kernel", _KDA, "the chunked gated delta rule's backward "
+        "(ops.kda._backward): a grid step is one chunk of a (row, head); a "
+        "group's chunks run forward from its kept state, then back, the "
+        "chunk's factors again and their own backward, with dS in VMEM "
+        "across the row's chunks in reverse", "kda_bwd_ms.train"),
     "flash_fwd": Name(
         "kernel", _KERNELS, "flash attention forward (run again in the "
         "backward under remat=full; under dots its named output and "
@@ -577,6 +582,13 @@ NAMES: Dict[str, Name] = {
         "calls the program's trace makes (one a KDA layer); equal to "
         "kda_chunks where every KDA forward is the kernel; label program",
         "registry only: that kda_fwd_ms.train times every forward"),
+    "kda_bwd_kernel_chunks": Name(
+        "gauge", _KDA, "routing manifest: (row, head, chunk) steps back the "
+        "grids of the KDA backward kernel (ops.kda._backward) walk, summed "
+        "over the calls the program's trace makes (one a KDA layer); equal "
+        "to kda_chunks where every KDA backward is the kernel; label "
+        "program", "registry only: that kda_bwd_ms.train times every "
+        "backward"),
     "kda_state_saved_bytes": Name(
         "gauge", _KDA, "routing manifest: bytes the KDA forwards of one "
         "step keep for their backward besides their inputs (the float32 "
@@ -804,9 +816,10 @@ _ROUTING = ("moe_rows_bound", "moe_rows_tight", "bd_tiles_visited",
             "mla_kv_expanded_bytes", "mla_latent_bytes", "mtp_modules",
             "flash_bwd_kernels", "flash_bwd_vmem_bytes",
             "window_tiles_visited", "window_tiles_total", "kda_chunks",
-            "kda_state_saved_bytes", "kda_fwd_kernel_chunks")
+            "kda_state_saved_bytes", "kda_fwd_kernel_chunks",
+            "kda_bwd_kernel_chunks")
 # what each call adds to, where the others' last call stands
-_ROUTING_SUMMED = ("kda_fwd_kernel_chunks",)
+_ROUTING_SUMMED = ("kda_fwd_kernel_chunks", "kda_bwd_kernel_chunks")
 
 
 def note_routing(**shapes) -> None:

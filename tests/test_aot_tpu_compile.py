@@ -550,9 +550,11 @@ def test_kda_compiles_for_v5e_at_the_cells_shape(v5e, mosaic):
     """The chunked gated delta rule and its own backward at the KDA cell's
     shape (one row of 16,384, 32 heads of 128; q, k, v in bfloat16, decays
     and write strengths in float32) compile for a described v5e: the
-    forward one Mosaic kernel, ``kda_fwd``, called once a forward, whose
-    VMEM lies under the flash kernels' cap; the backward plain XLA; and
-    nothing of a size that grows with positions x positions."""
+    forward one Mosaic kernel, ``kda_fwd``, and the backward one,
+    ``kda_bwd``, each called once, each asking for the VMEM it counts
+    where that is over the compiler's default and under the flash
+    kernels' cap; and nothing of a size that grows with positions x
+    positions."""
     kda = importlib.import_module("horovod_tpu.ops.kda")
     fa = importlib.import_module("horovod_tpu.ops.flash_attention")
     on = SingleDeviceSharding(v5e[0])
@@ -562,17 +564,37 @@ def test_kda_compiles_for_v5e_at_the_cells_shape(v5e, mosaic):
     lowered = jax.jit(jax.grad(
         lambda q, k, v, g, b: jnp.sum(kda.chunk_kda(q, k, v, g, b).astype(
             jnp.float32)), argnums=(0, 1, 2, 3, 4))).lower(x, x, x, g, b)
-    need = kda._vmem_need(kda.HEADS, kda.CHUNK, 128, 128, 2)
-    assert need < fa._VMEM_CAP
-    assert all(n < fa._VMEM_CAP for n in _vmem_asked(lowered))
+    need = [kda._vmem_need(kda.HEADS, kda.CHUNK, 128, 128, 2),
+            kda._bwd_vmem_need(kda.HEADS, kda.CHUNK, 128, 128, kda.GROUP,
+                               2)]
+    assert all(n < fa._VMEM_CAP for n in need)
+    assert sorted(_vmem_asked(lowered)) == sorted(
+        n for n in need if n > fa._VMEM_DEFAULT)
     compiled = lowered.compile()
     hlo = compiled.as_text()
     # named kda_fwd, jvp_kda_fwd_ where no scope of a caller is round it
-    assert len(re.findall(r"%\w*kda_fwd\w*[.\d]* = [^\n]*custom-call\(",
-                          hlo)) == 1
-    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    # (transpose_jvp_kda_bwd__ the backward)
+    for kernel in ("kda_fwd", "kda_bwd"):
+        assert len(re.findall(rf"%\w*{kernel}\w*[.\d]* = [^\n]*custom-call\(",
+                              hlo)) == 1, kernel
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 4e9, mem.temp_size_in_bytes
+
+
+def test_a_narrow_leaf_is_unpacked_without_reshaping_its_bucket(v5e):
+    """A (4096, 4) leaf (a short convolution's kernel) fused behind a
+    (1024, 1024) one: the chip's compiler, left to itself, reshapes the
+    whole bucket to (N, 4) before slicing the leaf out, each row of 4
+    padded to 128 lanes, 32 times the bucket in temporaries. The unpack
+    slices the leaf out first, so the step needs none."""
+    from horovod_tpu import fusion
+    on = SingleDeviceSharding(v5e[0])
+    leaves = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=on)
+              for s in ((1024, 1024), (4096, 4))]
+    compiled = jax.jit(lambda ls: fusion.fused_apply(
+        lambda b: b * 2.0, ls)).lower(leaves).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
 def test_window_attention_step_compiles_for_v5e_and_fits_the_chip(

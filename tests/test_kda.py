@@ -2,9 +2,10 @@
 recurrence position by position: forward and ``jax.grad`` of every input,
 whole chunks and ragged rows, one group and several, decays at
 the initialisation's extremes, write strengths at 0 and 1, and bfloat16
-inputs against float32. The forward kernel (run by the Pallas interpreter
-off a TPU) is also held to the forward in plain XLA that it replaced, and
-the backward's private interface to what patches it."""
+inputs against float32. The two kernels (run by the Pallas interpreter off
+a TPU) are also held to the forward and the backward in plain XLA that
+they replaced, kept here as their reference, and the backward's private
+interface to what patches it."""
 
 import importlib
 import re
@@ -19,13 +20,148 @@ from horovod_tpu.ops.kda import (CHUNK, GROUP, SUB, chunk_kda, chunks_run,
                                  saved_state_bytes)
 
 kda = importlib.import_module("horovod_tpu.ops.kda")
+_F32 = jnp.float32
 
 
-def recurrence(q, k, v, g, beta, scale):
+# ---------------------------------------------------------------------------
+# the plain-XLA form the kernels replaced: a chunk's own factors for any
+# leading batch of chunks (``_intra``, the module docstring's names), the
+# step on the state and its backward, over grouped inputs
+# ---------------------------------------------------------------------------
+
+def _mm(spec, *xs):
+    """A product in float32 at full precision (on a TPU a float32 product is
+    otherwise rounded like bfloat16)."""
+    return jnp.einsum(spec, *xs, precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=_F32)
+
+
+def _intra(q, k, v, g, beta):
+    """A chunk's own factors, for any leading batch of chunks. ``q``, ``k``
+    (..., C, dk), ``v`` (..., C, dv), ``g`` (..., C, dk) float32 log-decays,
+    ``beta`` (..., C) float32. Returns float32 ``(Qd (C, dk), Aqk (C, C),
+    W (C, dk), U0 (C, dv), Kr (C, dk), gamma (dk,))``, the names of the
+    module's docstring: ``Qd = scale Q e^G`` (``scale = dk ** -0.5``),
+    ``W`` and ``U0`` the solve's two halves (``U = U0 - W S0``), ``Kr = K
+    e^(G_C - G)`` and ``gamma = e^G_C``."""
+    C, dk = k.shape[-2:]
+    scale = dk ** -0.5
+    ns, sub = C // SUB, SUB
+    q, k, v = (x.astype(_F32) for x in (q, k, v))
+    G = jnp.cumsum(g, axis=-2)
+    last = G[..., -1, :]
+    Qd = scale * q * jnp.exp(G)
+    Kd = k * jnp.exp(G)
+    Kr = k * jnp.exp(last[..., None, :] - G)
+
+    # across sub-chunks: rows of sub-chunk I against every earlier
+    # position, both factors taken from ``ref_I``, G at the position before
+    # sub-chunk I (0 before the chunk's first)
+    Gs = G.reshape(*G.shape[:-2], ns, sub, dk)
+    ref = jnp.concatenate([jnp.zeros_like(Gs[..., :1, -1, :]),
+                           Gs[..., :-1, -1, :]], axis=-2)      # (..., ns, dk)
+    rows = jnp.exp(Gs - ref[..., None, :])                     # <= 1
+    pos = jnp.arange(C)
+    before = pos[None, :] < (jnp.arange(ns) * sub)[:, None]    # (ns, C)
+    cols = jnp.exp(jnp.where(before[:, :, None],
+                             ref[..., :, None, :] - G[..., None, :, :],
+                             -jnp.inf))                        # <= 1, or 0
+    kcols = k[..., None, :, :] * cols                   # (..., ns, C, dk)
+    qs, ks = (x.reshape(*x.shape[:-2], ns, sub, dk) for x in (q, k))
+    # (..., ns, sub, C): sub-chunk I's rows against the positions before it
+    off_qk = _mm("...isc,...ijc->...isj", qs * rows, kcols)
+    off_kk = _mm("...isc,...ijc->...isj", ks * rows, kcols)
+
+    # inside a sub-chunk: exp(G_i - G_j) element by element, j <= i
+    lower = jnp.arange(sub)[:, None] >= jnp.arange(sub)[None, :]
+    inside = jnp.exp(jnp.where(lower[:, :, None],
+                               Gs[..., :, None, :] - Gs[..., None, :, :],
+                               -jnp.inf))           # (..., ns, s, s, dk)
+    diag_qk = jnp.sum(qs[..., :, None, :] * ks[..., None, :, :] * inside, -1)
+    diag_kk = jnp.sum(ks[..., :, None, :] * ks[..., None, :, :] * inside, -1)
+    diag_kk = jnp.where(jnp.eye(sub, dtype=bool), 0.0, diag_kk)
+
+    def whole(off, diag):
+        # (..., ns, sub, C) + the diagonal blocks -> (..., C, C)
+        blocks = off.reshape(*off.shape[:-1], ns, sub)
+        eye = jnp.eye(ns, dtype=_F32)[:, None, :, None]
+        return (blocks + diag[..., :, :, None, :] * eye).reshape(
+            *off.shape[:-3], C, C)
+
+    Aqk = scale * whole(off_qk, diag_qk)
+    A = whole(off_kk, diag_kk)
+    # (I + diag(beta) A) [W | U0] = diag(beta) [Kd | V], A strictly lower
+    rhs = beta[..., None] * jnp.concatenate([Kd, v], axis=-1)
+    solved = _solve(beta[..., None] * A, rhs)
+    return Qd, Aqk, solved[..., :dk], solved[..., dk:], Kr, jnp.exp(last)
+
+
+def _solve(N, rhs):
+    """``(I + N)^-1 rhs`` for ``N`` (..., C, C) strictly lower, by blocks of
+    ``SUB``: each diagonal block's inverse as ``(I - D)(I + D^2)(I +
+    D^4)(I + D^8)`` (``D^SUB = 0``), then forward substitution over the
+    block rows by products. XLA's triangular solve walks the chunk's 64
+    rows one at a time."""
+    C = N.shape[-1]
+    blk = lambda I, J: N[..., I * SUB:(I + 1) * SUB, J * SUB:(J + 1) * SUB]
+    diag = jnp.stack([blk(I, I) for I in range(C // SUB)], axis=-3)
+    inv, power, m = jnp.eye(SUB, dtype=N.dtype) - diag, diag, 2
+    while m < SUB:
+        power = _mm("...ij,...jk->...ik", power, power)
+        inv = inv + _mm("...ij,...jk->...ik", inv, power)
+        m *= 2
+    rows = []
+    for I in range(C // SUB):
+        r = rhs[..., I * SUB:(I + 1) * SUB, :]
+        for J in range(I):
+            r = r - _mm("...ij,...jv->...iv", blk(I, J), rows[J])
+        rows.append(_mm("...ij,...jv->...iv", inv[..., I, :, :], r))
+    return jnp.concatenate(rows, axis=-2)
+
+
+def _step(S, xs):
+    """One chunk on the state ``S`` (batch, dk, dv): ``(S1, O)``."""
+    Qd, Aqk, W, U0, Kr, gamma = xs
+    U = U0 - _mm("...cd,...dv->...cv", W, S)
+    O = (_mm("...cd,...dv->...cv", Qd, S)
+         + _mm("...ij,...jv->...iv", Aqk, U))
+    S1 = gamma[..., :, None] * S + _mm("...cd,...cv->...dv", Kr, U)
+    return S1, O
+
+
+def _step_bwd(dS1, xs):
+    """:func:`_step` backward for one chunk: ``dS1`` is the cotangent of
+    the state it left, ``xs`` its factors, its ``S0`` and its output's
+    cotangent. ``(dS0, d factors)``."""
+    (Qd, Aqk, W, U0, Kr, gamma), S0, dO = xs
+    U = U0 - _mm("...cd,...dv->...cv", W, S0)
+    dU = (_mm("...ij,...iv->...jv", Aqk, dO)
+          + _mm("...cd,...dv->...cv", Kr, dS1))
+    dQd = _mm("...cv,...dv->...cd", dO, S0)
+    dAqk = _mm("...iv,...jv->...ij", dO, U)
+    dW = -_mm("...cv,...dv->...cd", dU, S0)
+    dKr = _mm("...cv,...dv->...cd", U, dS1)
+    dgamma = jnp.sum(S0 * dS1, axis=-1)
+    dS0 = (gamma[..., :, None] * dS1
+           + _mm("...cd,...cv->...dv", Qd, dO)
+           - _mm("...cd,...cv->...dv", W, dU))
+    return dS0, (dQd, dAqk, dW, dU, dKr, dgamma)
+
+
+def _chunks_first(tree):
+    """(batch, heads, chunks, ...) -> (chunks, batch, heads, ...)."""
+    return jax.tree_util.tree_map(lambda x: jnp.moveaxis(x, 2, 0), tree)
+
+
+def _chunks_back(tree):
+    return jax.tree_util.tree_map(lambda x: jnp.moveaxis(x, 0, 2), tree)
+
+
+def recurrence(q, k, v, g, beta, scale, dtype=jnp.float32):
     """``S' = Diag(exp g_t) S``, ``S_t = S' + beta_t k_t (v_t - S'^T
     k_t)^T``, ``o_t = scale S_t^T q_t``, one position at a time, in
-    float32."""
-    q, k, v, g, beta = (x.astype(jnp.float32) for x in (q, k, v, g, beta))
+    ``dtype``."""
+    q, k, v, g, beta = (x.astype(dtype) for x in (q, k, v, g, beta))
     dk, dv = q.shape[-1], v.shape[-1]
 
     def row(q, k, v, g, beta):          # (T, dk) ... one head
@@ -34,7 +170,8 @@ def recurrence(q, k, v, g, beta, scale):
             S = jnp.exp(gt)[:, None] * S
             S = S + bt * kt[:, None] * (vt - S.T @ kt)[None, :]
             return S, scale * (S.T @ qt)
-        return jax.lax.scan(step, jnp.zeros((dk, dv)), (q, k, v, g, beta))[1]
+        return jax.lax.scan(step, jnp.zeros((dk, dv), dtype),
+                            (q, k, v, g, beta))[1]
 
     heads = jax.vmap(row, in_axes=(1, 1, 1, 1, 1), out_axes=1)
     return jax.vmap(heads)(q, k, v, g, beta)
@@ -106,13 +243,37 @@ def xla_forward(q, k, v, g, beta):
     B, H, dk, dv = q.shape[1], q.shape[2], q.shape[-1], v.shape[-1]
 
     def body(S, grp):
-        S1, O = jax.lax.scan(kda._step, S,
-                             kda._chunks_first(kda._intra(*grp)))
-        return S1, (kda._chunks_back(O), S)
+        S1, O = jax.lax.scan(_step, S, _chunks_first(_intra(*grp)))
+        return S1, (_chunks_back(O), S)
 
     _, (O, starts) = jax.lax.scan(body, jnp.zeros((B, H, dk, dv)),
                                   (q, k, v, g, beta))
     return O.astype(v.dtype), starts
+
+
+def xla_backward(res, dO):
+    """The backward as plain XLA, ``_kda_bwd``'s interface: a reverse scan
+    over the groups carrying ``dS``; a group's factors again under
+    ``jax.vjp`` of ``_intra``, its chunks run forward from the kept start
+    for each chunk's ``S0``, walked back by ``_step_bwd``, and the factors'
+    cotangents pulled back to ``(dq, dk, dv, dg, dbeta)``."""
+    q, k, v, g, beta, starts = res
+
+    def body(dS, xs):
+        grp, S, dO_g = xs
+        factors, pull = jax.vjp(_intra, *grp)
+        factors = _chunks_first(factors)
+        _, S0s = jax.lax.scan(lambda S, x: (_step(S, x)[0], S), S, factors)
+        dS, d = jax.lax.scan(_step_bwd, dS,
+                             (factors, S0s,
+                              _chunks_first(dO_g.astype(_F32))),
+                             reverse=True)
+        return dS, pull(_chunks_back(d))
+
+    B, H, dk, dv = q.shape[1], q.shape[2], q.shape[-1], v.shape[-1]
+    _, grads = jax.lax.scan(body, jnp.zeros((B, H, dk, dv), _F32),
+                            ((q, k, v, g, beta), starts, dO), reverse=True)
+    return grads
 
 
 def grouped(x, T):
@@ -188,6 +349,126 @@ def test_the_forward_kernel_holds_the_hardest_chunk(beta):
     assert rel(got_starts, want_starts) < 5e-4
 
 
+def _cotangent(seed, T, dv, dtype):
+    """An output cotangent (1, T, 2, dv) in the grouped layout."""
+    r = np.random.default_rng(seed)
+    return grouped(jnp.asarray(r.standard_normal((2, T, 2, dv)), dtype), T)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(_CASES), ids=list(_CASES))
+def test_the_backward_kernel_is_the_xla_backward(case, dtype):
+    """The kernel against the backward it replaced, on the same residuals
+    (the inputs in ``dtype`` and the forward kernel's kept states) and
+    output cotangent: the five gradients in the inputs' dtypes and grouped
+    layout, to float32's rounding (a bfloat16 gradient to its own)."""
+    T, dk, dv, decay, beta = _CASES[case]
+    q, k, v, g, b = inputs(12, 2, T, 2, dk, dv, decay, beta)
+    q, k, v = (x.astype(dtype) for x in (q, k, v))
+    xs = [grouped(x, T) for x in (q, k, v, g, b)]
+    dO = _cotangent(13, T, dv, dtype)
+    with jax.default_matmul_precision("highest"):
+        res = (*xs, jax.jit(kda._forward)(*xs)[1])
+        got = jax.jit(kda._kda_bwd)(res, dO)
+        want = jax.jit(xla_backward)(res, dO)
+    for name, a, c, x in zip("q k v g beta".split(), got, want, xs):
+        assert a.dtype == c.dtype == x.dtype, name
+        assert a.shape == c.shape == x.shape, name
+        a, c = (np.asarray(y, np.float32) for y in (a, c))
+        assert np.all(np.isfinite(a)), name
+        top = max(float(np.max(np.abs(c))), 1e-6)
+        ulp = 1e-5 if x.dtype == jnp.float32 else 1e-2
+        np.testing.assert_allclose(a, c, rtol=ulp, atol=ulp * top,
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("beta", [0.5, 1.0])
+def test_the_backward_kernel_holds_the_hardest_chunk(beta):
+    """The forward's hardest chunk (one unit key repeated with no decay,
+    two groups of whole chunks) backward: the kernel's five gradients lie
+    as near ``jax.vjp`` of the float32 recurrence as the backward it
+    replaced, whose transposed solve meets the same cancelling powers."""
+    B, T, H, dk, dv = 1, 1024, 2, 32, 16
+    q, _, v, _, _ = inputs(14, B, T, H, dk, dv, (1e-3, 1.6))
+    key = np.random.default_rng(15).standard_normal(dk)
+    k = jnp.broadcast_to(jnp.asarray(key / np.linalg.norm(key), jnp.float32),
+                         q.shape)
+    g = jnp.zeros_like(q)
+    b = jnp.full((B, T, H), beta, jnp.float32)
+    w = jnp.asarray(np.random.default_rng(16).standard_normal(
+        (B, T, H, dv)), jnp.float32)
+    xs = [grouped(x, T) for x in (q, k, v, g, b)]
+    with jax.default_matmul_precision("highest"):
+        res = (*xs, jax.jit(kda._forward)(*xs)[1])
+        got = jax.jit(kda._kda_bwd)(res, grouped(w, T))
+        want = jax.jit(xla_backward)(res, grouped(w, T))
+        _, pull = jax.vjp(lambda *a: recurrence(*a, dk ** -0.5),
+                          q, k, v, g, b)
+        exact = [grouped(x, T) for x in pull(w)]
+    rel = lambda a, c: float(jnp.linalg.norm(a - c) / jnp.linalg.norm(c))
+    for name, a, c, e in zip("q k v g beta".split(), got, want, exact):
+        mine, theirs = rel(a, e), rel(c, e)
+        assert mine < max(2 * theirs, 1e-5) and mine < 1e-3, (
+            name, mine, theirs)
+
+
+def test_the_backward_kernel_lies_nearer_float64_than_the_xla_backward():
+    """Rows of one chunk (as the model tests run them), against ``jax.vjp``
+    of the recurrence in float64: each of the kernel's five gradients lies
+    no further than the XLA backward's, and so does what a head's ``A_log``
+    reads of ``dg``, its sum against the decays, which cancels. The kernel
+    takes a pair's part of ``dg`` only at the rows between its two
+    positions, and the decays between two rows of a sub-chunk from their
+    own sums; XLA's backward subtracts suffix sums of the whole chunk and
+    cumulative sums of the whole row, losing the digits that cancel."""
+    T, dk, dv = 40, 16, 16
+    ungroup = lambda x: jnp.moveaxis(x, (0, 2), (1, 4)).reshape(
+        1, -1, 2, *x.shape[5:])[:, :T]
+    worst = {"kernel": np.zeros(6), "xla": np.zeros(6)}
+    for seed in range(6):
+        q, k, v, g, b = inputs(20 + seed, 1, T, 2, dk, dv, (1e-3, 1.6))
+        w = jnp.asarray(np.random.default_rng(30 + seed).standard_normal(
+            (1, T, 2, dv)), jnp.float32)
+        with jax.enable_x64(True):
+            _, pull = jax.vjp(lambda *a: recurrence(
+                *a, dk ** -0.5, dtype=jnp.float64),
+                *(jnp.asarray(x, jnp.float64) for x in (q, k, v, g, b)))
+            exact = [np.asarray(x) for x in pull(jnp.asarray(w, jnp.float64))]
+        xs = [grouped(x, T) for x in (q, k, v, g, b)]
+        with jax.default_matmul_precision("highest"):
+            res = (*xs, jax.jit(kda._forward)(*xs)[1])
+            for name, backward in (("kernel", kda._kda_bwd),
+                                   ("xla", xla_backward)):
+                got = [np.asarray(ungroup(x), np.float64) for x in
+                       jax.jit(backward)(res, grouped(w, T))]
+                apart = [np.linalg.norm(a - e) / np.linalg.norm(e)
+                         for a, e in zip(got, exact)]
+                reads = lambda dg: np.sum(dg * np.asarray(g), axis=(1, 3))
+                apart.append(np.max(np.abs(reads(got[3]) - reads(exact[3]))
+                                    / np.abs(reads(exact[3]))))
+                worst[name] = np.maximum(worst[name], apart)
+    assert np.all(worst["kernel"] <= worst["xla"]), worst
+
+
+@pytest.mark.parametrize("width", [SUB, CHUNK])
+def test_the_kernels_sums_run_down_and_up_the_rows(width):
+    """``_sums_down`` (the forward's cumulative log-decays), ``_sums_up``
+    and ``_sums_before`` (the backward's: what reads ``g_m`` in the rows
+    from ``m`` on, or before it) against NumPy's cumulative sums, over
+    runs of ``width`` rows of a chunk."""
+    x = np.random.default_rng(17).standard_normal((2, CHUNK, 8))
+    pos = np.broadcast_to(np.arange(CHUNK)[None, :, None] % width, x.shape)
+    runs = x.reshape(2, CHUNK // width, width, 8)
+    down = np.cumsum(runs, 2).reshape(x.shape)
+    up = np.cumsum(runs[:, :, ::-1], 2)[:, :, ::-1].reshape(x.shape)
+    for fn, want in ((kda._sums_down, down), (kda._sums_up, up),
+                     (kda._sums_before, down - x)):
+        got = jax.jit(fn, static_argnums=2)(jnp.asarray(x, jnp.float32),
+                                            jnp.asarray(pos), width)
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5,
+                                   atol=1e-5)
+
+
 def test_a_backward_patched_in_as_the_controls_do_is_the_one_run():
     """``benchmark/controls_kimi_linear.py`` puts a faulty backward into
     ``chunk_kda`` by patching ``_kda`` with a ``custom_vjp`` of ``_kda_fwd``
@@ -254,17 +535,19 @@ def test_bf16_inputs_stay_near_float32():
 
 
 def test_no_position_is_visited_one_at_a_time():
-    """The forward is one kernel whose grid steps are chunks of a (row,
-    head), its backward scans over chunks and groups, never over
-    positions: no scan in the jaxpr of the gradient is as long as the row,
-    and the kernel is called once."""
+    """The forward and the backward are one kernel each, whose grid steps
+    are chunks of a (row, block of heads): the gradient's jaxpr calls each
+    once, no scan in it is longer than a group (the backward has none
+    left), and the grids' (row, head, chunk) steps are the row's chunks,
+    once a head forward and once backward."""
     B, T, H, dk, dv = 1, 2048, 2, 16, 16
     q, k, v, g, b = inputs(4, B, T, H, dk, dv, (1e-3, 1.6))
     text = str(jax.make_jaxpr(jax.grad(lambda q: jnp.sum(chunk_kda(
         q, k, v, g, b))))(q))
     lengths = [int(n) for n in re.findall(r"length=(\d+)", text)]
-    assert lengths and max(lengths) <= GROUP, lengths
+    assert max(lengths, default=0) <= GROUP, lengths
     assert text.count("name=kda_fwd") == 1
+    assert text.count("name=kda_bwd") == 1
     heads, grid = kda._grid(grouped(q, T))
     assert heads * np.prod(grid) == B * H * chunks_run(T)
 
@@ -298,7 +581,7 @@ def test_the_chunks_triangular_system_is_solved_by_blocks(beta):
         rhs = jnp.asarray(r.standard_normal((len(A), 64, 40)), jnp.float32)
         want = jax.lax.linalg.triangular_solve(
             N, rhs, left_side=True, lower=True, unit_diagonal=True)
-        got = kda._solve(N, rhs)
+        got = _solve(N, rhs)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-4, atol=1e-4 * float(
                                        jnp.max(jnp.abs(want))))

@@ -55,7 +55,7 @@ ROUTING = ["moe_rows_bound", "moe_rows_tight", "moe_rows_overflow_layers",
            "flash_bwd_kernels", "flash_bwd_vmem_bytes",
            "moe_local_assignments", "moe_load_max_over_mean",
            "moe_bias_moved_share", "kda_chunks", "kda_state_saved_bytes",
-           "kda_fwd_kernel_chunks"]
+           "kda_fwd_kernel_chunks", "kda_bwd_kernel_chunks"]
 ENGINE_PHASES = ["sweep", "admit", "build", "dispatch", "readback", "commit"]
 
 
@@ -760,16 +760,19 @@ def kda_step():
 def test_lowered_kda_step_carries_its_scopes_and_manifest(kda_step):
     """The step of the sixth model family, lowered: its scopes (latent
     attention's own nested in ``kimi/mla``) and the flash kernels in the
-    text, the KDA forward kernel, and the manifest: the
+    text, the KDA forward and backward kernels, and the manifest: the
     chunks the four KDA layers run (a row of 64 is one chunk, two heads),
-    as many as the forward kernel's grids walk, and the state their
-    forwards keep (one group's start a head and layer)."""
-    for name in KIMI_SCOPES + ["flash_fwd", "flash_dkv", "kda_fwd"]:
+    as many as the forward kernel's grids walk and as many as the backward
+    kernel's walk back, and the state their forwards keep (one group's
+    start a head and layer)."""
+    for name in KIMI_SCOPES + ["flash_fwd", "flash_dkv", "kda_fwd",
+                               "kda_bwd"]:
         assert name in kda_step["text"], name
     read = {name: _program_gauge(name, "kda_step")
             for name in tracing._ROUTING}
     assert read["kda_chunks"] == [1 * 1 * 2 * 4]
     assert read["kda_fwd_kernel_chunks"] == read["kda_chunks"]
+    assert read["kda_bwd_kernel_chunks"] == read["kda_chunks"]
     assert read["kda_state_saved_bytes"] == [4 * 1 * 1 * 2 * 16 * 16 * 4]
     assert read["mla_kv_expanded_bytes"] == []
     table = kda_step["table"]
