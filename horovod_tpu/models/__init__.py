@@ -3,20 +3,14 @@ MNIST CNN, ResNet-50, BERT-large, GPT-2 medium, ViT-B/16 — implemented in
 flax for TPU (bf16 compute, MXU-friendly shapes), not ported from the
 reference's TF/torch example scripts. Plus the Llama family (RoPE +
 RMSNorm + SwiGLU + GQA, optional Mixtral-style MoE) and the T5
-encoder-decoder family for modern-LLM migrations — all three
-architecture classes (decoder-only, encoder-only, encoder-decoder) — and a
-block-diffusion decoder with dropless routed experts (``models/sdar.py``,
-training only), and a hybrid of gated short convolutions and grouped-query
-attention with a dense SwiGLU first and bias-selected routed experts after
-(``models/lfm2.py``, training only), and a decoder with latent attention, a
-shared expert beside the routed ones and a multi-token-prediction module in
-its loss (``models/glm4_moe_lite.py``, training only), and a decoder of
-position-free global attention beside sliding-window attention with RoPE,
-whose router reads a block's input before attention and whose experts are
-ReGLU (``models/smallthinker.py``, training only), and a decoder of chunked
-gated delta-rule layers (KDA, ``ops/kda.py``) beside position-free latent
-attention, a dense SwiGLU first and routed experts with a shared one after
-(``models/kimi_linear.py``, training only).
+encoder-decoder family — all three architecture classes — and five families
+trained here and not served, each with routed experts: block diffusion
+(``sdar.py``), gated short convolutions beside attention (``lfm2.py``),
+latent attention with multi-token prediction (``glm4_moe_lite.py``), window
+beside position-free global attention (``smallthinker.py``) and KDA beside
+latent attention (``kimi_linear.py``). The decoder parts that more than one
+family uses (RMSNorm, RoPE, grouped-query and latent attention, the
+next-token head) are ``layers.py``'s; no training family imports another.
 """
 
 from horovod_tpu.models.mnist import MnistCNN  # noqa: F401
@@ -28,20 +22,18 @@ __all__ = ["MnistCNN", "ResNet50", "ResNet18", "LFM2", "LFM2Config",
            "get_model"]
 
 
+# flax-heavy families load when they are asked for, as get_model does
+_LAZY = {"LFM2": "lfm2", "LFM2Config": "lfm2",
+         "Glm4MoeLite": "glm4_moe_lite", "Glm4MoeLiteConfig": "glm4_moe_lite",
+         "SmallThinker": "smallthinker", "SmallThinkerConfig": "smallthinker",
+         "KimiLinear": "kimi_linear", "KimiLinearConfig": "kimi_linear"}
+
+
 def __getattr__(name):
-    # flax-heavy families load when they are asked for, as get_model does
-    if name in ("LFM2", "LFM2Config"):
-        from horovod_tpu.models import lfm2
-        return getattr(lfm2, name)
-    if name in ("Glm4MoeLite", "Glm4MoeLiteConfig"):
-        from horovod_tpu.models import glm4_moe_lite
-        return getattr(glm4_moe_lite, name)
-    if name in ("SmallThinker", "SmallThinkerConfig"):
-        from horovod_tpu.models import smallthinker
-        return getattr(smallthinker, name)
-    if name in ("KimiLinear", "KimiLinearConfig"):
-        from horovod_tpu.models import kimi_linear
-        return getattr(kimi_linear, name)
+    if name in _LAZY:
+        import importlib
+        return getattr(importlib.import_module(
+            f"{__name__}.{_LAZY[name]}"), name)
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 

@@ -184,10 +184,10 @@ def _gpt2_step(cfg, params, cache, tok, idx):
 
 def _rope_one(x, pos, theta):
     """RoPE for a single position per row: x (B, H, hd) — THE training
-    rotation (``models.llama.apply_rope``) on a length-1 sequence, so
+    rotation (``models.layers.apply_rope``) on a length-1 sequence, so
     decode can never drift from the training convention. Scalar ``pos``
     rotates every row alike; ``(B,)`` rotates per row (serving lanes)."""
-    from horovod_tpu.models.llama import apply_rope
+    from horovod_tpu.models.layers import apply_rope
     pos = jnp.asarray(pos)
     pos = pos[:, None] if pos.ndim else pos[None]
     return apply_rope(x[:, None], pos, theta)[:, 0]
@@ -445,41 +445,14 @@ _FAMILIES = {
 }
 
 
-# Families that are trained here and not served, and what serving them
-# lacks (ROADMAP.md, Reach).
-_TRAINING_ONLY = {
-    "SDARConfig": "block-diffusion sampling yields a block a step and the "
-                  "expert layer has no decode path",
-    "LFM2Config": "a conv layer's decode state (its last K - 1 gated "
-                  "inputs) has no place in the cache beside keys and "
-                  "values, and the expert layer has no decode path",
-    "Glm4MoeLiteConfig": "latent attention's cache of one latent and one "
-                         "rotated key a token, and the absorbed decode "
-                         "path that reads it, are not built, and the "
-                         "expert layer and its shared expert have no "
-                         "decode path",
-    "SmallThinkerConfig": "the cache has one kind of block table and the "
-                          "decode kernels no window (a window layer's "
-                          "blocks would have to be a ring of its last "
-                          "sliding_window keys beside a global layer's "
-                          "whole row), and the expert layer has no decode "
-                          "path",
-    "KimiLinearConfig": "a KDA layer's recurrent state (d_k x d_v a head) "
-                        "and its three convolutions' last K - 1 inputs have "
-                        "no place in the cache beside a latent cache, no "
-                        "decode step advances them a token at a time, and "
-                        "the expert layer has no decode path",
-}
-
-
 def decode_family(cfg) -> DecodeFamily:
     """The :class:`DecodeFamily` for a model config (by config type)."""
     name = type(cfg).__name__
     fam = _FAMILIES.get(name)
     if fam is None:
-        if name in _TRAINING_ONLY:
-            raise TypeError(f"{name} is trained here and not served: "
-                            f"{_TRAINING_ONLY[name]}")
+        why = getattr(cfg, "TRAINING_ONLY", None)   # what serving lacks
+        if why:
+            raise TypeError(f"{name} is trained here and not served: {why}")
         raise TypeError(
             f"no decode family registered for {name}; "
             f"known: {sorted(_FAMILIES)}")
@@ -572,7 +545,7 @@ def _step_fn(model):
     elif isinstance(model, GPT2):
         fam = _FAMILIES["GPT2Config"]
     else:
-        why = _TRAINING_ONLY.get(type(getattr(model, "cfg", None)).__name__)
+        why = getattr(getattr(model, "cfg", None), "TRAINING_ONLY", None)
         raise TypeError(f"generate() supports GPT2 and Llama models, got "
                         f"{type(model).__name__}"
                         + (f" (trained here and not served: {why})"

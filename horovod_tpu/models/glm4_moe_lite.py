@@ -5,15 +5,11 @@ Every block is ``h = x + Attn(RMSNorm(x))``, ``y = h + FF(RMSNorm(h))``, no
 bias anywhere (the ``glm4_moe_lite`` family, whose layer equations are
 DeepSeek-V3's):
 
-* ``Attn`` is latent attention in its expanded form. Queries go through a
-  bottleneck of ``q_lora_rank`` with an RMSNorm inside it; keys and values
-  come from one latent of ``kv_lora_rank`` a token, normed, and one RoPE key
-  of ``qk_rope_head_dim`` a token that **all heads share**. A head's query
-  and key are a per-head part without position (``qk_nope_head_dim``) and a
-  rotated part; its key's rotated part is the shared one, broadcast. The
-  scale is ``1 / sqrt(qk_nope_head_dim + qk_rope_head_dim)``. Keys and
-  values are expanded a head before the kernel; the absorbed form (the
-  latent itself as the key) is a decode-time rewrite and is not here.
+* ``Attn`` is latent attention in its expanded form
+  (``models/layers.LatentAttention``): queries through a normed bottleneck
+  of ``q_lora_rank``; keys and values from one normed latent of
+  ``kv_lora_rank`` a token and one RoPE key of ``qk_rope_head_dim`` a token
+  that **all heads share**.
 * ``FF`` is a dense SwiGLU in the first ``num_dense_layers`` blocks. In the
   rest it is the dropless routed layer (``ops/moe.RoutedExperts``, told
   which experts it holds) under the family's rule: a sigmoid score an
@@ -38,7 +34,7 @@ input (``expert_bias``, a row a layer and one for the module), so that it
 has no gradient and no optimizer state; nothing here moves it. Training
 only: the serving cache has no place for one latent a token.
 
-RoPE and RMSNorm are ``models/llama.py``'s.
+RMSNorm, latent attention and the head are ``models/layers.py``'s.
 """
 
 from __future__ import annotations
@@ -47,15 +43,15 @@ import dataclasses
 from typing import Optional, Tuple
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 
 from horovod_tpu import tracing as _tracing
-from horovod_tpu.models.llama import RMSNorm, apply_rope
+from horovod_tpu.models.layers import (LatentAttention, RMSNorm,
+                                       next_token_cross_entropy)
 from horovod_tpu.models.remat import remat_block
 
-__all__ = ["Glm4MoeLite", "Glm4MoeLiteConfig", "LatentAttention", "loss_fn",
-           "loss_terms"]
+__all__ = ["Glm4MoeLite", "Glm4MoeLiteConfig", "latent_attention",
+           "loss_fn", "loss_terms"]
 
 _NORM_EPS = 1e-20       # what the family adds to the sum of the chosen gates
 
@@ -65,6 +61,10 @@ class Glm4MoeLiteConfig:
     """Shapes of the decoder and what of it is held here; the defaults are
     GLM-4.7-Flash's published ones with every expert and the whole
     vocabulary held."""
+    TRAINING_ONLY = ("latent attention's cache of one latent and one "
+                     "rotated key a token, and the absorbed decode path "
+                     "that reads it, are not built, and the expert layer "
+                     "and its shared expert have no decode path")
     vocab_size: int = 154880         # rows of the embedding and of the head
     num_layers: int = 47             # the trunk's blocks
     num_dense_layers: int = 1        # first_k_dense_replace
@@ -75,7 +75,7 @@ class Glm4MoeLiteConfig:
     qk_nope_head_dim: int = 192
     qk_rope_head_dim: int = 64
     v_head_dim: int = 256
-    mla_use_nope: bool = False       # True: no RoPE anywhere (LatentAttention)
+    mla_use_nope: bool = False       # True: no RoPE anywhere
     d_ff: int = 10240                # the dense SwiGLU's width
     d_expert: int = 1536             # width of one expert's SwiGLU
     experts_total: int = 64          # the router's width
@@ -112,64 +112,14 @@ class Glm4MoeLiteConfig:
         return Glm4MoeLiteConfig(**base)
 
 
-def _dense(cfg, width, name):
-    return nn.Dense(width, use_bias=False, dtype=cfg.dtype, name=name)
-
-
-class LatentAttention(nn.Module):
-    """Latent attention in its expanded form, with or without a query
-    bottleneck and positions (the module's docstring). Two options of
-    ``cfg`` that GLM-4.7-Flash does not take: ``q_lora_rank``
-    None projects the queries straight from ``x`` (one ``q`` product, no
-    bottleneck and no norm inside it), and ``mla_use_nope`` leaves the
-    ``qk_rope_head_dim`` parts of the query and of the shared key
-    unrotated: no position enters the scores. ``v_head_dim`` may differ
-    from ``qk_nope_head_dim + qk_rope_head_dim`` on either path."""
-    cfg: Glm4MoeLiteConfig
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.cfg
-        B, T, _ = x.shape
-        H, rank = cfg.num_heads, cfg.kv_lora_rank
-        nope, rope, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
-                          cfg.v_head_dim)
-        with _tracing.scope("glm4/mla_down"):
-            if cfg.q_lora_rank is not None:
-                c_q = RMSNorm(cfg.rms_eps, name="q_norm")(
-                    _dense(cfg, cfg.q_lora_rank, "q_a")(x))
-            kv = _dense(cfg, rank + rope, "kv_a")(x)
-            c_kv = RMSNorm(cfg.rms_eps, name="kv_norm")(kv[..., :rank])
-        with _tracing.scope("glm4/mla_up"):
-            if cfg.q_lora_rank is None:
-                q = _dense(cfg, H * (nope + rope), "q")(x)
-            else:
-                q = _dense(cfg, H * (nope + rope), "q_b")(c_q)
-            q = q.reshape(B, T, H, nope + rope)
-            kv_up = _dense(cfg, H * (nope + dv), "kv_b")(c_kv).reshape(
-                B, T, H, nope + dv)
-            if cfg.mla_use_nope:
-                k_rope = kv[:, :, None, rank:]
-            else:
-                positions = jnp.arange(T)
-                q = jnp.concatenate(
-                    [q[..., :nope],
-                     apply_rope(q[..., nope:], positions, cfg.rope_theta)],
-                    -1)
-                # one rotated key a token, the same for every head
-                k_rope = apply_rope(kv[:, :, None, rank:], positions,
-                                    cfg.rope_theta)
-            k = jnp.concatenate(
-                [kv_up[..., :nope],
-                 jnp.broadcast_to(k_rope, (B, T, H, rope))], -1)
-            v = kv_up[..., nope:]
-        with _tracing.scope("glm4/attn"):
-            from horovod_tpu.ops.attention import multihead_attention
-            o = multihead_attention(q, k, v, impl=cfg.attention, causal=True,
-                                    out_dtype=cfg.dtype,
-                                    flash_blocks=cfg.flash_blocks,
-                                    scale=(nope + rope) ** -0.5)
-            return _dense(cfg, cfg.d_model, "o")(o.reshape(B, T, H * dv))
+def latent_attention(cfg: Glm4MoeLiteConfig, **kw) -> LatentAttention:
+    """The family's latent attention from ``cfg``; ``kw`` names it."""
+    return LatentAttention(
+        cfg.num_heads, cfg.d_model, cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+        cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.rms_eps, cfg.dtype,
+        q_lora_rank=cfg.q_lora_rank,
+        rope_theta=None if cfg.mla_use_nope else cfg.rope_theta,
+        impl=cfg.attention, flash_blocks=cfg.flash_blocks, **kw)
 
 
 class Block(nn.Module):
@@ -186,7 +136,7 @@ class Block(nn.Module):
         from horovod_tpu.ops.moe import RoutedExperts, SharedExpert
         cfg = self.cfg
         with _tracing.scope("glm4/block"):
-            x = x + LatentAttention(cfg, name="attn")(
+            x = x + latent_attention(cfg, name="attn")(
                 RMSNorm(cfg.rms_eps, name="norm_in")(x))
             u = RMSNorm(cfg.rms_eps, name="norm_post")(x)
             if self.layer < cfg.num_dense_layers:
@@ -218,7 +168,8 @@ class MTP(nn.Module):
             m = jnp.concatenate(
                 [RMSNorm(cfg.rms_eps, name="norm_e")(ahead),
                  RMSNorm(cfg.rms_eps, name="norm_h")(hidden)], axis=-1)
-            m = _dense(cfg, cfg.d_model, "eh_proj")(m)
+            m = nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
+                         name="eh_proj")(m)
             r = remat_block(Block, cfg)(cfg, cfg.num_layers, name="block")(
                 m, select_bias)
             return RMSNorm(cfg.rms_eps, name="norm_s")(r)
@@ -275,18 +226,6 @@ class Glm4MoeLite(nn.Module):
                                             bias(cfg.num_layers))
 
 
-def _cross_entropy(hidden, head, tokens, ahead: int):
-    """Mean cross entropy of the token ``ahead`` positions on, over the
-    ``T - ahead`` positions of each row that have one. Log-sum-exp minus
-    the target's logit (``models/lfm2.loss_fn`` says why); the positions
-    without a target are left out at the end, on (B, T) values."""
-    logits = jnp.einsum("btd,vd->btv", hidden.astype(jnp.float32), head)
-    target = jnp.take_along_axis(
-        logits, jnp.roll(tokens, -ahead, axis=1)[..., None], axis=-1)[..., 0]
-    nll = jax.nn.logsumexp(logits, axis=-1) - target
-    return jnp.mean(nll[:, :-ahead])
-
-
 def loss_terms(model: Glm4MoeLite, params, tokens, expert_bias=None):
     """``(CE_main, CE_mtp)`` of ``tokens`` (B, T) through the one untied
     head over the rows of the vocabulary held here: the next token's mean
@@ -295,9 +234,9 @@ def loss_terms(model: Glm4MoeLite, params, tokens, expert_bias=None):
     hidden, ahead = model.apply({"params": params}, tokens, expert_bias)
     with _tracing.scope("glm4/loss_head"):
         head = params["lm_head"]
-        return (_cross_entropy(hidden, head, tokens, 1),
+        return (next_token_cross_entropy(hidden, head, tokens, 1),
                 None if ahead is None
-                else _cross_entropy(ahead, head, tokens, 2))
+                else next_token_cross_entropy(ahead, head, tokens, 2))
 
 
 def loss_fn(model: Glm4MoeLite, params, tokens, expert_bias=None):

@@ -18,13 +18,10 @@ Linear technical report, arXiv 2510.26692):
   beta k v^T``, ``o_t = S_t^T q_t / sqrt(d_k)``); out through a per-head
   RMSNorm of ``kda_head_dim`` (one weight for all heads) times ``sigmoid(W_g_up
   W_g_down x)``, and ``o_proj``.
-* ``Mixer`` is latent attention in the layers ``mla_layers`` names:
-  ``models/glm4_moe_lite.LatentAttention`` with ``q_lora_rank`` None (the
-  queries straight from ``x``) and ``mla_use_nope`` (nothing rotated: no
-  position enters the scores; the convolutions and decays of KDA carry
-  position), keys ``qk_nope_head_dim + qk_rope_head_dim`` wide and values
-  ``v_head_dim``, through the causal flash kernels with a value width of its
-  own.
+* ``Mixer`` is latent attention (``models/layers.LatentAttention``) in the
+  layers ``mla_layers`` names, with no query bottleneck and nothing rotated
+  (``mla_use_nope``: the convolutions and decays of KDA carry position),
+  its values ``v_head_dim`` wide, narrower than its keys.
 * ``FFN`` is a dense SwiGLU of ``d_ff`` in the first ``num_dense_layers``
   blocks; in the rest the dropless routed layer (``ops/moe.RoutedExperts``,
   told which experts it holds) under the sigmoid rule (the top ``top_k`` of
@@ -48,11 +45,12 @@ import jax
 import jax.numpy as jnp
 
 from horovod_tpu import tracing as _tracing
-from horovod_tpu.models.glm4_moe_lite import LatentAttention, _cross_entropy
-from horovod_tpu.models.llama import RMSNorm
+from horovod_tpu.models.layers import (LatentAttention, RMSNorm,
+                                       next_token_cross_entropy)
 from horovod_tpu.models.remat import remat_block
 
-__all__ = ["KimiLinear", "KimiLinearConfig", "loss_fn", "layer_kinds"]
+__all__ = ["KimiLinear", "KimiLinearConfig", "latent_attention", "loss_fn",
+           "layer_kinds"]
 
 _NORM_EPS = 1e-20       # what the family adds to the sum of the chosen gates
 _L2_EPS = 1e-6          # inside the square root of q's and k's L2 norms
@@ -68,6 +66,11 @@ class KimiLinearConfig:
     """Shapes of the decoder and what of it is held here; the defaults are
     Kimi-Linear-48B-A3B's published ones with every expert and the whole
     vocabulary held."""
+    TRAINING_ONLY = ("a KDA layer's recurrent state (d_k x d_v a head) and "
+                     "its three convolutions' last K - 1 inputs have no "
+                     "place in the cache beside a latent cache, no decode "
+                     "step advances them a token at a time, and the expert "
+                     "layer has no decode path")
     vocab_size: int = 163840
     num_layers: int = 27
     num_dense_layers: int = 1                 # first_k_dense_replace
@@ -77,7 +80,7 @@ class KimiLinearConfig:
     kda_heads: int = 32
     kda_head_dim: int = 128
     conv_size: int = 4
-    # latent attention (the names LatentAttention reads)
+    # latent attention
     num_heads: int = 32
     q_lora_rank: Optional[int] = None
     kv_lora_rank: int = 512
@@ -124,6 +127,16 @@ def layer_kinds(cfg: KimiLinearConfig):
     return [("mla" if i in cfg.mla_layers else "kda",
              "dense" if i < cfg.num_dense_layers else "routed")
             for i in range(cfg.num_layers)]
+
+
+def latent_attention(cfg: KimiLinearConfig, **kw) -> LatentAttention:
+    """The family's latent attention from ``cfg``; ``kw`` names it."""
+    return LatentAttention(
+        cfg.num_heads, cfg.d_model, cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+        cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.rms_eps, cfg.dtype,
+        q_lora_rank=cfg.q_lora_rank,
+        rope_theta=None if cfg.mla_use_nope else cfg.rope_theta,
+        impl=cfg.attention, flash_blocks=cfg.flash_blocks, **kw)
 
 
 def _dense(cfg, width, name):
@@ -209,7 +222,7 @@ class Block(nn.Module):
             u = RMSNorm(cfg.rms_eps, name="norm_in")(x)
             if mixer == "mla":
                 with _tracing.scope("kimi/mla"):
-                    x = x + LatentAttention(cfg, name="attn")(u)
+                    x = x + latent_attention(cfg, name="attn")(u)
             else:
                 x = x + KDA(cfg, name="kda")(u)
             u = RMSNorm(cfg.rms_eps, name="norm_post")(x)
@@ -266,4 +279,4 @@ def loss_fn(model: KimiLinear, params, tokens, expert_bias=None):
     vocabulary held here (log-sum-exp minus the target's logit)."""
     hidden = model.apply({"params": params}, tokens, expert_bias)
     with _tracing.scope("kimi/loss_head"):
-        return _cross_entropy(hidden, params["lm_head"], tokens, 1)
+        return next_token_cross_entropy(hidden, params["lm_head"], tokens)

@@ -26,7 +26,7 @@ here moves it. The head is the embedding's own rows (tied), after one more
 RMSNorm. Training only: a conv layer's state while decoding (its last ``K -
 1`` gated inputs) has no place in the serving cache.
 
-RoPE, RMSNorm and the grouped-query expansion are ``models/llama.py``'s.
+RMSNorm, the grouped-query attention and the head are ``models/layers.py``'s.
 """
 
 from __future__ import annotations
@@ -35,11 +35,11 @@ import dataclasses
 from typing import Optional, Tuple
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 
 from horovod_tpu import tracing as _tracing
-from horovod_tpu.models.llama import RMSNorm, apply_rope, repeat_kv
+from horovod_tpu.models.layers import (GroupedQueryAttention, RMSNorm,
+                                       next_token_cross_entropy)
 from horovod_tpu.models.remat import remat_block
 from horovod_tpu.ops.short_conv import gated_short_conv
 
@@ -53,6 +53,9 @@ class LFM2Config:
     """Shapes of the decoder and what of it is held here; the defaults are
     LFM2-24B-A2B's published ones with every expert and the whole vocabulary
     held."""
+    TRAINING_ONLY = ("a conv layer's decode state (its last K - 1 gated "
+                     "inputs) has no place in the cache beside keys and "
+                     "values, and the expert layer has no decode path")
     vocab_size: int = 65536          # rows of the tied embedding held
     num_layers: int = 40
     layer_types: Tuple[str, ...] = _PERIOD * 10    # one a layer
@@ -109,32 +112,6 @@ class ShortConv(nn.Module):
                         name="out_proj")(gated_short_conv(bcx, taps))
 
 
-class Attention(nn.Module):
-    cfg: LFM2Config
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.cfg
-        B, T, _ = x.shape
-        H, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-        proj = lambda heads, name: nn.Dense(
-            heads * hd, use_bias=False, dtype=cfg.dtype,
-            name=name)(x).reshape(B, T, heads, hd)
-        q, k, v = proj(H, "wq"), proj(Hkv, "wk"), proj(Hkv, "wv")
-        positions = jnp.arange(T)
-        q = apply_rope(RMSNorm(cfg.rms_eps, name="q_norm")(q), positions,
-                       cfg.rope_theta)
-        k = apply_rope(RMSNorm(cfg.rms_eps, name="k_norm")(k), positions,
-                       cfg.rope_theta)
-        k, v = repeat_kv(k, v, H)
-        from horovod_tpu.ops.attention import multihead_attention
-        o = multihead_attention(q, k, v, impl=cfg.attention, causal=True,
-                                out_dtype=cfg.dtype,
-                                flash_blocks=cfg.flash_blocks)
-        return nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
-                        name="wo")(o.reshape(B, T, H * hd))
-
-
 class DenseMLP(nn.Module):
     cfg: LFM2Config
 
@@ -166,7 +143,11 @@ class Block(nn.Module):
                     x = x + ShortConv(cfg, name="conv")(u)
             elif kind == "full_attention":
                 with _tracing.scope("lfm2/attn"):
-                    x = x + Attention(cfg, name="attn")(u)
+                    x = x + GroupedQueryAttention(
+                        cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
+                        cfg.d_model, cfg.dtype, cfg.attention,
+                        cfg.flash_blocks, qk_norm_eps=cfg.rms_eps,
+                        rope_theta=cfg.rope_theta, name="attn")(u)
             else:
                 raise ValueError(f"layer_types[{self.layer}] = {kind!r}: "
                                  "expected 'conv' or 'full_attention'")
@@ -199,10 +180,6 @@ class LFM2(nn.Module):
             raise ValueError(
                 f"layer_types names {len(cfg.layer_types)} layers, "
                 f"num_layers={cfg.num_layers}")
-        if cfg.num_heads % cfg.num_kv_heads:
-            raise ValueError(
-                f"num_kv_heads={cfg.num_kv_heads} must divide "
-                f"num_heads={cfg.num_heads}")
         if expert_bias is not None and not cfg.use_expert_bias:
             raise ValueError("expert_bias given and use_expert_bias=False")
         wte = self.param("wte", nn.initializers.normal(cfg.embed_std),
@@ -222,16 +199,4 @@ def loss_fn(model: LFM2, params, tokens, expert_bias=None):
     the rows of the vocabulary held here."""
     hidden = model.apply({"params": params}, tokens, expert_bias)
     with _tracing.scope("lfm2/loss_head"):
-        logits = jnp.einsum("btd,vd->btv", hidden.astype(jnp.float32),
-                            params["wte"])
-        # log-sum-exp minus the target's logit, not a gather from
-        # log_softmax: the (B, T, V) array of log-probabilities is never
-        # made (on the v5e at 4 x 8,192 x 8,192 the head's forward and
-        # backward take 20 ms this way and 131 ms the other, whose
-        # soft-max reductions XLA lays out across the position axis). The
-        # row's last position has no next token and is left out at the end,
-        # on (B, T) values.
-        target = jnp.take_along_axis(
-            logits, jnp.roll(tokens, -1, axis=1)[..., None], axis=-1)[..., 0]
-        nll = jax.nn.logsumexp(logits, axis=-1) - target
-        return jnp.mean(nll[:, :-1])
+        return next_token_cross_entropy(hidden, params["wte"], tokens)

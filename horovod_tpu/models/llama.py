@@ -25,12 +25,12 @@ import dataclasses
 from typing import Optional
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.models.gpt2 import loss_fn  # same next-token CE  # noqa: F401
 from horovod_tpu.models.gpt2 import loss_fn_moe  # CE + aux  # noqa: F401
+from horovod_tpu.models.layers import RMSNorm, apply_rope, repeat_kv
 from horovod_tpu.models.remat import remat_block
 from horovod_tpu.parallel.sharding import PartitionRules
 
@@ -82,56 +82,6 @@ class LlamaConfig:
                     num_heads=4, num_kv_heads=2, d_model=64, d_ff=128)
         base.update(kw)          # overrides of the tiny defaults allowed
         return LlamaConfig(**base)
-
-
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
-               theta: float) -> jnp.ndarray:
-    """Rotary position embedding over (B, T, H, D) with (T,) or (B, T)
-    positions.
-
-    Pair-rotation ("rotate half") form in fp32, cast back to x.dtype.
-    Positions are explicit so sequence-parallel shards pass their GLOBAL
-    token positions (contiguous offset or striped interleave) and rotation
-    commutes with the ring: every shard rotates its own K before any hop.
-    (B, T) positions carry per-row packing offsets (pos-in-document).
-    """
-    d2 = x.shape[-1] // 2
-    freq = theta ** (-jnp.arange(d2, dtype=jnp.float32) / d2)
-    ang = positions.astype(jnp.float32)[..., None] * freq  # (..., T, d2)
-    if ang.ndim == 2:                                      # (T, d2)
-        cos = jnp.cos(ang)[None, :, None, :]
-        sin = jnp.sin(ang)[None, :, None, :]
-    else:                                                  # (B, T, d2)
-        cos = jnp.cos(ang)[:, :, None, :]
-        sin = jnp.sin(ang)[:, :, None, :]
-    xf = x.astype(jnp.float32)
-    x1, x2 = xf[..., :d2], xf[..., d2:]
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
-
-
-def repeat_kv(k: jnp.ndarray, v: jnp.ndarray, num_heads: int):
-    """Grouped-query attention: expand the key/value heads of (B, T, Hkv, D)
-    to ``num_heads``, each serving ``num_heads // Hkv`` query heads in
-    order, so that the attention ops see plain multi-head shapes."""
-    q_per_kv = num_heads // k.shape[2]
-    if q_per_kv == 1:
-        return k, v
-    return jnp.repeat(k, q_per_kv, axis=2), jnp.repeat(v, q_per_kv, axis=2)
-
-
-class RMSNorm(nn.Module):
-    """fp32 root-mean-square norm with a learned scale (no mean removal)."""
-    eps: float = 1e-6
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
-                           jnp.float32)
-        xf = x.astype(jnp.float32)
-        y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
-                               + self.eps)
-        return (y * scale).astype(x.dtype)
 
 
 class Attention(nn.Module):

@@ -16,7 +16,7 @@ clean blocks before it, a clean block sees the clean blocks up to itself.
 Only the noisy half goes through the head; the loss is the cross entropy of
 the masked positions, each weighted ``1 / t`` of its block.
 
-RoPE, RMSNorm and the grouped-query expansion are ``models/llama.py``'s.
+RMSNorm and the grouped-query attention are ``models/layers.py``'s.
 """
 
 from __future__ import annotations
@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from horovod_tpu import tracing as _tracing
-from horovod_tpu.models.llama import RMSNorm, apply_rope, repeat_kv
+from horovod_tpu.models.layers import GroupedQueryAttention, RMSNorm
 from horovod_tpu.models.remat import remat_block
 
 __all__ = ["SDAR", "SDARConfig", "block_noise", "loss_fn"]
@@ -40,6 +40,8 @@ class SDARConfig:
     """Shapes of the decoder and what of it is held here; the defaults are
     SDAR-30B-A3B-Chat's published ones with every expert and the whole
     vocabulary held."""
+    TRAINING_ONLY = ("block-diffusion sampling yields a block a step and "
+                     "the expert layer has no decode path")
     vocab_size: int = 151936         # rows of the embedding and head held
     num_layers: int = 48
     num_heads: int = 32
@@ -80,32 +82,6 @@ class SDARConfig:
         return SDARConfig(**base)
 
 
-class Attention(nn.Module):
-    cfg: SDARConfig
-
-    @nn.compact
-    def __call__(self, x, positions):
-        cfg = self.cfg
-        B, S, _ = x.shape
-        H, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-        proj = lambda heads, name: nn.Dense(
-            heads * hd, use_bias=False, dtype=cfg.dtype,
-            name=name)(x).reshape(B, S, heads, hd)
-        q, k, v = proj(H, "wq"), proj(Hkv, "wk"), proj(Hkv, "wv")
-        q = RMSNorm(cfg.rms_eps, name="q_norm")(q)
-        k = RMSNorm(cfg.rms_eps, name="k_norm")(k)
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
-        k, v = repeat_kv(k, v, H)
-        from horovod_tpu.ops.attention import multihead_attention
-        o = multihead_attention(
-            q, k, v, impl=cfg.attention, causal=False, out_dtype=cfg.dtype,
-            flash_blocks=cfg.flash_blocks,
-            block_diffusion=(S // 2, cfg.block_len))
-        return nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
-                        name="wo")(o.reshape(B, S, H * hd))
-
-
 class Block(nn.Module):
     cfg: SDARConfig
 
@@ -114,8 +90,12 @@ class Block(nn.Module):
         cfg = self.cfg
         from horovod_tpu.ops.moe import RoutedExperts
         with _tracing.scope("sdar/attn"):
-            x = x + Attention(cfg, name="attn")(
-                RMSNorm(cfg.rms_eps, name="norm_attn")(x), positions)
+            x = x + GroupedQueryAttention(
+                cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.d_model,
+                cfg.dtype, cfg.attention, cfg.flash_blocks,
+                qk_norm_eps=cfg.rms_eps, rope_theta=cfg.rope_theta,
+                diffusion_block=cfg.block_len, name="attn")(
+                    RMSNorm(cfg.rms_eps, name="norm_attn")(x), positions)
         with _tracing.scope("sdar/block"):
             return x + RoutedExperts(
                 cfg.experts_total, cfg.experts_held, cfg.top_k, cfg.d_expert,
@@ -134,10 +114,6 @@ class SDAR(nn.Module):
         norm: ``noisy`` and ``clean`` (B, T) go through every layer as one
         row of 2T positions. :func:`loss_fn` applies the head."""
         cfg = self.cfg
-        if cfg.num_heads % cfg.num_kv_heads:
-            raise ValueError(
-                f"num_kv_heads={cfg.num_kv_heads} must divide "
-                f"num_heads={cfg.num_heads}")
         B, T = clean.shape
         if noisy.shape != clean.shape or T % cfg.block_len:
             raise ValueError(

@@ -28,7 +28,7 @@ The head is untied, after one more RMSNorm. Training only: the serving cache
 has one kind of block table and no window, and the expert layer no decode
 path.
 
-RoPE, RMSNorm and the grouped-query expansion are ``models/llama.py``'s.
+RMSNorm, the grouped-query attention and the head are ``models/layers.py``'s.
 """
 
 from __future__ import annotations
@@ -37,14 +37,15 @@ import dataclasses
 from typing import Optional, Tuple
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 
 from horovod_tpu import tracing as _tracing
-from horovod_tpu.models.llama import RMSNorm, apply_rope, repeat_kv
+from horovod_tpu.models.layers import (GroupedQueryAttention, RMSNorm,
+                                       next_token_cross_entropy)
 from horovod_tpu.models.remat import remat_block
 
-__all__ = ["SmallThinker", "SmallThinkerConfig", "loss_fn"]
+__all__ = ["Attention", "SmallThinker", "SmallThinkerConfig", "attention",
+           "loss_fn"]
 
 _PERIOD = (0, 1, 1, 1)
 
@@ -54,6 +55,11 @@ class SmallThinkerConfig:
     """Shapes of the decoder and what of it is held here; the defaults are
     SmallThinker-21BA3B-Instruct's published ones with every expert and the
     whole vocabulary held."""
+    TRAINING_ONLY = ("the cache has one kind of block table and the decode "
+                     "kernels no window (a window layer's blocks would have "
+                     "to be a ring of its last sliding_window keys beside a "
+                     "global layer's whole row), and the expert layer has "
+                     "no decode path")
     vocab_size: int = 151936         # rows of the embedding and of the head
     num_layers: int = 52
     sliding_window_layout: Tuple[int, ...] = _PERIOD * 13   # 1: windowed
@@ -92,34 +98,18 @@ class SmallThinkerConfig:
         return SmallThinkerConfig(**base)
 
 
-class Attention(nn.Module):
-    """Grouped-query causal attention of layer ``layer``: rotated or not,
-    windowed or not, as the two layouts say."""
-    cfg: SmallThinkerConfig
-    layer: int
+Attention = GroupedQueryAttention      # the class of every h<i>/attn
 
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.cfg
-        B, T, _ = x.shape
-        H, Hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-        proj = lambda heads, name: nn.Dense(
-            heads * hd, use_bias=False, dtype=cfg.dtype,
-            name=name)(x).reshape(B, T, heads, hd)
-        q, k, v = proj(H, "wq"), proj(Hkv, "wk"), proj(Hkv, "wv")
-        if cfg.rope_layout[self.layer]:
-            positions = jnp.arange(T)
-            q = apply_rope(q, positions, cfg.rope_theta)
-            k = apply_rope(k, positions, cfg.rope_theta)
-        k, v = repeat_kv(k, v, H)
-        from horovod_tpu.ops.attention import multihead_attention
-        o = multihead_attention(
-            q, k, v, impl=cfg.attention, causal=True, out_dtype=cfg.dtype,
-            flash_blocks=cfg.flash_blocks,
-            window=(cfg.sliding_window
-                    if cfg.sliding_window_layout[self.layer] else None))
-        return nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
-                        name="wo")(o.reshape(B, T, H * hd))
+
+def attention(cfg: SmallThinkerConfig, layer: int, **kw) -> Attention:
+    """Grouped-query causal attention of layer ``layer``: rotated or not,
+    windowed or not, as the two layouts say; ``kw`` names it."""
+    return Attention(
+        cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.d_model,
+        cfg.dtype, cfg.attention, cfg.flash_blocks,
+        rope_theta=cfg.rope_theta if cfg.rope_layout[layer] else None,
+        window=cfg.sliding_window if cfg.sliding_window_layout[layer]
+        else None, **kw)
 
 
 class Block(nn.Module):
@@ -134,7 +124,7 @@ class Block(nn.Module):
         windowed = cfg.sliding_window_layout[self.layer]
         with _tracing.scope("smallthinker/block"):
             u = RMSNorm(cfg.rms_eps, name="norm_in")(x)
-            attn = Attention(cfg, self.layer, name="attn")
+            attn = attention(cfg, self.layer, name="attn")
             if windowed:
                 with _tracing.scope("smallthinker/attn_window"):
                     h = x + attn(u)
@@ -164,10 +154,6 @@ class SmallThinker(nn.Module):
                 raise ValueError(
                     f"{name} names {len(getattr(cfg, name))} layers, "
                     f"num_layers={cfg.num_layers}")
-        if cfg.num_heads % cfg.num_kv_heads:
-            raise ValueError(
-                f"num_kv_heads={cfg.num_kv_heads} must divide "
-                f"num_heads={cfg.num_heads}")
         wte = self.param("wte", nn.initializers.normal(cfg.embed_std),
                          (cfg.vocab_size, cfg.d_model), jnp.float32)
         self.param("lm_head", nn.initializers.normal(0.02),
@@ -185,12 +171,4 @@ def loss_fn(model: SmallThinker, params, tokens):
     over the rows of the vocabulary held here."""
     hidden = model.apply({"params": params}, tokens)
     with _tracing.scope("smallthinker/loss_head"):
-        logits = jnp.einsum("btd,vd->btv", hidden.astype(jnp.float32),
-                            params["lm_head"])
-        # log-sum-exp minus the target's logit (models/lfm2.loss_fn says
-        # why); the row's last position has no next token and is left out
-        # at the end, on (B, T) values
-        target = jnp.take_along_axis(
-            logits, jnp.roll(tokens, -1, axis=1)[..., None], axis=-1)[..., 0]
-        nll = jax.nn.logsumexp(logits, axis=-1) - target
-        return jnp.mean(nll[:, :-1])
+        return next_token_cross_entropy(hidden, params["lm_head"], tokens)

@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from horovod_tpu.models.llama import RMSNorm
+from horovod_tpu.models.layers import RMSNorm
 from horovod_tpu.models.remat import remat_block
 from horovod_tpu.parallel.sharding import PartitionRules
 

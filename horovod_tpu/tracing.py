@@ -353,18 +353,18 @@ NAMES: Dict[str, Name] = {
         "feed-forward's and the expert layer's scopes nest inside)",
         _PRINTED + ": its own part, and all it holds"),
     "glm4/mla_down": Name(
-        "scope", _GLM4, "models.glm4_moe_lite: latent attention's two "
+        "scope", _GLM4, "models.layers.LatentAttention: its two "
         "down-projections (to the query bottleneck, and to the key/value "
         "latent with the shared RoPE key) and the norms inside them",
         _PRINTED),
     "glm4/mla_up": Name(
-        "scope", _GLM4, "models.glm4_moe_lite: the up-projections to every "
-        "head's query, key and value, RoPE, the broadcast of the one rotated "
-        "key to all heads and the concatenations",
+        "scope", _GLM4, "models.layers.LatentAttention: the up-projections to "
+        "every head's query, key and value, RoPE, the broadcast of the one "
+        "rotated key to all heads and the concatenations",
         "mla_expand_ms.train (what it writes: mla_kv_expanded_mb.train)"),
     "glm4/attn": Name(
-        "scope", _GLM4, "models.glm4_moe_lite: the causal attention call at "
-        "head 256 and the output projection",
+        "scope", _GLM4, "models.layers.LatentAttention: the causal attention "
+        "call at head 256 and the output projection",
         _PRINTED + "; its kernels: mla_flash_time_share.train"),
     "glm4/dense_mlp": Name(
         "scope", _GLM4, "models.glm4_moe_lite: the dense SwiGLU of a leading "
@@ -424,7 +424,7 @@ NAMES: Dict[str, Name] = {
         "gate and o_proj", _PRINTED),
     "kimi/mla": Name(
         "scope", _KIMI, "models.kimi_linear: a latent-attention layer whole "
-        "(models.glm4_moe_lite.LatentAttention without positions and "
+        "(models.layers.LatentAttention without positions and "
         "without a query bottleneck; its glm4/mla_down, glm4/mla_up and "
         "glm4/attn nest inside)",
         _PRINTED + "; its kernels: kimi_flash_roofline.train"),

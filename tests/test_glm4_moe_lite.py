@@ -140,7 +140,7 @@ def test_the_reference_without_a_part_reads_otherwise(what, change):
 
 def _attention_setup(t, **kw):
     cfg = glm.Glm4MoeLiteConfig.tiny(dtype=jnp.float32, **kw)
-    layer = glm.LatentAttention(cfg)
+    layer = glm.latent_attention(cfg)
     u = jax.random.normal(jax.random.PRNGKey(3), (2, t, cfg.d_model))
     params = dict(layer.init(jax.random.PRNGKey(4), u)["params"])
     # norms away from one, so that a norm left out shows

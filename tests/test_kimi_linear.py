@@ -21,7 +21,6 @@ import numpy as np
 import pytest
 
 from horovod_tpu.models import kimi_linear as kl
-from horovod_tpu.models.glm4_moe_lite import LatentAttention
 from horovod_tpu.ops import moe
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
@@ -178,7 +177,7 @@ def test_latent_attention_takes_no_position_and_no_bottleneck():
     and the flash path gives what the dense one gives."""
     cfg = kl.KimiLinearConfig.tiny(dtype=jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 32, cfg.d_model))
-    layer = LatentAttention(cfg)
+    layer = kl.latent_attention(cfg)
     params = layer.init(jax.random.PRNGKey(4), x)["params"]
     assert set(params) == {"q", "kv_a", "kv_norm", "kv_b", "o"}
     assert params["kv_b"]["kernel"].shape[1] == cfg.num_heads * (
@@ -189,8 +188,8 @@ def test_latent_attention_takes_no_position_and_no_bottleneck():
     np.testing.assert_allclose(np.asarray(shuffled[:, -1]),
                                np.asarray(out[:, -1]), atol=1e-5)
     assert np.abs(np.asarray(shuffled[:, :-1] - out[:, :-1])).max() > 1e-3
-    flash = LatentAttention(dataclasses.replace(cfg, attention="flash",
-                                                flash_blocks=(16, 16)))
+    flash = kl.latent_attention(dataclasses.replace(
+        cfg, attention="flash", flash_blocks=(16, 16)))
     np.testing.assert_allclose(np.asarray(flash.apply({"params": params},
                                                       x)),
                                np.asarray(out), atol=1e-4)

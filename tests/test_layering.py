@@ -1,18 +1,24 @@
 """The package's layers, held by its import graph: which part of
 ``horovod_tpu`` may not import which. Read with ``ast`` from the source
 (lazy imports inside functions count; nothing is imported or run). The
-same nine lines are the legend of ``docs/ARCHITECTURE.md`` section 8."""
+same ten lines are the legend of ``docs/ARCHITECTURE.md`` section 8."""
 
 import ast
+import fnmatch
 import os
 
 import pytest
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _PKG = "horovod_tpu"
+# the families trained here and not served; of models/ they import the
+# shared parts (layers.py, remat.py) alone
+_TRAINING = ["sdar", "lfm2", "glm4_moe_lite", "smallthinker", "kimi_linear"]
+_SHARED = ["__init__.py", "layers.py", "remat.py"]
 
 # (rule, the part held: package-relative directories or modules,
-#  what it may not import: package-relative names, top-level modules)
+#  what it may not import: package-relative names or patterns of them,
+#  top-level modules)
 RULES = [
     ("ops imports nothing of models, serving, parallel, optimizer, elastic",
      ["ops/"],
@@ -36,6 +42,12 @@ RULES = [
       "pytorch_lightning"]),
     ("nothing in the package imports benchmark, tools, bench or chip_smoke",
      [""], [], ["benchmark", "tools", "bench", "chip_smoke"]),
+    ("a training family imports no other family and no private name of "
+     "models", [f"models/{name}.py" for name in _TRAINING],
+     [f"models.{name[:-3]}" for name in sorted(os.listdir(
+         os.path.join(_REPO, _PKG, "models")))
+      if name.endswith(".py") and name not in _SHARED] + ["models.*._*"],
+     []),
 ]
 
 # (file, imported module): what a rule lets through, with the reason.
@@ -93,7 +105,10 @@ def test_the_arrows_point_one_way(rule, held, inner, outer):
             for mod, line in _imports(path):
                 parts = mod.split(".")
                 if parts[0] == _PKG and len(parts) > 1:
-                    bad = parts[1] in inner or "*" in inner
+                    inside = ".".join(parts[1:])
+                    bad = any(fnmatch.fnmatchcase(inside, held)
+                              or fnmatch.fnmatchcase(inside, held + ".*")
+                              for held in inner)
                 else:
                     bad = parts[0] in outer
                 if bad and (name, ".".join(parts[:2])) not in ALLOWED:

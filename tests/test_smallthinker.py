@@ -173,7 +173,7 @@ def test_attention_by_layer_kind_against_a_numpy_loop(attention, layer):
                                         flash_blocks=(16, 16))
     u = jax.random.normal(jax.random.PRNGKey(3), (1, T, cfg.d_model))
     p = params[f"h{layer}"]["attn"]
-    got = st.Attention(cfg, layer).apply({"params": p}, u)[0]
+    got = st.attention(cfg, layer).apply({"params": p}, u)[0]
     want = _attention_by_hand(u[0], p, cfg, rotated=bool(layer),
                               window=cfg.sliding_window if layer else None)
     np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
@@ -191,7 +191,7 @@ def test_the_layouts_say_each_layers_kind():
     u = jax.random.normal(jax.random.PRNGKey(3), (1, T, cfg.d_model))
     far = u.at[0, 0].add(1.0)           # position 0, seen from position 31?
     for layer, reaches in ((0, True), (1, False)):
-        attn = st.Attention(cfg, layer)
+        attn = st.attention(cfg, layer)
         p = params[f"h{layer}"]["attn"]
         moved = (attn.apply({"params": p}, far)
                  - attn.apply({"params": p}, u))[0]

@@ -45,6 +45,7 @@ MODULES = [
     "horovod_tpu.parallel.conjugate",
     "horovod_tpu.models",
     "horovod_tpu.models.gpt2_pipeline",
+    "horovod_tpu.models.layers",
     "horovod_tpu.models.llama",
     "horovod_tpu.models.sdar",
     "horovod_tpu.models.lfm2",
